@@ -262,9 +262,9 @@ def _join(srv, wid: int) -> dict:
 
 
 def _commit(srv, wid: int, seq: int) -> dict:
-    """An empty-delta commit: ``validate_delta([])`` is falsy so no
-    backend resolve happens, but ``_fold_locked`` still runs the full
-    dedup / commit_log / last_seq bookkeeping — the machine under test."""
+    """An empty-delta commit: nothing to validate or fold, but
+    ``_fold_locked`` still runs the full dedup / commit_log / last_seq
+    bookkeeping — the machine under test."""
     from distkeras_tpu.netps import wire
 
     reply, _ = srv._dispatch(

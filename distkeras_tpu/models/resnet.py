@@ -54,8 +54,7 @@ class GN(nn.Module):
             from distkeras_tpu.ops.pallas.groupnorm import group_norm
 
             return group_norm(x, gamma, beta, groups=self.num_groups,
-                              relu=self.relu,
-                              interpret=jax.default_backend() != "tpu")
+                              relu=self.relu)
         # Functional GroupNorm, flax-equivalent: float32 stats over
         # (spatial..., C/G) with biased variance, eps 1e-6.
         G = self.num_groups

@@ -28,11 +28,8 @@ from distkeras_tpu.runtime.mesh import MODEL_AXIS
 
 def _axis_is_auto(abstract_mesh, name: str) -> bool:
     """True if ``name`` is a GSPMD-managed (Auto) axis of the ambient mesh."""
-    try:
-        types = dict(zip(abstract_mesh.axis_names, abstract_mesh.axis_types))
-        return "auto" in str(types[name]).lower()
-    except Exception:
-        return False
+    types = dict(zip(abstract_mesh.axis_names, abstract_mesh.axis_types))
+    return types[name] == jax.sharding.AxisType.Auto
 
 
 def _global_positions(local_len: int, seq_axis: Optional[str]) -> jax.Array:
@@ -42,13 +39,21 @@ def _global_positions(local_len: int, seq_axis: Optional[str]) -> jax.Array:
     return pos
 
 
-def _flash_supported_len(L: int) -> bool:
-    """Whether the flash kernel can handle sequence length ``L`` here: on
-    TPU the Mosaic kernel needs lane-aligned blocks (L a multiple of 128);
-    the CPU interpreter also accepts any single short block."""
+def _flash_block(L: int) -> int:
+    """The flash kernel's q-block for sequence length ``L``. The Mosaic
+    kernel needs lane-aligned blocks (L a multiple of 128); the interpreter
+    also accepts any single short block. Anything else is an error naming
+    ``L`` — never a quiet switch to the O(L^2) dense path."""
+    from distkeras_tpu.ops.pallas import mode
+
     if L % 128 == 0:
-        return True
-    return jax.default_backend() != "tpu" and L < 128
+        return 128
+    if L < 128 and not mode.compiles():
+        return L
+    raise ValueError(
+        f"attn_impl='flash' needs a sequence length that is a multiple of "
+        f"128 (the Mosaic kernel's lane-aligned block), got L={L}; pad the "
+        "sequences or build the model with attn_impl='dense'")
 
 
 class CausalSelfAttention(nn.Module):
@@ -72,37 +77,32 @@ class CausalSelfAttention(nn.Module):
 
             out = ring_attention(q, k, v, axis_name=self.seq_axis)
         elif (self.seq_axis is None and self.attn_impl == "flash"
-              and _flash_supported_len(L)):
-            # On TPU, L must be lane-aligned (a multiple of 128) for the
-            # Mosaic kernel; shorter/odd lengths — e.g. the (1, 1) dummy
-            # used for shape inference at Model.build — take the dense path
-            # below, which is numerically identical.
+              and not self.is_initializing()):
+            # Init only declares params (attention has none of its own), so
+            # Model.build's shape-inference pass — any L, often a (1, 1)
+            # dummy, eagerly on the default device — takes the numerically
+            # identical dense path below instead of compiling the kernel.
             from distkeras_tpu.ops.pallas import flash_attention
 
+            block = _flash_block(L)
+
             def fa(q, k, v):
-                return flash_attention(
-                    q, k, v,
-                    block_size=min(128, L),
-                    interpret=jax.default_backend() != "tpu",
-                )
+                return flash_attention(q, k, v, block_size=block)
 
             # Tensor parallelism: a Mosaic kernel cannot be GSPMD-auto-
             # partitioned, so when the ambient mesh carries an (auto) model
             # axis we manualize it locally — each shard runs flash on its own
             # heads (attention has no cross-head communication). Works inside
             # the SPMD engine's partially-manual region via nested shard_map.
-            am = getattr(jax.sharding, "get_abstract_mesh", lambda: None)()
-            names = getattr(am, "axis_names", ())
-            if MODEL_AXIS in names and am.shape[MODEL_AXIS] > 1 and (
-                _axis_is_auto(am, MODEL_AXIS)
-            ):
-                from distkeras_tpu.ops.collectives import shard_map
+            am = jax.sharding.get_abstract_mesh()
+            if (MODEL_AXIS in am.axis_names and am.shape[MODEL_AXIS] > 1
+                    and _axis_is_auto(am, MODEL_AXIS)):
                 from jax.sharding import PartitionSpec as P
 
                 spec = P(None, None, MODEL_AXIS, None)
-                fa = shard_map(fa, mesh=am, in_specs=(spec, spec, spec),
-                               out_specs=spec, axis_names={MODEL_AXIS},
-                               check_vma=False)
+                fa = jax.shard_map(fa, mesh=am, in_specs=(spec, spec, spec),
+                                   out_specs=spec, axis_names={MODEL_AXIS},
+                                   check_vma=False)
             out = fa(q, k, v)
         else:
             q_pos = _global_positions(L, self.seq_axis)

@@ -19,16 +19,14 @@ pull-time counter.
 spec)`` pair in its *wire* dtype (the netps handlers read frames with
 ``decode=False``): int8 with a per-tensor scale, or bf16 bit-truncated.
 Those fold without a decode-to-f32 pass — the dequantization is fused
-into the accumulate. Two backends, one dispatch point (here, so parity
-evidence stays transferable):
-
-* a **pure-numpy reference** (CPU CI, and the default for a stdlib-only
-  server process): ``center += (commit_scale · tensor_scale) · q`` in one
-  fused expression;
-* the **Pallas kernel** (``distkeras_tpu.ops.pallas.fold``) when jax sees
-  a TPU — the dequant+accumulate as one VMEM-resident pass per tensor.
-  Interpret-mode parity against the numpy reference is pinned by
-  ``tests/test_pallas_fold.py`` and the CI fold-parity job.
+into the accumulate: ``center += (commit_scale · tensor_scale) · q`` in one
+numpy expression. This host fold never touches a jax backend: a parameter-
+server process runs beside trainers that own the chips, and a chip belongs
+to one process. The device-side twin of the same arithmetic is the Pallas
+kernel (``distkeras_tpu.ops.pallas.fold``), reached only through a server
+constructed with ``transport="mesh"`` (``netps/mesh.py``), whose process owns
+its devices by construction; kernel-vs-numpy parity is pinned by
+``tests/test_pallas_fold.py``.
 
 Fold throughput is exported by the netps server as the
 ``netps.fold.tensors_per_sec`` gauge (docs/OBSERVABILITY.md) so the
@@ -103,19 +101,17 @@ def decode_entry(entry) -> np.ndarray:
     return wire.codec_decode(a, spec) if spec else np.asarray(a)
 
 
-def validate_delta(delta) -> bool:
+def validate_delta(delta) -> None:
     """Up-front spec validation for a commit's wire entries — the rules
     ``codec_decode`` enforced before the ``decode=False`` path existed
     (unknown codec, int8 without a scale), applied BEFORE any fold or
     bookkeeping: a spec that failed mid-:func:`fold_delta` would leave
     the already-folded prefix tensors in the center with no commit_log
     entry, and the retransmit would fold them AGAIN. Raises
-    ``ProtocolError``; returns whether any entry folds in the compressed
-    domain (the caller's cue to resolve the accelerator backend)."""
+    ``ProtocolError``."""
     from distkeras_tpu.netps import wire
     from distkeras_tpu.netps.errors import ProtocolError
 
-    compressed = False
     for entry in delta:
         _a, spec = split_entry(entry)
         codec = spec.get("codec") if spec else None
@@ -128,82 +124,17 @@ def validate_delta(delta) -> bool:
                 raise ProtocolError(f"int8 array spec without a scale: {e}")
         elif codec != wire.CODEC_BF16:
             raise ProtocolError(f"unknown codec {codec!r} in array spec")
-        compressed = True
-    return compressed
 
 
-# -- compressed-domain backends ---------------------------------------------
-
-_ACCEL = None
-_ACCEL_RESOLVED = False
-
-
-def _accel():
-    """The on-accelerator fold backend, or None. Resolved once: the Pallas
-    kernel is used only when jax is importable AND a TPU is the default
-    backend — the stdlib-only server process never pays a jax import."""
-    global _ACCEL, _ACCEL_RESOLVED
-    if not _ACCEL_RESOLVED:
-        _ACCEL_RESOLVED = True
-        try:
-            import jax
-
-            if jax.default_backend() == "tpu":
-                from distkeras_tpu.ops.pallas import fold as pallas_fold
-
-                _ACCEL = pallas_fold
-        except Exception:
-            _ACCEL = None
-    return _ACCEL
-
-
-def _reset_accel() -> None:
-    """Forget the resolved backend (tests swap backends per-case)."""
-    global _ACCEL, _ACCEL_RESOLVED
-    _ACCEL = None
-    _ACCEL_RESOLVED = False
-
-
-def backend_name() -> str:
-    """The resolved compressed-fold backend's name, for the server stats
-    scrape and the chaos smokes (which assert which arithmetic actually
-    ran): ``numpy`` (the pure reference), ``pallas-tpu`` (the fused
-    kernel on a real chip), ``pallas-interpret`` (the same kernel under
-    the interpreter — test/parity runs that force ``_ACCEL``), or
-    ``unresolved`` before the first codec'd commit resolves it. A
-    device-resident center reports ``mesh`` one level up (the server
-    overrides — the mesh dialect folds through its own jitted collective,
-    not this dispatch point)."""
-    if not _ACCEL_RESOLVED:
-        return "unresolved"
-    if _ACCEL is None:
-        return "numpy"
-    try:
-        import jax
-
-        tpu = jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - jax vanished mid-run
-        tpu = False
-    return "pallas-tpu" if tpu else "pallas-interpret"
-
-
-def resolve_backend():
-    """Resolve (and cache) the compressed-fold backend NOW; returns it (or
-    None). Callers that hold a lock across :func:`fold_delta` must call
-    this first, outside the lock: the first resolution imports jax and
-    initializes its backend — seconds, not microseconds — and every
-    pull/commit/heartbeat (i.e. every lease renewal) queues behind that
-    lock meanwhile. The netps server does this per codec'd commit before
-    taking its center lock; after the first call it is a bool check."""
-    return _accel()
-
+# -- compressed-domain fold ------------------------------------------------
 
 def fold_compressed_numpy(center: np.ndarray, a: np.ndarray, spec: dict,
                           scale: float) -> None:
-    """The pure-numpy reference: accumulate a wire-dtype tensor into the
-    f32 ``center`` in place, dequantization fused into the add. Specs are
-    assumed valid (:func:`validate_delta` runs before any fold): a missing
-    int8 scale raises rather than silently folding zero."""
+    """Accumulate a wire-dtype tensor into the f32 ``center`` in place,
+    dequantization fused into the add (the oracle the Pallas kernel and the
+    mesh dialect's collective body are held to). Specs are assumed valid
+    (:func:`validate_delta` runs before any fold): a missing int8 scale
+    raises rather than silently folding zero."""
     from distkeras_tpu.netps import wire
 
     codec = spec.get("codec")
@@ -227,11 +158,7 @@ def _fold_entry(c: np.ndarray, entry, scale: float) -> None:
     if not codec:
         c += scale * np.asarray(a, c.dtype)
         return
-    accel = _accel()
-    if accel is not None:
-        c[...] = accel.fold_compressed(c, a, spec, float(scale))
-    else:
-        fold_compressed_numpy(c, np.asarray(a), spec, float(scale))
+    fold_compressed_numpy(c, np.asarray(a), spec, float(scale))
 
 
 def fold_delta(center: Sequence[np.ndarray], delta: Sequence,

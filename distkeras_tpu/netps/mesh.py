@@ -72,17 +72,6 @@ def local_mesh_id() -> str:
     return f"{shm.local_boot_id()}:{os.getpid()}"
 
 
-def mesh_available() -> bool:
-    """Whether this process can host a device-resident center at all
-    (jax importable and at least one device). Never raises."""
-    try:
-        import jax
-
-        return len(jax.devices()) > 0
-    except Exception:
-        return False
-
-
 # ---------------------------------------------------------------------------
 # The in-process dispatch registry
 # ---------------------------------------------------------------------------
@@ -254,7 +243,6 @@ class MeshFolder:
     def _build_add(self, codecs):
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from distkeras_tpu.ops.pallas import fold as pallas_fold
@@ -283,12 +271,12 @@ class MeshFolder:
             return tuple(out) + (folded,)
 
         scalar = tuple(P() for _ in range(n)) if fused else ()
-        mapped = shard_map(
+        mapped = jax.shard_map(
             body, mesh=self._mesh,
             in_specs=specs + specs + scalar,
             out_specs=specs + (P(),),
             # pallas_call inside the body: replication checking must be off.
-            check_rep=False)
+            check_vma=False)
 
         def fold_all(center, deltas, scales=()):
             return mapped(*center, *deltas, *scales)
@@ -307,11 +295,12 @@ class MeshFolder:
 
         from distkeras_tpu.netps import wire
         from distkeras_tpu.netps.fold import split_entry
+        from distkeras_tpu.ops.pallas import mode
 
         if len(delta) != len(self._center):
             raise ValueError(
                 f"delta has {len(delta)} tensors, center {len(self._center)}")
-        fused = self.backend == "tpu" or self.interpret
+        fused = mode.compiles() or self.interpret
         arrs, scales, codecs = [], [], []
         for entry, shape in zip(delta, self._shapes):
             a, spec = split_entry(entry)

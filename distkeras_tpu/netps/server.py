@@ -56,10 +56,9 @@ from distkeras_tpu.netps import mesh as _mesh
 from distkeras_tpu.netps import shm, wire
 from distkeras_tpu.netps import state as _state
 from distkeras_tpu.netps.errors import ProtocolError
-from distkeras_tpu.netps.fold import (backend_name, check_discipline,
-                                      commit_scale, counter_staleness,
-                                      decode_entry, fold_delta,
-                                      resolve_backend, validate_delta)
+from distkeras_tpu.netps.fold import (check_discipline, commit_scale,
+                                      counter_staleness, decode_entry,
+                                      fold_delta, validate_delta)
 from distkeras_tpu.resilience import faults as _faults
 from distkeras_tpu.runtime import config
 from distkeras_tpu.telemetry import tracing as _tracing
@@ -327,21 +326,25 @@ class PSServer:
 
     def _ensure_mesh_folder(self) -> None:
         """Seat the center on device (idempotent; no-op until a center
-        exists). The jax import/device init happens OUTSIDE the center
-        lock — same discipline as ``resolve_backend`` — then the folder is
-        built from the live center under it. A build failure demotes this
-        server to host folds permanently (``_mesh_failed``): every wire
-        guarantee still holds, only the dialect advertisement is gone."""
+        exists). Only a ``transport="mesh"`` server ever touches a jax
+        backend — it was constructed to own this process's devices; every
+        other server folds in numpy and initializes none. The device init
+        (seconds) happens OUTSIDE the center lock, then the folder is built
+        from the live center under it. A build failure — including a jax
+        runtime with no usable device — demotes this server to host folds
+        permanently (``_mesh_failed``) and is counted and evented: every
+        wire guarantee still holds, only the dialect advertisement is
+        gone."""
         if (self.transport != "mesh" or self._mesh_failed
                 or self._mesh_folder is not None):
-            return
-        if not _mesh.mesh_available():
-            self._mesh_failed = True
             return
         plan = (self.shard_plan
                 if self.shard_plan is not None and self.shard_index is None
                 else None)
         try:
+            import jax
+
+            jax.devices()
             with self._lock:
                 if self._mesh_folder is None and self._center is not None:
                     self._mesh_folder = _mesh.MeshFolder(self._center,
@@ -980,13 +983,9 @@ class PSServer:
         duplicate = pending = False
         # Validate specs BEFORE any bookkeeping or fold: a bad spec that
         # raised mid-fold under the lock would leave a partially-applied
-        # delta the retransmit then double-folds. A codec'd commit also
-        # resolves the fold backend BEFORE taking the center lock — the
-        # first resolution may import jax / init its backend (seconds),
-        # and every member's lease renewal queues behind that lock.
+        # delta the retransmit then double-folds.
         try:
-            if validate_delta(arrays):
-                resolve_backend()
+            validate_delta(arrays)
         except ProtocolError as e:
             telemetry.counter("netps.protocol_errors").add(1)
             return self._err("protocol", str(e))
@@ -1256,10 +1255,10 @@ class PSServer:
                      "ready": (not self._draining and not self._fenced
                                and not self._not_primary),
                      # Which arithmetic actually folds commits right now:
-                     # a live device-resident center reports "mesh"; the
-                     # compressed-domain dispatch's resolution otherwise.
+                     # a live device-resident center reports "mesh"; every
+                     # other server folds on the host in numpy.
                      "fold_backend": ("mesh" if self._mesh_folder is not None
-                                      else backend_name())}
+                                      else "numpy")}
         # The ring rides the JSON header: round-trip through json with a
         # str fallback first — event fields may carry non-JSON scalars,
         # and a scrape must never poison the reply frame.

@@ -37,44 +37,40 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu is unavailable on non-TPU builds; kernels still run interpreted
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    _VMEM = None
+from distkeras_tpu.ops.pallas import mode
 
 _NEG = -1e30
 
 
-def _kw(**extra):
-    return {**({"memory_space": _VMEM} if _VMEM else {}), **extra}
-
-
 def _qblock_spec(block, D):
-    return pl.BlockSpec((1, block, D), lambda bh, i: (bh, i, 0), **_kw())
+    return pl.BlockSpec((1, block, D), lambda bh, i: (bh, i, 0),
+                        memory_space=pltpu.VMEM)
 
 
 def _full_spec(L, D):
-    return pl.BlockSpec((1, L, D), lambda bh, i: (bh, 0, 0), **_kw())
+    return pl.BlockSpec((1, L, D), lambda bh, i: (bh, 0, 0),
+                        memory_space=pltpu.VMEM)
 
 
 def _rowblock_spec(block):
     # lse/delta as [BH, nq, 1, block_q]: one exact block per program —
     # blocked, never revisited, so the grid stays order-independent. The
     # trailing (1, block) dims equal the array dims, satisfying TPU tiling.
-    return pl.BlockSpec((1, 1, 1, block), lambda bh, i: (bh, i, 0, 0), **_kw())
+    return pl.BlockSpec((1, 1, 1, block), lambda bh, i: (bh, i, 0, 0),
+                        memory_space=pltpu.VMEM)
 
 
 def _fullrow_spec(nq, block):
-    return pl.BlockSpec((1, nq, 1, block), lambda bh, i: (bh, 0, 0, 0), **_kw())
+    return pl.BlockSpec((1, nq, 1, block), lambda bh, i: (bh, 0, 0, 0),
+                        memory_space=pltpu.VMEM)
 
 
 def _parallel_kw(interpret: bool, dims: int = 2) -> dict:
     """All grid dims order-independent -> Mosaic overlaps fetch/compute
     across programs. Only valid because no output block is revisited."""
-    if interpret or _VMEM is None:
+    if interpret:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("parallel",) * dims)}
@@ -277,14 +273,17 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, block_size: int = 128, block_k: int | None = None,
-                    interpret: bool = False):
+                    interpret: bool | None = None):
     """Causal FlashAttention. ``q, k, v``: [B, L, H, D], q pre-scaled by
     1/sqrt(D). Returns [B, L, H, D]. ``block_size`` is the q-block;
     ``block_k`` is the inner k-chunk — by default the largest multiple of
     ``block_size`` up to ``8*block_size`` that divides ``L`` (e.g. L=1280,
     block 128 -> 640, not 1024). Large k-chunks keep the MXU busy when
     d_head is small (see module doc). ``L`` must be divisible by both.
+    ``interpret=None`` compiles on TPU and interprets elsewhere
+    (:mod:`distkeras_tpu.ops.pallas.mode`).
     """
+    interpret = mode.interpret("flash_attention", interpret)
     B, L, H, D = q.shape
     if block_k is None:
         # Largest multiple of block_size that divides L, capped at 8x — so

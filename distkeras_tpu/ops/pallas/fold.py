@@ -29,6 +29,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distkeras_tpu.ops.pallas import mode
+
 _LANES = 128
 #: rows per grid step (512 x 128 f32 = 256 KiB center block in VMEM).
 _BLOCK_ROWS = 512
@@ -49,12 +51,9 @@ def _fold_kernel(s_ref, c_ref, q_ref, o_ref, *, codec):
 def _compiler_kw(interpret: bool) -> dict:
     if interpret:
         return {}
-    params = (getattr(pltpu, "CompilerParams", None)
-              or getattr(pltpu, "TPUCompilerParams", None))
-    if params is None:  # pragma: no cover - very old pallas
-        return {}
     # Each program owns its own center block: order-independent grid.
-    return {"compiler_params": params(dimension_semantics=("parallel",))}
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel",))}
 
 
 @functools.lru_cache(maxsize=None)
@@ -81,7 +80,7 @@ def _folder(codec: str, rows: int, wire_dtype: str, interpret: bool):
     )
 
 
-def fold_traced(center, q, s, *, codec: str, interpret: bool = False):
+def fold_traced(center, q, s, *, codec: str, interpret: bool | None = None):
     """Traceable twin of :func:`fold_compressed` for use INSIDE a jitted
     collective body (the netps mesh dialect folds each device's center
     shard through this under ``shard_map``): same kernel, same pad/
@@ -89,10 +88,12 @@ def fold_traced(center, q, s, *, codec: str, interpret: bool = False):
     trace into the surrounding program instead of staging through host
     numpy. ``center`` is the local f32 shard, ``q`` the matching
     wire-dtype shard, ``s`` a traced f32 scalar already folded to
-    ``commit_scale · tensor_scale``."""
+    ``commit_scale · tensor_scale``. ``interpret=None`` compiles on TPU and
+    interprets elsewhere (:mod:`distkeras_tpu.ops.pallas.mode`)."""
     n = int(np.prod(center.shape, dtype=np.int64)) if center.ndim else 1
     if n == 0:
         return center
+    interpret = mode.interpret("fold", interpret)
     rows = -(-n // _LANES)
     rows += (-rows) % _ROW_ALIGN
     if rows > _BLOCK_ROWS:
@@ -112,7 +113,7 @@ def fold_traced(center, q, s, *, codec: str, interpret: bool = False):
 
 
 def fold_compressed(center, wire_arr, spec: dict, scale: float,
-                    interpret: bool = False) -> np.ndarray:
+                    interpret: bool | None = None) -> np.ndarray:
     """``center + scale * dequant(wire_arr)`` with the dequant fused into
     the accumulate — returns a NEW array shaped like ``center`` (the
     caller assigns; the numpy reference mutates in place instead).
@@ -133,6 +134,7 @@ def fold_compressed(center, wire_arr, spec: dict, scale: float,
     c = np.ascontiguousarray(center, np.float32)
     if c.size == 0 or s == 0.0:
         return c.copy().reshape(np.shape(center))
+    interpret = mode.interpret("fold", interpret)
     q = np.ascontiguousarray(wire_arr, wire_dtype).reshape(-1)
     n = c.size
     rows = -(-n // _LANES)

@@ -31,6 +31,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distkeras_tpu.ops.pallas import mode
+
 
 def _group_matrix(C: int, G: int, fold: int = 1) -> np.ndarray:
     """One-hot [C*fold, G] membership: channel c belongs to group
@@ -295,10 +297,11 @@ def _make_group_norm(groups: int, relu: bool, interpret: bool):
 
 
 def group_norm(x, gamma, beta, *, groups: int, relu: bool = False,
-               interpret: bool = False):
+               interpret: bool | None = None):
     """Fused GroupNorm(+optional ReLU) over NHWC (or any [..., spatial..., C])
     input. ``gamma``/``beta`` are per-channel [C]. Returns x's dtype;
-    statistics are float32 (flax parity)."""
+    statistics are float32 (flax parity). ``interpret=None`` compiles on TPU
+    and interprets elsewhere (:mod:`distkeras_tpu.ops.pallas.mode`)."""
     shape = x.shape
     C = shape[-1]
     if C % groups:
@@ -313,5 +316,6 @@ def group_norm(x, gamma, beta, *, groups: int, relu: bool = False,
         # instead of a plan-blowing kernel.
         y = _xla_group_norm(x3, gamma, beta, groups, relu)
     else:
+        interpret = mode.interpret("group_norm", interpret)
         y = _make_group_norm(groups, relu, interpret)(x3, gamma, beta)
     return y.reshape(shape)

@@ -18,7 +18,6 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 
-from distkeras_tpu.ops.collectives import axis_size
 
 _NEG = -1e30
 
@@ -36,7 +35,7 @@ def ring_attention(q, k, v, axis_name: str):
     """
     B, L, H, D = q.shape
     out_dtype = q.dtype
-    S = axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
 
     qf = q.astype(jnp.float32)

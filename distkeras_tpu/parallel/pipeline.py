@@ -18,8 +18,6 @@ from typing import Callable
 import jax.numpy as jnp
 from jax import lax
 
-from distkeras_tpu.ops.collectives import axis_size
-
 
 def gpipe(stage_fn: Callable, stage_params, microbatches, axis_name: str):
     """Run ``microbatches`` through the stage pipeline.
@@ -37,7 +35,7 @@ def gpipe(stage_fn: Callable, stage_params, microbatches, axis_name: str):
       ``[M, ...]`` outputs, valid on the **last** stage (zeros elsewhere —
       callers typically follow with a masked ``psum`` broadcast).
     """
-    S = axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     M = microbatches.shape[0]
     T = M + S - 1
@@ -75,6 +73,6 @@ def gpipe(stage_fn: Callable, stage_params, microbatches, axis_name: str):
 
 def last_stage_broadcast(y, axis_name: str):
     """Broadcast the last stage's pipeline output to every stage (masked psum)."""
-    S = axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     return lax.psum(jnp.where(idx == S - 1, y, jnp.zeros_like(y)), axis_name)

@@ -20,10 +20,9 @@ from typing import Any, NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from distkeras_tpu.ops.collectives import shard_map
 from distkeras_tpu.ops.losses import get_loss
 from distkeras_tpu.ops.precision import cast_floats
 from distkeras_tpu.ops.optimizers import get_optimizer

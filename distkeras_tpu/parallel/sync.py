@@ -18,11 +18,10 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distkeras_tpu.data.batching import BatchPlan
-from distkeras_tpu.ops.collectives import shard_map
 from distkeras_tpu.ops.losses import get_loss
 from distkeras_tpu.ops.optimizers import get_optimizer
 from distkeras_tpu.runtime.mesh import DATA_AXIS, put_global

@@ -173,10 +173,10 @@ ENV_REGISTRY: dict = _declare(
            "observability"),
     EnvVar("DKTPU_VITALS_S", "float", 0.0,
            "Process-vitals sample interval (seconds): periodic "
-           "`runtime.rss_mb`, `runtime.open_fds`, and (when jax sees a "
-           "device) `device.bytes_in_use` gauges feeding the hub via the "
-           "stats op. 0 = off; the netps CLI and the serving frontend "
-           "start the sampler when set.",
+           "`runtime.rss_mb`, `runtime.open_fds`, and (in a process that "
+           "already owns a jax device) `device.bytes_in_use` gauges "
+           "feeding the hub via the stats op. 0 = off; the netps CLI and "
+           "the serving frontend start the sampler when set.",
            "observability"),
     EnvVar("DKTPU_NAN_GUARD", "bool", True,
            "On-device NaN/Inf round skip in the engine round bodies; `0` "

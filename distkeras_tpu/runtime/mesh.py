@@ -48,14 +48,12 @@ def distributed_initialize(**kwargs) -> None:
     The reference reached other hosts via Spark's JVM scheduler + ssh
     (``job_deployment.py -> Job/Punchcard``); on TPU pods the runtime handles
     cross-host wiring once this is called on every host. Safe to call when already
-    initialized (no-op).
+    initialized (no-op, mirroring Spark's idempotent context lookup); a bootstrap
+    that fails raises — a host that silently stayed single-process would train
+    alone on its own shard.
     """
-    try:
+    if not jax.distributed.is_initialized():
         jax.distributed.initialize(**kwargs)
-    except RuntimeError:
-        # Already initialized (or single-process run) — mirror Spark's idempotent
-        # context lookup rather than erroring.
-        pass
 
 
 def data_mesh(num_workers: int | None = None, devices: Sequence[jax.Device] | None = None) -> Mesh:

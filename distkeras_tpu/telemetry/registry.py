@@ -92,6 +92,10 @@ _METRICS = [
        "Bytes moved by the native gather path."),
     _m("native.gather_fallback_calls", "counter", "data",
        "Silent numpy fallbacks (a data-plane regression signal)."),
+    # -- kernels ----------------------------------------------------------
+    _m("pallas.interpreted_calls", "counter", "kernels",
+       "Pallas kernel calls traced under the interpreter instead of "
+       "compiled by Mosaic (0 on a TPU; `ops/pallas/mode.py`)."),
     # -- inference --------------------------------------------------------
     _m("predict.chunk", "span", "inference",
        "Per-chunk end-to-end predict latency."),

@@ -6,9 +6,12 @@ A tiny background thread that samples, every ``DKTPU_VITALS_S`` seconds:
   falling back to ``resource.getrusage`` off Linux);
 * ``runtime.open_fds`` — open file descriptors (``/proc/self/fd``);
 * ``device.bytes_in_use`` — accelerator memory from jax's
-  ``device.memory_stats()``, only when jax is already imported *and*
-  sees a device that reports stats (never imports jax itself — the
-  telemetry layer stays contractually jax-free).
+  ``device.memory_stats()``, only in a process that has ALREADY
+  initialized a jax backend (never imports jax itself — the telemetry
+  layer stays contractually jax-free — and never initializes a backend:
+  a chip belongs to one process, and a parameter-server child that merely
+  imported jax must not open a TPU client beside the trainer that owns
+  the chip).
 
 The gauges land in the ordinary telemetry registry, so they ride the
 stats op for free and the health plane's ``MetricsHub`` picks them up on
@@ -76,16 +79,15 @@ def _open_fds() -> Optional[int]:
 
 def _device_bytes_in_use() -> Optional[int]:
     jax = sys.modules.get("jax")
-    if jax is None:  # vitals never forces the jax import
+    bridge = sys.modules.get("jax._src.xla_bridge")
+    # vitals never forces the jax import, and never the backend init:
+    # jax.devices() in a process that owns no device would open a client.
+    if jax is None or bridge is None or not bridge.backends_are_initialized():
         return None
-    try:
-        for dev in jax.devices():
-            stats = getattr(dev, "memory_stats", None)
-            stats = stats() if callable(stats) else None
-            if stats and "bytes_in_use" in stats:
-                return int(stats["bytes_in_use"])
-    except Exception:
-        return None
+    for dev in jax.devices():
+        stats = dev.memory_stats()  # None on backends that report nothing
+        if stats and "bytes_in_use" in stats:
+            return int(stats["bytes_in_use"])
     return None
 
 

@@ -212,6 +212,10 @@ class Trainer:
         self.device_transform = device_transform
         self.history: np.ndarray | None = None
         self.worker_histories: np.ndarray | None = None
+        #: the engine the last ``train()`` ran on — for inspection after
+        #: the fact (its compiled round program, ``feed_waits``); None
+        #: before the first run and on the ``remote=`` path.
+        self.engine = None
         self.training_time: float = 0.0
         self._t_start: float | None = None
 
@@ -360,6 +364,7 @@ class Trainer:
 
     def _execute(self, engine, plan):
         """Shared run harness: resume from checkpoint, per-round metrics/saves."""
+        self.engine = engine
         state = None
         start = 0
         # Orbax step = round + step_offset. Orbax declines saves at any

@@ -11,13 +11,6 @@ tp-sharded over 2 chips of a (data, model) mesh — the same
         python examples/async_tensor_parallel.py
 """
 
-import os
-
-if os.environ.get("JAX_PLATFORMS"):
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import numpy as np
 
 

@@ -25,11 +25,6 @@ import json
 import os
 import tempfile
 
-if os.environ.get("JAX_PLATFORMS"):  # honor even under overriding site hooks
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import numpy as np
 
 

@@ -23,8 +23,6 @@ from distkeras_tpu.parallel.engine import AsyncEngine
 from distkeras_tpu.parallel.sharding import TRANSFORMER_TP_RULES
 from distkeras_tpu.runtime.mesh import data_mesh, hybrid_mesh
 
-import envcaps
-
 
 def _blob_df(n=512, d=8, c=3, seed=0):
     rng = np.random.default_rng(seed)
@@ -35,7 +33,6 @@ def _blob_df(n=512, d=8, c=3, seed=0):
 
 
 @pytest.mark.parametrize("disc_name", ["aeasgd", "adag", "dynsgd"])
-@envcaps.skip_unless_key_sharding()
 def test_tp_async_matches_flat_worker_run(disc_name):
     """(W=2, tp=2) == flat W=2 on a TP-invariant model: same worker ids,
     same rngs, same commits — sharding must not change the math."""
@@ -66,7 +63,6 @@ def test_tp_async_matches_flat_worker_run(disc_name):
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=1e-6)
 
 
-@envcaps.skip_unless_key_sharding()
 def test_transformer_tensor_shards_and_trains_under_aeasgd():
     """The composition in anger: a TransformerLM whose per-worker replicas
     are genuinely tp-sharded (param leaves carry the 'model' axis) trains
@@ -109,7 +105,6 @@ def test_transformer_tensor_shards_and_trains_under_aeasgd():
     assert np.mean(losses[-2:]) < np.mean(losses[:2])
 
 
-@envcaps.skip_unless_key_sharding()
 def test_trainer_surface_accepts_parallel_model():
     """Reference-shaped call: AEASGD(model, num_workers=2,
     parallel={'model': 2}).train(df) -> trained model."""
@@ -133,7 +128,6 @@ def plan_rounds(n, W, K, B):
     return n // (W * K * B)
 
 
-@envcaps.skip_unless_key_sharding()
 def test_checkpoint_resume_under_tp_async(tmp_path):
     """The full trainer surface holds for the composed engine: a
     checkpointed W=2 x tp=2 AEASGD run resumes to exactly the
@@ -194,7 +188,6 @@ def _transformer(attn_impl="dense", seq_axis=None, L=16, V=64, seed=0):
 
 
 @pytest.mark.parametrize("disc_name", ["aeasgd", "adag"])
-@envcaps.skip_unless_key_sharding()
 def test_flash_attention_under_async_tp(disc_name):
     """The r4 gap (VERDICT r4 missing #1): the flagship flash-attention
     transformer trains under the async disciplines with tp>1. The Mosaic
@@ -217,7 +210,6 @@ def test_flash_attention_under_async_tp(disc_name):
     assert np.mean(losses["flash"][-2:]) < np.mean(losses["flash"][:2])
 
 
-@envcaps.skip_unless_key_sharding()
 def test_sequence_parallel_under_async_tp():
     """Sequence parallelism composes with the async disciplines: a
     seq-sharded ring-attention worker (sp=2 x tp=2 submesh per worker)
@@ -240,7 +232,6 @@ def test_sequence_parallel_under_async_tp():
     np.testing.assert_allclose(losses_sp, losses_flat, rtol=2e-3, atol=1e-5)
 
 
-@envcaps.skip_unless_key_sharding()
 def test_trainer_surface_accepts_parallel_seq():
     """Reference-shaped call with the composed mesh: AEASGD(transformer,
     num_workers=2, parallel={'model': 2, 'seq': 2}).train(df)."""

@@ -7,8 +7,6 @@ import numpy as np
 from distkeras_tpu.datasets import cifar10, imdb, mnist, synthetic_lm
 from distkeras_tpu.job_deployment import Job, Punchcard
 
-import envcaps
-
 
 def test_mnist_shapes():
     df = mnist(n=256)
@@ -84,7 +82,6 @@ def test_mnist_workflow_example(monkeypatch, capsys):
     assert "test accuracy" in out
 
 
-@envcaps.skip_unless_key_sharding()
 def test_transformer_spmd_example(monkeypatch, capsys):
     _run_example(monkeypatch, "transformer_spmd",
                  ["x", "--steps", "4", "--layers", "1", "--d-model", "32",

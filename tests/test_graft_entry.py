@@ -5,8 +5,6 @@ import os
 
 import jax
 
-import envcaps
-
 
 def _load_entry():
     path = os.path.join(os.path.dirname(__file__), "..", "__graft_entry__.py")
@@ -23,7 +21,6 @@ def test_entry_forward_jits():
     assert out.shape == (8, 128, 8192)
 
 
-@envcaps.skip_unless_key_sharding()
 def test_dryrun_multichip_8():
     _load_entry().dryrun_multichip(8)
 

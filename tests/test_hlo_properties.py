@@ -19,8 +19,6 @@ from distkeras_tpu.parallel.engine import AsyncEngine
 from distkeras_tpu.parallel.sync import SyncEngine
 from distkeras_tpu.runtime.mesh import data_mesh
 
-import envcaps
-
 
 def _compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
@@ -41,7 +39,6 @@ def setup():
 
 
 @pytest.mark.parametrize("disc", ["downpour", "adag", "dynsgd", "aeasgd"])
-@envcaps.skip_unless_allreduce_combiner()
 def test_async_round_is_one_fused_all_reduce(setup, disc, request):
     mesh, model, xs, ys = setup
     fold = get_discipline(disc) if disc != "aeasgd" else get_discipline(
@@ -55,7 +52,6 @@ def test_async_round_is_one_fused_all_reduce(setup, disc, request):
     assert 1 <= n <= 2, f"{disc}: expected one fused fold, got {n} all-reduces"
 
 
-@envcaps.skip_unless_allreduce_combiner()
 def test_sync_round_is_one_fused_all_reduce_per_step(setup):
     mesh, model, xs, ys = setup
     eng = SyncEngine(model, "sgd", "sparse_categorical_crossentropy", mesh,

@@ -126,7 +126,7 @@ def test_round_feeder_propagates_errors():
 
 
 def test_round_feeder_abandonment_stops_thread():
-    """A consumer that dies mid-loop (OOM, tunnel flake) must not leave the
+    """A consumer that dies mid-loop (OOM, a lost device) must not leave the
     feeder thread blocked on Queue.put holding staged batches forever."""
     import time
     import weakref

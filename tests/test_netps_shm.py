@@ -3,6 +3,9 @@ negotiation (boot-id check + caps fallback matrix), ring-level chaos,
 exactly-once/eviction guarantees on the ring, compressed-domain folds,
 and the per-host aggregator's flat-topology parity."""
 
+import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -537,35 +540,42 @@ def test_bad_codec_spec_is_typed_error_and_never_partially_folds():
         srv.close()
 
 
-def test_codec_commit_resolves_fold_backend_outside_server_lock():
-    """The first compressed-domain fold may import jax / init its backend
-    (seconds): the server must resolve the backend BEFORE taking the
-    center lock, or every other member's lease renewal queues behind the
-    import and a short lease evicts the lot."""
-    from distkeras_tpu.netps import server as server_mod
+_NO_BACKEND_SERVER = """
+import numpy as np
+import jax  # imported, as the package does anyway -- but never initialized
+from jax._src import xla_bridge
+from distkeras_tpu.netps import PSClient, PSServer
+from distkeras_tpu.telemetry import vitals
 
-    calls = []
-    real = server_mod.resolve_backend
-    srv = PSServer(discipline="downpour").start()
+srv = PSServer(discipline="downpour").start()
+try:
+    with PSClient(srv.endpoint, worker_id=0, compress="int8", timeout=5.0,
+                  retries=3, backoff=0.01) as c:
+        _, upd = c.join(init=[np.zeros(8, np.float32)])
+        assert c.commit([np.full(8, 0.5, np.float32)], upd).applied
+        stats = c.stats(ring=0)
+    vitals.sample_vitals()
+    assert stats["fold_backend"] == "numpy", stats["fold_backend"]
+    assert abs(float(srv.center()[0][0]) - 0.5) < 0.01, srv.center()
+    assert not xla_bridge.backends_are_initialized(), list(xla_bridge._backends)
+finally:
+    srv.close()
+print("NO_BACKEND_OK")
+"""
 
-    def spy():
-        # A non-reentrant Lock held by THIS thread would deadlock here:
-        # acquiring proves the handler called us before taking it.
-        assert srv._lock.acquire(timeout=1.0), "center lock held by caller"
-        srv._lock.release()
-        calls.append(1)
-        return real()
 
-    server_mod.resolve_backend = spy
-    try:
-        with PSClient(srv.endpoint, worker_id=0, compress="int8",
-                      **FAST) as c:
-            _, upd = c.join(init=[np.zeros(8, np.float32)])
-            assert c.commit([np.full(8, 0.5, np.float32)], upd).applied
-        assert calls, "codec'd commit never resolved the fold backend"
-    finally:
-        server_mod.resolve_backend = real
-        srv.close()
+def test_codec_commit_initializes_no_jax_backend_in_server_process():
+    """A chip belongs to one process. A parameter server runs beside the
+    trainers that own the chips, so handling a codec'd commit -- the path
+    that used to probe ``jax.default_backend()`` for a Pallas fold -- and
+    sampling vitals must leave jax's backends uninitialized: folds are
+    numpy unless the server was constructed as ``transport="mesh"``."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", _NO_BACKEND_SERVER],
+                          cwd=repo, capture_output=True, text=True,
+                          timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "NO_BACKEND_OK" in proc.stdout
 
 
 def test_fold_delta_accepts_wire_pairs_and_matches_plain():

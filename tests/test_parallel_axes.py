@@ -7,11 +7,11 @@ the dense single-device computation on the same inputs.
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distkeras_tpu.models import Model, small_transformer_lm
 from distkeras_tpu.models.transformer import TransformerLM
-from distkeras_tpu.ops.collectives import shard_map
 from distkeras_tpu.ops.ring_attention import ring_attention
 from distkeras_tpu.parallel.sharding import (
     TRANSFORMER_TP_RULES,
@@ -20,7 +20,6 @@ from distkeras_tpu.parallel.sharding import (
 )
 from distkeras_tpu.runtime.mesh import hybrid_mesh
 
-import envcaps
 
 B, L, H, D = 2, 32, 2, 8  # global seq L sharded over 4 chips -> 8 per chip
 
@@ -113,7 +112,6 @@ def test_tp_sharded_forward_matches_dense():
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect), atol=3e-4)
 
 
-@envcaps.skip_unless_key_sharding()
 def test_flash_attention_under_tensor_parallelism():
     """attn_impl='flash' on a dp x tp mesh: the Mosaic kernel is manualized
     over the model axis by a nested shard_map (heads are independent), so

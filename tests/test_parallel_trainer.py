@@ -16,7 +16,6 @@ from distkeras_tpu import ParallelTrainer, TransformerTrainer
 from distkeras_tpu.datasets import synthetic_lm
 from distkeras_tpu.models.transformer import small_transformer_lm
 
-import envcaps
 
 SEQ = 32
 VOCAB = 64
@@ -71,7 +70,6 @@ def test_gspmd_tp_trains_and_logs_metrics(tmp_path):
     assert any(r.get("samples_per_sec_per_chip") for r in recs)
 
 
-@envcaps.skip_unless_key_sharding()
 def test_spmd_seq_axis_autobind():
     """A seq axis in `parallel` rebinds the module with seq_axis set, so
     positions/causality are computed globally; loss must still fall."""
@@ -93,7 +91,6 @@ def test_spmd_inferred_seq_size_still_rebinds():
     assert engine.inner.model.module.seq_axis == "seq"
 
 
-@envcaps.skip_unless_key_sharding()
 def test_spmd_route_without_seq_axis_gets_unit_seq():
     """A flash/ring model on a dp×tp layout routes to SPMDEngine, which
     always shard_maps over (data, seq) — the trainer injects seq=1."""
@@ -174,7 +171,6 @@ def test_checkpoint_resume_equals_uninterrupted(tmp_path, parallel):
     np.testing.assert_allclose(t2.get_history(), tail, rtol=1e-5)
 
 
-@envcaps.skip_unless_key_sharding()
 def test_checkpoint_resume_spmd(tmp_path):
     """Same resume-equivalence for the SPMDEngine (dp×sp×tp shard_map path)."""
     df = _data()

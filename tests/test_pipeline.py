@@ -3,11 +3,11 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from distkeras_tpu.models.base import Model
 from distkeras_tpu.models.transformer import TransformerLM
-from distkeras_tpu.ops.collectives import shard_map
 from distkeras_tpu.parallel.pipeline import gpipe, last_stage_broadcast
 from distkeras_tpu.parallel.pipeline_engine import (
     PipelineEngine,
