@@ -197,6 +197,16 @@ def _count_all_reduce(hlo: str) -> int:
     return len(re.findall(r"all-reduce(?:-start)?\(", hlo))
 
 
+def _dk_scopes(hlo: str) -> list:
+    """The ``dk_*`` named scopes and kernel names that the compiled program's
+    ``op_name`` metadata carries (what a device trace finds its phases by)."""
+    import re
+
+    return sorted({part for name in re.findall(r'op_name="([^"]*)"', hlo)
+                   for part in re.split(r"[/;]", name)
+                   if part.startswith("dk_")})
+
+
 def train_lm(sz: Sizes, *, discipline: str, layers: int, num_workers: int,
              parallel=None, seed: int, on_tpu: bool) -> dict:
     """Train the flagship TransformerLM through ``dk.<discipline>(...)
@@ -241,11 +251,16 @@ def train_lm(sz: Sizes, *, discipline: str, layers: int, num_workers: int,
                  * sz.seq,
                  hlo_text_s=round(time.perf_counter() - t0, 2),
                  mosaic_calls=hlo.count("tpu_custom_call"),
-                 all_reduces=_count_all_reduce(hlo))
+                 all_reduces=_count_all_reduce(hlo),
+                 dk_scopes=_dk_scopes(hlo))
     if on_tpu:
         _require(facts["mosaic_calls"] > 0,
                  "the compiled round program holds no Mosaic custom call: "
                  "flash attention interpreted or gave way to dense")
+    _require("dk_flash_fwd" in facts["dk_scopes"],
+             "the round program runs flash attention and nothing in it is "
+             f"named dk_flash_fwd (scopes found: {facts['dk_scopes']}): the "
+             "benchmark's readers would not find the kernel")
     if num_workers > 1:
         _require(facts["all_reduces"] >= 1,
                  "the W>1 round program holds no all-reduce: nothing folds")
@@ -276,7 +291,7 @@ def train_cifar(sz: Sizes, num_workers: int) -> dict:
     # One fused all-reduce for the fold; the loss gather may add one more op
     # at most — never one per parameter tensor (tests/test_hlo_properties.py).
     _require(1 <= n <= 2, f"expected one fused fold all-reduce, found {n}")
-    return dict(clock.facts(), all_reduces=n)
+    return dict(clock.facts(), all_reduces=n, dk_scopes=_dk_scopes(hlo))
 
 
 def phase_four_chip(sz: Sizes, on_tpu: bool) -> dict:

@@ -93,6 +93,9 @@ from distkeras_tpu.fleet import (  # noqa: F401
     FleetJob,
     FleetScheduler,
 )
+from distkeras_tpu.runtime.compile_cache import watch_compiles
+
+watch_compiles()  # every compilation's phases, onto the telemetry timeline
 
 __all__ = [
     "Trainer",

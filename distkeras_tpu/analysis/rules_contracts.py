@@ -55,7 +55,8 @@ def _metric_call(node: ast.Call):
     if not name:
         return None
     parts = name.split(".")
-    kind = parts[-1]
+    # ``observe_span`` records a span its caller timed: the same namespace.
+    kind = "span" if parts[-1] == "observe_span" else parts[-1]
     if kind not in _METRIC_KINDS:
         return None
     if len(parts) > 1 and parts[-2] not in _TELEMETRY_RECEIVERS:

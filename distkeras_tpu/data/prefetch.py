@@ -88,7 +88,8 @@ class RoundFeeder:
         self.retry_backoff_s = float(retry_backoff_s)
         self._q: queue.Queue = queue.Queue(maxsize=self.depth)
         self._stop = threading.Event()
-        self._thread = threading.Thread(target=self._run, daemon=True)
+        self._thread = threading.Thread(target=self._run, daemon=True,
+                                        name="dk-feeder")
         #: consumer-side seconds blocked waiting for each yielded round —
         #: the feed-overlap diagnostic. Because jax dispatch is async, the
         #: consumer loop runs ahead of the device; per-round waits beyond
@@ -159,17 +160,15 @@ class RoundFeeder:
         from distkeras_tpu import telemetry
 
         tele = telemetry.get()
-        stage_span = tele.histogram("feeder.stage")
         try:
             for r, item in self._item_source():
                 if self._stop.is_set():
                     return
-                t0 = time.perf_counter()
-                batch = self._stage_with_retry(r, item, tele)
                 # Producer-side cost (gather + transform + device_put), the
                 # counterpart of the consumer's ``input_stall``: staging
                 # slower than dispatch is what makes stalls appear.
-                stage_span.observe(time.perf_counter() - t0)
+                with tele.span("feeder.stage", id=r):
+                    batch = self._stage_with_retry(r, item, tele)
                 if not self._put((r, batch, None)):
                     return
         except BaseException as e:  # noqa: BLE001 - propagate to consumer

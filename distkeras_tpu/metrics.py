@@ -1,9 +1,8 @@
-"""Structured training metrics + profiling hooks.
+"""Structured training metrics.
 
 SURVEY.md §5: the reference records wall-clock only (``Trainer.record_training_start/
 stop``) with print-level logging. Here every fold round can emit a JSONL record
-(loss, samples/sec/chip, scaling efficiency inputs) and any span can be wrapped in a
-``jax.profiler`` trace for Perfetto/XProf.
+(loss, samples/sec/chip, scaling efficiency inputs).
 
 ``MetricsLogger`` is a client of the unified telemetry layer
 (``distkeras_tpu/telemetry/``): every round also feeds the ambient registry's
@@ -21,7 +20,6 @@ import json
 import time
 from typing import Optional
 
-import jax
 import numpy as np
 
 
@@ -186,12 +184,3 @@ def scaling_efficiency(sps_n: float, sps_1: float, n_chips: int) -> float:
         return 0.0
     return sps_n / (n_chips * sps_1)
 
-
-@contextlib.contextmanager
-def profile_trace(log_dir: str):
-    """``jax.profiler`` span -> Perfetto/XProf trace in ``log_dir``."""
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

@@ -129,8 +129,11 @@ class Model:
         used (abstract init under ``jax.eval_shape`` would also work, but a concrete
         init keeps custom modules simple).
         """
+        from distkeras_tpu import telemetry
+
         inputs = sample_input if isinstance(sample_input, tuple) else (sample_input,)
-        variables = module.init(jax.random.key(seed), *inputs, train=False)
+        with telemetry.span("model_build"):
+            variables = module.init(jax.random.key(seed), *inputs, train=False)
         params = variables["params"]
         state = {k: v for k, v in variables.items() if k != "params"} or None
         spec = tuple(jax.ShapeDtypeStruct(jnp.shape(a), jnp.asarray(a).dtype)

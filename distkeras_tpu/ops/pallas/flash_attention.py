@@ -206,7 +206,7 @@ def _flash_bhld(q, k, v, block_q, block_k, interpret):
     """Forward on [BH, L, D] inputs; returns (out, lse [BH, nq, 1, block_q])."""
     BH, L, D = q.shape
     grid = (BH, L // block_q)
-    out, lse = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_fwd_kernel, block_q=block_q, block_k=block_k),
         grid=grid,
         in_specs=[_qblock_spec(block_q, D), _full_spec(L, D), _full_spec(L, D)],
@@ -219,8 +219,13 @@ def _flash_bhld(q, k, v, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((BH, L // block_q, 1, block_q), jnp.float32),
         ],
         interpret=interpret,
+        name="dk_flash_fwd",
         **_parallel_kw(interpret),
-    )(q, k, v)
+    )
+    # The kernel's name (the Mosaic call's, on a chip) and the scope of the
+    # same name (the interpreted ops', on a CPU) are what a trace finds it by.
+    with jax.named_scope("dk_flash_fwd"):
+        out, lse = call(q, k, v)
     return out, lse
 
 
@@ -242,7 +247,7 @@ def _flash_bwd(block_q, block_k, interpret, res, do):
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(BH, nq, 1, block_q)
 
-    dq = pl.pallas_call(
+    dq_call = pl.pallas_call(
         functools.partial(_dq_kernel, block_q=block_q, block_k=block_k),
         grid=(BH, nq),
         in_specs=[_qblock_spec(block_q, D), _full_spec(L, D), _full_spec(L, D),
@@ -251,10 +256,13 @@ def _flash_bwd(block_q, block_k, interpret, res, do):
         out_specs=_qblock_spec(block_q, D),
         out_shape=jax.ShapeDtypeStruct((BH, L, D), q.dtype),
         interpret=interpret,
+        name="dk_flash_dq",
         **_parallel_kw(interpret),
-    )(q, k, v, do, lse, delta)
+    )
+    with jax.named_scope("dk_flash_dq"):
+        dq = dq_call(q, k, v, do, lse, delta)
 
-    dk, dv = pl.pallas_call(
+    dkv_call = pl.pallas_call(
         functools.partial(_dkv_kernel, block_q=block_q, block_k=block_k),
         grid=(BH, L // block_k),
         in_specs=[_full_spec(L, D), _qblock_spec(block_k, D),
@@ -264,8 +272,11 @@ def _flash_bwd(block_q, block_k, interpret, res, do):
         out_shape=[jax.ShapeDtypeStruct((BH, L, D), k.dtype),
                    jax.ShapeDtypeStruct((BH, L, D), v.dtype)],
         interpret=interpret,
+        name="dk_flash_dkv",
         **_parallel_kw(interpret),
-    )(q, k, v, do, lse, delta)
+    )
+    with jax.named_scope("dk_flash_dkv"):
+        dk, dv = dkv_call(q, k, v, do, lse, delta)
     return dq, dk, dv
 
 

@@ -64,7 +64,7 @@ def _folder(codec: str, rows: int, wire_dtype: str, interpret: bool):
     # VMEM budget at compile time on a real chip).
     block = min(rows, _BLOCK_ROWS)
     grid = rows // block
-    return pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_fold_kernel, codec=codec),
         grid=(grid,),
         in_specs=[
@@ -76,8 +76,15 @@ def _folder(codec: str, rows: int, wire_dtype: str, interpret: bool):
         out_specs=pl.BlockSpec((block, _LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.float32),
         interpret=interpret,
+        name="dk_fold_decode",
         **_compiler_kw(interpret),
     )
+
+    def folder(s, c, q):
+        with jax.named_scope("dk_fold_decode"):
+            return call(s, c, q)
+
+    return folder
 
 
 def fold_traced(center, q, s, *, codec: str, interpret: bool | None = None):

@@ -238,7 +238,7 @@ def _make_group_norm(groups: int, relu: bool, interpret: bool):
         B, N, C = x.shape
         x3, g, b, M, npg, fold = _prep(x, gamma, beta)
         Nf, Cf = x3.shape[1], x3.shape[2]
-        y = pl.pallas_call(
+        call = pl.pallas_call(
             functools.partial(_fwd_kernel, n_per_group=npg,
                               relu=relu, out_dtype=x.dtype,
                               nck=_num_chunks(Nf, Cf)),
@@ -252,8 +252,11 @@ def _make_group_norm(groups: int, relu: bool, interpret: bool):
             out_specs=pl.BlockSpec((1, Nf, Cf), lambda i: (i, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((B, Nf, Cf), x.dtype),
             interpret=interpret,
+            name="dk_groupnorm_fwd",
             **_vmem_kw(interpret, parallel=True),
-        )(x3, g, b, M)
+        )
+        with jax.named_scope("dk_groupnorm_fwd"):
+            y = call(x3, g, b, M)
         return y.reshape(B, N, C), (x, gamma, beta)
 
     def _bwd(res, dy):
@@ -261,7 +264,7 @@ def _make_group_norm(groups: int, relu: bool, interpret: bool):
         B, N, C = x.shape
         x3, g, b, M, npg, fold = _prep(x, gamma, beta)
         Nf, Cf = x3.shape[1], x3.shape[2]
-        dx, dg, db = pl.pallas_call(
+        call = pl.pallas_call(
             functools.partial(_bwd_kernel, n_per_group=npg,
                               relu=relu, out_dtype=x.dtype,
                               nck=_num_chunks(Nf, Cf)),
@@ -284,8 +287,11 @@ def _make_group_norm(groups: int, relu: bool, interpret: bool):
                 jax.ShapeDtypeStruct((1, Cf), jnp.float32),
             ],
             interpret=interpret,
+            name="dk_groupnorm_bwd",
             **_vmem_kw(interpret),
-        )(x3, dy.reshape(B, Nf, Cf), g, b, M)
+        )
+        with jax.named_scope("dk_groupnorm_bwd"):
+            dx, dg, db = call(x3, dy.reshape(B, Nf, Cf), g, b, M)
         # Un-fold the per-lane param grads: lane c' is true channel c' % C.
         dg = dg.reshape(fold, C).sum(0)
         db = db.reshape(fold, C).sum(0)

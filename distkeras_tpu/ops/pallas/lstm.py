@@ -188,7 +188,7 @@ def _run_fwd(wx, wh, b, x_tbe, interpret: bool, stash: bool = True):
     stash_specs = [_step_spec(B, H), _step_spec(B, 4 * H)] if stash else []
     stash_shapes = ([jax.ShapeDtypeStruct((T, B, H), dt),
                      jax.ShapeDtypeStruct((T, B, 4 * H), dt)] if stash else [])
-    outs = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_fwd_kernel, T=T, H=H, stash=stash),
         grid=(T,),
         in_specs=[
@@ -205,8 +205,11 @@ def _run_fwd(wx, wh, b, x_tbe, interpret: bool, stash: bool = True):
             jax.ShapeDtypeStruct((B, H), f32),
         ],
         interpret=interpret,
+        name="dk_lstm_fwd",
         **_vmem_kw(interpret),
-    )(x_tbe, wx, wh, b.reshape(1, -1))
+    )
+    with jax.named_scope("dk_lstm_fwd"):
+        outs = call(x_tbe, wx, wh, b.reshape(1, -1))
     if stash:
         hs, cs, gates = outs[0], outs[1], outs[2]
         return hs, cs, gates
@@ -229,7 +232,7 @@ def _lstm_bwd(interpret, res, dhs):
     T, B, E = x_tbe.shape
     H = wh.shape[0]
     f32 = jnp.float32
-    dx, dwx, dwh, db, _dh, _dc = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_bwd_kernel, T=T, H=H),
         grid=(T,),
         in_specs=[
@@ -259,8 +262,12 @@ def _lstm_bwd(interpret, res, dhs):
             jax.ShapeDtypeStruct((B, H), f32),
         ],
         interpret=interpret,
+        name="dk_lstm_bwd",
         **_vmem_kw(interpret),
-    )(dhs, x_tbe, hs, cs, cs, gates, wx, wh)
+    )
+    with jax.named_scope("dk_lstm_bwd"):
+        dx, dwx, dwh, db, _dh, _dc = call(
+            dhs, x_tbe, hs, cs, cs, gates, wx, wh)
     return (dwx.astype(wx.dtype), dwh.astype(wh.dtype),
             db[0].astype(b.dtype), dx)
 

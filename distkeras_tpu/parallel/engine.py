@@ -203,19 +203,23 @@ class AsyncEngine:
             wid = jax.lax.axis_index(DATA_AXIS)
             start = center if disc.pulls_center else local
             worker_rng = fold_rng(rng, wid)
-            new_local, new_opt, mstate, losses = local_loop(
-                start, opt, xs0, ys0, worker_rng, mstate)
+            with jax.named_scope("dk_local_steps"):
+                new_local, new_opt, mstate, losses = local_loop(
+                    start, opt, xs0, ys0, worker_rng, mstate)
             if disc.syncs_state:
-                mstate = lax.pmean(mstate, DATA_AXIS)
-                if seq_manual:
-                    mstate = lax.pmean(mstate, SEQ_AXIS)
+                with jax.named_scope("dk_state_sync"):
+                    mstate = lax.pmean(mstate, DATA_AXIS)
+                    if seq_manual:
+                        mstate = lax.pmean(mstate, SEQ_AXIS)
             # disc.fold = commit + psum + pulls_center + advance: the
             # single-worker reference semantics live in ONE place
             # (disciplines.py); only the m>1 path inlines the vmapped twin.
-            new_center, new_local, new_fold_state = disc.fold(
-                center, new_local, fold_state, axis_name=DATA_AXIS,
-                window=window, num_workers=num_workers)
-            loss = lax.all_gather(jnp.mean(losses), DATA_AXIS)
+            with jax.named_scope("dk_fold"):
+                new_center, new_local, new_fold_state = disc.fold(
+                    center, new_local, fold_state, axis_name=DATA_AXIS,
+                    window=window, num_workers=num_workers)
+            with jax.named_scope("dk_loss_gather"):
+                loss = lax.all_gather(jnp.mean(losses), DATA_AXIS)
             return (new_center,
                     jax.tree.map(lambda a: a[None], new_local),
                     jax.tree.map(lambda a: a[None], new_opt),
@@ -232,34 +236,40 @@ class AsyncEngine:
                 lambda a: jnp.broadcast_to(a, (m,) + a.shape), center)
                 if disc.pulls_center else locals_)
             worker_rngs = jax.vmap(lambda w: jax.random.fold_in(rng, w))(wids)
-            new_local, new_opt, mstate, losses = jax.vmap(local_loop)(
-                start, opt_state, xs, ys, worker_rngs, model_state)
+            with jax.named_scope("dk_local_steps"):
+                new_local, new_opt, mstate, losses = jax.vmap(local_loop)(
+                    start, opt_state, xs, ys, worker_rngs, model_state)
             if disc.syncs_state:
                 # Stats fold: cross-worker mean (running statistics average;
                 # they are not gradient-like deltas). Ensemble members keep
                 # their own stats — each must match its own params.
-                mstate = jax.tree.map(
-                    lambda a: jnp.broadcast_to(
-                        a.mean(axis=0, keepdims=True), a.shape), mstate)
-                mstate = lax.pmean(mstate, DATA_AXIS)
+                with jax.named_scope("dk_state_sync"):
+                    mstate = jax.tree.map(
+                        lambda a: jnp.broadcast_to(
+                            a.mean(axis=0, keepdims=True), a.shape), mstate)
+                    mstate = lax.pmean(mstate, DATA_AXIS)
             if disc.communicates:
-                commits, new_local = jax.vmap(
-                    lambda loc, w: disc.commit(
-                        center, loc, fold_state, worker_id=w, window=window,
-                        num_workers=num_workers))(new_local, wids)
-                total = lax.psum(
-                    jax.tree.map(lambda a: a.sum(axis=0), commits), DATA_AXIS)
-                new_center = jax.tree.map(jnp.add, center, total)
-                if disc.pulls_center:
-                    new_local = jax.tree.map(
-                        lambda a: jnp.broadcast_to(a, (m,) + a.shape),
-                        new_center)
+                with jax.named_scope("dk_fold"):
+                    commits, new_local = jax.vmap(
+                        lambda loc, w: disc.commit(
+                            center, loc, fold_state, worker_id=w,
+                            window=window, num_workers=num_workers))(
+                                new_local, wids)
+                    total = lax.psum(
+                        jax.tree.map(lambda a: a.sum(axis=0), commits),
+                        DATA_AXIS)
+                    new_center = jax.tree.map(jnp.add, center, total)
+                    if disc.pulls_center:
+                        new_local = jax.tree.map(
+                            lambda a: jnp.broadcast_to(a, (m,) + a.shape),
+                            new_center)
             else:
                 new_center = center
             # all_gather gives [chips, m]; worker-major reshape -> [W].
-            loss = lax.all_gather(
-                jnp.mean(losses, axis=tuple(range(1, losses.ndim))),
-                DATA_AXIS).reshape(-1)
+            with jax.named_scope("dk_loss_gather"):
+                loss = lax.all_gather(
+                    jnp.mean(losses, axis=tuple(range(1, losses.ndim))),
+                    DATA_AXIS).reshape(-1)
             return (new_center, new_local, new_opt, mstate,
                     disc.advance(fold_state), loss)
 
@@ -282,14 +292,15 @@ class AsyncEngine:
                 # takes the same branch. Cost when healthy: an isfinite
                 # reduce + one cond select (measured cheaper than per-leaf
                 # where) — below run-to-run noise next to the K-step loop.
-                ok = jnp.all(jnp.isfinite(loss))
-                (new_center, new_local, new_opt, new_model_state,
-                 new_fold_state) = lax.cond(
-                    ok,
-                    lambda: (new_center, new_local, new_opt,
-                             new_model_state, new_fold_state),
-                    lambda: (center, locals_, opt_state, model_state,
-                             fold_state))
+                with jax.named_scope("dk_nan_guard"):
+                    ok = jnp.all(jnp.isfinite(loss))
+                    (new_center, new_local, new_opt, new_model_state,
+                     new_fold_state) = lax.cond(
+                        ok,
+                        lambda: (new_center, new_local, new_opt,
+                                 new_model_state, new_fold_state),
+                        lambda: (center, locals_, opt_state, model_state,
+                                 fold_state))
             model_state = new_model_state
             # Per-worker window-mean losses, all-gathered so the [W] history
             # vector is REPLICATED (fully addressable on every process of a
@@ -700,22 +711,25 @@ def run_rounds(engine, plan, state, start_round, on_round, rounds_per_program):
     return state, losses
 
 
-def _record_feed_waits(engine, feeder) -> None:
-    """Persist the feeder's consumer-side wait times on the engine AND in
-    telemetry: ``input_stall`` is the time the run loop sat blocked on the
-    data plane — the compute-vs-data split every bench round needs."""
-    from distkeras_tpu import telemetry
+def _observe_feed_wait(tele, feeder, r) -> None:
+    """The run loop just popped round ``r``: record how long it sat blocked
+    on the data plane, live, so that a window over the registry
+    (``mark``/``delta``) and the timeline both see the stall with the round
+    it delayed. ``input_stall`` is the compute-vs-data split every bench
+    round needs."""
+    wait = feeder.waits[-1]
+    tele.histogram("input_stall").observe(wait)
+    tele.counter("input_stall_seconds").add(wait)
+    tele.observe_span("feed_wait", wait, id=r)
 
+
+def _record_feed_waits(engine, feeder) -> None:
+    """Keep the feeder's consumer-side wait times on the engine."""
     engine.feed_waits = list(feeder.waits)
     # The running sum, NOT sum(waits): the per-round deque is bounded
     # (prefetch.WAITS_KEEP) and an open-ended stream evicts old entries —
     # the total must keep counting them.
     engine.feed_wait_seconds = float(feeder.wait_seconds)
-    tele = telemetry.get()
-    stall = tele.histogram("input_stall")
-    for w in feeder.waits:
-        stall.observe(w)
-    tele.counter("input_stall_seconds").add(engine.feed_wait_seconds)
 
 
 def run_per_round(engine, plan, state, start_round, on_round):
@@ -732,16 +746,18 @@ def run_per_round(engine, plan, state, start_round, on_round):
                          start_round=start_round)
     try:
         for r, (xs, ys) in feeder:
+            _observe_feed_wait(tele, feeder, r)
             guard.pre_round(r)  # crash/kill fault injection, if scheduled
             # Dispatch span: host-side enqueue only (jax dispatch is async);
             # the first round's entry absorbs compile time.
-            with tele.span("dispatch[per-round]"):
+            with tele.span("dispatch[per-round]", id=r):
                 new_state, loss = engine._round_fn(state, xs, ys)
             # Keep the device value: fetching here would fence every
             # dispatch; convert once at the end.
             losses.append(loss)
             if on_round is not None:
-                on_round(r, loss, new_state)
+                with tele.span("on_round", id=r):
+                    on_round(r, loss, new_state)
             # Divergent-worker reset (no-op — and no fence — unless enabled).
             state = guard.post_round(r, loss, new_state)
     except BaseException:
@@ -816,12 +832,14 @@ def run_stream(engine, items, state=None, on_item=None, start_index=0,
     with tele.span("engine_run"):
         try:
             for i, (xs, ys) in feeder:
+                _observe_feed_wait(tele, feeder, i)
                 guard.pre_round(i)  # crash/kill fault injection
-                with tele.span("dispatch[stream]"):
+                with tele.span("dispatch[stream]", id=i):
                     new_state, loss = engine._round_fn(state, xs, ys)
                 pending.append(loss)
                 if on_item is not None:
-                    on_item(i, loss, new_state)
+                    with tele.span("on_round", id=i):
+                        on_item(i, loss, new_state)
                 state = guard.post_round(i, loss, new_state)
                 if len(pending) >= fetch_every:
                     # Incremental fetch: bounds live device scalars AND is
@@ -1011,18 +1029,20 @@ def run_blocked(engine, plan, state, start_round, on_round, R, mode="blocked"):
     feeder = RoundFeeder(len(starts), stage)
     try:
         for i, (xs, ys) in feeder:
+            # Span ids here are the block's index, as the feeder's are.
+            _observe_feed_wait(tele, feeder, i)
             n = xs.shape[0]
             # Crash/kill faults land at the block boundary containing their
             # round — interior rounds of a compiled program are indivisible.
             for rr in range(starts[i], starts[i] + n):
                 guard.pre_round(rr)
-            with tele.span(dispatch_span):
+            with tele.span(dispatch_span, id=i):
                 new_state, block_losses = engine.multi_round_fn(n)(
                     state, xs, ys)
             if on_round is not None:
                 # The block fence: np.asarray blocks until the whole
                 # dispatched program retires — per-block retire latency.
-                with tele.span(retire_span):
+                with tele.span(retire_span, id=i):
                     host_losses = np.asarray(block_losses)
                 for j in range(n):
                     # Only the block-final call carries state: interior
@@ -1031,7 +1051,8 @@ def run_blocked(engine, plan, state, start_round, on_round, R, mode="blocked"):
                     # would let a checkpoint resume re-apply rounds it
                     # already contains.
                     st = new_state if j == n - 1 else None
-                    on_round(starts[i] + j, host_losses[j], st)
+                    with tele.span("on_round", id=starts[i] + j):
+                        on_round(starts[i] + j, host_losses[j], st)
                 losses.extend(host_losses)
                 state = guard.post_round(starts[i] + n - 1, block_losses[-1],
                                          new_state,

@@ -128,22 +128,25 @@ class SyncEngine:
             # Per-replica dropout stream; the *carried* rng stays replicated (the
             # divergent key never leaves the local loop).
             step_rng = jax.random.fold_in(rng, jax.lax.axis_index(DATA_AXIS))
-            new_params, new_opt, new_model_state, losses = local_loop(
-                params, opt_state, xs0, ys0, step_rng, model_state)
+            with jax.named_scope("dk_local_steps"):
+                new_params, new_opt, new_model_state, losses = local_loop(
+                    params, opt_state, xs0, ys0, step_rng, model_state)
             # Running statistics re-sync: each replica saw its own batch slice;
             # the mean is the canonical cross-replica estimate (params need no
             # such sync — the per-step gradient pmean keeps them identical).
-            new_model_state = lax.pmean(new_model_state, DATA_AXIS)
+            with jax.named_scope("dk_state_sync"):
+                new_model_state = lax.pmean(new_model_state, DATA_AXIS)
             if nan_guard:
                 # Resilience NaN/Inf skip: a non-finite window would leave
                 # every replica's params poisoned through the gradient pmean
                 # — discard the round instead. ``losses`` are the pmean'd
                 # (replicated) per-step losses, so all replicas agree.
-                ok = jnp.all(jnp.isfinite(losses))
-                new_params, new_opt, new_model_state = lax.cond(
-                    ok,
-                    lambda: (new_params, new_opt, new_model_state),
-                    lambda: (params, opt_state, model_state))
+                with jax.named_scope("dk_nan_guard"):
+                    ok = jnp.all(jnp.isfinite(losses))
+                    new_params, new_opt, new_model_state = lax.cond(
+                        ok,
+                        lambda: (new_params, new_opt, new_model_state),
+                        lambda: (params, opt_state, model_state))
             next_rng = jax.random.split(rng, 1)[0]
             return new_params, new_opt, next_rng, new_model_state, losses
 

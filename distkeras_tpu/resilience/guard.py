@@ -79,6 +79,12 @@ class RoundGuard:
         Returns the (possibly replaced) state."""
         if self.divergence_reset is None:
             return state
+        from distkeras_tpu import telemetry
+
+        with telemetry.span("guard", id=round_idx):
+            return self._reset_divergent(round_idx, loss, state, host_loss)
+
+    def _reset_divergent(self, round_idx: int, loss, state, host_loss):
         host = np.asarray(host_loss if host_loss is not None
                           else __import__("jax").device_get(loss))
         host = host.reshape(-1).astype(np.float64)

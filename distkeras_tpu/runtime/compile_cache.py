@@ -8,6 +8,11 @@ and otherwise the cache sits at one fixed, git-ignored place beside the
 package. Entry scripts (``chip_smoke.py``, ``bench.py``, ``accuracy_gate.py``)
 call :func:`ensure_compile_cache` before their first compile; importing the
 package never does.
+
+Importing the package does call :func:`watch_compiles`, which puts what JAX
+itself reports of every compilation (tracing, lowering, the backend's compile
+or the cache's load) into the telemetry registry and onto its timeline, so an
+operator sees which round recompiled.
 """
 
 from __future__ import annotations
@@ -32,3 +37,48 @@ def ensure_compile_cache() -> str:
         path = DEFAULT_CACHE_DIR
         jax.config.update("jax_compilation_cache_dir", path)
     return path
+
+
+#: JAX's duration events -> the span each is recorded as. A cache hit is the
+#: backend's event too (it wraps the lookup), so ``compile.backend`` takes
+#: that event less the load reported just before it on the same thread.
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/core/compile/backend_compile_duration": "compile.backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "compile.cache_load",
+}
+_watching = False
+
+
+def watch_compiles() -> None:
+    """Register, once per process, the one ``jax.monitoring`` listener that
+    records each compilation's phases as flat timeline spans
+    (``compile.trace``, ``compile.lower``, ``compile.backend``,
+    ``compile.cache_load``) and counts ``compile.programs`` and
+    ``compile.cache_hits``. Touches no backend."""
+    global _watching
+    if _watching:
+        return
+    _watching = True
+    import threading
+
+    from distkeras_tpu import telemetry
+
+    loaded = threading.local()  # seconds of cache load not yet taken off
+
+    def on_duration(event: str, seconds: float, **_) -> None:
+        span = _COMPILE_SPANS.get(event)
+        if span is None:
+            return
+        tele = telemetry.get()
+        if span == "compile.cache_load":
+            loaded.seconds = seconds
+            tele.counter("compile.cache_hits").add(1)
+        elif span == "compile.backend":
+            seconds = max(seconds - getattr(loaded, "seconds", 0.0), 0.0)
+            loaded.seconds = 0.0
+            tele.counter("compile.programs").add(1)
+        tele.observe_span(span, seconds, nest=False)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)

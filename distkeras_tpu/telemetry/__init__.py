@@ -61,8 +61,8 @@ __all__ = [
 
 
 # -- module-level shorthands routing to the ambient registry ---------------
-def span(name: str):
-    return get().span(name)
+def span(name: str, id=None):
+    return get().span(name, id)
 
 
 def counter(name: str):

@@ -5,10 +5,10 @@ trainer, engines, data plane, and predictors observe (the reference recorded
 wall-clock only — ``Trainer.record_training_start/stop``; SURVEY.md §5).
 Design constraints, in order:
 
-* **Low overhead.** A span is two ``perf_counter`` calls plus one locked
-  histogram update (~1-2 µs); hot paths (a fold round, a native gather) are
-  hundreds of µs to ms. ``DKTPU_TELEMETRY=0`` swaps in no-op singletons so
-  even that cost vanishes.
+* **Low overhead.** A span is two ``perf_counter_ns`` calls, one locked
+  histogram update and one append to a bounded ring (~1-2 µs); hot paths (a
+  fold round, a native gather) are hundreds of µs to ms.
+  ``DKTPU_TELEMETRY=0`` swaps in no-op singletons so even that cost vanishes.
 * **Thread-safe.** The RoundFeeder stages batches on its own thread and the
   consumer loop observes from the main thread; every metric guards its state
   with one lock. Span nesting is tracked per-thread (``threading.local``).
@@ -19,6 +19,7 @@ Design constraints, in order:
 from __future__ import annotations
 
 import bisect
+import collections
 import re
 import threading
 import time
@@ -36,6 +37,11 @@ BUCKET_BOUNDS = tuple(2.0 ** e for e in range(-20, 7))
 #: MetricsLogger segmentation, the live straggler monitor, and the offline
 #: report must all agree or they silently diverge.
 BURST_EPS_S = 1e-4
+
+#: how many ended spans the registry's timeline ring keeps. A round of the
+#: run loop writes about five; 32,768 hold the last hour of 1 s rounds or
+#: the last ten minutes of 100 ms ones.
+TIMELINE_CAPACITY = 32768
 
 
 class Counter:
@@ -248,27 +254,28 @@ class _SpanContext:
     ``span("dispatch")`` records under ``round`` and ``round/dispatch``.
     """
 
-    __slots__ = ("_tele", "_name", "_t0", "_path")
+    __slots__ = ("_tele", "_name", "_id", "_t0", "_path")
 
-    def __init__(self, tele: "Telemetry", name: str):
+    def __init__(self, tele: "Telemetry", name: str, id=None):
         self._tele = tele
         self._name = name
-        self._t0 = 0.0
+        self._id = id
+        self._t0 = 0
         self._path = name
 
     def __enter__(self) -> "_SpanContext":
         stack = self._tele._span_stack()
         self._path = (stack[-1] + "/" + self._name) if stack else self._name
         stack.append(self._path)
-        self._t0 = time.perf_counter()
+        self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> None:
-        dt = time.perf_counter() - self._t0
+        dur = time.perf_counter_ns() - self._t0
         stack = self._tele._span_stack()
         if stack and stack[-1] == self._path:
             stack.pop()
-        self._tele.histogram(self._path).observe(dt)
+        self._tele._record(self._path, self._t0, dur, self._id)
         return None
 
 
@@ -340,6 +347,15 @@ class Telemetry:
         self._lock = threading.Lock()
         self._local = threading.local()
         self._events: list[dict] = []
+        #: ended spans, oldest first: ``(path, t0_ns, dur_ns, id, thread)``
+        #: with ``t0_ns`` on ``time.perf_counter_ns``. ``deque.append`` is
+        #: atomic, so the run loop's thread and the feeder's share it
+        #: without a lock.
+        self._timeline: collections.deque = collections.deque(
+            maxlen=TIMELINE_CAPACITY)
+        #: one reading of both clocks, so :meth:`timeline` can also say when
+        #: on the wall clock (``time.time_ns``) a span began.
+        self._clock_pair = (time.perf_counter_ns(), time.time_ns())
 
     # -- span nesting ------------------------------------------------------
     def _span_stack(self) -> list:
@@ -348,11 +364,43 @@ class Telemetry:
             stack = self._local.stack = []
         return stack
 
-    def span(self, name: str):
-        """Timed context manager; nested spans record under ``parent/child``."""
+    def span(self, name: str, id=None):
+        """Timed context manager; nested spans record under ``parent/child``.
+        On exit the span goes into its histogram and onto the timeline, which
+        keeps ``id`` with it: the run loops pass the round index, so that one
+        round's spans on their thread and on the feeder's share it."""
         if not self.enabled:
             return _NOOP_SPAN
-        return _SpanContext(self, name)
+        return _SpanContext(self, name, id)
+
+    def observe_span(self, name: str, seconds: float, id=None,
+                     nest: bool = True) -> None:
+        """Record a span that the caller timed itself and that ends now (a
+        wait summed over several polls, a duration a library reports).
+        ``nest=False`` keeps ``name`` flat whatever span is open."""
+        if not self.enabled:
+            return
+        stack = self._span_stack() if nest else None
+        path = (stack[-1] + "/" + name) if stack else name
+        dur = int(seconds * 1e9)
+        self._record(path, time.perf_counter_ns() - dur, dur, id)
+
+    def _record(self, path: str, t0_ns: int, dur_ns: int, id) -> None:
+        self.histogram(path).observe(dur_ns * 1e-9)
+        self._timeline.append(
+            (path, t0_ns, dur_ns, id, threading.current_thread().name))
+
+    def timeline(self, since: Optional[int] = None) -> list[dict]:
+        """The ended spans still in the ring, oldest end first, each as
+        ``{"path", "t0_ns", "dur_ns", "id", "thread", "wall_ns"}``.
+        ``t0_ns`` is on ``time.perf_counter_ns`` and ``wall_ns`` is the same
+        instant on ``time.time_ns``; ``since`` (a ``perf_counter_ns``
+        reading) keeps the spans that began at or after it."""
+        perf0, wall0 = self._clock_pair
+        return [{"path": path, "t0_ns": t0, "dur_ns": dur, "id": id,
+                 "thread": thread, "wall_ns": t0 - perf0 + wall0}
+                for path, t0, dur, id, thread in list(self._timeline)
+                if since is None or t0 >= since]
 
     # -- metric accessors (create-on-first-use) ----------------------------
     def counter(self, name: str) -> Counter:
@@ -475,6 +523,7 @@ class Telemetry:
             self._gauges.clear()
             self._histograms.clear()
             self._events.clear()
+        self._timeline.clear()
 
 
 # -- ambient (process-global) registry ------------------------------------

@@ -70,16 +70,26 @@ _METRICS = [
     _m("retire[stream]", "span", "engine",
        "Streaming retire fence: end-of-run drain."),
     _m("input_stall", "histogram", "engine",
-       "Consumer time blocked on the data plane, per round."),
+       "Consumer time blocked on the data plane, per round (observed live "
+       "as each round is popped)."),
     _m("input_stall_seconds", "counter", "engine",
        "Total consumer seconds blocked on the data plane."),
+    _m("feed_wait", "span", "engine",
+       "The same wait as a timeline span carrying the round's id "
+       "(`engine_run/feed_wait`)."),
+    _m("on_round", "span", "engine",
+       "The trainer's per-round hook: metrics logger, checkpoint save, the "
+       "user's callback."),
+    _m("guard", "span", "engine",
+       "Divergent-worker reset check after a round (only when enabled)."),
     _m("pipeline.dispatch", "span", "engine",
        "Pipeline engine step dispatch latency."),
     _m("stage[tp-local]", "span", "engine",
        "AsyncTP local parameter staging per round."),
     # -- data plane -------------------------------------------------------
-    _m("feeder.stage", "histogram", "data",
-       "Producer-side gather+transform+device_put seconds per round."),
+    _m("feeder.stage", "span", "data",
+       "Producer-side gather+transform+device_put seconds per round, on "
+       "the feeder's thread, with the round's id."),
     _m("feeder.queue_depth", "gauge", "data",
        "Prefetch queue depth at each pop (0 = stalls imminent)."),
     _m("feeder.fill_ratio", "gauge", "data",
@@ -92,6 +102,32 @@ _METRICS = [
        "Bytes moved by the native gather path."),
     _m("native.gather_fallback_calls", "counter", "data",
        "Silent numpy fallbacks (a data-plane regression signal)."),
+    # -- set-up and compilation --------------------------------------------
+    _m("model_build", "span", "setup",
+       "`Model.build`: the module's eager init on the sample input."),
+    _m("setup.build_engine", "span", "setup",
+       "Trainer set-up: constructing the engine (optimizer, round "
+       "function; nothing compiles yet)."),
+    _m("setup.plan", "span", "setup",
+       "Trainer set-up: `make_batches` (the epoch schedule over the "
+       "DataFrame)."),
+    _m("setup.init_state", "span", "setup",
+       "Trainer set-up: `engine.init_state` (host copies of params and "
+       "optimizer state, put on the mesh)."),
+    _m("setup.resume", "span", "setup",
+       "Trainer set-up: restoring the newest usable checkpoint."),
+    _m("compile.trace", "span", "setup",
+       "JAX's own duration of tracing a function to a jaxpr."),
+    _m("compile.lower", "span", "setup",
+       "JAX's own duration of lowering a jaxpr to an MLIR module."),
+    _m("compile.backend", "span", "setup",
+       "JAX's own duration of the backend compile, less a cache load."),
+    _m("compile.cache_load", "span", "setup",
+       "Time to fetch an executable from the persistent compile cache."),
+    _m("compile.programs", "counter", "setup",
+       "Programs that reached the backend (compiled or loaded)."),
+    _m("compile.cache_hits", "counter", "setup",
+       "Programs served from the persistent compile cache."),
     # -- kernels ----------------------------------------------------------
     _m("pallas.interpreted_calls", "counter", "kernels",
        "Pallas kernel calls traced under the interpreter instead of "

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distkeras_tpu import telemetry
 from distkeras_tpu.data.batching import make_batches
 from distkeras_tpu.data.dataframe import DataFrame
 from distkeras_tpu.models.base import Model
@@ -308,7 +309,6 @@ class Trainer:
         whose payload fails to restore or fails its integrity check falls
         back to the previous step. Returns ``(state, start, step_offset)``;
         ``state`` is None when nothing was restorable (fresh start)."""
-        from distkeras_tpu import telemetry
         from distkeras_tpu.checkpoint import resume_candidates
 
         steps = ckpt.steps_desc()
@@ -382,15 +382,17 @@ class Trainer:
             ckpt = Checkpointer(self.checkpoint_dir)
             latest = ckpt.latest_step()
             if self.resume and latest is not None:
-                state, start, step_offset = self._resume_from_checkpoint(
-                    engine, plan, ckpt)
+                with telemetry.span("setup.resume"):
+                    state, start, step_offset = self._resume_from_checkpoint(
+                        engine, plan, ckpt)
             elif latest is not None:
                 # Fresh run (resume=False) into a dir with prior checkpoints:
                 # rounds restart at 0, so without an offset every save would
                 # land at a step Orbax has already seen and be declined.
                 step_offset = latest + 1
         if state is None:
-            state = engine.init_state()
+            with telemetry.span("setup.init_state"):
+                state = engine.init_state()
         if self.metrics_path:
             from distkeras_tpu.metrics import MetricsLogger
             from distkeras_tpu.telemetry.training import DisciplineMonitor
@@ -541,17 +543,19 @@ class SingleTrainer(Trainer):
     def train(self, dataframe: DataFrame, shuffle: bool = False) -> Model:
         self.record_training_start()
         mesh = data_mesh(num_workers=1)
-        engine = SyncEngine(
-            self.model, self.worker_optimizer, self.loss, mesh,
-            learning_rate=self.learning_rate, compute_dtype=self.compute_dtype,
-            seed=self.seed, grad_accum=self.grad_accum,
-            device_transform=self.device_transform,
-        )
-        plan = make_batches(
-            dataframe, self.features_col, self.label_col, self.batch_size,
-            num_workers=1, window=self.steps_per_program, num_epoch=self.num_epoch,
-            shuffle=shuffle, seed=self.seed, transform=self.transform,
-        )
+        with telemetry.span("setup.build_engine"):
+            engine = SyncEngine(
+                self.model, self.worker_optimizer, self.loss, mesh,
+                learning_rate=self.learning_rate, compute_dtype=self.compute_dtype,
+                seed=self.seed, grad_accum=self.grad_accum,
+                device_transform=self.device_transform,
+            )
+        with telemetry.span("setup.plan"):
+            plan = make_batches(
+                dataframe, self.features_col, self.label_col, self.batch_size,
+                num_workers=1, window=self.steps_per_program, num_epoch=self.num_epoch,
+                shuffle=shuffle, seed=self.seed, transform=self.transform,
+            )
         state = self._execute(engine, plan)
         self.record_training_stop()
         return self._finish_model(state.params, state)
@@ -594,17 +598,19 @@ class SynchronousDistributedTrainer(DistributedTrainer):
     def train(self, dataframe: DataFrame, shuffle: bool = False) -> Model:
         self.record_training_start()
         mesh, m = self._mesh()
-        engine = SyncEngine(
-            self.model, self.worker_optimizer, self.loss, mesh,
-            learning_rate=self.learning_rate, compute_dtype=self.compute_dtype,
-            seed=self.seed, grad_accum=self.grad_accum, workers_per_chip=m,
-            device_transform=self.device_transform,
-        )
-        plan = make_batches(
-            dataframe, self.features_col, self.label_col, self.batch_size,
-            num_workers=engine.num_workers, window=self.steps_per_program,
-            num_epoch=self.num_epoch, shuffle=shuffle, seed=self.seed, transform=self.transform,
-        )
+        with telemetry.span("setup.build_engine"):
+            engine = SyncEngine(
+                self.model, self.worker_optimizer, self.loss, mesh,
+                learning_rate=self.learning_rate, compute_dtype=self.compute_dtype,
+                seed=self.seed, grad_accum=self.grad_accum, workers_per_chip=m,
+                device_transform=self.device_transform,
+            )
+        with telemetry.span("setup.plan"):
+            plan = make_batches(
+                dataframe, self.features_col, self.label_col, self.batch_size,
+                num_workers=engine.num_workers, window=self.steps_per_program,
+                num_epoch=self.num_epoch, shuffle=shuffle, seed=self.seed, transform=self.transform,
+            )
         state = self._execute(engine, plan)
         self.record_training_stop()
         return self._finish_model(state.params, state)
@@ -702,24 +708,27 @@ class AsynchronousDistributedTrainer(DistributedTrainer):
 
     def _run(self, dataframe: DataFrame, shuffle: bool):
         if self.parallel:
-            engine = self._tp_engine()
+            with telemetry.span("setup.build_engine"):
+                engine = self._tp_engine()
         else:
             mesh, m = self._mesh()
-            engine = AsyncEngine(
-                self.model, self.worker_optimizer, self.loss,
-                self._discipline(), mesh,
-                window=self.communication_window,
-                learning_rate=self.learning_rate,
-                compute_dtype=self.compute_dtype, seed=self.seed,
-                grad_accum=self.grad_accum, workers_per_chip=m,
-                device_transform=self.device_transform,
-                divergence_reset=self.divergence_reset,
+            with telemetry.span("setup.build_engine"):
+                engine = AsyncEngine(
+                    self.model, self.worker_optimizer, self.loss,
+                    self._discipline(), mesh,
+                    window=self.communication_window,
+                    learning_rate=self.learning_rate,
+                    compute_dtype=self.compute_dtype, seed=self.seed,
+                    grad_accum=self.grad_accum, workers_per_chip=m,
+                    device_transform=self.device_transform,
+                    divergence_reset=self.divergence_reset,
+                )
+        with telemetry.span("setup.plan"):
+            plan = make_batches(
+                dataframe, self.features_col, self.label_col, self.batch_size,
+                num_workers=engine.num_workers, window=self.communication_window,
+                num_epoch=self.num_epoch, shuffle=shuffle, seed=self.seed, transform=self.transform,
             )
-        plan = make_batches(
-            dataframe, self.features_col, self.label_col, self.batch_size,
-            num_workers=engine.num_workers, window=self.communication_window,
-            num_epoch=self.num_epoch, shuffle=shuffle, seed=self.seed, transform=self.transform,
-        )
         return self._execute(engine, plan)
 
     def _remote_endpoint(self) -> Optional[str]:
@@ -741,12 +750,13 @@ class AsynchronousDistributedTrainer(DistributedTrainer):
                 "checkpoint_dir/metrics_path are ignored on this path",
                 stacklevel=2)
         W = self.num_workers or jax.device_count()
-        plan = make_batches(
-            dataframe, self.features_col, self.label_col, self.batch_size,
-            num_workers=W, window=self.communication_window,
-            num_epoch=self.num_epoch, shuffle=shuffle, seed=self.seed,
-            transform=self.transform,
-        )
+        with telemetry.span("setup.plan"):
+            plan = make_batches(
+                dataframe, self.features_col, self.label_col, self.batch_size,
+                num_workers=W, window=self.communication_window,
+                num_epoch=self.num_epoch, shuffle=shuffle, seed=self.seed,
+                transform=self.transform,
+            )
         disc = self._discipline()
         params, losses = run_remote(
             endpoint=endpoint, model=self.model,
@@ -974,7 +984,8 @@ class ParallelTrainer(Trainer):
 
     def train(self, dataframe: DataFrame, shuffle: bool = False) -> Model:
         self.record_training_start()
-        engine = self._build_engine()
+        with telemetry.span("setup.build_engine"):
+            engine = self._build_engine()
         # Multi-process sharded stores plan one "worker" per dp rank so each
         # host stages only its own ranks' rows (the engine merges the
         # rank-major stack back into the global batch — a sharding-preserving
@@ -989,11 +1000,12 @@ class ParallelTrainer(Trainer):
                     f"parallel size {plan_workers} for multi-process sharded "
                     "stores (rows are staged per dp rank)")
             per_worker_batch = self.batch_size // plan_workers
-        plan = make_batches(
-            dataframe, self.features_col, self.label_col, per_worker_batch,
-            num_workers=plan_workers, window=self.steps_per_program,
-            num_epoch=self.num_epoch, shuffle=shuffle, seed=self.seed, transform=self.transform,
-        )
+        with telemetry.span("setup.plan"):
+            plan = make_batches(
+                dataframe, self.features_col, self.label_col, per_worker_batch,
+                num_workers=plan_workers, window=self.steps_per_program,
+                num_epoch=self.num_epoch, shuffle=shuffle, seed=self.seed, transform=self.transform,
+            )
         state = self._execute(engine, plan)
         self.record_training_stop()
         inner = engine.inner
@@ -1028,17 +1040,19 @@ class AveragingTrainer(DistributedTrainer):
         # descend within one loss basin; averaging independently-initialized
         # nets produces a point between basins (verified: accuracy collapses).
         # The reference likewise broadcast one serialized model to executors.
-        engine = AsyncEngine(
-            self.model, self.worker_optimizer, self.loss, EnsembleFold(), mesh,
-            window=self.communication_window, learning_rate=self.learning_rate,
-            compute_dtype=self.compute_dtype, seed=self.seed,
-            grad_accum=self.grad_accum, workers_per_chip=m,
-        )
-        plan = make_batches(
-            dataframe, self.features_col, self.label_col, self.batch_size,
-            num_workers=engine.num_workers, window=self.communication_window,
-            num_epoch=self.num_epoch, shuffle=shuffle, seed=self.seed, transform=self.transform,
-        )
+        with telemetry.span("setup.build_engine"):
+            engine = AsyncEngine(
+                self.model, self.worker_optimizer, self.loss, EnsembleFold(), mesh,
+                window=self.communication_window, learning_rate=self.learning_rate,
+                compute_dtype=self.compute_dtype, seed=self.seed,
+                grad_accum=self.grad_accum, workers_per_chip=m,
+            )
+        with telemetry.span("setup.plan"):
+            plan = make_batches(
+                dataframe, self.features_col, self.label_col, self.batch_size,
+                num_workers=engine.num_workers, window=self.communication_window,
+                num_epoch=self.num_epoch, shuffle=shuffle, seed=self.seed, transform=self.transform,
+            )
         state = self._execute(engine, plan)
         averaged = jax.tree.map(lambda a: jnp.mean(a, axis=0), state.locals_)
         self.record_training_stop()
@@ -1059,17 +1073,19 @@ class EnsembleTrainer(DistributedTrainer):
     def train(self, dataframe: DataFrame, shuffle: bool = False) -> list[Model]:
         self.record_training_start()
         mesh, m = self._mesh()
-        engine = AsyncEngine(
-            self.model, self.worker_optimizer, self.loss, EnsembleFold(), mesh,
-            window=self.communication_window, learning_rate=self.learning_rate,
-            compute_dtype=self.compute_dtype, seed=self.seed, per_worker_init=True,
-            grad_accum=self.grad_accum, workers_per_chip=m,
-        )
-        plan = make_batches(
-            dataframe, self.features_col, self.label_col, self.batch_size,
-            num_workers=engine.num_workers, window=self.communication_window,
-            num_epoch=self.num_epoch, shuffle=shuffle, seed=self.seed, transform=self.transform,
-        )
+        with telemetry.span("setup.build_engine"):
+            engine = AsyncEngine(
+                self.model, self.worker_optimizer, self.loss, EnsembleFold(), mesh,
+                window=self.communication_window, learning_rate=self.learning_rate,
+                compute_dtype=self.compute_dtype, seed=self.seed, per_worker_init=True,
+                grad_accum=self.grad_accum, workers_per_chip=m,
+            )
+        with telemetry.span("setup.plan"):
+            plan = make_batches(
+                dataframe, self.features_col, self.label_col, self.batch_size,
+                num_workers=engine.num_workers, window=self.communication_window,
+                num_epoch=self.num_epoch, shuffle=shuffle, seed=self.seed, transform=self.transform,
+            )
         state = self._execute(engine, plan)
         self.record_training_stop()
         stacked = jax.device_get(state.locals_)

@@ -150,19 +150,25 @@ def make_local_loop(
             inv = 1.0 / grad_accum
             return l_sum * inv, st, jax.tree.map(lambda g: g * inv, g_sum)
 
+        # The scopes name the step's phases in the compiled program's
+        # ``op_name`` metadata (and so in a device trace); they add no op.
         def step(carry, batch):
             p, s, st, key = carry
             x, y = batch
             if input_transform is not None:
                 key, sub, akey = jax.random.split(key, 3)
-                x, y = input_transform(akey, x, y)
+                with jax.named_scope("dk_input_transform"):
+                    x, y = input_transform(akey, x, y)
             else:
                 key, sub = jax.random.split(key)
-            loss, st, grads = grad_of_step(p, st, x, y, sub)
+            with jax.named_scope("dk_fwd_bwd"):
+                loss, st, grads = grad_of_step(p, st, x, y, sub)
             if grad_transform is not None:
-                grads, loss = grad_transform(grads, loss)
-            updates, s = tx.update(grads, s, p)
-            p = optax.apply_updates(p, updates)
+                with jax.named_scope("dk_grad_sync"):
+                    grads, loss = grad_transform(grads, loss)
+            with jax.named_scope("dk_optimizer"):
+                updates, s = tx.update(grads, s, p)
+                p = optax.apply_updates(p, updates)
             return (p, s, st, key), loss
 
         (params, opt_state, state, _), losses = lax.scan(
