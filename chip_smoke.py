@@ -65,7 +65,7 @@ class Sizes:
     tp_layers: int
     cnn_batch: int
     cnn_window: int
-    flash: tuple      # (B, L, H, Dh)
+    flash: tuple      # (B, L, H, Dh), one per sequence length checked
     lstm: tuple       # (B, T, E, H)
     groupnorm: tuple  # (B, H, W, C, groups)
     fold: tuple       # tensor shape
@@ -75,13 +75,14 @@ class Sizes:
 FULL = Sizes(layers=8, d_model=1024, heads=16, d_ff=4096, vocab=32768,
              seq=2048, lm_batch=8, lm_window=8, lm_rounds=4, tp_layers=2,
              cnn_batch=2048, cnn_window=8,
-             flash=(8, 2048, 16, 64), lstm=(2048, 200, 64, 128),
+             flash=((8, 2048, 16, 64), (8, 1024, 16, 64)),  # flagship; gpt2m cell
+             lstm=(2048, 200, 64, 128),
              groupnorm=(128, 112, 112, 64, 32), fold=(8192, 512),
              mlp_rows=8192)
 TOY = Sizes(layers=1, d_model=64, heads=2, d_ff=128, vocab=256,
             seq=128, lm_batch=2, lm_window=2, lm_rounds=3, tp_layers=1,
             cnn_batch=16, cnn_window=2,
-            flash=(1, 128, 2, 16), lstm=(8, 6, 8, 128),
+            flash=((1, 128, 2, 16), (1, 64, 2, 16)), lstm=(8, 6, 8, 128),
             groupnorm=(2, 8, 8, 64, 32), fold=(70, 33),
             mlp_rows=2048)
 
@@ -466,7 +467,9 @@ def check_fold(shape) -> dict:
 
 def phase_kernels(sz: Sizes) -> dict:
     out = {}
-    for name, check, shape in (("flash_attention", check_flash, sz.flash),
+    flash = [(f"flash_attention_L{shape[1]}", check_flash, shape)
+             for shape in sz.flash]
+    for name, check, shape in (*flash,
                                ("lstm_seq", check_lstm, sz.lstm),
                                ("group_norm", check_groupnorm, sz.groupnorm),
                                ("fold", check_fold, sz.fold)):

@@ -40,7 +40,9 @@ def _global_positions(local_len: int, seq_axis: Optional[str]) -> jax.Array:
 
 
 def _flash_block(L: int) -> int:
-    """The flash kernel's q-block for sequence length ``L``. The Mosaic
+    """The flash kernel's grain for sequence length ``L``: every tile edge
+    is a multiple of it, and the kernel sizes its tiles from ``L`` and the
+    head width itself (``flash_attention.default_tiling``). The Mosaic
     kernel needs lane-aligned blocks (L a multiple of 128); the interpreter
     also accepts any single short block. Anything else is an error naming
     ``L`` — never a quiet switch to the O(L^2) dense path."""

@@ -1,30 +1,63 @@
 """Causal FlashAttention as a Pallas TPU kernel (forward + backward).
 
 The transformer's attention is the one op where XLA's default lowering
-materializes an O(L^2) score matrix through HBM. This kernel streams K/V
-chunks through VMEM with the usual online-softmax recurrence, so peak memory
-is O(BLOCK_Q x BLOCK_K) per core and the MXU sees back-to-back matmuls.
-Causality is exploited structurally: a q-block only loops over k-chunks at or
-before its diagonal (half the FLOPs of full attention).
+materializes an O(L^2) score matrix through HBM. These kernels keep K/V of
+one head in VMEM and walk the score matrix tile by tile with the usual
+online-softmax recurrence, so the scores never leave the core.
 
-Performance shape (v5e, d_head 64, measured round 3):
+**The schedule** (:func:`causal_span`, :func:`diagonal_cuts`,
+:func:`tile_schedule`; one copy of the arithmetic, which the kernels' loop
+bounds, the tests and the gauge ``pallas.flash.visited_share`` all read):
 
-* **Asymmetric blocks.** Scores/PV matmuls contract over d_head (64), so a
-  [128, 64]x[64, 128] tile spends more time in staging than in the MXU —
-  symmetric 128-blocks measured 14.7 TFLOPS. A small q-block with a LARGE
-  k-chunk (block_k 1024) turns each inner step into [128,64]x[64,1024] +
-  [128,1024]x[1024,64] and cuts loop trips ~8x.
-* **No revisited output blocks.** lse/delta live as [BH, nq, 1, block_q] —
-  one exact block per program — so every grid dim is declared ``parallel``
-  and Mosaic overlaps fetch/compute across programs. (A revisited [1, 1, L]
-  lse row forced the whole grid sequential in an earlier revision.)
-* bf16 operands, f32 accumulation via ``preferred_element_type`` (the same
-  numerics XLA's own attention lowering uses).
+* square tiles of ``block`` x ``block`` scores. Tiles wholly above the
+  diagonal are never visited; tiles wholly below it run the plain step in a
+  ``fori_loop``; the one tile on the diagonal is cut into ``cut`` x ``cut``
+  squares in straight-line code, of which those above the diagonal are
+  skipped, those below run the plain step and only those on it are masked,
+  by the constant ``row >= col``. Visited share of L^2: (1 + cut / L) / 2.
+* the default ``(block, cut)`` follows L and D (:func:`default_tiling`):
+  up to L = 2048 one tile is the whole sequence — one program a head and no
+  loop at all, every step a static rectangle up to ``cut`` x L that the
+  scheduler can interleave with the next — and beyond that the largest tile
+  up to 1024 that divides L and fits Mosaic's 16 MiB of scoped VMEM beside
+  the resident K and V. ``cut`` is 256, and 512 from L = 2048.
+* an explicit ``block_k`` gives rectangular ``block_size`` x ``block_k``
+  tiles whose straddling tiles are visited and masked whole (the general
+  path; what ran everywhere until PR 27, with a k-chunk of up to 8 q-blocks,
+  which at L <= 1024 was the whole sequence: nothing skipped, all masked).
 
-Layout: inputs are [B, H, L, D] (wrapper transposes from the model's
-[B, L, H, D]). Forward/dq grids are (B*H, L/block_q); the dk+dv kernel's
-grid is (B*H, L/block_k), each program owning one k-chunk. Backward is two
-kernels (dq; dk+dv) using the saved logsumexp, wrapped in ``jax.custom_vjp``.
+**Measured** (TPU v5e, 30 Sep 2026, ``[B, L, H, D] = [8, L, 16, 64]`` bf16,
+each kernel's device time from a profiler trace, us a call; TFLOP/s on the
+products a kernel executes, 2 / 3 / 4 of them for fwd / dq / dk+dv):
+
+====================  =====  =====  =====  =======================
+L = 1024, tiling      fwd    dq     dk+dv  visited
+====================  =====  =====  =====  =======================
+128 x 1024 (before)   820    976    1689   100 %
+128 x 128             1804   1691   2078   56 %  (loop trips)
+256 x 256             877    833    1468   62.5 %
+512 x 512             618    649    780    75 %
+512 x 512, cut 256    622    611    792    62.5 %
+1024 x 1024, cut 512  424    415    598    75 %
+1024 x 1024, cut 256  468    363    694    62.5 %  (the default)
+====================  =====  =====  =====  =======================
+
+At L = 2048: 2286 / 2648 / 5313 before (128 x 1024), 1271 / 1338 / 1887 with
+the default 2048 x 2048, cut 512. What decides is the cost of a step, not the
+operations: a ``fori_loop`` trip is a wall the scheduler cannot move work
+across, a [128, 64] x [64, 128] step leaves the MXU waiting on its own
+latency (128 x 128 runs at 11-19 TFLOP/s, the default at 37-57), and d_head
+64 fills half the MXU's contraction depth whatever the tile.
+
+Kept from earlier rounds: lse/delta live as [BH, nq, 1, block_q], one exact
+block per program, so no output block is revisited and every grid dim is
+``parallel``; bf16 operands with f32 accumulation (``preferred_element_type``),
+f32 softmax statistics and ``exp``.
+
+Layout: inputs are [B, H, L, D] (the wrapper transposes from the model's
+[B, L, H, D]). Forward/dq grids are (B*H, L/block); the dk+dv kernel's grid
+is the same, each program owning one block of keys. Backward is two kernels
+(dq; dk+dv) using the saved logsumexp, wrapped in ``jax.custom_vjp``.
 
 ``interpret=True`` runs the same kernels through the Pallas interpreter —
 that is what CI exercises on the CPU mesh; the compiled path runs on TPU.
@@ -42,6 +75,7 @@ from jax.experimental.pallas import tpu as pltpu
 from distkeras_tpu.ops.pallas import mode
 
 _NEG = -1e30
+_VMEM_BYTES = 16 * 2 ** 20  # Mosaic's scoped limit for one kernel on a v5e
 
 
 def _qblock_spec(block, D):
@@ -76,35 +110,106 @@ def _parallel_kw(interpret: bool, dims: int = 2) -> dict:
         dimension_semantics=("parallel",) * dims)}
 
 
-def _causal_mask(bq, bk, q0, k0):
-    row = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return (q0 + row) >= (k0 + col)
+def causal_span(i, own: int, other: int):
+    """Tiles of width ``other`` that block ``i`` of width ``own`` overlaps,
+    as ``(lo, hi)`` — the arithmetic of the causal schedule, once.
+
+    The program that owns q-block ``i`` (forward, dq: ``own`` = block_q,
+    ``other`` = block_k) walks k-tiles: ``[0, lo)`` lie wholly at or below the
+    diagonal and need no mask, ``[lo, hi)`` straddle it, ``[hi, ...)`` lie
+    wholly above it and are never visited. The program that owns k-block ``i``
+    (dk/dv: ``own`` = block_k, ``other`` = block_q) walks q-tiles: ``[0, lo)``
+    are never visited, ``[lo, hi)`` straddle, ``[hi, nq)`` need no mask. ``i``
+    is a Python int (:func:`tile_schedule`) or a traced program id (the
+    kernels' loop bounds)."""
+    return (i * own) // other, ((i + 1) * own + other - 1) // other
+
+
+def diagonal_cuts(tile: int, cut: int, by_rows: bool) -> list:
+    """The lower triangle of a square ``tile`` on the diagonal, as one ``(b0,
+    b1, rectangles)`` per block ``[b0, b1)`` of ``cut`` rows (forward and dq
+    own rows) or columns (dk/dv owns columns). A rectangle is ``(r0, r1, c0,
+    c1, masked)`` relative to the tile's corner: a block of rows has its part
+    wholly below the diagonal, then its masked ``cut x cut`` square; a block
+    of columns its square, then the part below it. The squares above the
+    diagonal are in no list."""
+    blocks = []
+    for b0 in range(0, tile, cut):
+        b1 = b0 + cut
+        square = (b0, b1, b0, b1, True)
+        if by_rows:
+            rects = [(b0, b1, 0, b0, False)] * (b0 > 0) + [square]
+        else:
+            rects = [square] + [(b1, tile, b0, b1, False)] * (b1 < tile)
+        blocks.append((b0, b1, rects))
+    return blocks
+
+
+def tile_schedule(L: int, block_q: int, block_k: int, cut=None) -> dict:
+    """What each kernel visits at ``(L, block_q, block_k, cut)``: per kernel
+    a list of rectangles ``(q0, q1, k0, k1, masked)`` of the score matrix,
+    from :func:`causal_span` and :func:`diagonal_cuts` exactly as the kernel
+    walks. Static, so it is known as the kernel is traced: the tests prove
+    coverage on it and ``pallas.flash.visited_share`` reads it. ``cut`` is the
+    grain of a square tiling's diagonal tiles; without it a tile that
+    straddles the diagonal is visited, and masked, whole."""
+
+    def tiles(qi, ki, straddles, by_rows):
+        q0, k0 = qi * block_q, ki * block_k
+        if not straddles or cut is None:
+            return [(q0, q0 + block_q, k0, k0 + block_k, straddles)]
+        return [(q0 + r0, q0 + r1, k0 + c0, k0 + c1, m)
+                for _, _, rects in diagonal_cuts(block_q, cut, by_rows)
+                for r0, r1, c0, c1, m in rects]
+
+    nq, nk = L // block_q, L // block_k
+    by_q, by_k = [], []
+    for qi in range(nq):
+        lo, hi = causal_span(qi, block_q, block_k)
+        for ki in range(hi):
+            by_q += tiles(qi, ki, ki >= lo, True)
+    for ki in range(nk):
+        lo, hi = causal_span(ki, block_k, block_q)
+        for qi in range(lo, nq):
+            by_k += tiles(qi, ki, qi < hi, False)
+    return {"dk_flash_fwd": by_q, "dk_flash_dq": by_q, "dk_flash_dkv": by_k}
+
+
+def visited_share(L: int, block_q: int, block_k: int, cut=None) -> float:
+    """Score elements a kernel visits over the ``L**2`` it would without the
+    causal skip (the three kernels visit the same ones, in another order).
+    1.0: the skip is not engaging."""
+    tiles = tile_schedule(L, block_q, block_k, cut)["dk_flash_fwd"]
+    return sum((q1 - q0) * (k1 - k0) for q0, q1, k0, k1, _ in tiles) / (L * L)
+
+
+def _keep(rows: int, cols: int, shift):
+    """Causal mask of a ``rows x cols`` tile whose corner lies ``shift``
+    columns right of the diagonal: True where row >= column."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    return row - col >= shift
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
-                block_k: int):
+                block_k: int, cut):
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.bfloat16)  # [BQ, D]
     BQ, D = q.shape
 
-    m0 = jnp.full((BQ, 1), _NEG, jnp.float32)
-    l0 = jnp.zeros((BQ, 1), jnp.float32)
-    acc0 = jnp.zeros((BQ, D), jnp.float32)
-
-    def step(ki, carry, masked: bool):
+    def attend(q, k0, width, carry, keep):
+        """One online-softmax step of rows ``q`` over keys [k0, k0 + width)."""
         m, l, acc = carry
-        kb = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.bfloat16)
-        vb = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.bfloat16)
+        kb = k_ref[0, pl.ds(k0, width), :].astype(jnp.bfloat16)
+        vb = v_ref[0, pl.ds(k0, width), :].astype(jnp.bfloat16)
         s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        if masked:
-            mask = _causal_mask(BQ, block_k, qi * block_q, ki * block_k)
-            s = jnp.where(mask, s, _NEG)
+        if keep is not None:
+            # A row's first step always keeps a column (column 0, or its own
+            # diagonal), so m is finite and exp() of a masked score is 0.0.
+            s = jnp.where(keep, s, _NEG)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
-        if masked:
-            p = jnp.where(mask, p, 0.0)
         corr = jnp.exp(m - m_new)
         l = l * corr + p.sum(axis=-1, keepdims=True)
         acc = acc * corr + jax.lax.dot_general(
@@ -112,22 +217,35 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
             preferred_element_type=jnp.float32)
         return m_new, l, acc
 
-    # Two phases: k-chunks entirely at/below the diagonal need no mask (and
-    # no iota/select VPU work — the fwd loop is VPU-bound, not MXU-bound);
-    # only the chunk(s) straddling the diagonal mask. Chunks strictly after
-    # the diagonal contribute nothing and are never visited.
-    nfull = (qi * block_q) // block_k
-    nk = (qi * block_q + block_q + block_k - 1) // block_k
+    def finish(r0, r1, carry):
+        m, l, acc = carry
+        o_ref[0, r0:r1, :] = (acc / l).astype(o_ref.dtype)
+        lse_ref[0, 0, 0, r0:r1] = (m + jnp.log(l))[:, 0]
+
+    lo, hi = causal_span(qi, block_q, block_k)
     carry = jax.lax.fori_loop(
-        0, nfull, lambda ki, c: step(ki, c, masked=False), (m0, l0, acc0))
-    m, l, acc = jax.lax.fori_loop(
-        nfull, nk, lambda ki, c: step(ki, c, masked=True), carry)
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    lse_ref[0, 0, 0] = (m + jnp.log(l))[:, 0]
+        0, lo, lambda ki, c: attend(q, ki * block_k, block_k, c, None),
+        (jnp.full((BQ, 1), _NEG, jnp.float32), jnp.zeros((BQ, 1), jnp.float32),
+         jnp.zeros((BQ, D), jnp.float32)))
+    if cut is None:
+        finish(0, BQ, jax.lax.fori_loop(lo, hi, lambda ki, c: attend(
+            q, ki * block_k, block_k, c,
+            _keep(BQ, block_k, ki * block_k - qi * block_q)), carry))
+        return
+    # Square tiles: the one tile on the diagonal, cut so that its squares
+    # above the diagonal are skipped too and only `cut`-squares are masked,
+    # by a constant. No loop: each block of rows is its own chain of steps.
+    keep = _keep(cut, cut, 0)
+    for b0, b1, rects in diagonal_cuts(BQ, cut, True):
+        sub = tuple(x[b0:b1] for x in carry)
+        for _, _, c0, c1, masked in rects:
+            sub = attend(q[b0:b1], qi * block_k + c0, c1 - c0, sub,
+                         keep if masked else None)
+        finish(b0, b1, sub)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-               block_q: int, block_k: int):
+               block_q: int, block_k: int, cut):
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.bfloat16)
     do = do_ref[0].astype(jnp.bfloat16)
@@ -135,79 +253,114 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
     delta = delta_ref[0, 0, 0][:, None]
     BQ, D = q.shape
 
-    def step(ki, dq, masked: bool):
-        kb = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.bfloat16)
-        vb = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.bfloat16)
-        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+    def grad(rows, k0, width, dq, keep):
+        """dq of the q-rows ``rows`` from keys [k0, k0 + width)."""
+        kb = k_ref[0, pl.ds(k0, width), :].astype(jnp.bfloat16)
+        vb = v_ref[0, pl.ds(k0, width), :].astype(jnp.bfloat16)
+        s = jax.lax.dot_general(q[rows], kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        p = jnp.exp(s - lse)
-        if masked:
-            mask = _causal_mask(BQ, block_k, qi * block_q, ki * block_k)
-            p = jnp.where(mask, p, 0.0)
-        dp = jax.lax.dot_general(do, vb, (((1,), (1,)), ((), ())),
+        p = jnp.exp(s - lse[rows])
+        if keep is not None:
+            p = jnp.where(keep, p, 0.0)
+        dp = jax.lax.dot_general(do[rows], vb, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(jnp.bfloat16)
+        ds = (p * (dp - delta[rows])).astype(jnp.bfloat16)
         return dq + jax.lax.dot_general(ds, kb, (((1,), (0,)), ((), ())),
                                         preferred_element_type=jnp.float32)
 
-    nfull = (qi * block_q) // block_k
-    nk = (qi * block_q + block_q + block_k - 1) // block_k
-    dq = jax.lax.fori_loop(0, nfull, lambda ki, a: step(ki, a, masked=False),
-                           jnp.zeros((BQ, D), jnp.float32))
-    dq = jax.lax.fori_loop(nfull, nk, lambda ki, a: step(ki, a, masked=True),
-                           dq)
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    whole = slice(0, BQ)
+    lo, hi = causal_span(qi, block_q, block_k)
+    dq = jax.lax.fori_loop(
+        0, lo, lambda ki, a: grad(whole, ki * block_k, block_k, a, None),
+        jnp.zeros((BQ, D), jnp.float32))
+    if cut is None:
+        dq = jax.lax.fori_loop(lo, hi, lambda ki, a: grad(
+            whole, ki * block_k, block_k, a,
+            _keep(BQ, block_k, ki * block_k - qi * block_q)), dq)
+        dq_ref[0] = dq.astype(dq_ref.dtype)
+        return
+    keep = _keep(cut, cut, 0)
+    for b0, b1, rects in diagonal_cuts(BQ, cut, True):
+        sub = dq[b0:b1]
+        for _, _, c0, c1, masked in rects:
+            sub = grad(slice(b0, b1), qi * block_k + c0, c1 - c0, sub,
+                       keep if masked else None)
+        dq_ref[0, b0:b1, :] = sub.astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-                dv_ref, *, block_q: int, block_k: int):
+                dv_ref, *, block_q: int, block_k: int, cut):
     ki = pl.program_id(1)
     kb = k_ref[0].astype(jnp.bfloat16)  # [BK, D] (this program's k chunk)
     vb = v_ref[0].astype(jnp.bfloat16)
     BK, D = kb.shape
     nq = q_ref.shape[1] // block_q
 
-    def step(qi, carry, masked: bool):
+    def grad(qi, r0, r1, cols, carry, keep):
+        """dk, dv of this program's keys ``cols`` from rows [r0, r1) of
+        q-tile ``qi``."""
         dk, dv = carry
-        q = q_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.bfloat16)
-        do = do_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.bfloat16)
-        lse = lse_ref[0, qi, 0, :][:, None]
-        delta = delta_ref[0, qi, 0, :][:, None]
-        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+        at = pl.ds(qi * block_q + r0, r1 - r0)
+        q = q_ref[0, at, :].astype(jnp.bfloat16)
+        do = do_ref[0, at, :].astype(jnp.bfloat16)
+        lse = lse_ref[0, qi, 0, r0:r1][:, None]
+        delta = delta_ref[0, qi, 0, r0:r1][:, None]
+        s = jax.lax.dot_general(q, kb[cols], (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         p = jnp.exp(s - lse)  # [Q, K]
-        if masked:
-            mask = _causal_mask(block_q, BK, qi * block_q, ki * block_k)
-            p = jnp.where(mask, p, 0.0)
+        if keep is not None:
+            p = jnp.where(keep, p, 0.0)
         pb = p.astype(jnp.bfloat16)
         dv = dv + jax.lax.dot_general(pb, do, (((0,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, vb, (((1,), (1,)), ((), ())),
+        dp = jax.lax.dot_general(do, vb[cols], (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = (p * (dp - delta)).astype(jnp.bfloat16)
         dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
         return dk, dv
 
-    # q-blocks strictly before this k-chunk contribute nothing; blocks
-    # straddling the diagonal mask; blocks fully past it don't need to.
+    def finish(c0, c1, carry):
+        dk_ref[0, c0:c1, :] = carry[0].astype(dk_ref.dtype)
+        dv_ref[0, c0:c1, :] = carry[1].astype(dv_ref.dtype)
+
+    whole = slice(0, BK)
     zero = jnp.zeros((BK, D), jnp.float32)
-    qstart = ki * block_k // block_q
-    qfull = (ki * block_k + BK + block_q - 1) // block_q
+    lo, hi = causal_span(ki, block_k, block_q)
     carry = jax.lax.fori_loop(
-        qstart, qfull, lambda qi, c: step(qi, c, masked=True), (zero, zero))
-    dk, dv = jax.lax.fori_loop(
-        qfull, nq, lambda qi, c: step(qi, c, masked=False), carry)
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+        hi, nq, lambda qi, c: grad(qi, 0, block_q, whole, c, None),
+        (zero, zero))
+    if cut is None:
+        finish(0, BK, jax.lax.fori_loop(lo, hi, lambda qi, c: grad(
+            qi, 0, block_q, whole, c,
+            _keep(block_q, BK, ki * block_k - qi * block_q)), carry))
+        return
+    keep = _keep(cut, cut, 0)
+    for b0, b1, rects in diagonal_cuts(BK, cut, False):
+        sub = tuple(x[b0:b1] for x in carry)
+        for r0, r1, _, _, masked in rects:
+            sub = grad(ki, r0, r1, slice(b0, b1), sub,
+                       keep if masked else None)
+        finish(b0, b1, sub)
 
 
-def _flash_bhld(q, k, v, block_q, block_k, interpret):
+def _traced_once(fn):
+    """A model calls these wrappers once a layer and pass with one shape, and
+    a kernel body of static cuts is some hundred ops to trace: jit caches the
+    trace, and ``inline`` replays it into the caller's jaxpr under the
+    caller's name stack, so the program and its ``op_name``s stay the same."""
+    return jax.jit(fn, static_argnames=("block_q", "block_k", "cut",
+                                        "interpret"), inline=True)
+
+
+@_traced_once
+def _flash_bhld(q, k, v, block_q, block_k, cut, interpret):
     """Forward on [BH, L, D] inputs; returns (out, lse [BH, nq, 1, block_q])."""
     BH, L, D = q.shape
     grid = (BH, L // block_q)
     call = pl.pallas_call(
-        functools.partial(_fwd_kernel, block_q=block_q, block_k=block_k),
+        functools.partial(_fwd_kernel, block_q=block_q, block_k=block_k,
+                          cut=cut),
         grid=grid,
         in_specs=[_qblock_spec(block_q, D), _full_spec(L, D), _full_spec(L, D)],
         out_specs=[
@@ -229,26 +382,31 @@ def _flash_bhld(q, k, v, block_q, block_k, interpret):
     return out, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, block_q, block_k, interpret):
-    out, _ = _flash_bhld(q, k, v, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, block_q, block_k, cut, interpret):
+    out, _ = _flash_bhld(q, k, v, block_q, block_k, cut, interpret)
     return out
 
 
-def _flash_fwd(q, k, v, block_q, block_k, interpret):
-    out, lse = _flash_bhld(q, k, v, block_q, block_k, interpret)
+def _flash_fwd(q, k, v, block_q, block_k, cut, interpret):
+    out, lse = _flash_bhld(q, k, v, block_q, block_k, cut, interpret)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(block_q, block_k, interpret, res, do):
-    q, k, v, out, lse = res
+def _flash_bwd(block_q, block_k, cut, interpret, res, do):
+    return _flash_grads(*res, do, block_q, block_k, cut, interpret)
+
+
+@_traced_once
+def _flash_grads(q, k, v, out, lse, do, block_q, block_k, cut, interpret):
     BH, L, D = q.shape
     nq = L // block_q
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(BH, nq, 1, block_q)
 
     dq_call = pl.pallas_call(
-        functools.partial(_dq_kernel, block_q=block_q, block_k=block_k),
+        functools.partial(_dq_kernel, block_q=block_q, block_k=block_k,
+                          cut=cut),
         grid=(BH, nq),
         in_specs=[_qblock_spec(block_q, D), _full_spec(L, D), _full_spec(L, D),
                   _qblock_spec(block_q, D), _rowblock_spec(block_q),
@@ -263,7 +421,8 @@ def _flash_bwd(block_q, block_k, interpret, res, do):
         dq = dq_call(q, k, v, do, lse, delta)
 
     dkv_call = pl.pallas_call(
-        functools.partial(_dkv_kernel, block_q=block_q, block_k=block_k),
+        functools.partial(_dkv_kernel, block_q=block_q, block_k=block_k,
+                          cut=cut),
         grid=(BH, L // block_k),
         in_specs=[_full_spec(L, D), _qblock_spec(block_k, D),
                   _qblock_spec(block_k, D), _full_spec(L, D),
@@ -283,35 +442,61 @@ def _flash_bwd(block_q, block_k, interpret, res, do):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def default_tiling(L: int, D: int, block_size: int = 128):
+    """``(block_q, block_k, cut)`` for sequences of ``L`` and heads of ``D``,
+    every edge a multiple of ``block_size`` that divides ``L`` (the module
+    doc has the measurements). Up to 16 blocks the tile is the sequence: no
+    loop, only the diagonal's static cuts. Longer, the largest tile up to 8
+    blocks whose score temporaries (some 10 bytes an element) fit the scoped
+    VMEM beside K and V. ``cut`` is a quarter of L between 2 and 4 blocks,
+    and at most half the tile."""
+    units = L // block_size
+    if units <= 16:
+        tile = units
+    else:
+        resident = 4 * L * max(D, 128) * 2  # K and V whole, double-buffered
+        tile = max(m for m in range(1, 9) if units % m == 0 and (
+            m == 1 or resident + 10 * (m * block_size) ** 2 <= _VMEM_BYTES))
+    want = min(4, max(2, units // 4))
+    cut = max(m for m in range(1, want + 1)
+              if tile % m == 0 and (2 * m <= tile or m == 1))
+    return tile * block_size, tile * block_size, cut * block_size
+
+
 def flash_attention(q, k, v, block_size: int = 128, block_k: int | None = None,
                     interpret: bool | None = None):
     """Causal FlashAttention. ``q, k, v``: [B, L, H, D], q pre-scaled by
-    1/sqrt(D). Returns [B, L, H, D]. ``block_size`` is the q-block;
-    ``block_k`` is the inner k-chunk — by default the largest multiple of
-    ``block_size`` up to ``8*block_size`` that divides ``L`` (e.g. L=1280,
-    block 128 -> 640, not 1024). Large k-chunks keep the MXU busy when
-    d_head is small (see module doc). ``L`` must be divisible by both.
-    ``interpret=None`` compiles on TPU and interprets elsewhere
+    1/sqrt(D). Returns [B, L, H, D]. ``block_size`` is the grain: ``L`` must
+    be a multiple of it (128 on a TPU: the lane width). With ``block_k=None``
+    the tiling follows ``L`` and ``D`` (:func:`default_tiling`: square tiles
+    of up to the whole sequence, the diagonal tile cut at 2-4 grains;
+    1024 x 1024 cut at 256 for L = 1024, which visits 62.5 % of the scores).
+    An explicit ``block_k`` asks for ``block_size`` x ``block_k`` tiles as
+    they are, straddling tiles masked whole. The share of L^2 the schedule
+    visits is set on the gauge ``pallas.flash.visited_share`` as the call is
+    traced. ``interpret=None`` compiles on TPU and interprets elsewhere
     (:mod:`distkeras_tpu.ops.pallas.mode`).
     """
     interpret = mode.interpret("flash_attention", interpret)
     B, L, H, D = q.shape
-    if block_k is None:
-        # Largest multiple of block_size that divides L, capped at 8x — so
-        # every L the q-block accepts (L % block_size == 0) keeps working
-        # (L=1280/1536/... are not multiples of a fixed 1024 chunk).
-        block_k = block_size
-        for mult in range(2, 9):
-            if L % (block_size * mult) == 0:
-                block_k = block_size * mult
-    if L % block_size != 0 or L % block_k != 0:
+    if L % block_size != 0 or (block_k is not None and L % block_k != 0):
         raise ValueError(
             f"seq_len {L} not divisible by block_q {block_size} / "
             f"block_k {block_k}")
+    if block_k is None:
+        block_q, block_k, cut = default_tiling(L, D, block_size)
+    else:
+        # A square tiling masks its diagonal tile by a constant (cut = tile).
+        block_q = block_size
+        cut = block_size if block_k == block_size else None
+    from distkeras_tpu import telemetry
+
+    telemetry.gauge("pallas.flash.visited_share").set(
+        visited_share(L, block_q, block_k, cut))
 
     def to_bhld(x):
         return x.transpose(0, 2, 1, 3).reshape(B * H, L, D)
 
-    out = _flash(to_bhld(q), to_bhld(k), to_bhld(v), block_size, block_k,
+    out = _flash(to_bhld(q), to_bhld(k), to_bhld(v), block_q, block_k, cut,
                  interpret)
     return out.reshape(B, H, L, D).transpose(0, 2, 1, 3)

@@ -132,6 +132,10 @@ _METRICS = [
     _m("pallas.interpreted_calls", "counter", "kernels",
        "Pallas kernel calls traced under the interpreter instead of "
        "compiled by Mosaic (0 on a TPU; `ops/pallas/mode.py`)."),
+    _m("pallas.flash.visited_share", "gauge", "kernels",
+       "Score elements the flash kernels' causal schedule visits over L^2, "
+       "set as a call is traced (1.0: nothing is skipped; "
+       "`flash_attention.tile_schedule`)."),
     # -- inference --------------------------------------------------------
     _m("predict.chunk", "span", "inference",
        "Per-chunk end-to-end predict latency."),

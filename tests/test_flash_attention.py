@@ -9,10 +9,15 @@ floor rather than f32 exactness.
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from distkeras_tpu import telemetry
 from distkeras_tpu.models import small_transformer_lm
 from distkeras_tpu.models.transformer import TransformerLM
 from distkeras_tpu.ops.pallas import flash_attention
+from distkeras_tpu.ops.pallas.flash_attention import (default_tiling,
+                                                      tile_schedule,
+                                                      visited_share)
 
 B, L, H, D = 2, 64, 2, 16
 BLOCK = 16
@@ -100,3 +105,72 @@ def test_transformer_flash_impl_matches_dense():
     out_flash = flash_module.apply({"params": dense_model.params}, tokens, train=False)
     np.testing.assert_allclose(np.asarray(out_flash), np.asarray(out_dense),
                                atol=5e-2)
+
+
+def _old_tiling(L):
+    """What the kernels ran before the schedule followed L: q-block 128 and
+    the largest k-chunk up to 8 blocks that divides L (1024 at L = 1024)."""
+    return 128, max(128 * m for m in range(1, 9) if L % (128 * m) == 0), None
+
+
+@pytest.mark.parametrize("tiling_of", [lambda L: default_tiling(L, 64),
+                                       _old_tiling],
+                         ids=["default", "old"])
+@pytest.mark.parametrize("L", [128, 256, 640, 1024, 1280, 2048])
+def test_schedule_covers_the_causal_triangle_once(L, tiling_of):
+    """Per kernel: every (q, k) with k <= q lies in exactly one visited tile,
+    no visited tile lies wholly above the diagonal, and every tile that
+    straddles it is marked masked."""
+    tiling = tiling_of(L)
+    tril = np.tril(np.ones((L, L), bool))
+    for name, tiles in tile_schedule(L, *tiling).items():
+        hits = np.zeros((L, L), np.int8)
+        for q0, q1, k0, k1, masked in tiles:
+            hits[q0:q1, k0:k1] += 1
+            inside = tril[q0:q1, k0:k1]
+            assert inside.any(), f"{name}: tile at {(q0, k0)} is all above"
+            assert masked or inside.all(), \
+                f"{name}: tile at {(q0, k0)} straddles the diagonal unmasked"
+        assert (hits[tril] == 1).all(), f"{name}: a causal pair missed/twice"
+        assert hits.max() == 1
+    if L == 1024:  # the old tiling is what the cell ran before: no skip
+        share = visited_share(L, *tiling)
+        assert (share == 1.0) if tiling_of is _old_tiling else (share <= 0.65)
+
+
+@pytest.mark.parametrize("L,block", [(1024, 128), (640, 128)],
+                         ids=["cell-1024", "five-blocks-640"])
+def test_default_tiling_values_and_grads_match_dense(L, block):
+    """The tiling the benchmark's cell runs (default blocks at L = 1024), and
+    a length that is not a multiple of 8 blocks: out, dq, dk and dv."""
+    rng = np.random.default_rng(L)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, L, 1, 16)), jnp.float32)
+               for _ in range(3))
+    q = q * 0.25
+
+    def run(fn):
+        out, vjp = jax.vjp(fn, q, k, v)
+        return (out, *vjp(2.0 * out))
+
+    got = run(lambda q, k, v: flash_attention(q, k, v, block_size=block,
+                                              interpret=True))
+    ref = run(dense_causal)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]),
+                               atol=5e-2)
+    for a, b, name in zip(got[1:], ref[1:], "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=0.35,
+                                   rtol=0.02, err_msg=f"d{name} mismatch")
+
+
+def test_visited_share_gauge_reads_the_schedule():
+    """`pallas.flash.visited_share` is set as the call is traced, from the
+    same schedule the kernels' loop bounds come from."""
+    q, k, v = _inputs(5)
+    jax.jit(lambda q, k, v: flash_attention(q, k, v, block_size=BLOCK,
+                                            interpret=True)).lower(q, k, v)
+    want = visited_share(L, *default_tiling(L, D, BLOCK))
+    assert telemetry.gauge("pallas.flash.visited_share").value == want
+    assert 0.5 < want < 1.0
+    jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, block_size=BLOCK, block_k=L, interpret=True)).lower(q, k, v)
+    assert telemetry.gauge("pallas.flash.visited_share").value == 1.0
