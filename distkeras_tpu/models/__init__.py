@@ -10,6 +10,7 @@ from distkeras_tpu.models.cnn import SimpleCNN, mnist_cnn, cifar10_cnn  # noqa: 
 from distkeras_tpu.models.lstm import LSTMClassifier, imdb_lstm  # noqa: F401
 from distkeras_tpu.models.resnet import ResNet, resnet50  # noqa: F401
 from distkeras_tpu.models.transformer import TransformerLM, small_transformer_lm  # noqa: F401
+from distkeras_tpu.models.smallthinker import SmallThinkerLM, small_smallthinker_lm  # noqa: F401
 
 __all__ = [
     "DKModule",
@@ -26,4 +27,6 @@ __all__ = [
     "resnet50",
     "TransformerLM",
     "small_transformer_lm",
+    "SmallThinkerLM",
+    "small_smallthinker_lm",
 ]

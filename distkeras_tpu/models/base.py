@@ -23,6 +23,17 @@ from distkeras_tpu.runtime.serialization import (
 )
 
 
+#: A mutable collection of float32 counts that a module adds to at every step
+#: (``self.variable(ROUND_COUNTERS, ...)``): what a round routed where. It
+#: rides the state path like BatchNorm's statistics, with two differences:
+#: ``workers.make_local_loop`` zeroes it as a round begins, so that it leaves
+#: the round program holding that round's sums, and the run loop
+#: (``parallel/engine.py::run_per_round``) copies it out beside the loss and
+#: hands it, a round later and as numpy, to the module's
+#: ``publish_round_counters(round, counters)``, which owns the telemetry names.
+ROUND_COUNTERS = "round_counters"
+
+
 def _coerce(v):
     # JSON round-trips tuples as lists; flax module fields want tuples back.
     return tuple(_coerce(x) for x in v) if isinstance(v, list) else v

@@ -1,0 +1,285 @@
+"""Blocks of today's open decoders, for the modules that are built from them
+(``models/smallthinker.py``): RMSNorm, rotary positions by the half-split
+rule, grouped-query attention without biases under a full or a sliding-window
+causal mask, and a dropless mixture of gated experts that holds a share of the
+experts it routes over. ``TransformerLM`` and ``MoETransformerLM`` share none
+of these parts (LayerNorm, learned positions, biases, GELU, capacity).
+
+**The expert layer** (:class:`DroplessExperts`) is the layer expert
+parallelism needs: it is told which experts it holds (``first``, ``held``),
+takes the routing over all of them, and computes its own experts' part of the
+result. The assignments to held experts are sorted by expert (a stable sort;
+the others sort behind them), their tokens' rows gathered into one buffer,
+multiplied group by group (``jax.lax.ragged_dot``: on a TPU one grouped
+Mosaic matmul a product, which visits the tiles that hold rows and no
+others), weighted and summed back per token. Nothing is dropped: the buffer
+has a row for every assignment a step can produce, ``tokens x k``, because
+any token may choose all of its ``k`` experts here.
+
+Rows move between token order and sorted order by :func:`rows_of_tokens` (a
+gather) and :func:`tokens_from_rows` (a scatter-add), each the other's
+transpose. On a TPU both cost by the row, dead rows like live ones (61 ns a
+row of 2560 measured), and the live rows are the buffer's head: so the buffer
+is cut into ``HEADS`` equal parts and a ``switch`` on the live count moves the
+shortest head that holds them (``HEADS`` says why three). A router that sends every assignment here
+moves the whole buffer. The products are under no control flow.
+
+What a round routed here leaves the program with the loss: the layer adds its
+counts to the collection ``ROUND_COUNTERS`` (``models/base.py`` says who
+zeroes, carries and publishes it).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from distkeras_tpu.models.base import ROUND_COUNTERS
+
+
+class RMSNorm(nn.Module):
+    """``x / sqrt(mean(x^2) + eps) * scale``, the statistics in float32."""
+
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
+        return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def rope(x, theta: float):
+    """Rotary positions over the whole head width by the half-split rule:
+    ``x``: [B, L, H, D]; pair ``i`` is ``(x[i], x[i + D/2])``, turned by
+    ``pos * theta^(-2i/D)``. Angles and products in float32."""
+    L, D = x.shape[1], x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
+    angle = jnp.arange(L, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+class GroupedQueryAttention(nn.Module):
+    """Causal attention of ``num_heads`` query heads over ``num_kv_heads``
+    K/V heads (query head ``n`` reads K/V head ``n // group``), no biases.
+    ``window``: query ``i`` sees keys ``j`` with ``0 <= i - j < window``.
+    ``rope_theta``: rotate q and k, or leave positions out (``None``)."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    window: int | None = None
+    rope_theta: float | None = None
+    attn_impl: str = "dense"  # 'dense' | 'flash'
+
+    @nn.compact
+    def __call__(self, x):
+        B, L, D = x.shape
+        H, G, Dh = self.num_heads, self.num_kv_heads, self.head_dim
+
+        def proj(heads, name):
+            return nn.DenseGeneral((heads, Dh), use_bias=False, name=name)(x)
+
+        q, k, v = proj(H, "query"), proj(G, "key"), proj(G, "value")
+        if self.rope_theta is not None:
+            q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
+        q = q / jnp.sqrt(Dh).astype(q.dtype)
+        if self.attn_impl == "flash" and not self.is_initializing():
+            # Init only declares parameters: it takes the dense path on
+            # whatever short sample it is given (transformer.py says why).
+            from distkeras_tpu.models.transformer import _flash_block
+            from distkeras_tpu.ops.pallas import flash_attention, mode
+
+            block = _flash_block(L)
+            if self.window is not None and self.window < L \
+                    and self.window % block and not mode.compiles():
+                # The interpreter takes any grain; a CPU preset's window is
+                # shorter than a lane-aligned block.
+                block = math.gcd(L, self.window)
+            out = flash_attention(q, k, v, block_size=block,
+                                  window=self.window)
+        else:
+            i, j = jnp.arange(L)[:, None], jnp.arange(L)[None, :]
+            seen = j <= i
+            if self.window is not None:
+                seen &= i - j < self.window
+            qg = q.reshape(B, L, G, H // G, Dh)
+            scores = jnp.einsum("bqgnd,bkgd->bgnqk", qg, k)
+            scores = jnp.where(seen, scores, jnp.finfo(scores.dtype).min)
+            probs = jax.nn.softmax(scores, axis=-1)
+            out = jnp.einsum("bgnqk,bkgd->bqgnd", probs, v).reshape(B, L, H, Dh)
+        return nn.DenseGeneral(D, axis=(-2, -1), use_bias=False,
+                               name="out")(out)
+
+
+def route_top_k(logits, k: int):
+    """The published router: softmax in float32 over all the experts, the
+    ``k`` largest, their weights renormalised to sum to one
+    (``norm_topk_prob``). Returns ``(weights [T, k] float32, experts [T, k])``."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    w, e = jax.lax.top_k(probs, k)
+    return w / jnp.sum(w, axis=-1, keepdims=True), e
+
+
+#: the heads of the sorted buffer a step may move: a third, two thirds, all of
+#: it. A movement costs 2.6 ms and 37 ns a row on a v5e (measured, PERF.md PR
+#: 28), so a long head is cheap and a head's edge is not: with 8 of 64 experts
+#: held a third is 2.7 times the even share, which the deep layers' load, up
+#: to 1.8 times it as training moves the stream under the router, stays
+#: inside; at a sixth the steps that crossed the edge cost a round 1.7 %.
+HEADS = 3
+
+
+def _shortest_head(live, rows: int, move):
+    """``move(n)`` for the shortest of the ``HEADS`` heads of a buffer of
+    ``rows`` rows that holds its ``live`` first rows."""
+    part = -(-rows // HEADS // 8) * 8  # whole sublane tiles
+    heads = sorted({min(rows, part * (i + 1)) for i in range(HEADS)})
+    index = jnp.clip((live + part - 1) // part - 1, 0, len(heads) - 1)
+    return jax.lax.switch(index, [functools.partial(move, n) for n in heads])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def rows_of_tokens(x, token, live, tokens: int):
+    """``out[r] = x[token[r]]`` for the ``live`` first rows of the sorted
+    buffer, zero for the others. ``x``: [tokens, D]; ``token``: [N]."""
+    N = token.shape[0]
+
+    def move(n):
+        got = jnp.where(jnp.arange(n)[:, None] < live, x[token[:n]], 0)
+        return jnp.pad(got, ((0, N - n), (0, 0)))
+
+    return _shortest_head(live, N, move)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def tokens_from_rows(rows, token, live, tokens: int):
+    """``out[t]`` = the sum of the ``live`` first rows ``r`` with ``token[r]
+    = t``, in float32. ``rows``: [N, D]; returns [tokens, D]."""
+
+    def move(n):
+        part = jnp.where(jnp.arange(n)[:, None] < live,
+                         rows[:n].astype(jnp.float32), 0)
+        return jnp.zeros((tokens, rows.shape[1]), jnp.float32) \
+            .at[token[:n]].add(part)
+
+    return _shortest_head(live, token.shape[0], move)
+
+
+# Each is linear in its first argument and the other's transpose; a gradient
+# takes the dtype of what it is the gradient of.
+rows_of_tokens.defvjp(
+    lambda x, token, live, tokens: (
+        rows_of_tokens(x, token, live, tokens), (token, live)),
+    lambda tokens, res, g: (
+        tokens_from_rows(g, *res, tokens).astype(g.dtype), None, None))
+tokens_from_rows.defvjp(
+    lambda rows, token, live, tokens: (
+        tokens_from_rows(rows, token, live, tokens),
+        (token, live, rows[:0])),
+    lambda tokens, res, g: (
+        rows_of_tokens(g, res[0], res[1], tokens).astype(res[2].dtype),
+        None, None))
+
+
+class _ExpertBank(nn.Module):
+    """One matrix an expert, stacked: ``kernel`` [held, d_in, d_out]; the
+    leading axis is the expert's (``parallel/sharding.py::MOE_RULES``)."""
+
+    held: int
+    d_in: int
+    d_out: int
+
+    @nn.compact
+    def __call__(self):
+        return self.param(
+            "kernel", nn.initializers.variance_scaling(
+                1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1,
+                batch_axis=(0,)), (self.held, self.d_in, self.d_out))
+
+
+class _GatedExperts(nn.Module):
+    """``(relu(g W_gate) * (g W_up)) W_down`` for sorted rows, group by
+    group. No loop: three grouped products the trace can see."""
+
+    held: int
+    d_model: int
+    d_expert: int
+
+    @nn.compact
+    def __call__(self, rows, group_sizes):
+        D, F = self.d_model, self.d_expert
+        gate = _ExpertBank(self.held, D, F, name="gate")().astype(rows.dtype)
+        up = _ExpertBank(self.held, D, F, name="up")().astype(rows.dtype)
+        down = _ExpertBank(self.held, F, D, name="down")().astype(rows.dtype)
+
+        def grouped(a, w):
+            return jax.lax.ragged_dot(a, w, group_sizes,
+                                      preferred_element_type=rows.dtype)
+
+        return grouped(nn.relu(grouped(rows, gate)) * grouped(rows, up), down)
+
+
+class DroplessExperts(nn.Module):
+    """This chip's part of a routed expert layer: experts ``first .. first +
+    held - 1`` of those ``experts [T, k]`` names, weighted by ``weights``
+    (already normalised over all ``k``, held or not). A token none of whose
+    experts is held gets zero."""
+
+    first: int
+    held: int
+    d_model: int
+    d_expert: int
+
+    @nn.compact
+    def __call__(self, x, weights, experts):
+        T, D = x.shape
+        k = experts.shape[-1]
+        N = T * k
+        with jax.named_scope("dk_moe_route"):
+            local = experts.reshape(N) - self.first
+            here = (local >= 0) & (local < self.held)
+            # Held assignments first, by expert; the rest behind them.
+            key = jnp.where(here, local, self.held)
+            order = jnp.argsort(key, stable=True)
+            group_sizes = jnp.sum(
+                key[:, None] == jnp.arange(self.held)[None, :], axis=0,
+                dtype=jnp.int32)
+            live = jnp.sum(group_sizes)
+            token = order // k
+            w = weights.reshape(N)[order]
+            rows = rows_of_tokens(x, token, live, T)
+        if self.is_mutable_collection(ROUND_COUNTERS):
+            self._count(group_sizes, here.reshape(T, k), T)
+        with jax.named_scope("dk_moe_experts"):
+            out = _GatedExperts(self.held, D, self.d_expert,
+                                name="experts")(rows, group_sizes)
+        with jax.named_scope("dk_moe_combine"):
+            # The grouped product writes the tiles that hold rows and leaves
+            # the others as they were: selected away, never multiplied.
+            out = jnp.where(jnp.arange(N)[:, None] < live, out, 0)
+            out = out * w[:, None].astype(out.dtype)
+            return tokens_from_rows(out, token, live, T).astype(x.dtype)
+
+    def _count(self, group_sizes, here, tokens):
+        """Add this step's routing to the round's counters (float32: exact
+        up to 2**24 a round)."""
+        for name, value in (
+                ("assignments_held", group_sizes.astype(jnp.float32)),
+                ("tokens_without_held_expert", jnp.sum(
+                    ~jnp.any(here, axis=-1)).astype(jnp.float32)),
+                ("tokens", jnp.float32(tokens)),
+                ("steps", jnp.float32(1))):
+            var = self.variable(ROUND_COUNTERS, name,
+                                lambda v=value: jnp.zeros_like(v))
+            if not self.is_initializing():  # init declares them, at zero
+                var.value = var.value + value

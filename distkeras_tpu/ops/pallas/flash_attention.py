@@ -26,6 +26,21 @@ bounds, the tests and the gauge ``pallas.flash.visited_share`` all read):
   path; what ran everywhere until PR 27, with a k-chunk of up to 8 q-blocks,
   which at L <= 1024 was the whole sequence: nothing skipped, all masked).
 
+* ``window`` (sliding-window layers: query ``i`` sees keys ``j`` with ``0 <=
+  i - j < window``) needs square tiles that divide it. Tiles wholly older
+  than the window are never visited, as tiles past the diagonal are not; the
+  one tile the window's edge crosses (``window / block`` tiles left of the
+  diagonal tile, :func:`window_span`) keeps its strict upper triangle, cut
+  like the diagonal tile (:func:`edge_cuts`). A program that has no such
+  tile (the first rows) skips it under a ``cond``.
+* K/V heads fewer than Q heads (grouped-query attention): the program of
+  query head ``h`` reads K/V head ``h // group`` through its block index, so
+  a group's K and V are fetched once. dk/dv get a third grid dimension over
+  the group, ``arbitrary``: each program adds its query head's part to
+  float32 accumulators in VMEM and the output block leaves once a group.
+  Without a window and with as many K/V heads as Q heads the kernels, their
+  grids and their text are what they were.
+
 **Measured** (TPU v5e, 30 Sep 2026, ``[B, L, H, D] = [8, L, 16, 64]`` bf16,
 each kernel's device time from a profiler trace, us a call; TFLOP/s on the
 products a kernel executes, 2 / 3 / 4 of them for fwd / dq / dk+dv):
@@ -78,14 +93,12 @@ _NEG = -1e30
 _VMEM_BYTES = 16 * 2 ** 20  # Mosaic's scoped limit for one kernel on a v5e
 
 
-def _qblock_spec(block, D):
-    return pl.BlockSpec((1, block, D), lambda bh, i: (bh, i, 0),
-                        memory_space=pltpu.VMEM)
+def _qblock_spec(block, D, index=lambda bh, i: (bh, i, 0)):
+    return pl.BlockSpec((1, block, D), index, memory_space=pltpu.VMEM)
 
 
-def _full_spec(L, D):
-    return pl.BlockSpec((1, L, D), lambda bh, i: (bh, 0, 0),
-                        memory_space=pltpu.VMEM)
+def _full_spec(L, D, index=lambda bh, i: (bh, 0, 0)):
+    return pl.BlockSpec((1, L, D), index, memory_space=pltpu.VMEM)
 
 
 def _rowblock_spec(block):
@@ -96,18 +109,19 @@ def _rowblock_spec(block):
                         memory_space=pltpu.VMEM)
 
 
-def _fullrow_spec(nq, block):
-    return pl.BlockSpec((1, nq, 1, block), lambda bh, i: (bh, 0, 0, 0),
-                        memory_space=pltpu.VMEM)
+def _fullrow_spec(nq, block, index=lambda bh, i: (bh, 0, 0, 0)):
+    return pl.BlockSpec((1, nq, 1, block), index, memory_space=pltpu.VMEM)
 
 
-def _parallel_kw(interpret: bool, dims: int = 2) -> dict:
-    """All grid dims order-independent -> Mosaic overlaps fetch/compute
-    across programs. Only valid because no output block is revisited."""
+def _parallel_kw(interpret: bool, arbitrary: int = 0) -> dict:
+    """Two grid dims order-independent -> Mosaic overlaps fetch/compute
+    across programs. Only valid because no output block is revisited, but
+    along the ``arbitrary`` trailing dims (dk/dv's walk over a K/V head's
+    group of query heads, which revisits one output block in order)."""
     if interpret:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=("parallel",) * dims)}
+        dimension_semantics=("parallel",) * 2 + ("arbitrary",) * arbitrary)}
 
 
 def causal_span(i, own: int, other: int):
@@ -145,25 +159,77 @@ def diagonal_cuts(tile: int, cut: int, by_rows: bool) -> list:
     return blocks
 
 
-def tile_schedule(L: int, block_q: int, block_k: int, cut=None) -> dict:
-    """What each kernel visits at ``(L, block_q, block_k, cut)``: per kernel
-    a list of rectangles ``(q0, q1, k0, k1, masked)`` of the score matrix,
-    from :func:`causal_span` and :func:`diagonal_cuts` exactly as the kernel
-    walks. Static, so it is known as the kernel is traced: the tests prove
-    coverage on it and ``pallas.flash.visited_share`` reads it. ``cut`` is the
-    grain of a square tiling's diagonal tiles; without it a tile that
-    straddles the diagonal is visited, and masked, whole."""
+def edge_cuts(tile: int, cut: int, by_rows: bool) -> list:
+    """The strict upper triangle of the square ``tile`` that a window's edge
+    crosses (a window of a whole number of tiles leaves of it the scores with
+    column > row), in :func:`diagonal_cuts`' form: a block of rows has its
+    masked ``cut x cut`` square, then the part right of it; a block of
+    columns the part above its square, then the square. The squares below
+    the diagonal are in no list."""
+    blocks = []
+    for b0 in range(0, tile, cut):
+        b1 = b0 + cut
+        square = (b0, b1, b0, b1, True)
+        if by_rows:
+            rects = [square] + [(b0, b1, b1, tile, False)] * (b1 < tile)
+        else:
+            rects = [(0, b0, b0, b1, False)] * (b0 > 0) + [square]
+        blocks.append((b0, b1, rects))
+    return blocks
 
-    def tiles(qi, ki, straddles, by_rows):
+
+def window_span(i, reach: int, n: int, by_rows: bool):
+    """Square tiles under a window of ``reach`` tiles, as ``(edge, lo, hi)``:
+    the program that owns block ``i`` of ``n`` walks the tiles ``[lo, hi)``
+    whole and unmasked, between its diagonal tile ``i`` and the tile ``edge``
+    that the window's edge crosses, which exists where ``0 <= edge < n``.
+    Rows (forward, dq) look left: ``edge = i - reach``; columns (dk/dv) look
+    down: ``edge = i + reach``. Everything beyond ``edge`` is wholly outside
+    the window and never visited. ``i`` is an int or a traced program id."""
+    if by_rows:
+        lo = i - reach + 1
+        return i - reach, (max(lo, 0) if isinstance(lo, int)
+                           else jnp.maximum(lo, 0)), i
+    hi = i + reach
+    return hi, i + 1, (min(hi, n) if isinstance(hi, int)
+                       else jnp.minimum(hi, n))
+
+
+def tile_schedule(L: int, block_q: int, block_k: int, cut=None,
+                  window=None) -> dict:
+    """What each kernel visits at ``(L, block_q, block_k, cut, window)``: per
+    kernel a list of rectangles ``(q0, q1, k0, k1, masked)`` of the score
+    matrix, from :func:`causal_span`, :func:`diagonal_cuts`,
+    :func:`window_span` and :func:`edge_cuts` exactly as the kernel walks.
+    Static, so it is known as the kernel is traced: the tests prove coverage
+    on it and ``pallas.flash.visited_share`` reads it. ``cut`` is the grain of
+    a square tiling's diagonal tiles; without it a tile that straddles the
+    diagonal is visited, and masked, whole. ``window`` (a multiple of the
+    square tile) drops what lies wholly outside it."""
+
+    def tiles(qi, ki, straddles, by_rows, cuts=diagonal_cuts):
         q0, k0 = qi * block_q, ki * block_k
         if not straddles or cut is None:
             return [(q0, q0 + block_q, k0, k0 + block_k, straddles)]
         return [(q0 + r0, q0 + r1, k0 + c0, k0 + c1, m)
-                for _, _, rects in diagonal_cuts(block_q, cut, by_rows)
+                for _, _, rects in cuts(block_q, cut, by_rows)
                 for r0, r1, c0, c1, m in rects]
 
     nq, nk = L // block_q, L // block_k
     by_q, by_k = [], []
+    if window is not None:
+        reach = window // block_q
+        for i in range(nq):
+            for by_rows, out in ((True, by_q), (False, by_k)):
+                edge, lo, hi = window_span(i, reach, nq, by_rows)
+                at = (lambda j: (i, j)) if by_rows else (lambda j: (j, i))
+                if 0 <= edge < nq:
+                    out += tiles(*at(edge), True, by_rows, edge_cuts)
+                for j in range(lo, hi):
+                    out += tiles(*at(j), False, by_rows)
+                out += tiles(i, i, True, by_rows)
+        return {"dk_flash_fwd": by_q, "dk_flash_dq": by_q,
+                "dk_flash_dkv": by_k}
     for qi in range(nq):
         lo, hi = causal_span(qi, block_q, block_k)
         for ki in range(hi):
@@ -175,11 +241,12 @@ def tile_schedule(L: int, block_q: int, block_k: int, cut=None) -> dict:
     return {"dk_flash_fwd": by_q, "dk_flash_dq": by_q, "dk_flash_dkv": by_k}
 
 
-def visited_share(L: int, block_q: int, block_k: int, cut=None) -> float:
+def visited_share(L: int, block_q: int, block_k: int, cut=None,
+                  window=None) -> float:
     """Score elements a kernel visits over the ``L**2`` it would without the
     causal skip (the three kernels visit the same ones, in another order).
     1.0: the skip is not engaging."""
-    tiles = tile_schedule(L, block_q, block_k, cut)["dk_flash_fwd"]
+    tiles = tile_schedule(L, block_q, block_k, cut, window)["dk_flash_fwd"]
     return sum((q1 - q0) * (k1 - k0) for q0, q1, k0, k1, _ in tiles) / (L * L)
 
 
@@ -191,8 +258,37 @@ def _keep(rows: int, cols: int, shift):
     return row - col >= shift
 
 
+def _edge_then_diagonal(has_edge, tile: int, cut: int, by_rows: bool, carry,
+                        step, finish):
+    """The two cut tiles of a program under a window, after its whole tiles:
+    the tile the window's edge crosses (its strict upper triangle,
+    :func:`edge_cuts`; under a ``cond``, because the first programs have
+    none) and the diagonal tile (:func:`diagonal_cuts`), one chain of
+    ``step(on_edge, b0, b1, rect, sub, keep)`` a block of ``cut`` rows or
+    columns, ended by ``finish(b0, b1, sub)``. ``carry`` is what the whole
+    tiles left, whole; each chain takes its slice of it."""
+    keep = _keep(cut, cut, 0)
+    beyond = jnp.logical_not(keep)
+    edge_blocks = edge_cuts(tile, cut, by_rows)
+
+    def chain(on_edge, mask, b0, b1, rects, sub):
+        for rect in rects:
+            sub = step(on_edge, b0, b1, rect, sub, mask if rect[4] else None)
+        return sub
+
+    subs = jax.lax.cond(
+        has_edge,
+        lambda subs: tuple(chain(True, beyond, b0, b1, rects, sub)
+                           for (b0, b1, rects), sub in zip(edge_blocks, subs)),
+        lambda subs: subs,
+        tuple(jax.tree.map(lambda x: x[b0:b1], carry)
+              for b0, b1, _ in edge_blocks))
+    for (b0, b1, rects), sub in zip(diagonal_cuts(tile, cut, by_rows), subs):
+        finish(b0, b1, chain(False, keep, b0, b1, rects, sub))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
-                block_k: int, cut):
+                block_k: int, cut, window=None):
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.bfloat16)  # [BQ, D]
     BQ, D = q.shape
@@ -223,10 +319,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
         lse_ref[0, 0, 0, r0:r1] = (m + jnp.log(l))[:, 0]
 
     lo, hi = causal_span(qi, block_q, block_k)
+    first = 0
+    if window is not None:
+        edge, first, _ = window_span(qi, window // block_k, 0, True)
     carry = jax.lax.fori_loop(
-        0, lo, lambda ki, c: attend(q, ki * block_k, block_k, c, None),
+        first, lo, lambda ki, c: attend(q, ki * block_k, block_k, c, None),
         (jnp.full((BQ, 1), _NEG, jnp.float32), jnp.zeros((BQ, 1), jnp.float32),
          jnp.zeros((BQ, D), jnp.float32)))
+    if window is not None:
+        # A row that keeps nothing of the edge tile (the tile's last) leaves
+        # p = 1 behind only where no whole tile came before it, and the
+        # diagonal tile's step, which always keeps a column, scales that away
+        # by exp(_NEG - m) = 0.
+        def step(on_edge, b0, b1, rect, sub, keep):
+            _, _, c0, c1, _ = rect
+            return attend(q[b0:b1], (edge if on_edge else qi) * block_k + c0,
+                          c1 - c0, sub, keep)
+
+        _edge_then_diagonal(edge >= 0, BQ, cut, True, carry, step, finish)
+        return
     if cut is None:
         finish(0, BQ, jax.lax.fori_loop(lo, hi, lambda ki, c: attend(
             q, ki * block_k, block_k, c,
@@ -245,7 +356,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-               block_q: int, block_k: int, cut):
+               block_q: int, block_k: int, cut, window=None):
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.bfloat16)
     do = do_ref[0].astype(jnp.bfloat16)
@@ -270,9 +381,23 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
 
     whole = slice(0, BQ)
     lo, hi = causal_span(qi, block_q, block_k)
+    first = 0
+    if window is not None:
+        edge, first, _ = window_span(qi, window // block_k, 0, True)
     dq = jax.lax.fori_loop(
-        0, lo, lambda ki, a: grad(whole, ki * block_k, block_k, a, None),
+        first, lo, lambda ki, a: grad(whole, ki * block_k, block_k, a, None),
         jnp.zeros((BQ, D), jnp.float32))
+    if window is not None:
+        def step(on_edge, b0, b1, rect, sub, keep):
+            _, _, c0, c1, _ = rect
+            return grad(slice(b0, b1), (edge if on_edge else qi) * block_k + c0,
+                        c1 - c0, sub, keep)
+
+        def finish(b0, b1, sub):
+            dq_ref[0, b0:b1, :] = sub.astype(dq_ref.dtype)
+
+        _edge_then_diagonal(edge >= 0, BQ, cut, True, dq, step, finish)
+        return
     if cut is None:
         dq = jax.lax.fori_loop(lo, hi, lambda ki, a: grad(
             whole, ki * block_k, block_k, a,
@@ -289,7 +414,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-                dv_ref, *, block_q: int, block_k: int, cut):
+                dv_ref, *acc_refs, block_q: int, block_k: int, cut,
+                window=None):
     ki = pl.program_id(1)
     kb = k_ref[0].astype(jnp.bfloat16)  # [BK, D] (this program's k chunk)
     vb = v_ref[0].astype(jnp.bfloat16)
@@ -321,15 +447,36 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         return dk, dv
 
     def finish(c0, c1, carry):
+        # With a group of query heads (the grid's third dimension) the
+        # output block stays in VMEM until the group's last program has
+        # written it; the float32 sums live in the scratch accumulators.
+        for acc_ref, part in zip(acc_refs, carry):
+            acc_ref[c0:c1, :] = part
         dk_ref[0, c0:c1, :] = carry[0].astype(dk_ref.dtype)
         dv_ref[0, c0:c1, :] = carry[1].astype(dv_ref.dtype)
 
     whole = slice(0, BK)
     zero = jnp.zeros((BK, D), jnp.float32)
+    start = (zero, zero)
+    if acc_refs:
+        # A select, not a product: what the scratch holds before the
+        # group's first program wrote it is anything.
+        fresh = pl.program_id(2) == 0
+        start = tuple(jnp.where(fresh, zero, ref[...]) for ref in acc_refs)
     lo, hi = causal_span(ki, block_k, block_q)
+    last = nq
+    if window is not None:
+        edge, _, last = window_span(ki, window // block_q, nq, False)
     carry = jax.lax.fori_loop(
-        hi, nq, lambda qi, c: grad(qi, 0, block_q, whole, c, None),
-        (zero, zero))
+        hi, last, lambda qi, c: grad(qi, 0, block_q, whole, c, None), start)
+    if window is not None:
+        def step(on_edge, b0, b1, rect, sub, keep):
+            r0, r1, _, _, _ = rect
+            return grad(edge if on_edge else ki, r0, r1, slice(b0, b1), sub,
+                        keep)
+
+        _edge_then_diagonal(edge < nq, BK, cut, False, carry, step, finish)
+        return
     if cut is None:
         finish(0, BK, jax.lax.fori_loop(lo, hi, lambda qi, c: grad(
             qi, 0, block_q, whole, c,
@@ -350,19 +497,30 @@ def _traced_once(fn):
     trace, and ``inline`` replays it into the caller's jaxpr under the
     caller's name stack, so the program and its ``op_name``s stay the same."""
     return jax.jit(fn, static_argnames=("block_q", "block_k", "cut",
-                                        "interpret"), inline=True)
+                                        "interpret", "window"), inline=True)
+
+
+def _kv_specs(L, D, group: int):
+    """K and V whole, for the program of query head ``bh``: its own, or with
+    grouped queries head ``bh // group``'s (consecutive programs of a group
+    name the same block, which is then not fetched again)."""
+    if group == 1:
+        return [_full_spec(L, D), _full_spec(L, D)]
+    return [_full_spec(L, D, lambda bh, i: (bh // group, 0, 0))] * 2
 
 
 @_traced_once
-def _flash_bhld(q, k, v, block_q, block_k, cut, interpret):
-    """Forward on [BH, L, D] inputs; returns (out, lse [BH, nq, 1, block_q])."""
+def _flash_bhld(q, k, v, block_q, block_k, cut, interpret, window=None):
+    """Forward on [BH, L, D] inputs (k, v: [BH / group, L, D]); returns
+    (out, lse [BH, nq, 1, block_q])."""
     BH, L, D = q.shape
     grid = (BH, L // block_q)
     call = pl.pallas_call(
         functools.partial(_fwd_kernel, block_q=block_q, block_k=block_k,
-                          cut=cut),
+                          cut=cut, window=window),
         grid=grid,
-        in_specs=[_qblock_spec(block_q, D), _full_spec(L, D), _full_spec(L, D)],
+        in_specs=[_qblock_spec(block_q, D),
+                  *_kv_specs(L, D, BH // k.shape[0])],
         out_specs=[
             _qblock_spec(block_q, D),
             _rowblock_spec(block_q),
@@ -382,33 +540,35 @@ def _flash_bhld(q, k, v, block_q, block_k, cut, interpret):
     return out, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, block_q, block_k, cut, interpret):
-    out, _ = _flash_bhld(q, k, v, block_q, block_k, cut, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, block_q, block_k, cut, interpret, window):
+    out, _ = _flash_bhld(q, k, v, block_q, block_k, cut, interpret, window)
     return out
 
 
-def _flash_fwd(q, k, v, block_q, block_k, cut, interpret):
-    out, lse = _flash_bhld(q, k, v, block_q, block_k, cut, interpret)
+def _flash_fwd(q, k, v, block_q, block_k, cut, interpret, window):
+    out, lse = _flash_bhld(q, k, v, block_q, block_k, cut, interpret, window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(block_q, block_k, cut, interpret, res, do):
-    return _flash_grads(*res, do, block_q, block_k, cut, interpret)
+def _flash_bwd(block_q, block_k, cut, interpret, window, res, do):
+    return _flash_grads(*res, do, block_q, block_k, cut, interpret, window)
 
 
 @_traced_once
-def _flash_grads(q, k, v, out, lse, do, block_q, block_k, cut, interpret):
+def _flash_grads(q, k, v, out, lse, do, block_q, block_k, cut, interpret,
+                 window=None):
     BH, L, D = q.shape
     nq = L // block_q
+    group = BH // k.shape[0]
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(BH, nq, 1, block_q)
 
     dq_call = pl.pallas_call(
         functools.partial(_dq_kernel, block_q=block_q, block_k=block_k,
-                          cut=cut),
+                          cut=cut, window=window),
         grid=(BH, nq),
-        in_specs=[_qblock_spec(block_q, D), _full_spec(L, D), _full_spec(L, D),
+        in_specs=[_qblock_spec(block_q, D), *_kv_specs(L, D, group),
                   _qblock_spec(block_q, D), _rowblock_spec(block_q),
                   _rowblock_spec(block_q)],
         out_specs=_qblock_spec(block_q, D),
@@ -420,20 +580,52 @@ def _flash_grads(q, k, v, out, lse, do, block_q, block_k, cut, interpret):
     with jax.named_scope("dk_flash_dq"):
         dq = dq_call(q, k, v, do, lse, delta)
 
-    dkv_call = pl.pallas_call(
-        functools.partial(_dkv_kernel, block_q=block_q, block_k=block_k,
-                          cut=cut),
-        grid=(BH, L // block_k),
-        in_specs=[_full_spec(L, D), _qblock_spec(block_k, D),
-                  _qblock_spec(block_k, D), _full_spec(L, D),
-                  _fullrow_spec(nq, block_q), _fullrow_spec(nq, block_q)],
-        out_specs=[_qblock_spec(block_k, D), _qblock_spec(block_k, D)],
-        out_shape=[jax.ShapeDtypeStruct((BH, L, D), k.dtype),
-                   jax.ShapeDtypeStruct((BH, L, D), v.dtype)],
-        interpret=interpret,
-        name="dk_flash_dkv",
-        **_parallel_kw(interpret),
-    )
+    kernel = functools.partial(_dkv_kernel, block_q=block_q, block_k=block_k,
+                               cut=cut, window=window)
+    out_shape = [jax.ShapeDtypeStruct(k.shape, k.dtype),
+                 jax.ShapeDtypeStruct(v.shape, v.dtype)]
+    if group == 1:
+        dkv_call = pl.pallas_call(
+            kernel,
+            grid=(BH, L // block_k),
+            in_specs=[_full_spec(L, D), _qblock_spec(block_k, D),
+                      _qblock_spec(block_k, D), _full_spec(L, D),
+                      _fullrow_spec(nq, block_q), _fullrow_spec(nq, block_q)],
+            out_specs=[_qblock_spec(block_k, D), _qblock_spec(block_k, D)],
+            out_shape=out_shape,
+            interpret=interpret,
+            name="dk_flash_dkv",
+            **_parallel_kw(interpret),
+        )
+    else:
+        # One program a (K/V head, block of keys, query head of the group);
+        # the group runs innermost and in order over one output block.
+        def of_query(bh, i, g):
+            return (bh * group + g, 0, 0)
+
+        def of_keys(bh, i, g):
+            return (bh, i, 0)
+
+        def rows_of_query(bh, i, g):
+            return (bh * group + g, 0, 0, 0)
+
+        dkv_call = pl.pallas_call(
+            kernel,
+            grid=(k.shape[0], L // block_k, group),
+            in_specs=[_full_spec(L, D, of_query),
+                      _qblock_spec(block_k, D, of_keys),
+                      _qblock_spec(block_k, D, of_keys),
+                      _full_spec(L, D, of_query),
+                      _fullrow_spec(nq, block_q, rows_of_query),
+                      _fullrow_spec(nq, block_q, rows_of_query)],
+            out_specs=[_qblock_spec(block_k, D, of_keys),
+                       _qblock_spec(block_k, D, of_keys)],
+            out_shape=out_shape,
+            scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32)] * 2,
+            interpret=interpret,
+            name="dk_flash_dkv",
+            **_parallel_kw(interpret, arbitrary=1),
+        )
     with jax.named_scope("dk_flash_dkv"):
         dk, dv = dkv_call(q, k, v, do, lse, delta)
     return dq, dk, dv
@@ -442,21 +634,26 @@ def _flash_grads(q, k, v, out, lse, do, block_q, block_k, cut, interpret):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def default_tiling(L: int, D: int, block_size: int = 128):
+def default_tiling(L: int, D: int, block_size: int = 128, window=None):
     """``(block_q, block_k, cut)`` for sequences of ``L`` and heads of ``D``,
     every edge a multiple of ``block_size`` that divides ``L`` (the module
     doc has the measurements). Up to 16 blocks the tile is the sequence: no
     loop, only the diagonal's static cuts. Longer, the largest tile up to 8
     blocks whose score temporaries (some 10 bytes an element) fit the scoped
     VMEM beside K and V. ``cut`` is a quarter of L between 2 and 4 blocks,
-    and at most half the tile."""
+    and at most half the tile. Under a ``window`` shorter than ``L`` the tile
+    also divides the window, whatever ``L``: a tile as long as the sequence
+    would hold the whole window and skip none of it."""
     units = L // block_size
-    if units <= 16:
+    reach = units if window is None else window // block_size
+    if units <= 16 and window is None:
         tile = units
     else:
         resident = 4 * L * max(D, 128) * 2  # K and V whole, double-buffered
-        tile = max(m for m in range(1, 9) if units % m == 0 and (
-            m == 1 or resident + 10 * (m * block_size) ** 2 <= _VMEM_BYTES))
+        tile = max(m for m in range(1, 9)
+                   if units % m == 0 and reach % m == 0 and (
+                       m == 1
+                       or resident + 10 * (m * block_size) ** 2 <= _VMEM_BYTES))
     want = min(4, max(2, units // 4))
     cut = max(m for m in range(1, want + 1)
               if tile % m == 0 and (2 * m <= tile or m == 1))
@@ -464,39 +661,57 @@ def default_tiling(L: int, D: int, block_size: int = 128):
 
 
 def flash_attention(q, k, v, block_size: int = 128, block_k: int | None = None,
-                    interpret: bool | None = None):
-    """Causal FlashAttention. ``q, k, v``: [B, L, H, D], q pre-scaled by
-    1/sqrt(D). Returns [B, L, H, D]. ``block_size`` is the grain: ``L`` must
+                    interpret: bool | None = None, window: int | None = None):
+    """Causal FlashAttention. ``q``: [B, L, H, D], pre-scaled by 1/sqrt(D);
+    ``k, v``: [B, L, H / group, D], where query head ``h`` reads K/V head
+    ``h // group`` (group 1: plain multi-head attention). Returns
+    [B, L, H, D]. ``block_size`` is the grain: ``L`` must
     be a multiple of it (128 on a TPU: the lane width). With ``block_k=None``
     the tiling follows ``L`` and ``D`` (:func:`default_tiling`: square tiles
     of up to the whole sequence, the diagonal tile cut at 2-4 grains;
     1024 x 1024 cut at 256 for L = 1024, which visits 62.5 % of the scores).
     An explicit ``block_k`` asks for ``block_size`` x ``block_k`` tiles as
-    they are, straddling tiles masked whole. The share of L^2 the schedule
+    they are, straddling tiles masked whole. ``window``: query ``i`` sees the
+    keys ``j`` with ``0 <= i - j < window``; it must be a multiple of the
+    (square) tile, and one that reaches past the sequence changes nothing.
+    The share of L^2 the schedule
     visits is set on the gauge ``pallas.flash.visited_share`` as the call is
     traced. ``interpret=None`` compiles on TPU and interprets elsewhere
     (:mod:`distkeras_tpu.ops.pallas.mode`).
     """
     interpret = mode.interpret("flash_attention", interpret)
     B, L, H, D = q.shape
+    kv_heads = k.shape[2]
+    if k.shape != v.shape or k.shape != (B, L, kv_heads, D) or H % kv_heads:
+        raise ValueError(f"q {q.shape} against k {k.shape} and v {v.shape}: "
+                         "K/V heads must divide the query heads")
     if L % block_size != 0 or (block_k is not None and L % block_k != 0):
         raise ValueError(
             f"seq_len {L} not divisible by block_q {block_size} / "
             f"block_k {block_k}")
+    if window is not None and window >= L:
+        window = None
     if block_k is None:
-        block_q, block_k, cut = default_tiling(L, D, block_size)
+        if window is not None and window % block_size:
+            raise ValueError(f"window {window} is not a multiple of the "
+                             f"grain {block_size}")
+        block_q, block_k, cut = default_tiling(L, D, block_size, window)
     else:
         # A square tiling masks its diagonal tile by a constant (cut = tile).
         block_q = block_size
         cut = block_size if block_k == block_size else None
+        if window is not None and (cut is None or window % block_q):
+            raise ValueError(
+                f"window {window} needs square tiles that divide it, got "
+                f"{block_q} x {block_k}")
     from distkeras_tpu import telemetry
 
     telemetry.gauge("pallas.flash.visited_share").set(
-        visited_share(L, block_q, block_k, cut))
+        visited_share(L, block_q, block_k, cut, window))
 
     def to_bhld(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, L, D)
+        return x.transpose(0, 2, 1, 3).reshape(-1, L, D)
 
     out = _flash(to_bhld(q), to_bhld(k), to_bhld(v), block_q, block_k, cut,
-                 interpret)
+                 interpret, window)
     return out.reshape(B, H, L, D).transpose(0, 2, 1, 3)

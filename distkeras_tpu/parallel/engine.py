@@ -31,6 +31,7 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distkeras_tpu.data.batching import BatchPlan
+from distkeras_tpu.models.base import ROUND_COUNTERS
 from distkeras_tpu.ops.losses import get_loss
 from distkeras_tpu.ops.optimizers import get_optimizer
 from distkeras_tpu.parallel.disciplines import Discipline
@@ -732,6 +733,41 @@ def _record_feed_waits(engine, feeder) -> None:
     engine.feed_wait_seconds = float(feeder.wait_seconds)
 
 
+class _RoundCounters:
+    """The model's ``ROUND_COUNTERS`` (``models/base.py``), out of the round
+    program beside the loss: after each dispatch a copy of the collection is
+    queued on the device (the next dispatch donates the state it lives in),
+    and the copy of the round before, which has finished or is about to, is
+    fetched and handed to the module's ``publish_round_counters``. The host
+    therefore stays one round ahead of the device, never further, and the
+    device's queue is never drained for it."""
+
+    def __init__(self, engine):
+        # Engines of other kinds (`parallel/runner.py`) share this run loop
+        # and hold no `Model`: they have no counters to publish.
+        model = getattr(engine, "model", None)
+        self.publish = getattr(model.module, "publish_round_counters", None) \
+            if ROUND_COUNTERS in (getattr(model, "state", None) or {}) else None
+        self._copy = jax.jit(lambda tree: jax.tree.map(jnp.copy, tree))
+        self._pending = None
+
+    def after_dispatch(self, r: int, new_state) -> None:
+        if self.publish is None:
+            return
+        queued = (r, self._copy(new_state.model_state[ROUND_COUNTERS]))
+        self.flush()
+        self._pending = queued
+
+    def flush(self) -> None:
+        if self._pending is not None:
+            r, tree = self._pending
+            self._pending = None
+            # [W, ...] a leaf: the workers hold the same experts, and what
+            # one of them was routed is the mean.
+            self.publish(r, jax.tree.map(
+                lambda a: np.asarray(a).mean(axis=0), tree))
+
+
 def run_per_round(engine, plan, state, start_round, on_round):
     """One XLA dispatch per fold round, with background batch staging."""
     from distkeras_tpu import telemetry
@@ -740,6 +776,7 @@ def run_per_round(engine, plan, state, start_round, on_round):
 
     tele = telemetry.get()
     guard = RoundGuard(engine)
+    counters = _RoundCounters(engine)
     losses = []
     feeder = RoundFeeder(plan.num_rounds,
                          lambda r: stage_round(engine, plan, r),
@@ -755,11 +792,13 @@ def run_per_round(engine, plan, state, start_round, on_round):
             # Keep the device value: fetching here would fence every
             # dispatch; convert once at the end.
             losses.append(loss)
+            counters.after_dispatch(r, new_state)
             if on_round is not None:
                 with tele.span("on_round", id=r):
                     on_round(r, loss, new_state)
             # Divergent-worker reset (no-op — and no fence — unless enabled).
             state = guard.post_round(r, loss, new_state)
+        counters.flush()
     except BaseException:
         # A crash mid-run still accounts the rounds already executed (the
         # supervised-recovery path reads resilience.nonfinite_rounds for

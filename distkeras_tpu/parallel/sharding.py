@@ -37,7 +37,8 @@ TRANSFORMER_TP_RULES: list[tuple[str, P]] = [
 # shard it over the ``expert`` mesh axis (GSPMD turns the dispatch/combine
 # einsums into all-to-alls). Router stays replicated.
 MOE_RULES: list[tuple[str, P]] = [
-    (r".*/moe/experts/up/kernel$", P(EXPERT_AXIS, None, None)),
+    # `gate` is the gated experts' third matrix (models/blocks.py).
+    (r".*/moe/experts/(up|gate)/kernel$", P(EXPERT_AXIS, None, None)),
     (r".*/moe/experts/up/bias$", P(EXPERT_AXIS, None)),
     (r".*/moe/experts/down/kernel$", P(EXPERT_AXIS, None, None)),
     (r".*/moe/experts/down/bias$", P(EXPERT_AXIS, None)),

@@ -136,6 +136,17 @@ _METRICS = [
        "Score elements the flash kernels' causal schedule visits over L^2, "
        "set as a call is traced (1.0: nothing is skipped; "
        "`flash_attention.tile_schedule`)."),
+    # -- expert layers ----------------------------------------------------
+    _m("moe.assignments_held", "counter", "models",
+       "Token-to-expert assignments routed to the experts held here, summed "
+       "over layers, added once a round (`models/blocks.py::DroplessExperts` "
+       "counts; `publish_round_counters` adds)."),
+    _m("moe.load_max_over_mean", "gauge", "models",
+       "Last round's largest load of a held expert over the mean load, in "
+       "the layer where that is worst (1.0: even)."),
+    _m("moe.tokens_without_held_expert_share", "gauge", "models",
+       "Last round's share of tokens none of whose chosen experts is held "
+       "here (they get nothing from the layer's experts)."),
     # -- inference --------------------------------------------------------
     _m("predict.chunk", "span", "inference",
        "Per-chunk end-to-end predict latency."),

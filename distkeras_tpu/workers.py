@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import optax
 from jax import lax
 
-from distkeras_tpu.models.base import _warn_uint8_rescale
+from distkeras_tpu.models.base import ROUND_COUNTERS, _warn_uint8_rescale
 
 
 def make_local_loop(
@@ -58,7 +58,8 @@ def make_local_loop(
     and the updated state is carried across the window — the engines
     cross-replica-mean it at each fold (see AsyncEngine/SyncEngine). State is
     deliberately NOT cast to ``compute_dtype`` — running statistics stay in
-    their stored precision.
+    their stored precision. The collection ``ROUND_COUNTERS``
+    (``models/base.py``) is zeroed here as the window begins.
 
     ``grad_accum=A`` splits every step's batch into A sequential micro-batches
     and applies ONE optimizer update on their mean gradient at 1/A the
@@ -124,6 +125,10 @@ def make_local_loop(
                     state=None):
         if rng is None:
             rng = jax.random.key(0)
+        if ROUND_COUNTERS in cols:
+            # What leaves the round is the round's own sums.
+            state = {**state, ROUND_COUNTERS: jax.tree.map(
+                jnp.zeros_like, state[ROUND_COUNTERS])}
 
         def grad_of_step(p, st, x, y, sub):
             if grad_accum == 1:
