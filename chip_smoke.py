@@ -69,6 +69,7 @@ class Sizes:
     lstm: tuple       # (B, T, E, H)
     groupnorm: tuple  # (B, H, W, C, groups)
     fold: tuple       # tensor shape
+    rows: tuple       # (tokens, k, D, held of 64 experts) of an expert layer
     mlp_rows: int
 
 
@@ -78,12 +79,13 @@ FULL = Sizes(layers=8, d_model=1024, heads=16, d_ff=4096, vocab=32768,
              flash=((8, 2048, 16, 64), (8, 1024, 16, 64)),  # flagship; gpt2m cell
              lstm=(2048, 200, 64, 128),
              groupnorm=(128, 112, 112, 64, 32), fold=(8192, 512),
+             rows=(16384, 6, 2560, 8),  # the smallthinker cell's step
              mlp_rows=8192)
 TOY = Sizes(layers=1, d_model=64, heads=2, d_ff=128, vocab=256,
             seq=128, lm_batch=2, lm_window=2, lm_rounds=3, tp_layers=1,
             cnn_batch=16, cnn_window=2,
             flash=((1, 128, 2, 16), (1, 64, 2, 16)), lstm=(8, 6, 8, 128),
-            groupnorm=(2, 8, 8, 64, 32), fold=(70, 33),
+            groupnorm=(2, 8, 8, 64, 32), fold=(70, 33), rows=(64, 2, 32, 16),
             mlp_rows=2048)
 
 
@@ -465,6 +467,40 @@ def check_fold(shape) -> dict:
     return out
 
 
+def check_rows(shape) -> dict:
+    """The expert layer's row kernels (``ops/pallas/rows.py``) against XLA's
+    gather and segment sum, on the live rows of a seeded routing."""
+    import jax
+    import jax.numpy as jnp
+
+    from distkeras_tpu.ops.pallas import rows
+
+    tokens, k, width, held = shape
+    rng = np.random.default_rng(4)
+    experts = np.argsort(rng.random((tokens, 64)), axis=1)[:, :k].reshape(-1)
+    order = np.argsort(np.where(experts < held, experts, held), kind="stable")
+    slot = np.empty(tokens * k, np.int32)
+    slot[order] = np.arange(tokens * k, dtype=np.int32)
+    live = int(np.sum(experts < held))
+    token = jnp.asarray(order // k, jnp.int32)
+    slot = jnp.asarray(slot.reshape(tokens, k))
+    x = jnp.asarray(rng.normal(size=(tokens, width)), jnp.bfloat16)
+    buffer = jnp.asarray(rng.normal(size=(tokens * k, width)), jnp.bfloat16)
+    weights = jnp.asarray(rng.random((tokens, k)), jnp.float32)
+    by_row = weights.reshape(-1)[order]
+    got = jax.jit(lambda: {
+        "gather": rows.gather(x, token, live, by_row)[:live],
+        "combine": rows.combine(buffer, slot, live, weights)})()
+    ref = jax.jit(lambda: {
+        "gather": (x[token[:live]].astype(jnp.float32)
+                   * by_row[:live, None]).astype(jnp.bfloat16),
+        "combine": jax.ops.segment_sum(
+            buffer[:live].astype(jnp.float32)
+            * by_row[:live, None].astype(jnp.bfloat16).astype(jnp.float32),
+            token[:live], tokens)})()
+    return dict(_compare("rows", got, ref, tol=1e-6), live=live)
+
+
 def phase_kernels(sz: Sizes) -> dict:
     out = {}
     flash = [(f"flash_attention_L{shape[1]}", check_flash, shape)
@@ -472,7 +508,8 @@ def phase_kernels(sz: Sizes) -> dict:
     for name, check, shape in (*flash,
                                ("lstm_seq", check_lstm, sz.lstm),
                                ("group_norm", check_groupnorm, sz.groupnorm),
-                               ("fold", check_fold, sz.fold)):
+                               ("fold", check_fold, sz.fold),
+                               ("rows", check_rows, sz.rows)):
         t0 = time.perf_counter()
         out[name] = dict(check(shape), shape=list(shape),
                          seconds=round(time.perf_counter() - t0, 2))

@@ -17,12 +17,18 @@ has a row for every assignment a step can produce, ``tokens x k``, because
 any token may choose all of its ``k`` experts here.
 
 Rows move between token order and sorted order by :func:`rows_of_tokens` (a
-gather) and :func:`tokens_from_rows` (a scatter-add), each the other's
-transpose. On a TPU both cost by the row, dead rows like live ones (61 ns a
-row of 2560 measured), and the live rows are the buffer's head: so the buffer
-is cut into ``HEADS`` equal parts and a ``switch`` on the live count moves the
-shortest head that holds them (``HEADS`` says why three). A router that sends every assignment here
-moves the whole buffer. The products are under no control flow.
+gather) and :func:`tokens_from_rows` (its transpose, as a gather by token,
+which also weights the rows as it sums them), each the other's transpose
+under ``jax.custom_vjp``: the Pallas kernels of ``ops/pallas/rows.py``, which
+start a DMA a live row and visit the live tiles only, so a movement costs by
+the rows a step routed here and not by the buffer. The buffer's rows from
+``live`` on are zeros up to their tile's end and beyond it **unwritten and
+never read**: the grouped products visit the tiles that hold rows, the
+elementwise passes between them compute on whatever the dead rows hold, and
+nothing takes a dead row to a live one, to an output or to a gradient (what
+reads the buffer by row reads below ``live``, or selects). A router that
+sends every assignment here moves the whole buffer. The products are under
+no control flow.
 
 What a round routed here leaves the program with the loss: the layer adds its
 counts to the collection ``ROUND_COUNTERS`` (``models/base.py`` says who
@@ -39,6 +45,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from distkeras_tpu.models.base import ROUND_COUNTERS
+from distkeras_tpu.ops.pallas import rows
 
 
 class RMSNorm(nn.Module):
@@ -130,65 +137,57 @@ def route_top_k(logits, k: int):
     return w / jnp.sum(w, axis=-1, keepdims=True), e
 
 
-#: the heads of the sorted buffer a step may move: a third, two thirds, all of
-#: it. A movement costs 2.6 ms and 37 ns a row on a v5e (measured, PERF.md PR
-#: 28), so a long head is cheap and a head's edge is not: with 8 of 64 experts
-#: held a third is 2.7 times the even share, which the deep layers' load, up
-#: to 1.8 times it as training moves the stream under the router, stays
-#: inside; at a sixth the steps that crossed the edge cost a round 1.7 %.
-HEADS = 3
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def rows_of_tokens(x, order, slot, live, k: int):
+    """``out[r] = x[order[r] // k]`` for the ``live`` first rows of the sorted
+    buffer; later rows are zeros or unwritten (``ops/pallas/rows.py``).
+    ``x``: [tokens, D]; ``order``: [N], the sorted assignments (``t * k +
+    j``); ``slot``: [tokens, k], its inverse."""
+    return rows.gather(x, order // k, live)
 
 
-def _shortest_head(live, rows: int, move):
-    """``move(n)`` for the shortest of the ``HEADS`` heads of a buffer of
-    ``rows`` rows that holds its ``live`` first rows."""
-    part = -(-rows // HEADS // 8) * 8  # whole sublane tiles
-    heads = sorted({min(rows, part * (i + 1)) for i in range(HEADS)})
-    index = jnp.clip((live + part - 1) // part - 1, 0, len(heads) - 1)
-    return jax.lax.switch(index, [functools.partial(move, n) for n in heads])
+@jax.custom_vjp
+def tokens_from_rows(buffer, weights, order, slot, live):
+    """``out[t]`` = the sum over ``j`` of ``weights[t, j]`` times row
+    ``slot[t, j]`` of ``buffer``, for the rows below ``live``, in float32 and
+    in a fixed order; later rows are not read. ``buffer``: [N, D];
+    ``weights``: [tokens, k] float32; returns [tokens, D]."""
+    return rows.combine(buffer, slot, live, weights)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def rows_of_tokens(x, token, live, tokens: int):
-    """``out[r] = x[token[r]]`` for the ``live`` first rows of the sorted
-    buffer, zero for the others. ``x``: [tokens, D]; ``token``: [N]."""
-    N = token.shape[0]
-
-    def move(n):
-        got = jnp.where(jnp.arange(n)[:, None] < live, x[token[:n]], 0)
-        return jnp.pad(got, ((0, N - n), (0, 0)))
-
-    return _shortest_head(live, N, move)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def tokens_from_rows(rows, token, live, tokens: int):
-    """``out[t]`` = the sum of the ``live`` first rows ``r`` with ``token[r]
-    = t``, in float32. ``rows``: [N, D]; returns [tokens, D]."""
-
-    def move(n):
-        part = jnp.where(jnp.arange(n)[:, None] < live,
-                         rows[:n].astype(jnp.float32), 0)
-        return jnp.zeros((tokens, rows.shape[1]), jnp.float32) \
-            .at[token[:n]].add(part)
-
-    return _shortest_head(live, token.shape[0], move)
-
-
-# Each is linear in its first argument and the other's transpose; a gradient
-# takes the dtype of what it is the gradient of.
+# Each is linear in its first argument and, but for the weights, the other's
+# transpose; a gradient takes the dtype of what it is the gradient of (before
+# the rows move: a cast and a movement commute, and narrow rows are cheaper
+# to move).
 rows_of_tokens.defvjp(
-    lambda x, token, live, tokens: (
-        rows_of_tokens(x, token, live, tokens), (token, live)),
-    lambda tokens, res, g: (
-        tokens_from_rows(g, *res, tokens).astype(g.dtype), None, None))
+    lambda x, order, slot, live, k: (
+        rows_of_tokens(x, order, slot, live, k), (slot, live)),
+    lambda k, res, g: (
+        rows.combine(g, *res).astype(g.dtype), None, None, None))
+
+
+def _tokens_from_rows_bwd(res, g):
+    buffer, weights, order, slot, live = res
+    k = slot.shape[1]
+    g = g.astype(buffer.dtype)
+    # d buffer[r] = weight of r . g[token of r]: the gather, scaled as it
+    # writes. d weights[t, j] = <buffer[slot[t, j]], g[t]> for the live
+    # assignments: computed by the row, then read by `slot`; a select keeps
+    # what dead rows hold out of it. Where nothing asks for it (a share does
+    # not train its router) it is dead code.
+    by_row = jnp.sum(rows.gather(g, order // k, live).astype(jnp.float32)
+                     * buffer.astype(jnp.float32), axis=-1)
+    return (rows.gather(g, order // k, live,
+                        scale=weights.reshape(-1)[order]),
+            jnp.where(slot < live, by_row[slot], 0).astype(weights.dtype),
+            None, None, None)
+
+
 tokens_from_rows.defvjp(
-    lambda rows, token, live, tokens: (
-        tokens_from_rows(rows, token, live, tokens),
-        (token, live, rows[:0])),
-    lambda tokens, res, g: (
-        rows_of_tokens(g, res[0], res[1], tokens).astype(res[2].dtype),
-        None, None))
+    lambda buffer, weights, order, slot, live: (
+        tokens_from_rows(buffer, weights, order, slot, live),
+        (buffer, weights, order, slot, live)),
+    _tokens_from_rows_bwd)
 
 
 class _ExpertBank(nn.Module):
@@ -251,33 +250,42 @@ class DroplessExperts(nn.Module):
             # Held assignments first, by expert; the rest behind them.
             key = jnp.where(here, local, self.held)
             order = jnp.argsort(key, stable=True)
-            group_sizes = jnp.sum(
-                key[:, None] == jnp.arange(self.held)[None, :], axis=0,
-                dtype=jnp.int32)
+            # The inverse of `order` without a second sort: an assignment's
+            # place is its group's first row plus how many of its group came
+            # before it (the sort is stable, and the key has held + 1 values).
+            of_group = key[None, :] == jnp.arange(self.held + 1)[:, None]
+            before = jnp.cumsum(of_group, axis=1, dtype=jnp.int32)
+            sizes = before[:, -1]
+            first_row = jnp.cumsum(sizes) - sizes
+            slot = jnp.sum(jnp.where(of_group, before - 1 + first_row[:, None],
+                                     0), axis=0).reshape(T, k)
+            group_sizes = sizes[:self.held]
             live = jnp.sum(group_sizes)
-            token = order // k
-            w = weights.reshape(N)[order]
-            rows = rows_of_tokens(x, token, live, T)
+            buffer = rows_of_tokens(x, order, slot, live, k)
         if self.is_mutable_collection(ROUND_COUNTERS):
-            self._count(group_sizes, here.reshape(T, k), T)
+            self._count(group_sizes, here.reshape(T, k), T, rows.visited_rows(
+                live, N, rows.gather_tile(N, D, x.dtype)))
         with jax.named_scope("dk_moe_experts"):
             out = _GatedExperts(self.held, D, self.d_expert,
-                                name="experts")(rows, group_sizes)
+                                name="experts")(buffer, group_sizes)
         with jax.named_scope("dk_moe_combine"):
             # The grouped product writes the tiles that hold rows and leaves
-            # the others as they were: selected away, never multiplied.
-            out = jnp.where(jnp.arange(N)[:, None] < live, out, 0)
-            out = out * w[:, None].astype(out.dtype)
-            return tokens_from_rows(out, token, live, T).astype(x.dtype)
+            # the others as they were: never read. The weights meet the rows
+            # in the kernel's sum, rounded to the rows' dtype as they were
+            # when a pass over the whole buffer multiplied by them.
+            return tokens_from_rows(out, weights.astype(jnp.float32), order,
+                                    slot, live).astype(x.dtype)
 
-    def _count(self, group_sizes, here, tokens):
+    def _count(self, group_sizes, here, tokens, rows_moved):
         """Add this step's routing to the round's counters (float32: exact
-        up to 2**24 a round)."""
+        up to 2**24 a round). ``rows_moved``: the buffer rows in the tiles
+        the row kernels visited."""
         for name, value in (
                 ("assignments_held", group_sizes.astype(jnp.float32)),
                 ("tokens_without_held_expert", jnp.sum(
                     ~jnp.any(here, axis=-1)).astype(jnp.float32)),
                 ("tokens", jnp.float32(tokens)),
+                ("rows_moved", rows_moved.astype(jnp.float32)),
                 ("steps", jnp.float32(1))):
             var = self.variable(ROUND_COUNTERS, name,
                                 lambda v=value: jnp.zeros_like(v))

@@ -157,9 +157,11 @@ class SmallThinkerLM(DKModule):
     def publish_round_counters(self, round_index: int, counters) -> None:
         """A round's expert load, from the layers' ``ROUND_COUNTERS``
         (numpy, one entry a layer): counter ``moe.assignments_held``, gauges
-        ``moe.load_max_over_mean`` (over the held experts, worst layer) and
-        ``moe.tokens_without_held_expert_share``, and one ``moe.round`` event
-        that keeps the round's index, its steps and the layers with them."""
+        ``moe.load_max_over_mean`` (over the held experts, worst layer),
+        ``moe.tokens_without_held_expert_share`` and ``moe.rows_moved_share``
+        (buffer rows in the tiles the row kernels visited over the buffers'
+        rows), and one ``moe.round`` event that keeps the round's index, its
+        steps and the layers with them."""
         from distkeras_tpu import telemetry
 
         layers = [c["moe"] for _, c in sorted(counters.items())]
@@ -170,16 +172,21 @@ class SmallThinkerLM(DKModule):
         imbalance = float(np.max(assigned.max(axis=1)
                                  / np.maximum(assigned.mean(axis=1), 1e-30)))
         share = without / tokens if tokens else 0.0
+        moved = sum(float(c["rows_moved"]) for c in layers)
+        moved_share = moved / (tokens * self.experts_per_token) \
+            if tokens else 0.0
         telemetry.counter("moe.assignments_held").add(float(assigned.sum()))
         telemetry.gauge("moe.load_max_over_mean").set(imbalance)
         telemetry.gauge("moe.tokens_without_held_expert_share").set(share)
+        telemetry.gauge("moe.rows_moved_share").set(moved_share)
         telemetry.event("moe.round", {
             "round": int(round_index), "layers": len(layers),
             "steps": float(layers[0]["steps"]),
             "assignments_held": float(assigned.sum()),
             "assignments_held_by_layer": assigned.sum(axis=1).tolist(),
             "load_max_over_mean": imbalance,
-            "tokens_without_held_expert_share": share})
+            "tokens_without_held_expert_share": share,
+            "rows_moved_share": moved_share})
 
 
 def small_smallthinker_lm(seq_len: int = 64, seed: int = 0, **kwargs) -> Model:

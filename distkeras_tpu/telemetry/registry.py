@@ -147,6 +147,10 @@ _METRICS = [
     _m("moe.tokens_without_held_expert_share", "gauge", "models",
        "Last round's share of tokens none of whose chosen experts is held "
        "here (they get nothing from the layer's experts)."),
+    _m("moe.rows_moved_share", "gauge", "models",
+       "Last round's rows of the sorted buffers in the tiles the row kernels "
+       "visited, over the buffers' rows (`ops/pallas/rows.py`; the live "
+       "share rounded up to a tile: 1.0 means every assignment was held)."),
     # -- inference --------------------------------------------------------
     _m("predict.chunk", "span", "inference",
        "Per-chunk end-to-end predict latency."),

@@ -24,6 +24,7 @@ from distkeras_tpu.models import SmallThinkerLM, small_smallthinker_lm  # noqa: 
 from distkeras_tpu.models.base import ROUND_COUNTERS  # noqa: E402
 from distkeras_tpu.models.blocks import DroplessExperts  # noqa: E402
 from distkeras_tpu.ops.losses import get_loss  # noqa: E402
+from distkeras_tpu.ops.pallas import rows as row_kernels  # noqa: E402
 from distkeras_tpu.parallel.sharding import MOE_RULES, param_path_specs  # noqa: E402
 from distkeras_tpu.runtime.mesh import EXPERT_AXIS  # noqa: E402
 
@@ -140,12 +141,14 @@ def _plain_loop(x, weights, experts, params, first, held):
     return out
 
 
-@pytest.mark.parametrize("held", [3, 4], ids=["a-head", "whole-buffer"])
+@pytest.mark.parametrize("held", [3, 4], ids=["live-rows-end-inside-the-buffer",
+                                              "live-rows-fill-the-buffer"])
 def test_expert_layer_drops_nothing_under_a_skewed_router(held):
     """Expert 0 takes nine tokens in ten, expert 1 none: every assignment to
     a held expert is computed, whatever the load. With expert 3 not held,
-    under two thirds of the buffer's rows are live and a head of it moves;
-    with it held every row is and the whole buffer moves."""
+    under two thirds of the buffer's rows are live and the row kernels stop
+    at the tile that holds the last of them; with it held every row is and
+    the whole buffer moves."""
     T, k = 200, 2
     rng = np.random.default_rng(2)
     first_choice = np.where(rng.random(T) < 0.9, 0, 2)
@@ -170,6 +173,9 @@ def test_expert_layer_drops_nothing_under_a_skewed_router(held):
     live = float(np.sum(counted["assignments_held"]))
     assert (live == T * k) if held == 4 else (0.5 < live / (T * k) < 0.68)
     assert float(counted["tokens"]) == T and float(counted["steps"]) == 1
+    # the rows the kernels visited: the live ones, rounded up to a tile
+    tile = row_kernels.gather_tile(T * k, 16, jnp.float32)
+    assert float(counted["rows_moved"]) == min(-(-live // tile) * tile, T * k)
     # and the gradient of every token reaches it through the sorted buffer
     grad = jax.grad(lambda x: jnp.sum(layer.apply(
         variables, x, weights, experts)))(x)
@@ -260,6 +266,14 @@ def test_counters_leave_the_round_program_with_the_loss():
         e["assignments_held"] for e in events)
     assert 0 < tele.gauge("moe.tokens_without_held_expert_share").value < 1
     assert tele.gauge("moe.load_max_over_mean").value >= 1.0
+    # the rows the kernels visited over the buffers' rows: the live share
+    # (a quarter here) rounded up to a tile, and at this size one tile is
+    # the buffer
+    moved = tele.gauge("moe.rows_moved_share").value
+    assert moved == events[-1]["rows_moved_share"]
+    assert moved == sum(float(c["moe"]["rows_moved"]) for c in
+                        counted[ROUND_COUNTERS].values()) / (2 * 4 * L * 2)
+    assert 0.25 <= moved <= 1.0
     assert np.isfinite(trainer.get_history()).all()
 
 
