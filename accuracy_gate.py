@@ -1,13 +1,14 @@
 """North-star accuracy leg (BASELINE.md #3; VERDICT r4 missing #2).
 
 The gate demands >= 90 % linear scaling *at ADAG-equivalent final accuracy*.
-The scaling half is bounded analytically and test-pinned
-(``tests/test_scaling_model.py``); THIS script closes the accuracy half on
-the gate's own model: the bench CIFAR-10 CNN (``models/cnn.py::cifar10_cnn``)
+The scaling half was bounded analytically in round 5 (``SCALING_r05.json``,
+``kind: analytic-bound``, one measured point); THIS script closes the
+accuracy half on the gate's own model: the round-5 benchmark's CIFAR-10 CNN
+(``models/cnn.py::cifar10_cnn``)
 trained to convergence under **ADAG**, **AEASGD** (the north-star
 discipline), and **sync-DP**, with matched sample budgets, at a W=8
 multiplexed-on-one-chip topology (window 8, global batch 1024; the
-throughput bench retuned its B separately — architecture and discipline
+round-5 throughput cell retuned its B separately — architecture and discipline
 are what the accuracy claim needs), across >= 3 seeds — final held-out
 accuracy must agree within epsilon. One chip suffices: this is an
 accuracy claim, not a scaling claim.

@@ -136,7 +136,8 @@ class _Conn:
         self.dialect: Optional[str] = None
 
 
-#: measured-bad knob pairings (the PR 6 bench rules, enforced at init
+#: measured-bad knob pairings (measured on a 2-core CPU box, PR 6;
+#: docs/PERFORMANCE.md "Host-plane rules"; enforced at init
 #: instead of living only in docs): (condition-name, why). Warned once
 #: per process per combo — a fleet of workers must not scream N times.
 _BAD_KNOB_COMBOS_WARNED: set = set()
@@ -145,7 +146,7 @@ _BAD_KNOB_COMBOS_WARNED: set = set()
 def _validate_knob_combo(codec: str, transport: str, shards: int) -> None:
     """One-time warning + telemetry event when a measured-bad pairing is
     forced. Purely advisory: the knobs still apply exactly as requested —
-    the user may know something the bench did not."""
+    the user may know something that measurement did not."""
     combos = []
     if transport == "shm" and codec == wire.CODEC_INT8:
         combos.append((

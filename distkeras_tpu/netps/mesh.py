@@ -43,9 +43,10 @@ contract instead of replacing it:
 Dispatch itself is a direct in-process call (no frames, no sockets, no
 copies): the client hands its wire-form delta — the same ``(array, spec)``
 pairs a frame would carry — straight to the server's dispatch under the
-server's own lock discipline. That handoff is what lets bench #8's
-``mesh`` arm meet the in-process baseline while keeping journal + dedup +
-fence semantics identical to the socket dialects.
+server's own lock discipline. That handoff is what lets the ``mesh``
+dialect meet the in-process engine's rate (2-core CPU box, PR 20: two
+repetitions, not a chip's number) while keeping journal + dedup + fence
+semantics identical to the socket dialects.
 """
 
 from __future__ import annotations

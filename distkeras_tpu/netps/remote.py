@@ -202,7 +202,6 @@ def run_remote(
     hier: Optional[bool] = None,
     hier_flush: Optional[float] = None,
     autotune: Optional[bool] = None,
-    loop_fn=None,
 ) -> tuple[Any, np.ndarray]:
     """Train ``plan.num_workers`` threads against the PS at ``endpoint``.
 
@@ -271,13 +270,10 @@ def run_remote(
     elastic = discipline in ("aeasgd", "eamsgd")
     treedef = jax.tree.structure(model.params)
     init_leaves = _leaves(model.params)
-    if loop_fn is None:
-        # Callers may pass a prebuilt jitted loop (bench.py A/Bs data-plane
-        # variants against ONE compiled executable).
-        loop_fn = jax.jit(make_local_loop(
-            model.module, loss_fn, tx, compute_dtype=compute_dtype,
-            state_collections=model.state_collections, grad_accum=grad_accum,
-            normalize_uint8=getattr(model, "normalize_uint8", True)))
+    loop_fn = jax.jit(make_local_loop(
+        model.module, loss_fn, tx, compute_dtype=compute_dtype,
+        state_collections=model.state_collections, grad_accum=grad_accum,
+        normalize_uint8=getattr(model, "normalize_uint8", True)))
     losses = np.full((plan.num_rounds, W), np.nan, np.float32)
     errors: list = []
     base_key = jax.random.key(seed)
@@ -304,7 +300,8 @@ def run_remote(
     if (tuner is not None and not hier
             and not config.env_is_set("DKTPU_NET_HIER")):
         # Nobody pinned the topology: pick it from the measured fan-in
-        # crossover (the bench hier_curve's break-even) — hierarchical
+        # crossover (break-even at a fan-in of 4; 2-core CPU box, PR 6,
+        # tests/fixtures/hier_curve.json) — hierarchical
         # combining only pays once this host's worker fan-in covers the
         # aggregator's window cost.
         hier = tuner.choose_topology() == "hier"
@@ -491,7 +488,7 @@ def run_remote(
                 drain_one()
             if tuner is not None and w == 0:
                 # The converged dialect + decision counts, for the report
-                # and the bench's auto arm (read from the event stream).
+                # (read from the event stream).
                 tuner.export_summary(client)
             client.leave()
         except BaseException as e:  # noqa: BLE001 - surface on main thread

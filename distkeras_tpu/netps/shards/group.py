@@ -2,7 +2,7 @@
 
 Production deployments launch one OS process per shard (the fleet's
 ``Punchcard.ps["shards"]`` gang, each a ``python -m distkeras_tpu.netps
---shard k/N``). Tests and the bench harness want the same topology without
+--shard k/N``). Tests and the chaos smokes want the same topology without
 process management, so this helper starts N :class:`~distkeras_tpu.netps.
 server.PSServer` instances in one process, each configured with its
 :class:`~distkeras_tpu.netps.shards.plan.PartitionPlan` slice identity,

@@ -3,9 +3,10 @@ telemetry to knobs.
 
 The data plane's knob space (``DKTPU_NET_INFLIGHT`` / ``COMPRESS`` /
 ``SHARDS`` / ``TRANSPORT`` / ``HIER``) is context-dependent by our own
-bench evidence: int8 wins on cross-host TCP but loses on the shm ring
-(quantize cost exceeds bytes saved at memcpy speed), and hierarchical
-aggregation only beats flat topology above a ~4-worker fan-in. Nobody
+measurements (2-core CPU box, PR 6): int8 wins on cross-host TCP but
+loses on the shm ring (quantize cost exceeds bytes saved at memcpy
+speed), and hierarchical aggregation only beats flat topology above a
+~4-worker fan-in. Nobody
 hand-tunes env vars per job at fleet scale, so — gated by
 ``DKTPU_NET_AUTOTUNE=1``, off by default — this package:
 

@@ -53,9 +53,9 @@ def autotune_enabled() -> bool:
 def recommended_topology(num_workers: int,
                          crossover: Optional[int] = None) -> str:
     """``"hier"`` at/above the measured fan-in crossover
-    (``DKTPU_TUNE_HIER_FANIN``), ``"flat"`` below it — the bench
-    ``hier_curve``'s break-even, as a one-liner the controller and the
-    bench both consult."""
+    (``DKTPU_TUNE_HIER_FANIN``), ``"flat"`` below it — the break-even
+    of the recorded ``hier_curve`` (2-core CPU box, PR 6;
+    ``tests/fixtures/hier_curve.json``)."""
     if crossover is None:
         crossover = config.env_int("DKTPU_TUNE_HIER_FANIN")
     return "hier" if int(num_workers) >= int(crossover) else "flat"
@@ -397,7 +397,7 @@ class Tuner:
     # -- end-of-run summary ----------------------------------------------
     def export_summary(self, client=None) -> dict:
         """The converged dialect + decision counts, as gauges and one
-        ``tuner_run_summary`` event (what the bench's auto arm reads)."""
+        ``tuner_run_summary`` event."""
         from distkeras_tpu import telemetry
 
         with self._lock:

@@ -8,7 +8,8 @@ fold, the journal, or the dedup table. The score is **logical f32 bytes
 per second of round trip**: a codec that shrinks the wire 4x wins on a
 slow link even after paying its quantize passes, and loses on the shm
 ring where payload copies run at memcpy speed — the measured crossover
-the bench A/B pinned, re-measured per connection at join time.
+an A/B on a 2-core CPU box pinned (PR 6), re-measured per connection
+at join time.
 
 Old peers are unaffected by construction: the client only probes a peer
 whose join reply carried the ``tuner`` caps bit; anything else returns

@@ -920,7 +920,7 @@ _AUTO_TARGET_S = 0.064
 
 def _auto_size_r(steady_s: float, round_bytes: int) -> int:
     """Rounds per program from a measured steady-state per-round time —
-    the single sizing rule shared by run_auto and bench.py's probe.
+    run_auto's sizing rule.
 
     Multi-process: every process must run identical blocked programs
     (mismatched R means mismatched collectives -> distributed hang), but
@@ -940,9 +940,9 @@ def _auto_size_r(steady_s: float, round_bytes: int) -> int:
 def probe_steady(dispatch_round, n: int = _AUTO_PROBE_ROUNDS) -> float:
     """Steady-state per-round seconds: ``n`` unfenced dispatches, ONE fence
     (a per-round fence would add its sync round-trip to every sample). The
-    shared measurement protocol for pre-staged probes (bench.py); run_auto
-    inlines the same loop because it also collects losses and excludes
-    staging time."""
+    measurement protocol for pre-staged probes
+    (``examples/imagenet_disk.py``); run_auto inlines the same loop because
+    it also collects losses and excludes staging time."""
     import time as _time
 
     t0 = _time.perf_counter()

@@ -5,9 +5,9 @@ path among other things, so a directory that moves (a temp name, a pid, a
 timestamp) never hits. The rule here: the operator's choice wins — JAX fills
 ``jax_compilation_cache_dir`` from ``JAX_COMPILATION_CACHE_DIR`` at import —
 and otherwise the cache sits at one fixed, git-ignored place beside the
-package. Entry scripts (``chip_smoke.py``, ``bench.py``, ``accuracy_gate.py``)
-call :func:`ensure_compile_cache` before their first compile; importing the
-package never does.
+package. Entry scripts (``chip_smoke.py``, ``benchmarks/run.py``,
+``accuracy_gate.py``) call :func:`ensure_compile_cache` before their first
+compile; importing the package never does.
 
 Importing the package does call :func:`watch_compiles`, which puts what JAX
 itself reports of every compilation (tracing, lowering, the backend's compile

@@ -161,8 +161,7 @@ ENV_REGISTRY: dict = _declare(
            "Calibration tolerance (percent) for the simulator's replay "
            "gates: `sim_drift` and the `hier_crossover` held-out "
            "predictions must land within this band of the measured "
-           "throughput or the gate (and the bench-regression sentinel "
-           "watching `sim_drift.within_band`) reports a miss.",
+           "throughput or the gate reports a miss.",
            "sim"),
     EnvVar("DKTPU_HEALTH_SLO", "str", "",
            "SLO specs for the health plane: inline JSON (starts with `[` "
@@ -374,9 +373,9 @@ ENV_REGISTRY: dict = _declare(
            "network"),
     EnvVar("DKTPU_TUNE_HIER_FANIN", "int", 4,
            "Per-host worker fan-in at/above which the controller picks "
-           "hierarchical aggregation over flat topology (the bench "
-           "`hier_curve` crossover; below it the aggregator's combining "
-           "window costs more than it saves).",
+           "hierarchical aggregation over flat topology (the recorded "
+           "`hier_curve` crossover, 2-core CPU box, PR 6; below it the "
+           "aggregator's combining window costs more than it saves).",
            "network"),
     EnvVar("DKTPU_TUNE_MIN_GAIN", "float", 0.1,
            "Fractional commit-rate improvement a grown worker count must "

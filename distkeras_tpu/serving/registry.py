@@ -32,7 +32,7 @@ Two streaming-loop extensions:
   weights — the streaming trainer writes it), the registry records
   event-to-served-weight freshness (``serving.freshness`` histogram,
   ``serving.freshness_s`` gauge) at the swap instant — the
-  close-the-loop metric the streaming bench reports.
+  close-the-loop metric of the streaming plane.
 """
 
 from __future__ import annotations

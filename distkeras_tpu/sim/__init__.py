@@ -23,7 +23,7 @@ Layout: :mod:`~distkeras_tpu.sim.core` (the seedable event engine),
 ``tracing.analysis.segment_model``), :mod:`~distkeras_tpu.sim.cluster`
 (centers, aggregation trees, link classes),
 :mod:`~distkeras_tpu.sim.fleet_driver` (the scheduler seams),
-:mod:`~distkeras_tpu.sim.calibrate` (bench replay + the flat→hier
+:mod:`~distkeras_tpu.sim.calibrate` (traced-run replay + the flat→hier
 crossover gate), :mod:`~distkeras_tpu.sim.scenarios` (preemption storms,
 failover cascades, region partitions, alert storms), and the
 ``python -m distkeras_tpu.sim`` CLI (``run`` / ``calibrate`` /

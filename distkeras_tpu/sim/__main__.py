@@ -7,15 +7,17 @@ Subcommands::
         any of the scenario's invariant checks fails — the CI
         ``sim-regression`` job is three of these plus ``calibrate``.
 
-    calibrate [--summary PATH] [--band PCT] [--seed N] [--json]
-        The flat->hier crossover replay against the bench summary's
-        ``hier_curve``: held-out predictions must land within the band
-        and the predicted crossover must match the measured one.
+    calibrate (--summary PATH | --tree-live PATH) [--band PCT] [--seed N]
+              [--json]
+        The flat->hier crossover replay against the ``hier_curve`` in
+        PATH (the repo's own record: ``tests/fixtures/hier_curve.json``):
+        held-out predictions must land within the band and the
+        predicted crossover must match the measured one.
         ``--tree-live live.json`` runs the aggregation-tree gate
         instead: re-fit ``region_partition`` from a recorded live tree
         run and assert the root ingress cut and partition staleness
         spike agree within the band (the ``tree_parity`` block the tree
-        chaos smoke writes into BENCH_SUMMARY.json).
+        chaos smoke prints).
 
     report --trace-dir DIR [--json]
         Fit the timing model from a trace stream and print it (the same
@@ -134,16 +136,16 @@ def main(argv=None) -> int:
     runp.add_argument("--workers", type=int, default=None)
     runp.add_argument("--json", action="store_true")
 
-    calp = sub.add_parser("calibrate",
-                          help="bench hier_curve replay gate")
-    calp.add_argument("--summary", default=None,
-                      help="BENCH_SUMMARY.json path (default: repo root)")
+    calp = sub.add_parser("calibrate", help="hier_curve replay gate")
+    curve = calp.add_mutually_exclusive_group(required=True)
+    curve.add_argument("--summary", default=None, metavar="PATH",
+                       help="JSON file holding the measured hier_curve")
     calp.add_argument("--band", type=float, default=None,
                       help="tolerance pct (default DKTPU_SIM_BAND_PCT)")
     calp.add_argument("--seed", type=int, default=None)
-    calp.add_argument("--tree-live", default=None, metavar="PATH",
-                      help="recorded live-tree run (JSON dict): run the "
-                           "tree_parity gate instead of the hier replay")
+    curve.add_argument("--tree-live", default=None, metavar="PATH",
+                       help="recorded live-tree run (JSON dict): run the "
+                            "tree_parity gate instead of the hier replay")
     calp.add_argument("--json", action="store_true")
 
     repp = sub.add_parser("report", help="fitted timing model from traces")

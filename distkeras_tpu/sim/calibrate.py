@@ -3,23 +3,21 @@
 Three replays keep the simulator honest:
 
 * :func:`predict_throughput` / :func:`sim_drift` — replay a *traced*
-  loopback deployment (bench config #8's data plane): fit the timing
-  model from its trace stream (:class:`~distkeras_tpu.sim.model.
-  TimingModel`), run the discrete-event replay (workers alternating
-  fitted work gaps and commit paths against one serialized fold
-  resource — queueing emerges from contention, it is never sampled),
-  and compare predicted to measured throughput. ``bench.py`` publishes
-  the ratio as the ``sim_drift`` block in BENCH_SUMMARY.json so the
-  bench-regression sentinel watches calibration rot like any other
-  regression.
+  netps loopback deployment: fit the timing model from its trace stream
+  (:class:`~distkeras_tpu.sim.model.TimingModel`), run the
+  discrete-event replay (workers alternating fitted work gaps and
+  commit paths against one serialized fold resource — queueing emerges
+  from contention, it is never sampled), and compare predicted to
+  measured throughput; the ratio comes back banded.
 
-* :func:`hier_crossover` — replay the bench ``hier_curve`` (flat vs
-  hierarchical topology at W ∈ {1, 2, 4}): calibrate the serialized
+* :func:`hier_crossover` — replay a measured ``hier_curve`` (flat vs
+  hierarchical topology at W ∈ {1, 2, 4}; the caller names the file
+  that holds it): calibrate the serialized
   root-fold service from the **flat W ∈ {1, 2}** points (flat W=4 held
   out), and split the hier path into a per-commit aggregator cost plus a
   per-flush root cost from the hier curve's **endpoints** (W=1, where
   every commit flushes, and the max-W point, where fan-in batching
-  amortizes the root visit — the root-commit counts in the summary pin
+  amortizes the root visit — the curve's root-commit counts pin
   the flush ratios). The middle hier point is then genuinely predicted:
   the DES runs the real :class:`~distkeras_tpu.sim.cluster.
   SimAggregator` flush policy (fan-in OR age), so the batching
@@ -35,9 +33,9 @@ Three replays keep the simulator honest:
   period, and partition window) and assert the sim reproduces the root
   ingress cut and the partitioned region's staleness spike within the
   band — the gate that licenses the tree what-ifs at 1000-worker scale.
-  The tree chaos smoke publishes it as the ``tree_parity`` block in
-  BENCH_SUMMARY.json; ``sim calibrate --tree-live live.json`` replays
-  one from a recorded live dict.
+  The tree chaos smoke prints the block (and writes it where
+  ``NETPS_SMOKE_SUMMARY`` says); ``sim calibrate --tree-live live.json``
+  replays one from a recorded live dict.
 """
 
 from __future__ import annotations
@@ -52,7 +50,7 @@ from distkeras_tpu.sim.core import SimEngine
 from distkeras_tpu.sim.model import TimingModel
 
 #: hier/flat throughput ratio at which the topology recommendation flips
-#: (the tuner flips on fan-in ≥ DKTPU_TUNE_HIER_FANIN = 4; on the bench
+#: (the tuner flips on fan-in ≥ DKTPU_TUNE_HIER_FANIN = 4; on the measured
 #: curve that corresponds to the ratio entering this band while the
 #: root-ingress cut pays for the residual gap).
 RATIO_BAND = 0.85
@@ -136,9 +134,8 @@ def sim_drift(records: list, measured_tokens_per_sec: float,
               tokens_per_round: float, workers: Optional[int] = None,
               rounds: Optional[int] = None,
               band_pct: Optional[float] = None, seed: int = 0) -> dict:
-    """The BENCH_SUMMARY ``sim_drift`` block: predicted/measured
-    throughput ratio for the traced deployment, banded so the
-    bench-regression sentinel can flag calibration rot."""
+    """Predicted/measured throughput ratio for the traced deployment,
+    with ``within_band`` saying whether calibration has rotted."""
     band = _band_pct(band_pct)
     pred = predict_throughput(records, workers=workers, rounds=rounds,
                               tokens_per_round=tokens_per_round, seed=seed)
@@ -249,19 +246,17 @@ def tree_parity(live: dict, band_pct: Optional[float] = None,
 # -- the flat->hier crossover replay ----------------------------------------
 
 def _curve_rows(summary) -> Tuple[List[dict], str]:
-    """The first config carrying a ``hier_curve``, resolved from a dict,
-    a path, or the repo-root default."""
-    if summary is None:
-        summary = "BENCH_SUMMARY.json"
+    """The first config carrying a ``hier_curve``, resolved from a dict
+    or from the path of a JSON file."""
     if isinstance(summary, str):
         if not os.path.exists(summary):
-            raise FileNotFoundError(f"no bench summary at {summary}")
+            raise FileNotFoundError(f"no hier_curve file at {summary}")
         with open(summary, "r", encoding="utf-8") as f:
             summary = json.load(f)
     for cfg in summary.get("configs", []):
         if cfg.get("hier_curve"):
             return list(cfg["hier_curve"]), str(cfg.get("metric"))
-    raise ValueError("bench summary carries no hier_curve block")
+    raise ValueError("summary carries no hier_curve block")
 
 
 def _replay_point(workers: int, rounds: int, topology: str,
@@ -314,10 +309,12 @@ def _replay_point(workers: int, rounds: int, topology: str,
             "worker_commits_per_sec": (commits / wall) if wall else None}
 
 
-def hier_crossover(summary=None, band_pct: Optional[float] = None,
+def hier_crossover(summary, band_pct: Optional[float] = None,
                    ratio_band: float = RATIO_BAND,
                    flush_s: float = 0.5, seed: int = 0) -> dict:
-    """Replay the bench ``hier_curve`` through the DES; see the module
+    """Replay the ``hier_curve`` of ``summary`` through the DES: a dict,
+    or the path of a JSON file. There is no default, since the curve is
+    a measurement and the caller says which one. See the module
     docstring for the calibration/held-out split. Returns per-point
     predictions, held-out errors, the predicted and measured crossover
     worker counts, and the root-ingress cut at the crossover."""

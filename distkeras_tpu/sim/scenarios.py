@@ -28,11 +28,6 @@ from distkeras_tpu.sim.cluster import LinkClass, SimCenter, TreeTopology
 from distkeras_tpu.sim.core import SimEngine
 from distkeras_tpu.sim.fleet_driver import SimJobRuntime, SimThreadFactory
 
-#: sentinel file paths that never exist — scenario Sentinels must not
-#: read whatever BENCH_SUMMARY.json happens to sit in the cwd.
-_ABSENT = "__dktpu_sim_absent__.json"
-
-
 def _direction_changes(series) -> int:
     """Shrink/expand thrash metric: sign flips of a granted-count
     series (one shrink-then-regrow episode costs 2)."""
@@ -489,8 +484,7 @@ def alert_storm(seed: Optional[int] = None, regions: int = 3,
                  slow_s=6 * sweep_s, severity="ticket",
                  target=f"region-{r}-*") for r in range(regions)],
         alerts=alerts)
-    sentinels = Sentinels(alerts=alerts, bench_summary=_ABSENT,
-                          bench_pin=_ABSENT)
+    sentinels = Sentinels(alerts=alerts)
     names = [f"region-{r}-t{i}" for r in range(regions)
              for i in range(targets_per_region)]
     silent = names[:5]                      # go dark during the breach
@@ -542,30 +536,11 @@ def alert_storm(seed: Optional[int] = None, regions: int = 3,
     }
 
 
-# -- 5. crossover (calibration gate as a scenario) --------------------------
-
-def crossover(seed: Optional[int] = None, summary=None) -> dict:
-    """The flat->hier crossover replay against the bench curve (see
-    :func:`distkeras_tpu.sim.calibrate.hier_crossover`)."""
-    from distkeras_tpu.sim.calibrate import hier_crossover
-
-    out = hier_crossover(summary=summary,
-                         seed=0 if seed is None else seed)
-    out["scenario"] = "crossover"
-    out["checks"] = {
-        "held_out_within_band": bool(out["within_band"]),
-        "crossover_reproduced": bool(out["crossover_reproduced"]),
-    }
-    out["ok"] = all(out["checks"].values())
-    return out
-
-
 SCENARIOS: Dict[str, Callable[..., dict]] = {
     "preemption_storm": preemption_storm,
     "failover_cascade": failover_cascade,
     "region_partition": region_partition,
     "alert_storm": alert_storm,
-    "crossover": crossover,
 }
 
 

@@ -16,7 +16,7 @@ at item granularity where it can also *act*:
   and the forensics snapshot).
 * **Recovery timing**: the clear transition records
   ``stream.recovery_seconds`` (drift detected -> loss back under the
-  hysteresis) — the bench's time-to-recover metric — and invokes
+  hysteresis) — the time-to-recover metric — and invokes
   ``on_recover``.
 
 The same windowed mean doubles as the serving registry's quality gate:

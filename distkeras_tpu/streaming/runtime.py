@@ -57,7 +57,7 @@ class StreamingTraining:
     ``source`` is any object with ``read(start_index, skip)`` yielding
     :class:`StreamRecord`-shaped items and a ``close()``. ``journal``
     is an :class:`OffsetJournal`, a path, or None (no durability — tests
-    only). ``max_items`` bounds the session (bench/tests): intake closes
+    only). ``max_items`` bounds the session (smoke/tests): intake closes
     once that many records have been admitted *beyond* what the journal
     already holds committed.
     """

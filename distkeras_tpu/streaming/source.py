@@ -215,7 +215,7 @@ class FileTailSource(_SourceBase):
 
 
 class StreamProducer:
-    """A TCP record feed for :class:`SocketSource` — the test/bench
+    """A TCP record feed for :class:`SocketSource` — the test/smoke
     producer. Keeps every appended record so any number of sequential
     connections can resume from any offset (the feed's durable upstream,
     playing the role a log broker would in production). ``kill`` drops

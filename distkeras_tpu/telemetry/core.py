@@ -548,5 +548,5 @@ def get() -> Telemetry:
 
 
 def reset() -> None:
-    """Clear the global registry (tests; between bench configs)."""
+    """Clear the global registry (tests; between runs in one process)."""
     get().reset()

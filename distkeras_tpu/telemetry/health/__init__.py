@@ -19,8 +19,7 @@ has gone silent. Four pieces:
   flight-recorder dump on page-severity alerts;
 * :mod:`~distkeras_tpu.telemetry.health.sentinels` — anomaly detectors
   computed from the hub's rings (straggler drift, staleness creep,
-  queue-depth growth, journal lag, shed spikes, silent targets, bench
-  regression against BENCH_PIN/BENCH_SUMMARY bands);
+  queue-depth growth, journal lag, shed spikes, silent targets);
 * the CLIs — ``python -m distkeras_tpu.telemetry health`` (one-shot
   fleet summary) and ``... telemetry top`` (live refreshing view).
 

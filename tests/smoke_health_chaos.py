@@ -132,12 +132,7 @@ def _build_fleet(trace_dir: str, ps_faults: str):
     alerts = AlertManager(clear_after=2)
     engine = SloEngine(parse_slo_specs(json.dumps(SLO_SPECS)),
                        alerts=alerts)
-    # Hermetic bench paths: the repo's own BENCH_* files are not under
-    # test here, and the control leg pins zero alerts.
-    sentinels = Sentinels(
-        alerts=alerts,
-        bench_summary=os.path.join(trace_dir, "no-summary.json"),
-        bench_pin=os.path.join(trace_dir, "no-pin.json"))
+    sentinels = Sentinels(alerts=alerts)
     hub = MetricsHub(interval=HUB_INTERVAL, down_after=DOWN_AFTER,
                      timeout=0.5)
     hub.on_sweep(engine.evaluate)

@@ -77,8 +77,8 @@ traced loopback tree then replays the partition and gates on simulator
 parity: ``sim.calibrate.tree_parity`` re-fits the PR 16
 ``region_partition`` scenario to the live run's shape and the root
 ingress cut + partition staleness spike must agree within
-``DKTPU_SIM_BAND_PCT`` — the ``tree_parity`` block written into
-``BENCH_SUMMARY.json``::
+``DKTPU_SIM_BAND_PCT`` — the ``tree_parity`` block, printed and (where
+``NETPS_SMOKE_SUMMARY`` names a file) written there::
 
     NETPS_SMOKE_TREE=1 DKTPU_PS_STATE_DIR=/tmp/ps-state \\
         python tests/smoke_netps_chaos.py          # region-partition path
@@ -525,13 +525,14 @@ def _scrape_tree_stats(endpoint) -> dict:
         c.close()
 
 
-def _run_tree_parity(repo_summary) -> dict:
+def _run_tree_parity() -> dict:
     """Phase 2 of the tree drill: a live in-process loopback tree under a
     pinned mid-run partition, re-fitted through the simulator. The sim's
     ``region_partition`` scenario — re-shaped to THIS tree — must
     reproduce the measured root ingress cut and the partitioned region's
-    staleness spike within the calibration band; the verdict lands in
-    ``BENCH_SUMMARY.json`` under ``tree_parity``."""
+    staleness spike within the calibration band; the verdict is printed,
+    and written as ``tree_parity`` to the file ``NETPS_SMOKE_SUMMARY``
+    names, if it names one."""
     import json
     import time
 
@@ -612,16 +613,11 @@ def _run_tree_parity(repo_summary) -> dict:
     assert parity["within_band"], (
         "the simulator's region_partition replay left the calibration "
         f"band: {json.dumps(parity, sort_keys=True)}")
-    summary_path = os.environ.get("NETPS_SMOKE_SUMMARY", repo_summary)
-    try:
-        with open(summary_path, encoding="utf-8") as f:
-            summary = json.load(f)
-    except (OSError, ValueError):
-        summary = {}
-    summary["tree_parity"] = parity
-    with open(summary_path, "w", encoding="utf-8") as f:
-        json.dump(summary, f, indent=2, sort_keys=True)
-        f.write("\n")
+    summary_path = os.environ.get("NETPS_SMOKE_SUMMARY")
+    if summary_path:
+        with open(summary_path, "w", encoding="utf-8") as f:
+            json.dump({"tree_parity": parity}, f, indent=2, sort_keys=True)
+            f.write("\n")
     return parity
 
 
@@ -788,10 +784,7 @@ def _run_tree(df, model) -> int:
     assert r1_stats["silent_loss"] == 0, (
         f"window conservation violated: {r1_stats}")
     assert acc >= 0.99, f"accuracy collapsed across the region drill: {acc}"
-    repo_summary = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "BENCH_SUMMARY.json")
-    _run_tree_parity(repo_summary)
+    _run_tree_parity()
     return 0
 
 

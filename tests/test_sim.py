@@ -3,11 +3,10 @@
 (real FleetScheduler on zero OS threads, fed MetricsHub series, with the
 production defaults pinned), counter-rule parity between SimCenter and
 the netps fold functions, the trace-fitted TimingModel over a REAL
-traced loopback run (the same stream bench #8's ``sim_drift`` block
-fits), the calibration gates against the committed BENCH_SUMMARY
-(held-out band + the flat->hier crossover at the measured W), the
-bench-regression sentinel's nested ``sim_drift`` pickup, bit-identical
-scenario determinism under a pinned seed, every scenario's invariant
+traced loopback run (the stream ``sim_drift`` fits), the calibration
+gates against the recorded curve in ``fixtures/hier_curve.json``
+(held-out band + the flat->hier crossover at the measured W, and no
+curve read that the caller did not name), bit-identical scenario determinism under a pinned seed, every scenario's invariant
 checks at full scale, and the ``python -m distkeras_tpu.sim`` CLI exit
 contract."""
 
@@ -34,8 +33,8 @@ from distkeras_tpu.sim.__main__ import main as sim_main
 from distkeras_tpu.sim.calibrate import predict_throughput
 from distkeras_tpu.sim.cluster import LinkClass, SimAggregator, TreeTopology
 
-SUMMARY = os.path.join(os.path.dirname(__file__), os.pardir,
-                       "BENCH_SUMMARY.json")
+SUMMARY = os.path.join(os.path.dirname(__file__), "fixtures",
+                       "hier_curve.json")
 
 
 # -- the event engine -------------------------------------------------------
@@ -225,7 +224,7 @@ def test_sim_runtime_crash_lose_ack_forces_deduped_retransmit():
 @pytest.fixture(scope="module")
 def traced_records(tmp_path_factory):
     """One real PSServer/PSClient loopback run with tracing on: the
-    stream the timing model fits (same shape bench #8 feeds sim_drift).
+    stream the timing model fits (the shape ``sim_drift`` takes).
     Returns (records, measured_commits_per_sec)."""
     from distkeras_tpu.netps.client import PSClient
     from distkeras_tpu.netps.server import PSServer
@@ -301,7 +300,7 @@ def test_predict_throughput_infers_workers_and_rounds(traced_records):
         128.0 * out["commits_per_sec"])
 
 
-# -- calibration gates vs the committed bench summary -----------------------
+# -- calibration gates vs the recorded curve --------------------------------
 
 def test_hier_crossover_gate_against_bench_summary():
     out = hier_crossover(summary=SUMMARY)
@@ -322,23 +321,23 @@ def test_hier_crossover_is_seed_deterministic():
     assert json.dumps(a, sort_keys=True) == json.dumps(b, sort_keys=True)
 
 
-def test_sentinel_picks_up_nested_sim_drift(tmp_path):
-    from distkeras_tpu.telemetry.health.sentinels import Sentinels
-
-    p = tmp_path / "BENCH_SUMMARY.json"
-    p.write_text(json.dumps({"configs": [{
-        "metric": "netps_loopback_aeasgd_tokens_per_sec_per_chip",
-        "value": 100.0, "within_band": True,
-        "sim_drift": {"metric": "sim_predicted_vs_measured_tokens_per_sec",
-                      "value": 1.9, "within_band": False}}]}))
-    regs = Sentinels.bench_regressions(str(p))
-    assert [r["metric"] for r in regs] \
-        == ["sim_predicted_vs_measured_tokens_per_sec"]
-    # a healthy sim_drift stays silent
-    p.write_text(json.dumps({"configs": [{
-        "metric": "m", "value": 1.0, "within_band": True,
-        "sim_drift": {"metric": "s", "value": 1.0, "within_band": True}}]}))
-    assert Sentinels.bench_regressions(str(p)) == []
+def test_hier_crossover_requires_its_curve(tmp_path, monkeypatch, capsys):
+    """No curve named is an error, in the function and in the CLI, and a
+    record lying in the working directory is not picked up in its place."""
+    with open(SUMMARY, encoding="utf-8") as f:
+        doctored = json.load(f)
+    for row in doctored["configs"][0]["hier_curve"]:
+        row["tokens_per_sec"] = 1.0
+    (tmp_path / "BENCH_SUMMARY.json").write_text(json.dumps(doctored))
+    monkeypatch.chdir(tmp_path)
+    with pytest.raises(TypeError, match="summary"):
+        hier_crossover()
+    with pytest.raises(SystemExit) as exc:
+        sim_main(["calibrate"])
+    assert exc.value.code != 0
+    assert "--summary" in capsys.readouterr().err
+    out = hier_crossover(summary=SUMMARY)
+    assert all(p["measured_tokens_per_sec"] > 1.0 for p in out["points"])
 
 
 # -- scenario determinism + invariants --------------------------------------

@@ -287,31 +287,38 @@ def test_metrics_logger_feeds_telemetry(tmp_path):
     assert snap["spans"]["round_seconds"]["count"] == 1
 
 
-def test_metrics_logger_burst_attribution_blocked_contract(tmp_path):
+def test_metrics_logger_burst_attribution_blocked_contract(tmp_path,
+                                                           monkeypatch):
     """The wired path: run_blocked fires callback bursts where the FIRST
     call of a block absorbs the whole block's wall time in dt but only the
     LAST call carries a state. The logger must mark boundaries as
     first-after-a-state-bearing-call — NOT the state-bearing calls
     themselves — or the straggler median anchors on JSONL-write jitter and
     a genuinely slow block never flags."""
-    from distkeras_tpu.metrics import MetricsLogger
+    import types
+
+    from distkeras_tpu import metrics
     from distkeras_tpu.telemetry.training import DisciplineMonitor
 
+    # The logger reads a clock the test owns: a block's wall is what the
+    # test says it is, whatever else the box is doing.
+    now = [100.0]
+    monkeypatch.setattr(metrics, "time", types.SimpleNamespace(
+        perf_counter=lambda: now[0], time=time.time))
     t = Telemetry()
     mon = DisciplineMonitor(None, num_workers=1, telemetry=t)
-    with MetricsLogger(str(tmp_path / "b.jsonl"), telemetry=t,
-                       monitor=mon) as logger:
+    with metrics.MetricsLogger(str(tmp_path / "b.jsonl"), telemetry=t,
+                               monitor=mon) as logger:
         state = object()
         R = 4
         for block in range(5):
-            # The slow block's wall lands on j=0's dt. 0.8s: far above any
-            # load-induced pause a busy CI box can inject into the fast
-            # blocks' boundary dts (a 0.25s gap flaked under parallel load).
-            if block == 4:
-                time.sleep(0.8)
+            # A block's wall lands on j=0's dt: 10 ms, and 1 s for the slow
+            # one; the burst's other callbacks follow 2 us apart.
+            now[0] += 1.0 if block == 4 else 0.010
             for j in range(R):
                 logger(block * R + j, np.float32(1.0),
                        state if j == R - 1 else None)
+                now[0] += 2e-6
     recs = logger.records
     # Block-first records are boundaries; everything else is a tail —
     # including the state-bearing block-final records. The marker is
