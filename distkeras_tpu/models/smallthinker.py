@@ -33,6 +33,12 @@ exchange this cut leaves out, so the share routes with the router it was
 given, and the load it sees is the deployment's. A module that holds every
 expert trains its router as any other parameter.
 
+``remat=True`` recomputes each block in the backward pass, all but the flash
+kernel's forward: its ``out`` and ``lse`` are kept from the first pass (2 B x
+tokens x heads x head_dim a layer in bfloat16, and 4 B a token and head), so
+``dk_flash_fwd`` runs once a layer. The gauge ``remat.flash_residual_bytes``
+says how much that is a step.
+
 Parameters do not depend on the sequence length: build with a short sample
 (``Model.build`` runs the dense attention path, whose scores at L = 8192
 would be ``[28, 8192, 8192]`` float32).
@@ -49,6 +55,8 @@ from distkeras_tpu.models.base import DKModule, Model, register_model
 from distkeras_tpu.models.blocks import (DroplessExperts,
                                          GroupedQueryAttention, RMSNorm,
                                          route_top_k)
+from distkeras_tpu.ops.pallas.flash_attention import (FLASH_RESIDUALS,
+                                                      residual_bytes)
 
 
 class _Router(nn.Module):
@@ -140,8 +148,22 @@ class SmallThinkerLM(DKModule):
         # stream is the same vector and every token chooses the same experts.
         x = nn.Embed(self.vocab_size, self.d_model, name="tok_embed",
                      embedding_init=nn.initializers.normal(1.0))(tokens)
-        block_cls = nn.remat(SmallThinkerBlock) if self.remat \
-            else SmallThinkerBlock
+        block_cls = SmallThinkerBlock
+        if self.remat:
+            # The flash forward's out and lse are not part of what is
+            # recomputed (the module doc says what that costs).
+            block_cls = nn.remat(
+                SmallThinkerBlock,
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *FLASH_RESIDUALS))
+            if not self.is_initializing():
+                from distkeras_tpu import telemetry
+
+                kept = 0
+                if self.attn_impl == "flash":
+                    kept = self.num_layers * residual_bytes(
+                        *tokens.shape, self.num_heads, self.head_dim, x.dtype)
+                telemetry.gauge("remat.flash_residual_bytes").set(kept)
         for l in range(self.num_layers):
             x = block_cls(
                 self.num_heads, self.num_kv_heads, self.head_dim,

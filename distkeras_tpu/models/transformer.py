@@ -12,6 +12,13 @@ rebuild treats long-context + model parallelism as first-class. Design points:
   streams K/V blocks around the ring with ``ppermute`` (``attn_impl='ring'``, see
   ``ops/ring_attention.py``); positions/causal masks are computed from the global
   offset ``axis_index(seq_axis) * local_len``.
+* ``remat=True`` recomputes each block in the backward pass from its input,
+  all but the flash kernel's forward (``attn_impl='flash'``): its ``out`` and
+  ``lse`` are kept from the first pass, so ``dk_flash_fwd`` runs once a layer.
+  That costs 2 B x tokens x heads x head_dim a layer in bfloat16 (and 4 B a
+  token and head); the gauge ``remat.flash_residual_bytes`` says how much it
+  is a step: 0.42 GB at GPT-2 medium's sizes (8 x 1024 tokens, 24 layers),
+  where it fits beside the rest (PERF.md §6, PR 31, has the measurement).
 """
 
 from __future__ import annotations
@@ -23,6 +30,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from distkeras_tpu.models.base import DKModule, Model, register_model
+from distkeras_tpu.ops.pallas.flash_attention import (FLASH_RESIDUALS,
+                                                      residual_bytes)
 from distkeras_tpu.runtime.mesh import MODEL_AXIS
 
 
@@ -169,7 +178,21 @@ class TransformerLM(DKModule):
         x = x + nn.Embed(self.max_seq_len, self.d_model, name="pos_embed")(pos)[None, :, :]
         block_cls = TransformerBlock
         if self.remat:
-            block_cls = nn.remat(TransformerBlock, static_argnums=(2,))
+            # The flash forward's out and lse are not part of what is
+            # recomputed (the module doc says what that costs).
+            block_cls = nn.remat(
+                TransformerBlock, static_argnums=(2,),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *FLASH_RESIDUALS))
+            if not self.is_initializing():
+                from distkeras_tpu import telemetry
+
+                kept = 0
+                if self.attn_impl == "flash":
+                    kept = self.num_layers * residual_bytes(
+                        B, L, self.num_heads, self.d_model // self.num_heads,
+                        x.dtype)
+                telemetry.gauge("remat.flash_residual_bytes").set(kept)
         for i in range(self.num_layers):
             x = block_cls(
                 self.num_heads, self.d_model, self.d_ff,

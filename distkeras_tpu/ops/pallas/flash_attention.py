@@ -73,6 +73,10 @@ Layout: inputs are [B, H, L, D] (the wrapper transposes from the model's
 [B, L, H, D]). Forward/dq grids are (B*H, L/block); the dk+dv kernel's grid
 is the same, each program owning one block of keys. Backward is two kernels
 (dq; dk+dv) using the saved logsumexp, wrapped in ``jax.custom_vjp``.
+The forward rule names its two outputs (:data:`FLASH_RESIDUALS`), so a block
+under ``nn.remat(..., policy=save_only_these_names(*FLASH_RESIDUALS))`` (both
+LM models) keeps them and runs ``dk_flash_fwd`` once a layer, not twice;
+with no such policy in force the names change nothing.
 
 ``interpret=True`` runs the same kernels through the Pallas interpreter —
 that is what CI exercises on the CPU mesh; the compiled path runs on TPU.
@@ -84,10 +88,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from distkeras_tpu.ops.pallas import mode
+
+#: The names of the forward's ``out`` and ``lse`` as the backward's residuals.
+FLASH_RESIDUALS = ("dk_flash_out", "dk_flash_lse")
 
 _NEG = -1e30
 _VMEM_BYTES = 16 * 2 ** 20  # Mosaic's scoped limit for one kernel on a v5e
@@ -548,7 +556,16 @@ def _flash(q, k, v, block_q, block_k, cut, interpret, window):
 
 def _flash_fwd(q, k, v, block_q, block_k, cut, interpret, window):
     out, lse = _flash_bhld(q, k, v, block_q, block_k, cut, interpret, window)
+    out, lse = map(checkpoint_name, (out, lse), FLASH_RESIDUALS)
     return out, (q, k, v, out, lse)
+
+
+def residual_bytes(batch: int, seq_len: int, heads: int, head_dim: int,
+                   dtype) -> int:
+    """Bytes of the ``out`` and ``lse`` (float32, one a row) that one call on
+    ``[batch, seq_len, heads, head_dim]`` queries leaves to its backward:
+    what a policy that saves :data:`FLASH_RESIDUALS` keeps a layer."""
+    return batch * heads * seq_len * (head_dim * jnp.dtype(dtype).itemsize + 4)
 
 
 def _flash_bwd(block_q, block_k, cut, interpret, window, res, do):

@@ -136,6 +136,11 @@ _METRICS = [
        "Score elements the flash kernels' causal schedule visits over L^2, "
        "set as a call is traced (1.0: nothing is skipped; "
        "`flash_attention.tile_schedule`)."),
+    _m("remat.flash_residual_bytes", "gauge", "kernels",
+       "Bytes of the flash forward's `out` and `lse` that a model built with "
+       "`remat=True` keeps from the first pass a step, over its layers, set "
+       "as the model is traced (`flash_attention.FLASH_RESIDUALS`; 0: the "
+       "recomputed block runs `dk_flash_fwd` again)."),
     # -- expert layers ----------------------------------------------------
     _m("moe.assignments_held", "counter", "models",
        "Token-to-expert assignments routed to the experts held here, summed "

@@ -208,31 +208,48 @@ def _round_text(engine, x, y):
         engine.init_state(), xs, ys).compile().as_text()
 
 
-def test_tiny_gpt2_round_program_carries_every_scope():
+def _tiny_gpt2():
     from distkeras_tpu.models.transformer import TransformerLM
-    from distkeras_tpu.parallel.disciplines import AEASGDFold
-    from distkeras_tpu.parallel.engine import AsyncEngine
-    from distkeras_tpu.runtime.mesh import data_mesh
 
-    model = Model.build(
+    return Model.build(
         TransformerLM(vocab_size=64, num_layers=2, d_model=32, num_heads=2,
                       d_ff=64, max_seq_len=128, attn_impl="flash",
                       remat=True),
         jnp.zeros((1, 128), jnp.int32))
+
+
+def _tiny_smallthinker():
+    from distkeras_tpu.models import small_smallthinker_lm
+
+    return small_smallthinker_lm(seq_len=128, attn_impl="flash", remat=True)
+
+
+@pytest.mark.parametrize("build,recomputed", [
+    (_tiny_gpt2, set()),
+    (_tiny_smallthinker, {"dk_moe_route", "dk_moe_experts"}),
+], ids=["gpt2", "smallthinker"])
+def test_tiny_lm_round_program_carries_every_scope(build, recomputed):
+    from distkeras_tpu.parallel.disciplines import AEASGDFold
+    from distkeras_tpu.parallel.engine import AsyncEngine
+    from distkeras_tpu.runtime.mesh import data_mesh
+
     engine = AsyncEngine(
-        model, "adam", "sparse_categorical_crossentropy", AEASGDFold(),
+        build(), "adam", "sparse_categorical_crossentropy", AEASGDFold(),
         data_mesh(num_workers=1), window=2, compute_dtype=jnp.bfloat16)
     text = _round_text(engine, ((128,), np.int32), ((128,), np.int32))
     assert {"dk_local_steps", "dk_fwd_bwd", "dk_optimizer", "dk_fold",
             "dk_loss_gather", "dk_nan_guard", "dk_flash_fwd", "dk_flash_dq",
-            "dk_flash_dkv"} <= _scopes(text)
+            "dk_flash_dkv"} | recomputed <= _scopes(text)
     names = re.findall(r'op_name="([^"]*)"', text)
     backward = [n for n in names if "dk_fwd_bwd" in n and "transpose(" in n]
     remat = [n for n in names if "rematted_computation" in n]
     assert backward and remat
-    # The kernels are found in each pass: forward, recomputed, backward.
+    # The block is recomputed, all but the flash forward: it runs in the first
+    # pass alone, its out and lse kept (`flash_attention.FLASH_RESIDUALS`).
     assert any("dk_flash_fwd" in n and "transpose(" not in n for n in names)
-    assert any("dk_flash_fwd" in n for n in remat)
+    assert not any("dk_flash_fwd" in n for n in remat)
+    assert recomputed <= {part for n in remat for part in n.split("/")}
+    assert any("dk_flash_dq" in n for n in backward)
     assert any("dk_flash_dkv" in n for n in backward)
 
 
