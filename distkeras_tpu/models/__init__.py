@@ -11,6 +11,7 @@ from distkeras_tpu.models.lstm import LSTMClassifier, imdb_lstm  # noqa: F401
 from distkeras_tpu.models.resnet import ResNet, resnet50  # noqa: F401
 from distkeras_tpu.models.transformer import TransformerLM, small_transformer_lm  # noqa: F401
 from distkeras_tpu.models.smallthinker import SmallThinkerLM, small_smallthinker_lm  # noqa: F401
+from distkeras_tpu.models.lfm2 import Lfm2MoeLM, small_lfm2_lm  # noqa: F401
 
 __all__ = [
     "DKModule",
@@ -29,4 +30,6 @@ __all__ = [
     "small_transformer_lm",
     "SmallThinkerLM",
     "small_smallthinker_lm",
+    "Lfm2MoeLM",
+    "small_lfm2_lm",
 ]

@@ -1,9 +1,13 @@
 """Blocks of today's open decoders, for the modules that are built from them
-(``models/smallthinker.py``): RMSNorm, rotary positions by the half-split
-rule, grouped-query attention without biases under a full or a sliding-window
-causal mask, and a dropless mixture of gated experts that holds a share of the
-experts it routes over. ``TransformerLM`` and ``MoETransformerLM`` share none
-of these parts (LayerNorm, learned positions, biases, GELU, capacity).
+(``models/smallthinker.py``, ``models/lfm2.py``): RMSNorm, rotary positions by
+the half-split rule, grouped-query attention without biases under a full or a
+sliding-window causal mask (with RMSNorm on each query and key head where the
+family has it), a gated short convolution in attention's place, a gated dense
+feed-forward, two routers (softmax, and sigmoid with a selection bias), and a
+dropless mixture of gated experts that holds a share of the experts it routes
+over. ``TransformerLM`` and ``MoETransformerLM`` share none of these parts
+(LayerNorm, learned positions, biases, GELU, capacity) but the one
+``nn.remat`` site (:func:`remat_block`).
 
 **The expert layer** (:class:`DroplessExperts`) is the layer expert
 parallelism needs: it is told which experts it holds (``first``, ``held``),
@@ -42,10 +46,35 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 from distkeras_tpu.models.base import ROUND_COUNTERS
 from distkeras_tpu.ops.pallas import rows
+from distkeras_tpu.ops.pallas.flash_attention import (FLASH_RESIDUALS,
+                                                      residual_bytes)
+
+#: the gate of a gated feed-forward, by the name a configuration gives it
+ACTIVATIONS = {"relu": nn.relu, "silu": nn.silu}
+
+
+def remat_block(block_cls, module, flash_layers: int, batch: int,
+                seq_len: int, heads: int, head_dim: int, dtype, **kwargs):
+    """``block_cls`` recomputed in the backward pass from its input, all but
+    the flash forward: the kernel's ``out`` and ``lse`` are kept from the
+    first pass (``flash_attention.FLASH_RESIDUALS``), so ``dk_flash_fwd``
+    runs once a layer. The one ``nn.remat`` site of the language models; the
+    gauge ``remat.flash_residual_bytes`` says what ``module``'s
+    ``flash_layers`` layers that run the kernel keep a step (0: none does)."""
+    if not module.is_initializing():
+        from distkeras_tpu import telemetry
+
+        telemetry.gauge("remat.flash_residual_bytes").set(
+            flash_layers * residual_bytes(batch, seq_len, heads, head_dim,
+                                          dtype))
+    return nn.remat(
+        block_cls, policy=jax.checkpoint_policies.save_only_these_names(
+            *FLASH_RESIDUALS), **kwargs)
 
 
 class RMSNorm(nn.Module):
@@ -79,7 +108,10 @@ class GroupedQueryAttention(nn.Module):
     """Causal attention of ``num_heads`` query heads over ``num_kv_heads``
     K/V heads (query head ``n`` reads K/V head ``n // group``), no biases.
     ``window``: query ``i`` sees keys ``j`` with ``0 <= i - j < window``.
-    ``rope_theta``: rotate q and k, or leave positions out (``None``)."""
+    ``rope_theta``: rotate q and k, or leave positions out (``None``).
+    ``qk_norm``: the epsilon of an RMSNorm over each head of q and of k (one
+    weight vector of ``head_dim`` each), before the rotation; ``None``: no
+    such norm and no such parameters."""
 
     num_heads: int
     num_kv_heads: int
@@ -87,6 +119,7 @@ class GroupedQueryAttention(nn.Module):
     window: int | None = None
     rope_theta: float | None = None
     attn_impl: str = "dense"  # 'dense' | 'flash'
+    qk_norm: float | None = None
 
     @nn.compact
     def __call__(self, x):
@@ -97,6 +130,9 @@ class GroupedQueryAttention(nn.Module):
             return nn.DenseGeneral((heads, Dh), use_bias=False, name=name)(x)
 
         q, k, v = proj(H, "query"), proj(G, "key"), proj(G, "value")
+        if self.qk_norm is not None:
+            q = RMSNorm(self.qk_norm, name="query_norm")(q)
+            k = RMSNorm(self.qk_norm, name="key_norm")(k)
         if self.rope_theta is not None:
             q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
         q = q / jnp.sqrt(Dh).astype(q.dtype)
@@ -135,6 +171,84 @@ def route_top_k(logits, k: int):
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     w, e = jax.lax.top_k(probs, k)
     return w / jnp.sum(w, axis=-1, keepdims=True), e
+
+
+def route_sigmoid_bias_top_k(logits, bias, k: int, scale: float = 1.0):
+    """The router of the families that balance their experts by a bias
+    (``use_expert_bias``): scores ``p = sigmoid(logits)`` in float32, the
+    ``k`` experts with the largest ``p + bias``, weighed by their *unbiased*
+    scores, renormalised (``w / (sum(w) + 1e-6)``) and scaled. The bias
+    chooses and never weighs, and no gradient reaches it. Returns ``(weights
+    [T, k] float32, experts [T, k], moved [T, k] bool)``; ``moved`` marks the
+    assignments the bias made: those whose expert is not among the ``k`` with
+    the largest unbiased score (fewer than ``k`` scores lie above it)."""
+    probs = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, e = jax.lax.top_k(probs + bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(probs, e, axis=-1)
+    above = jnp.sum(probs[:, None, :] > w[:, :, None], axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6) * scale
+    return w, e, above >= k
+
+
+class Router(nn.Module):
+    """``x W_router`` accumulated and kept in float32 whatever ``x`` is: a
+    logit rounded to bfloat16 moves a token across the top-k boundary."""
+
+    num_experts: int
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (x.shape[-1], self.num_experts))
+        return jnp.einsum("td,de->te", x, kernel.astype(x.dtype),
+                          preferred_element_type=jnp.float32)
+
+
+class GatedShortConv(nn.Module):
+    """A gated short convolution in attention's place (the ``conv`` layers of
+    the LFM2 family): ``[Bg, Cg, u] = split_3(h W_in)``, ``s = Bg * u``, ``c_t
+    = sum_j w_j * s_{t - (K-1) + j}`` a channel with ``s`` zero before the
+    sequence starts (causal and depthwise, ``K`` taps of which the last
+    multiplies the current position), ``y = (Cg * c) W_out``. No bias, no
+    activation, no state carried between sequences. The taps are shifted
+    multiply-adds, so the chain between the two projections is elementwise
+    and lies under one scope, ``dk_shortconv``, in every pass."""
+
+    kernel_size: int = 3
+
+    @nn.compact
+    def __call__(self, h):
+        L, D = h.shape[-2:]
+        K = self.kernel_size
+        bcu = nn.Dense(3 * D, use_bias=False, name="in_proj")(h)
+        taps = self.param(
+            "taps", nn.initializers.variance_scaling(
+                1.0, "fan_in", "truncated_normal", in_axis=-1, out_axis=-2),
+            (D, K)).astype(bcu.dtype)
+        with jax.named_scope("dk_shortconv"):
+            gate_b, gate_c, u = jnp.split(bcu, 3, axis=-1)
+            s = jnp.pad(gate_b * u, [(0, 0)] * (h.ndim - 2)
+                        + [(K - 1, 0), (0, 0)])
+            c = sum(taps[:, j] * s[..., j:j + L, :] for j in range(K))
+            y = gate_c * c
+        return nn.Dense(D, use_bias=False, name="out_proj")(y)
+
+
+class GatedMLP(nn.Module):
+    """``(act(g W_gate) * (g W_up)) W_down``, no bias: a dense layer's
+    feed-forward in the families whose experts are gated the same way."""
+
+    d_ff: int
+    activation: str = "silu"
+
+    @nn.compact
+    def __call__(self, g):
+        def proj(width, name):
+            return nn.Dense(width, use_bias=False, name=name)
+
+        hidden = ACTIVATIONS[self.activation](proj(self.d_ff, "gate")(g)) \
+            * proj(self.d_ff, "up")(g)
+        return proj(g.shape[-1], "down")(hidden)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -207,12 +321,13 @@ class _ExpertBank(nn.Module):
 
 
 class _GatedExperts(nn.Module):
-    """``(relu(g W_gate) * (g W_up)) W_down`` for sorted rows, group by
+    """``(act(g W_gate) * (g W_up)) W_down`` for sorted rows, group by
     group. No loop: three grouped products the trace can see."""
 
     held: int
     d_model: int
     d_expert: int
+    activation: str = "relu"
 
     @nn.compact
     def __call__(self, rows, group_sizes):
@@ -225,19 +340,22 @@ class _GatedExperts(nn.Module):
             return jax.lax.ragged_dot(a, w, group_sizes,
                                       preferred_element_type=rows.dtype)
 
-        return grouped(nn.relu(grouped(rows, gate)) * grouped(rows, up), down)
+        act = ACTIVATIONS[self.activation]
+        return grouped(act(grouped(rows, gate)) * grouped(rows, up), down)
 
 
 class DroplessExperts(nn.Module):
     """This chip's part of a routed expert layer: experts ``first .. first +
     held - 1`` of those ``experts [T, k]`` names, weighted by ``weights``
     (already normalised over all ``k``, held or not). A token none of whose
-    experts is held gets zero."""
+    experts is held gets zero. ``activation``: the experts' gate, ``"relu"``
+    (ReGLU) or ``"silu"`` (SwiGLU)."""
 
     first: int
     held: int
     d_model: int
     d_expert: int
+    activation: str = "relu"
 
     @nn.compact
     def __call__(self, x, weights, experts):
@@ -266,7 +384,7 @@ class DroplessExperts(nn.Module):
             self._count(group_sizes, here.reshape(T, k), T, rows.visited_rows(
                 live, N, rows.gather_tile(N, D, x.dtype)))
         with jax.named_scope("dk_moe_experts"):
-            out = _GatedExperts(self.held, D, self.d_expert,
+            out = _GatedExperts(self.held, D, self.d_expert, self.activation,
                                 name="experts")(buffer, group_sizes)
         with jax.named_scope("dk_moe_combine"):
             # The grouped product writes the tiles that hold rows and leaves
@@ -291,3 +409,51 @@ class DroplessExperts(nn.Module):
                                 lambda v=value: jnp.zeros_like(v))
             if not self.is_initializing():  # init declares them, at zero
                 var.value = var.value + value
+
+
+def publish_moe_round(round_index: int, counters,
+                      experts_per_token: int) -> None:
+    """A round's expert load, from the routed layers' ``ROUND_COUNTERS``
+    (numpy, one entry a routed layer: its expert layer's counts under
+    ``"moe"``, and ``"assignments_moved_by_bias"`` where its router has a
+    selection bias): counter ``moe.assignments_held``, gauges
+    ``moe.load_max_over_mean`` (over the held experts, worst layer),
+    ``moe.tokens_without_held_expert_share``, ``moe.rows_moved_share`` (buffer
+    rows in the tiles the row kernels visited over the buffers' rows) and,
+    with a bias, ``moe.bias_moved_share`` (assignments whose expert the
+    unbiased top-k would not have chosen, over all assignments), and one
+    ``moe.round`` event that keeps the round's index, its steps and the
+    layers with them. What a model's ``publish_round_counters`` calls."""
+    from distkeras_tpu import telemetry
+
+    routed = [c for _, c in sorted(counters.items())]
+    layers = [c["moe"] for c in routed]
+    assigned = np.stack([np.asarray(c["assignments_held"], np.float64)
+                         for c in layers])            # [layers, held]
+    without = sum(float(c["tokens_without_held_expert"]) for c in layers)
+    tokens = sum(float(c["tokens"]) for c in layers)
+    by_layer = (assigned.max(axis=1)
+                / np.maximum(assigned.mean(axis=1), 1e-30))
+    imbalance = float(np.max(by_layer))
+    share = without / tokens if tokens else 0.0
+    moved = sum(float(c["rows_moved"]) for c in layers)
+    assignments = tokens * experts_per_token
+    moved_share = moved / assignments if tokens else 0.0
+    telemetry.counter("moe.assignments_held").add(float(assigned.sum()))
+    telemetry.gauge("moe.load_max_over_mean").set(imbalance)
+    telemetry.gauge("moe.tokens_without_held_expert_share").set(share)
+    telemetry.gauge("moe.rows_moved_share").set(moved_share)
+    event = {
+        "round": int(round_index), "layers": len(layers),
+        "steps": float(layers[0]["steps"]),
+        "assignments_held": float(assigned.sum()),
+        "assignments_held_by_layer": assigned.sum(axis=1).tolist(),
+        "load_max_over_mean": imbalance,
+        "load_max_over_mean_by_layer": by_layer.tolist(),
+        "tokens_without_held_expert_share": share,
+        "rows_moved_share": moved_share}
+    if all("assignments_moved_by_bias" in c for c in routed):
+        by_bias = sum(float(c["assignments_moved_by_bias"]) for c in routed)
+        event["bias_moved_share"] = by_bias / assignments if tokens else 0.0
+        telemetry.gauge("moe.bias_moved_share").set(event["bias_moved_share"])
+    telemetry.event("moe.round", event)

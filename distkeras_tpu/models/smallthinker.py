@@ -48,29 +48,13 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from flax import linen as nn
 
 from distkeras_tpu.models.base import DKModule, Model, register_model
 from distkeras_tpu.models.blocks import (DroplessExperts,
                                          GroupedQueryAttention, RMSNorm,
-                                         route_top_k)
-from distkeras_tpu.ops.pallas.flash_attention import (FLASH_RESIDUALS,
-                                                      residual_bytes)
-
-
-class _Router(nn.Module):
-    """``x W_router`` accumulated and kept in float32 whatever ``x`` is: a
-    logit rounded to bfloat16 moves a token across the top-k boundary."""
-
-    num_experts: int
-
-    @nn.compact
-    def __call__(self, x):
-        kernel = self.param("kernel", nn.initializers.lecun_normal(),
-                            (x.shape[-1], self.num_experts))
-        return jnp.einsum("td,de->te", x, kernel.astype(x.dtype),
-                          preferred_element_type=jnp.float32)
+                                         Router, publish_moe_round,
+                                         remat_block, route_top_k)
 
 
 class SmallThinkerBlock(nn.Module):
@@ -91,7 +75,7 @@ class SmallThinkerBlock(nn.Module):
         B, L, D = x.shape
         first, held = self.experts_held
         with jax.named_scope("dk_moe_route"):
-            logits = _Router(self.num_experts, name="router")(
+            logits = Router(self.num_experts, name="router")(
                 x.reshape(B * L, D))
             if held < self.num_experts:
                 # A share does not train its router (the module doc says why).
@@ -150,20 +134,10 @@ class SmallThinkerLM(DKModule):
                      embedding_init=nn.initializers.normal(1.0))(tokens)
         block_cls = SmallThinkerBlock
         if self.remat:
-            # The flash forward's out and lse are not part of what is
-            # recomputed (the module doc says what that costs).
-            block_cls = nn.remat(
-                SmallThinkerBlock,
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    *FLASH_RESIDUALS))
-            if not self.is_initializing():
-                from distkeras_tpu import telemetry
-
-                kept = 0
-                if self.attn_impl == "flash":
-                    kept = self.num_layers * residual_bytes(
-                        *tokens.shape, self.num_heads, self.head_dim, x.dtype)
-                telemetry.gauge("remat.flash_residual_bytes").set(kept)
+            block_cls = remat_block(
+                SmallThinkerBlock, self,
+                self.num_layers if self.attn_impl == "flash" else 0,
+                *tokens.shape, self.num_heads, self.head_dim, x.dtype)
         for l in range(self.num_layers):
             x = block_cls(
                 self.num_heads, self.num_kv_heads, self.head_dim,
@@ -177,38 +151,7 @@ class SmallThinkerLM(DKModule):
         return nn.Dense(self.vocab_size, use_bias=False, name="lm_head")(x)
 
     def publish_round_counters(self, round_index: int, counters) -> None:
-        """A round's expert load, from the layers' ``ROUND_COUNTERS``
-        (numpy, one entry a layer): counter ``moe.assignments_held``, gauges
-        ``moe.load_max_over_mean`` (over the held experts, worst layer),
-        ``moe.tokens_without_held_expert_share`` and ``moe.rows_moved_share``
-        (buffer rows in the tiles the row kernels visited over the buffers'
-        rows), and one ``moe.round`` event that keeps the round's index, its
-        steps and the layers with them."""
-        from distkeras_tpu import telemetry
-
-        layers = [c["moe"] for _, c in sorted(counters.items())]
-        assigned = np.stack([np.asarray(c["assignments_held"], np.float64)
-                             for c in layers])            # [layers, held]
-        without = sum(float(c["tokens_without_held_expert"]) for c in layers)
-        tokens = sum(float(c["tokens"]) for c in layers)
-        imbalance = float(np.max(assigned.max(axis=1)
-                                 / np.maximum(assigned.mean(axis=1), 1e-30)))
-        share = without / tokens if tokens else 0.0
-        moved = sum(float(c["rows_moved"]) for c in layers)
-        moved_share = moved / (tokens * self.experts_per_token) \
-            if tokens else 0.0
-        telemetry.counter("moe.assignments_held").add(float(assigned.sum()))
-        telemetry.gauge("moe.load_max_over_mean").set(imbalance)
-        telemetry.gauge("moe.tokens_without_held_expert_share").set(share)
-        telemetry.gauge("moe.rows_moved_share").set(moved_share)
-        telemetry.event("moe.round", {
-            "round": int(round_index), "layers": len(layers),
-            "steps": float(layers[0]["steps"]),
-            "assignments_held": float(assigned.sum()),
-            "assignments_held_by_layer": assigned.sum(axis=1).tolist(),
-            "load_max_over_mean": imbalance,
-            "tokens_without_held_expert_share": share,
-            "rows_moved_share": moved_share})
+        publish_moe_round(round_index, counters, self.experts_per_token)
 
 
 def small_smallthinker_lm(seq_len: int = 64, seed: int = 0, **kwargs) -> Model:

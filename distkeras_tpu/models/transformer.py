@@ -30,8 +30,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from distkeras_tpu.models.base import DKModule, Model, register_model
-from distkeras_tpu.ops.pallas.flash_attention import (FLASH_RESIDUALS,
-                                                      residual_bytes)
+from distkeras_tpu.models.blocks import remat_block
 from distkeras_tpu.runtime.mesh import MODEL_AXIS
 
 
@@ -178,21 +177,11 @@ class TransformerLM(DKModule):
         x = x + nn.Embed(self.max_seq_len, self.d_model, name="pos_embed")(pos)[None, :, :]
         block_cls = TransformerBlock
         if self.remat:
-            # The flash forward's out and lse are not part of what is
-            # recomputed (the module doc says what that costs).
-            block_cls = nn.remat(
-                TransformerBlock, static_argnums=(2,),
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    *FLASH_RESIDUALS))
-            if not self.is_initializing():
-                from distkeras_tpu import telemetry
-
-                kept = 0
-                if self.attn_impl == "flash":
-                    kept = self.num_layers * residual_bytes(
-                        B, L, self.num_heads, self.d_model // self.num_heads,
-                        x.dtype)
-                telemetry.gauge("remat.flash_residual_bytes").set(kept)
+            block_cls = remat_block(
+                TransformerBlock, self,
+                self.num_layers if self.attn_impl == "flash" else 0, B, L,
+                self.num_heads, self.d_model // self.num_heads, x.dtype,
+                static_argnums=(2,))
         for i in range(self.num_layers):
             x = block_cls(
                 self.num_heads, self.d_model, self.d_ff,

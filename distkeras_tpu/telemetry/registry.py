@@ -156,6 +156,11 @@ _METRICS = [
        "Last round's rows of the sorted buffers in the tiles the row kernels "
        "visited, over the buffers' rows (`ops/pallas/rows.py`; the live "
        "share rounded up to a tile: 1.0 means every assignment was held)."),
+    _m("moe.bias_moved_share", "gauge", "models",
+       "Last round's share of token-to-expert assignments whose expert the "
+       "unbiased top-k would not have chosen: what the router's selection "
+       "bias moved (`blocks.route_sigmoid_bias_top_k` marks them; models "
+       "whose router has no bias never set it)."),
     # -- inference --------------------------------------------------------
     _m("predict.chunk", "span", "inference",
        "Per-chunk end-to-end predict latency."),

@@ -242,16 +242,17 @@ def one_chip():
         yield jax.sharding.SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("tokens, dtype", [
-    (16384, jnp.bfloat16), (128, jnp.float32), (128, jnp.bfloat16)],
-    ids=["the-cell", "build-float32", "build-bfloat16"])
-def test_the_kernels_compile_for_a_v5e(one_chip, tokens, dtype):
-    """Mosaic takes both kernels at the published width: the benchmark's
-    step (16,384 tokens, k = 6) and ``Model.build``'s sample of 128."""
+@pytest.mark.parametrize("tokens, dtype, k, width", [
+    (16384, jnp.bfloat16, 6, 2560), (128, jnp.float32, 6, 2560),
+    (128, jnp.bfloat16, 6, 2560), (16384, jnp.bfloat16, 4, 2048)],
+    ids=["the-cell", "build-float32", "build-bfloat16", "the-lfm2-cell"])
+def test_the_kernels_compile_for_a_v5e(one_chip, tokens, dtype, k, width):
+    """Mosaic takes both kernels at the published widths: the benchmark's
+    steps (16,384 tokens; k = 6 of 2,560 in SmallThinker's cell, k = 4 of
+    2,048 in LFM2's) and ``Model.build``'s sample of 128."""
     def shape(*dims, dtype=dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    k, width = 6, 2560
     scalar = shape(dtype=jnp.int32)
     for lowered in (
             jax.jit(lambda x, token, live, scale: rows.gather(
@@ -265,3 +266,24 @@ def test_the_kernels_compile_for_a_v5e(one_chip, tokens, dtype):
                     shape(tokens, k, dtype=jnp.float32))):
         text = lowered.compile().as_text()
         assert "tpu_custom_call" in text
+
+
+def test_flash_compiles_for_a_v5e_with_groups_at_head_width_64(one_chip):
+    """LFM2's attention layer: 32 query heads over 8 K/V heads of 64 at L =
+    8,192, forward and backward (GPT-2 runs width 64 without groups,
+    SmallThinker groups at width 128). Kept in this file: a process describes
+    the topology once (``on-chip-measurement`` guide, section 2)."""
+    from distkeras_tpu.ops.pallas import flash_attention
+
+    def shape(heads):
+        return jax.ShapeDtypeStruct((2, 8192, heads, 64), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, interpret=False).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(32), shape(8), shape(8)).compile().as_text()
+    for kernel in ("dk_flash_fwd", "dk_flash_dq", "dk_flash_dkv"):
+        assert kernel in text, kernel
