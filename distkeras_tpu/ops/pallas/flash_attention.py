@@ -41,28 +41,50 @@ bounds, the tests and the gauge ``pallas.flash.visited_share`` all read):
   Without a window and with as many K/V heads as Q heads the kernels, their
   grids and their text are what they were.
 
-**Measured** (TPU v5e, 30 Sep 2026, ``[B, L, H, D] = [8, L, 16, 64]`` bf16,
-each kernel's device time from a profiler trace, us a call; TFLOP/s on the
-products a kernel executes, 2 / 3 / 4 of them for fwd / dq / dk+dv):
+**Measured** (TPU v5e, ``[B, L, H, D] = [8, L, 16, 64]`` bf16, each kernel's
+device time from a profiler trace, us a call; TFLOP/s on the products a
+kernel executes, 2 / 3 / 4 of them for fwd / dq / dk+dv; the first three
+columns 30 Sep 2026 with dk+dv query-major, the last 2 Oct 2026 key-major):
 
-====================  =====  =====  =====  =======================
-L = 1024, tiling      fwd    dq     dk+dv  visited
-====================  =====  =====  =====  =======================
-128 x 1024 (before)   820    976    1689   100 %
-128 x 128             1804   1691   2078   56 %  (loop trips)
-256 x 256             877    833    1468   62.5 %
-512 x 512             618    649    780    75 %
-512 x 512, cut 256    622    611    792    62.5 %
-1024 x 1024, cut 512  424    415    598    75 %
-1024 x 1024, cut 256  468    363    694    62.5 %  (the default)
-====================  =====  =====  =====  =======================
+====================  =====  =====  =====  =========  ====================
+L = 1024, tiling      fwd    dq     dk+dv  key-major  visited
+====================  =====  =====  =====  =========  ====================
+128 x 1024 (before)   820    976    1689              100 %
+128 x 128             1804   1691   2078              56 %  (loop trips)
+256 x 256             877    833    1468              62.5 %
+512 x 512             618    649    780               75 %
+512 x 512, cut 256    622    611    792               62.5 %
+1024 x 1024, cut 512  424    415    598               75 %
+1024 x 1024, cut 256  468    363    694    484        62.5 %  (the default)
+====================  =====  =====  =====  =========  ====================
 
 At L = 2048: 2286 / 2648 / 5313 before (128 x 1024), 1271 / 1338 / 1887 with
-the default 2048 x 2048, cut 512. What decides is the cost of a step, not the
-operations: a ``fori_loop`` trip is a wall the scheduler cannot move work
-across, a [128, 64] x [64, 128] step leaves the MXU waiting on its own
-latency (128 x 128 runs at 11-19 TFLOP/s, the default at 37-57), and d_head
-64 fills half the MXU's contraction depth whatever the tile.
+the default 2048 x 2048, cut 512, and dk+dv 1776 key-major. What decides is
+the cost of a step, not the operations: a ``fori_loop`` trip is a wall the
+scheduler cannot move work across, a [128, 64] x [64, 128] step leaves the MXU
+waiting on its own latency (128 x 128 runs at 11-19 TFLOP/s, the default at
+37-57), and d_head 64 fills half the MXU's contraction depth whatever the
+tile.
+
+dk+dv alone at the calls the benchmark's cells and ``chip_smoke.py`` make
+(2 Oct 2026, parent beside change in one process, 10 calls a side and twice a
+side; dk and dv bit-equal at all five; fwd and dq read the same to 0.3 us):
+
+==============================  ===========  =========  ======
+``[B, L, H / KV heads, D]``     query-major  key-major
+==============================  ===========  =========  ======
+[8, 1024, 16 / 16, 64]          693.6        484.4      -30 %
+[2, 8192, 28 / 4, 128] w 4096   11963.1      10252.4    -14 %
+[2, 8192, 28 / 4, 128]          14485.0      12573.5    -13 %
+[2, 8192, 32 / 8, 64]           16832.8      14702.8    -13 %
+[8, 2048, 16 / 16, 64]          1886.5       1775.5     -6 %
+==============================  ===========  =========  ======
+
+Key-major is faster at every one, so there is one form and nothing selects
+it. It gains most where a program is one chain of static rectangles (L =
+1024: 7 steps of up to 768 x 256) and least where a step's products are large
+beside its two transposes (cut 512 at L = 2048; D = 128; the 512-tiles'
+loop at L = 8192).
 
 Kept from earlier rounds: lse/delta live as [BH, nq, 1, block_q], one exact
 block per program, so no output block is revisited and every grid dim is
@@ -73,6 +95,19 @@ Layout: inputs are [B, H, L, D] (the wrapper transposes from the model's
 [B, L, H, D]). Forward/dq grids are (B*H, L/block); the dk+dv kernel's grid
 is the same, each program owning one block of keys. Backward is two kernels
 (dq; dk+dv) using the saved logsumexp, wrapped in ``jax.custom_vjp``.
+Each kernel computes in the space of the block it owns. Forward and dq own
+query rows and work query-major: ``s = q k^T`` is [Q, K], the row statistics
+(``m``, ``l``; ``lse``, ``delta``) stand beside it as [Q, 1] columns, once a
+program, and every product is plain or contracts the two D dimensions. dk+dv
+owns keys and works key-major (PR 33): ``s^T = k q^T`` is [K, Q], ``p^T =
+exp(s^T - lse[None, :])``, ``dv += p^T do``, ``dp^T = v do^T``, ``dk += (p^T
+(dp^T - delta[None, :])) q``. Its sums over queries are then plain [K, Q] x
+[Q, D] products, and ``lse`` / ``delta`` are read as the [1, Q] lane vectors
+they are stored as and broadcast along sublanes. Query-major, as it was
+until PR 33, the same sums contract over dimension 0 of both operands, for
+which Mosaic transposes two [Q, K] tiles a step, and each step stood ``lse``
+and ``delta`` up as sublane columns; the masks of this side are the
+transposed constants (``_keep(..., key_major=True)``).
 The forward rule names its two outputs (:data:`FLASH_RESIDUALS`), so a block
 under ``nn.remat(..., policy=save_only_these_names(*FLASH_RESIDUALS))`` (both
 LM models) keeps them and runs ``dk_flash_fwd`` once a layer, not twice;
@@ -258,12 +293,15 @@ def visited_share(L: int, block_q: int, block_k: int, cut=None,
     return sum((q1 - q0) * (k1 - k0) for q0, q1, k0, k1, _ in tiles) / (L * L)
 
 
-def _keep(rows: int, cols: int, shift):
+def _keep(rows: int, cols: int, shift, key_major: bool = False):
     """Causal mask of a ``rows x cols`` tile whose corner lies ``shift``
-    columns right of the diagonal: True where row >= column."""
+    columns right of the diagonal: True where row >= column. ``key_major``:
+    the same tile held transposed (dk/dv's space: ``rows`` keys down the
+    sublanes, ``cols`` queries along the lanes), where query >= key is
+    column - row >= shift."""
     row = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
-    return row - col >= shift
+    return (col - row if key_major else row - col) >= shift
 
 
 def _edge_then_diagonal(has_edge, tile: int, cut: int, by_rows: bool, carry,
@@ -274,8 +312,10 @@ def _edge_then_diagonal(has_edge, tile: int, cut: int, by_rows: bool, carry,
     none) and the diagonal tile (:func:`diagonal_cuts`), one chain of
     ``step(on_edge, b0, b1, rect, sub, keep)`` a block of ``cut`` rows or
     columns, ended by ``finish(b0, b1, sub)``. ``carry`` is what the whole
-    tiles left, whole; each chain takes its slice of it."""
-    keep = _keep(cut, cut, 0)
+    tiles left, whole; each chain takes its slice of it. Blocks of columns
+    (dk/dv) hold their squares key-major, so their masks are the transposed
+    ones: the edge tile keeps column < row there."""
+    keep = _keep(cut, cut, 0, key_major=not by_rows)
     beyond = jnp.logical_not(keep)
     edge_blocks = edge_cuts(tile, cut, by_rows)
 
@@ -432,25 +472,26 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
 
     def grad(qi, r0, r1, cols, carry, keep):
         """dk, dv of this program's keys ``cols`` from rows [r0, r1) of
-        q-tile ``qi``."""
+        q-tile ``qi``, key-major: every tile is [K, Q], so the two sums are
+        plain products and lse / delta stay the lane vectors they are."""
         dk, dv = carry
         at = pl.ds(qi * block_q + r0, r1 - r0)
         q = q_ref[0, at, :].astype(jnp.bfloat16)
         do = do_ref[0, at, :].astype(jnp.bfloat16)
-        lse = lse_ref[0, qi, 0, r0:r1][:, None]
-        delta = delta_ref[0, qi, 0, r0:r1][:, None]
-        s = jax.lax.dot_general(q, kb[cols], (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        p = jnp.exp(s - lse)  # [Q, K]
-        if keep is not None:
-            p = jnp.where(keep, p, 0.0)
-        pb = p.astype(jnp.bfloat16)
-        dv = dv + jax.lax.dot_general(pb, do, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, vb[cols], (((1,), (1,)), ((), ())),
+        lse = lse_ref[0, qi, :, r0:r1]      # [1, Q]
+        delta = delta_ref[0, qi, :, r0:r1]
+        st = jax.lax.dot_general(kb[cols], q, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(jnp.bfloat16)
-        dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
+        pt = jnp.exp(st - lse)  # [K, Q]
+        if keep is not None:
+            pt = jnp.where(keep, pt, 0.0)
+        dv = dv + jax.lax.dot_general(
+            pt.astype(jnp.bfloat16), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(vb[cols], do, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - delta)).astype(jnp.bfloat16)
+        dk = dk + jax.lax.dot_general(dst, q, (((1,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
         return dk, dv
 
@@ -488,9 +529,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
     if cut is None:
         finish(0, BK, jax.lax.fori_loop(lo, hi, lambda qi, c: grad(
             qi, 0, block_q, whole, c,
-            _keep(block_q, BK, ki * block_k - qi * block_q)), carry))
+            _keep(BK, block_q, ki * block_k - qi * block_q, True)), carry))
         return
-    keep = _keep(cut, cut, 0)
+    keep = _keep(cut, cut, 0, key_major=True)
     for b0, b1, rects in diagonal_cuts(BK, cut, False):
         sub = tuple(x[b0:b1] for x in carry)
         for r0, r1, _, _, masked in rects:

@@ -138,22 +138,35 @@ def test_schedule_covers_the_causal_triangle_once(L, tiling_of):
         assert (share == 1.0) if tiling_of is _old_tiling else (share <= 0.65)
 
 
-@pytest.mark.parametrize("L,block", [(1024, 128), (640, 128)],
-                         ids=["cell-1024", "five-blocks-640"])
-def test_default_tiling_values_and_grads_match_dense(L, block):
-    """The tiling the benchmark's cell runs (default blocks at L = 1024), and
-    a length that is not a multiple of 8 blocks: out, dq, dk and dv."""
+@pytest.mark.parametrize("L, d, block, block_k, tiling", [
+    (1024, 16, 128, None, (1024, 1024, 256)),
+    (640, 16, 128, None, (640, 640, 128)),
+    (1024, 64, 128, None, (1024, 1024, 256)),
+    (384, 16, 16, None, (128, 128, 64)),
+    (128, 16, 16, 64, (16, 64, None)),
+], ids=["cell-1024", "five-blocks-640", "cell-1024-d64", "looped-tiles-384",
+        "rectangular-16x64"])
+def test_default_tiling_values_and_grads_match_dense(L, d, block, block_k,
+                                                     tiling):
+    """out, dq, dk and dv on the tiling the benchmark's cell runs (default
+    blocks at L = 1024; at its head width 64 too: one tile, static cuts), a
+    length that is not a multiple of 8 blocks, square tiles past 16 grains
+    (the ``fori_loop`` over whole tiles, then the cut diagonal tile) and
+    rectangular ``block_k`` tiles masked whole: with the window and the group
+    of test_flash_window_gqa.py, every path of the key-major dk/dv step."""
+    if block_k is None:
+        assert default_tiling(L, d, block) == tiling
     rng = np.random.default_rng(L)
-    q, k, v = (jnp.asarray(rng.normal(size=(1, L, 1, 16)), jnp.float32)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, L, 1, d)), jnp.float32)
                for _ in range(3))
-    q = q * 0.25
+    q = q * d ** -0.5
 
     def run(fn):
         out, vjp = jax.vjp(fn, q, k, v)
         return (out, *vjp(2.0 * out))
 
-    got = run(lambda q, k, v: flash_attention(q, k, v, block_size=block,
-                                              interpret=True))
+    got = run(lambda q, k, v: flash_attention(
+        q, k, v, block_size=block, block_k=block_k, interpret=True))
     ref = run(dense_causal)
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]),
                                atol=5e-2)
@@ -174,3 +187,4 @@ def test_visited_share_gauge_reads_the_schedule():
     jax.jit(lambda q, k, v: flash_attention(
         q, k, v, block_size=BLOCK, block_k=L, interpret=True)).lower(q, k, v)
     assert telemetry.gauge("pallas.flash.visited_share").value == 1.0
+

@@ -3,6 +3,8 @@ interpreter, small grains): forward and all three gradients against dense
 masked attention, the schedule's coverage of the band, and pins on what the
 schedule of the benchmark's dense cell returns."""
 
+import hashlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -33,13 +35,14 @@ def _run(fn, q, k, v):
 
 @pytest.mark.parametrize("L, heads, kv_heads, window, block, block_k", [
     (128, 4, 2, 64, 16, None),    # L > window, groups of 2, default tiling
+    (256, 4, 2, 64, 16, None),    # four tiles: three with an edge, one without
     (128, 6, 2, 32, 16, 16),      # groups of 3, explicit square 16-tiles
     (256, 3, 1, 128, 16, None),   # one K/V head for all, cut < tile
     (128, 2, 2, 64, 16, None),    # a window without groups
     (128, 4, 2, None, 16, None),  # groups without a window
     (64, 4, 1, 128, 16, None),    # L <= window: the window changes nothing
     (64, 7, 1, 64, 16, None),     # L == window, the cell's group of 7
-], ids=["win-gqa", "win-gqa-16", "win-mqa", "win-mha", "gqa", "L-le-window",
+], ids=["win-gqa", "win-gqa-256", "win-gqa-16", "win-mqa", "win-mha", "gqa", "L-le-window",
         "L-eq-window"])
 def test_windowed_grouped_flash_matches_dense(L, heads, kv_heads, window,
                                               block, block_k):
@@ -124,3 +127,123 @@ def test_visited_share_gauge_is_set_per_call():
             q, k, v, block_size=16, interpret=True,
             window=window)).lower(q, kv, kv)
         assert telemetry.gauge("pallas.flash.visited_share").value == want
+
+
+# The five paths of `_dkv_kernel`, as arguments of `flash_attention` on
+# [1, L, heads, d] queries: (L, heads, kv_heads, d, block, block_k, window).
+# dk and dv of each against dense attention: the cases `cell-1024-d64`,
+# `looped-tiles-384`, `rectangular-16x64` of test_flash_attention.py, and
+# `win-gqa-256`, `gqa` above.
+DKV_PATHS = {
+    "one-tile-cuts-1024x64": (1024, 1, 1, 64, 128, None, None),
+    "looped-square-tiles": (384, 1, 1, 16, 16, None, None),
+    "rectangular": (128, 1, 1, 16, 16, 64, None),
+    "window-group": (256, 4, 2, 16, 16, None, 64),
+    "group": (128, 4, 2, 16, 16, None, None),
+}
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold (loop
+    bodies, branches, inlined functions, a kernel's body)."""
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _eqns(sub)
+
+
+def _transposing_steps(jaxpr) -> list:
+    """What a key-major step must not hold: a product that contracts over
+    dimension 0 of its left operand (Mosaic transposes that tile; a plain
+    ``a @ b`` contracts over the last of ``a`` and the first of ``b``), a
+    value of shape [n, 1] (a lane vector stood up as a sublane column), or a
+    read of a row-statistics block ([1, nq, 1, block]) in another form than
+    its [1, Q] lanes."""
+    found = []
+    for e in _eqns(jaxpr):
+        if e.primitive.name == "dot_general":
+            (lhs, rhs), _ = e.params["dimension_numbers"]
+            if 0 in lhs:
+                found.append(f"dot_general contracting {lhs} x {rhs}")
+        for out in e.outvars:
+            shape = getattr(out.aval, "shape", ())
+            if len(shape) == 2 and shape[1] == 1 and shape[0] > 1:
+                found.append(f"{e.primitive.name} -> {shape}")
+        if e.primitive.name == "get" and e.invars[0].aval.ndim == 4:
+            shape = e.outvars[0].aval.shape
+            if len(shape) != 2 or shape[0] != 1:
+                found.append(f"row statistics read as {shape}")
+    return found
+
+
+def _kernel_jaxpr(name, L, heads, kv_heads, d, block, block_k, window):
+    q = jnp.zeros((1, L, heads, d))
+    kv = jnp.zeros((1, L, kv_heads, d))
+
+    def grads(q, k, v):
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, block_size=block, block_k=block_k, interpret=True,
+            window=window), q, k, v)
+        return vjp(out)
+
+    calls = [e for e in _eqns(jax.make_jaxpr(grads)(q, kv, kv).jaxpr)
+             if e.primitive.name == "pallas_call"
+             and e.params["name"] == name]
+    assert len(calls) == 1
+    return calls[0].params["jaxpr"]
+
+
+@pytest.mark.parametrize("path", list(DKV_PATHS))
+def test_dkv_step_is_key_major_on_every_path(path):
+    """No product of `dk_flash_dkv` contracts over dimension 0 of its left
+    operand, and lse / delta are read as the [1, Q] lane vectors they are
+    stored as: nothing in the step is transposed or stood up as a column."""
+    jaxpr = _kernel_jaxpr("dk_flash_dkv", *DKV_PATHS[path])
+    products = [e for e in _eqns(jaxpr) if e.primitive.name == "dot_general"]
+    assert len(products) >= 4 and len(products) % 4 == 0
+    assert _transposing_steps(jaxpr) == []
+
+
+def test_the_walk_finds_what_it_looks_for():
+    """The query-major step that left (`p.T @ do` as a contraction over
+    dimension 0 of both, `lse[:, None]`) is found by the same walk; so are
+    dq's columns, which are that kernel's own space."""
+    def old_step(p, do, lse):
+        return jax.lax.dot_general(p - lse[:, None], do,
+                                   (((0,), (0,)), ((), ())))
+
+    found = _transposing_steps(jax.make_jaxpr(old_step)(
+        jnp.zeros((32, 16)), jnp.zeros((32, 8)), jnp.zeros((32,))).jaxpr)
+    assert any("dot_general" in f for f in found)
+    assert any("(32, 1)" in f for f in found)
+    dq = _kernel_jaxpr("dk_flash_dq", *DKV_PATHS["group"])
+    assert any("(128, 1)" in f for f in _transposing_steps(dq))
+
+
+@pytest.mark.parametrize(
+    "L, d, window, tiling, share, rects, masked, by_q, by_k", [
+        (1024, 64, None, (1024, 1024, 256), 0.625, 7, 4,
+         "beaa4d972e91fa68", "a3b7773450e829d5"),
+        (8192, 128, 4096, (512, 512, 256), 0.3984375, 156, 48,
+         "5f34bdce27b71214", "6954507ead9473c9"),
+        (8192, 128, None, (512, 512, 256), 0.515625, 168, 32,
+         "52b92b9218b2b95b", "087671ae58192aff"),
+        (8192, 64, None, (512, 512, 256), 0.515625, 168, 32,
+         "52b92b9218b2b95b", "087671ae58192aff"),
+    ], ids=["gpt2m", "smallthinker-window", "smallthinker-full", "lfm2"])
+def test_the_cells_schedules_are_what_they_were(L, d, window, tiling, share,
+                                                rects, masked, by_q, by_k):
+    """The key-major step (PR 33) visits the rectangles the query-major one
+    did: tiling, visited share and the schedule itself (its digest, taken on
+    PR 32's tree) at the tilings of the benchmark's three LM cells."""
+    assert default_tiling(L, d, 128, window) == tiling
+    assert visited_share(L, *tiling, window) == share
+    sched = tile_schedule(L, *tiling, window)
+    assert sched["dk_flash_dq"] == sched["dk_flash_fwd"]
+    for name, digest in (("dk_flash_fwd", by_q), ("dk_flash_dkv", by_k)):
+        assert len(sched[name]) == rects
+        assert sum(m for *_, m in sched[name]) == masked
+        assert sum((q1 - q0) * (k1 - k0)
+                   for q0, q1, k0, k1, _ in sched[name]) == share * L * L
+        assert hashlib.sha1(repr(sched[name]).encode()).hexdigest()[
+            :16] == digest
