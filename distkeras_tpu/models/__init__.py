@@ -12,6 +12,7 @@ from distkeras_tpu.models.resnet import ResNet, resnet50  # noqa: F401
 from distkeras_tpu.models.transformer import TransformerLM, small_transformer_lm  # noqa: F401
 from distkeras_tpu.models.smallthinker import SmallThinkerLM, small_smallthinker_lm  # noqa: F401
 from distkeras_tpu.models.lfm2 import Lfm2MoeLM, small_lfm2_lm  # noqa: F401
+from distkeras_tpu.models.kimi_linear import KimiLinearLM, small_kimi_linear_lm  # noqa: F401
 
 __all__ = [
     "DKModule",
@@ -32,4 +33,6 @@ __all__ = [
     "small_smallthinker_lm",
     "Lfm2MoeLM",
     "small_lfm2_lm",
+    "KimiLinearLM",
+    "small_kimi_linear_lm",
 ]

@@ -1,13 +1,18 @@
 """Blocks of today's open decoders, for the modules that are built from them
-(``models/smallthinker.py``, ``models/lfm2.py``): RMSNorm, rotary positions by
-the half-split rule, grouped-query attention without biases under a full or a
-sliding-window causal mask (with RMSNorm on each query and key head where the
-family has it), a gated short convolution in attention's place, a gated dense
-feed-forward, two routers (softmax, and sigmoid with a selection bias), and a
-dropless mixture of gated experts that holds a share of the experts it routes
-over. ``TransformerLM`` and ``MoETransformerLM`` share none of these parts
-(LayerNorm, learned positions, biases, GELU, capacity) but the one
-``nn.remat`` site (:func:`remat_block`).
+(``models/smallthinker.py``, ``models/lfm2.py``, ``models/kimi_linear.py``):
+RMSNorm, rotary positions by the half-split rule, grouped-query attention
+without biases under a full or a sliding-window causal mask (with RMSNorm on
+each query and key head where the family has it), latent attention (a
+compressed K/V projection, keys wider than values, no positions), a gated
+short convolution and Kimi Delta Attention (a gated delta rule with a decay a
+channel, as a chunked scan) in attention's place, a gated dense feed-forward,
+two routers (softmax, and sigmoid with a selection bias), and a dropless
+mixture of gated experts that holds a share of the experts it routes over.
+The two attention modules and the delta rule are told how many *heads* they
+hold, as the expert layer is told its experts: a chip that shares a layer's
+heads computes its own heads' part of ``W_o``'s sum. ``TransformerLM`` and
+``MoETransformerLM`` share none of these parts (LayerNorm, learned positions,
+biases, GELU, capacity) but the one ``nn.remat`` site (:func:`remat_block`).
 
 **The expert layer** (:class:`DroplessExperts`) is the layer expert
 parallelism needs: it is told which experts it holds (``first``, ``held``),
@@ -50,6 +55,7 @@ import numpy as np
 from flax import linen as nn
 
 from distkeras_tpu.models.base import ROUND_COUNTERS
+from distkeras_tpu.ops.delta_rule import chunk_for, chunked_gated_delta_rule
 from distkeras_tpu.ops.pallas import rows
 from distkeras_tpu.ops.pallas.flash_attention import (FLASH_RESIDUALS,
                                                       residual_bytes)
@@ -173,11 +179,12 @@ def route_top_k(logits, k: int):
     return w / jnp.sum(w, axis=-1, keepdims=True), e
 
 
-def route_sigmoid_bias_top_k(logits, bias, k: int, scale: float = 1.0):
+def route_sigmoid_bias_top_k(logits, bias, k: int, scale: float = 1.0,
+                             eps: float = 1e-6):
     """The router of the families that balance their experts by a bias
     (``use_expert_bias``): scores ``p = sigmoid(logits)`` in float32, the
     ``k`` experts with the largest ``p + bias``, weighed by their *unbiased*
-    scores, renormalised (``w / (sum(w) + 1e-6)``) and scaled. The bias
+    scores, renormalised (``w / (sum(w) + eps)``) and scaled. The bias
     chooses and never weighs, and no gradient reaches it. Returns ``(weights
     [T, k] float32, experts [T, k], moved [T, k] bool)``; ``moved`` marks the
     assignments the bias made: those whose expert is not among the ``k`` with
@@ -186,7 +193,7 @@ def route_sigmoid_bias_top_k(logits, bias, k: int, scale: float = 1.0):
     _, e = jax.lax.top_k(probs + bias.astype(jnp.float32), k)
     w = jnp.take_along_axis(probs, e, axis=-1)
     above = jnp.sum(probs[:, None, :] > w[:, :, None], axis=-1)
-    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6) * scale
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps) * scale
     return w, e, above >= k
 
 
@@ -218,7 +225,7 @@ class GatedShortConv(nn.Module):
 
     @nn.compact
     def __call__(self, h):
-        L, D = h.shape[-2:]
+        D = h.shape[-1]
         K = self.kernel_size
         bcu = nn.Dense(3 * D, use_bias=False, name="in_proj")(h)
         taps = self.param(
@@ -227,11 +234,163 @@ class GatedShortConv(nn.Module):
             (D, K)).astype(bcu.dtype)
         with jax.named_scope("dk_shortconv"):
             gate_b, gate_c, u = jnp.split(bcu, 3, axis=-1)
-            s = jnp.pad(gate_b * u, [(0, 0)] * (h.ndim - 2)
-                        + [(K - 1, 0), (0, 0)])
-            c = sum(taps[:, j] * s[..., j:j + L, :] for j in range(K))
-            y = gate_c * c
+            y = gate_c * _causal_taps(gate_b * u, taps)
         return nn.Dense(D, use_bias=False, name="out_proj")(y)
+
+
+def _causal_taps(x, taps):
+    """``c_t = sum_j taps[:, j] * x_{t - (K-1) + j}`` a channel, ``x`` zero
+    before the sequence starts: a causal depthwise convolution as ``K``
+    shifted multiply-adds. ``x``: [..., L, D]; ``taps``: [D, K]."""
+    L, K = x.shape[-2], taps.shape[1]
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(K - 1, 0), (0, 0)])
+    return sum(taps[:, j] * x[..., j:j + L, :] for j in range(K))
+
+
+class KimiDeltaAttention(nn.Module):
+    """Kimi Delta Attention in attention's place (the ``kda`` layers of Kimi
+    Linear, arXiv:2510.26692), for the ``num_heads`` heads held here, each of
+    ``head_dim`` keys and values, no bias in any projection:
+
+    * ``q~, k~, v = SiLU(conv(h W_q)), SiLU(conv(h W_k)), SiLU(conv(h W_v))``,
+      ``conv`` a causal depthwise convolution of ``conv_kernel`` taps;
+    * ``q = q~ / |q~| * head_dim^-1/2``, ``k = k~ / |k~|`` over a head;
+    * the decay, a channel of the key: ``g = -exp(A_log) * softplus((h W_fa)
+      W_fb + dt_bias)`` in float32, ``alpha = exp(g)``; ``beta = sigmoid(h
+      W_b)`` a head;
+    * the state ``S`` ``[head_dim, head_dim]`` a head, zero as the sequence
+      starts: ``S' = Diag(alpha_t) S_{t-1}``, ``S_t = S' + beta_t k_t (v_t -
+      S'^T k_t)^T``, ``o_t = S_t^T q_t``: computed in the chunked form of
+      ``ops/delta_rule.py`` (which says how no exponential can overflow), in
+      chunks of ``chunk_for(L)`` positions;
+    * ``y = (RMSNorm_head(o; w) * sigmoid((h W_ga) W_gb)) W_o``.
+
+    Scopes: ``dk_kda_conv`` (the three tap sums and their SiLU), ``dk_kda``
+    (norms, decay, in-chunk products, the scan over chunks, the gated norm);
+    the projections and ``W_o`` are matmuls of the step. A round's smallest
+    summed log-decay of a chunk (how near the overflow hazard the run is: -88
+    is where ``e^-G`` would leave float32) and its mean ``beta`` leave the
+    program in ``ROUND_COUNTERS``."""
+
+    num_heads: int
+    head_dim: int = 128
+    conv_kernel: int = 4
+    rms_eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, h):
+        B, L, D = h.shape
+        H, Dh = self.num_heads, self.head_dim
+
+        def dense(width, name, x=h):
+            return nn.Dense(width, use_bias=False, name=name)(x)
+
+        def taps(name):
+            return self.param(
+                name, nn.initializers.variance_scaling(
+                    1.0, "fan_in", "truncated_normal", in_axis=-1,
+                    out_axis=-2), (H * Dh, self.conv_kernel)).astype(h.dtype)
+
+        projected = [(dense(H * Dh, f"{n}_proj"), taps(f"{n}_taps"))
+                     for n in ("q", "k", "v")]
+        a_log = self.param(
+            "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                key, shape, minval=1.0, maxval=16.0)), (H,))
+        dt_bias = self.param("dt_bias", _inverse_softplus_uniform(1e-3, 0.1),
+                             (H * Dh,))
+        decay_in = dense(H * Dh, "f_b", dense(Dh, "f_a"))
+        beta_in = dense(H, "b_proj")
+        gate_in = dense(H * Dh, "g_b", dense(Dh, "g_a"))
+        scale = self.param("o_norm", nn.initializers.ones, (Dh,))
+        with jax.named_scope("dk_kda_conv"):
+            q, k, v = (nn.silu(_causal_taps(x, w)).reshape(B, L, H, Dh)
+                       for x, w in projected)
+        with jax.named_scope("dk_kda"):
+            def unit(x):
+                x32 = x.astype(jnp.float32)
+                return (x32 * jax.lax.rsqrt(jnp.sum(
+                    jnp.square(x32), -1, keepdims=True) + 1e-6))
+
+            g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * jax.nn.softplus(
+                (decay_in.astype(jnp.float32) + dt_bias).reshape(B, L, H, Dh))
+            beta = jax.nn.sigmoid(beta_in.astype(jnp.float32))
+            o, least = chunked_gated_delta_rule(
+                (unit(q) * Dh ** -0.5).astype(h.dtype),
+                unit(k).astype(h.dtype), v, g, beta)
+            o32 = o.astype(jnp.float32)
+            o32 = o32 * jax.lax.rsqrt(jnp.mean(
+                jnp.square(o32), -1, keepdims=True) + self.rms_eps)
+            y = (o32 * scale.astype(jnp.float32) * jax.nn.sigmoid(
+                gate_in.astype(jnp.float32).reshape(B, L, H, Dh)))
+            y = y.astype(h.dtype).reshape(B, L, H * Dh)
+        if self.is_mutable_collection(ROUND_COUNTERS):
+            for name, value, join in (
+                    ("min_chunk_decay", least, jnp.minimum),
+                    ("beta_sum", jnp.sum(beta), jnp.add),
+                    ("beta_count", jnp.float32(beta.size), jnp.add),
+                    ("steps", jnp.float32(1), jnp.add)):
+                var = self.variable(ROUND_COUNTERS, name,
+                                    lambda: jnp.zeros((), jnp.float32))
+                if not self.is_initializing():  # init declares them, at zero
+                    var.value = join(var.value, value.astype(jnp.float32))
+        return dense(D, "o_proj", y)
+
+
+def _inverse_softplus_uniform(low: float, high: float):
+    """An initializer: ``x`` with ``softplus(x)`` uniform in ``[low, high)``."""
+    def init(key, shape, dtype=jnp.float32):
+        u = jax.random.uniform(key, shape, dtype, minval=low, maxval=high)
+        return u + jnp.log(-jnp.expm1(-u))
+    return init
+
+
+class LatentAttention(nn.Module):
+    """Causal attention over a **compressed K/V projection**, without
+    positions (the ``mla`` layers of Kimi Linear: ``mla_use_nope``), for the
+    ``num_heads`` heads held here, no biases: ``Q = h W_q``, a head's
+    ``qk_nope_dim + qk_rope_dim`` columns ``[q_nope | q_rot]``; ``[c | k_rot]
+    = h W_kva`` (``kv_rank + qk_rope_dim`` columns, whole on every chip);
+    ``[k_nope | v] = RMSNorm(c) W_kvb`` a head; a head's key is ``[k_nope_h |
+    k_rot]`` with ``k_rot`` **one vector for all heads**; no rotation is
+    applied to either part; softmax of ``q . k * (qk_nope_dim +
+    qk_rope_dim)^-1/2`` over the earlier positions; ``y = concat_h(P_h v_h)
+    W_o``. Keys are wider than values: ``attn_impl="flash"`` runs
+    ``flash_attention`` with a value width of its own."""
+
+    num_heads: int
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
+    kv_rank: int = 512
+    rms_eps: float = 1e-5
+    attn_impl: str = "dense"  # 'dense' | 'flash'
+
+    @nn.compact
+    def __call__(self, x):
+        B, L, D = x.shape
+        H, Dn, Dr, Dv = (self.num_heads, self.qk_nope_dim, self.qk_rope_dim,
+                         self.v_head_dim)
+        q = nn.DenseGeneral((H, Dn + Dr), use_bias=False, name="query")(x)
+        kva = nn.Dense(self.kv_rank + Dr, use_bias=False, name="kv_a")(x)
+        c, k_rot = kva[..., :self.kv_rank], kva[..., self.kv_rank:]
+        kv = nn.DenseGeneral((H, Dn + Dv), use_bias=False, name="kv_b")(
+            RMSNorm(self.rms_eps, name="kv_norm")(c))
+        k = jnp.concatenate([kv[..., :Dn], jnp.broadcast_to(
+            k_rot[:, :, None, :], (B, L, H, Dr))], axis=-1)
+        v = kv[..., Dn:]
+        q = q * jnp.asarray((Dn + Dr) ** -0.5, q.dtype)
+        if self.attn_impl == "flash" and not self.is_initializing():
+            from distkeras_tpu.models.transformer import _flash_block
+            from distkeras_tpu.ops.pallas import flash_attention
+
+            out = flash_attention(q, k, v, block_size=_flash_block(L))
+        else:
+            scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+            seen = jnp.arange(L)[None, :] <= jnp.arange(L)[:, None]
+            scores = jnp.where(seen, scores, jnp.finfo(scores.dtype).min)
+            out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v)
+        return nn.DenseGeneral(D, axis=(-2, -1), use_bias=False,
+                               name="out")(out)
 
 
 class GatedMLP(nn.Module):

@@ -339,7 +339,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
                 block_k: int, cut, window=None):
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.bfloat16)  # [BQ, D]
-    BQ, D = q.shape
+    BQ, Dv = q.shape[0], v_ref.shape[-1]
 
     def attend(q, k0, width, carry, keep):
         """One online-softmax step of rows ``q`` over keys [k0, k0 + width)."""
@@ -373,7 +373,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
     carry = jax.lax.fori_loop(
         first, lo, lambda ki, c: attend(q, ki * block_k, block_k, c, None),
         (jnp.full((BQ, 1), _NEG, jnp.float32), jnp.zeros((BQ, 1), jnp.float32),
-         jnp.zeros((BQ, D), jnp.float32)))
+         jnp.zeros((BQ, Dv), jnp.float32)))
     if window is not None:
         # A row that keeps nothing of the edge tile (the tile's last) leaves
         # p = 1 behind only where no whole tile came before it, and the
@@ -467,7 +467,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
     ki = pl.program_id(1)
     kb = k_ref[0].astype(jnp.bfloat16)  # [BK, D] (this program's k chunk)
     vb = v_ref[0].astype(jnp.bfloat16)
-    BK, D = kb.shape
+    BK = kb.shape[0]
     nq = q_ref.shape[1] // block_q
 
     def grad(qi, r0, r1, cols, carry, keep):
@@ -505,13 +505,17 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dv_ref[0, c0:c1, :] = carry[1].astype(dv_ref.dtype)
 
     whole = slice(0, BK)
-    zero = jnp.zeros((BK, D), jnp.float32)
-    start = (zero, zero)
+    zero = jnp.zeros(kb.shape, jnp.float32)
+    # one constant where keys and values are as wide: the kernel's text is
+    # then what it was before values could have a width of their own
+    start = (zero, zero if vb.shape == kb.shape
+             else jnp.zeros(vb.shape, jnp.float32))
     if acc_refs:
         # A select, not a product: what the scratch holds before the
         # group's first program wrote it is anything.
         fresh = pl.program_id(2) == 0
-        start = tuple(jnp.where(fresh, zero, ref[...]) for ref in acc_refs)
+        start = tuple(jnp.where(fresh, zero, ref[...])
+                      for zero, ref in zip(start, acc_refs))
     lo, hi = causal_span(ki, block_k, block_q)
     last = nq
     if window is not None:
@@ -549,33 +553,36 @@ def _traced_once(fn):
                                         "interpret", "window"), inline=True)
 
 
-def _kv_specs(L, D, group: int):
-    """K and V whole, for the program of query head ``bh``: its own, or with
-    grouped queries head ``bh // group``'s (consecutive programs of a group
-    name the same block, which is then not fetched again)."""
+def _kv_specs(L, D, Dv, group: int):
+    """K (width ``D``) and V (width ``Dv``) whole, for the program of query
+    head ``bh``: its own, or with grouped queries head ``bh // group``'s
+    (consecutive programs of a group name the same block, which is then not
+    fetched again)."""
     if group == 1:
-        return [_full_spec(L, D), _full_spec(L, D)]
-    return [_full_spec(L, D, lambda bh, i: (bh // group, 0, 0))] * 2
+        return [_full_spec(L, D), _full_spec(L, Dv)]
+    return [_full_spec(L, width, lambda bh, i: (bh // group, 0, 0))
+            for width in (D, Dv)]
 
 
 @_traced_once
 def _flash_bhld(q, k, v, block_q, block_k, cut, interpret, window=None):
-    """Forward on [BH, L, D] inputs (k, v: [BH / group, L, D]); returns
-    (out, lse [BH, nq, 1, block_q])."""
+    """Forward on [BH, L, D] queries (k: [BH / group, L, D], v: [BH / group,
+    L, Dv]); returns (out [BH, L, Dv], lse [BH, nq, 1, block_q])."""
     BH, L, D = q.shape
+    Dv = v.shape[-1]
     grid = (BH, L // block_q)
     call = pl.pallas_call(
         functools.partial(_fwd_kernel, block_q=block_q, block_k=block_k,
                           cut=cut, window=window),
         grid=grid,
         in_specs=[_qblock_spec(block_q, D),
-                  *_kv_specs(L, D, BH // k.shape[0])],
+                  *_kv_specs(L, D, Dv, BH // k.shape[0])],
         out_specs=[
-            _qblock_spec(block_q, D),
+            _qblock_spec(block_q, Dv),
             _rowblock_spec(block_q),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, L, D), q.dtype),
+            jax.ShapeDtypeStruct((BH, L, Dv), q.dtype),
             jax.ShapeDtypeStruct((BH, L // block_q, 1, block_q), jnp.float32),
         ],
         interpret=interpret,
@@ -617,6 +624,7 @@ def _flash_bwd(block_q, block_k, cut, interpret, window, res, do):
 def _flash_grads(q, k, v, out, lse, do, block_q, block_k, cut, interpret,
                  window=None):
     BH, L, D = q.shape
+    Dv = v.shape[-1]
     nq = L // block_q
     group = BH // k.shape[0]
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
@@ -626,8 +634,8 @@ def _flash_grads(q, k, v, out, lse, do, block_q, block_k, cut, interpret,
         functools.partial(_dq_kernel, block_q=block_q, block_k=block_k,
                           cut=cut, window=window),
         grid=(BH, nq),
-        in_specs=[_qblock_spec(block_q, D), *_kv_specs(L, D, group),
-                  _qblock_spec(block_q, D), _rowblock_spec(block_q),
+        in_specs=[_qblock_spec(block_q, D), *_kv_specs(L, D, Dv, group),
+                  _qblock_spec(block_q, Dv), _rowblock_spec(block_q),
                   _rowblock_spec(block_q)],
         out_specs=_qblock_spec(block_q, D),
         out_shape=jax.ShapeDtypeStruct((BH, L, D), q.dtype),
@@ -647,9 +655,9 @@ def _flash_grads(q, k, v, out, lse, do, block_q, block_k, cut, interpret,
             kernel,
             grid=(BH, L // block_k),
             in_specs=[_full_spec(L, D), _qblock_spec(block_k, D),
-                      _qblock_spec(block_k, D), _full_spec(L, D),
+                      _qblock_spec(block_k, Dv), _full_spec(L, Dv),
                       _fullrow_spec(nq, block_q), _fullrow_spec(nq, block_q)],
-            out_specs=[_qblock_spec(block_k, D), _qblock_spec(block_k, D)],
+            out_specs=[_qblock_spec(block_k, D), _qblock_spec(block_k, Dv)],
             out_shape=out_shape,
             interpret=interpret,
             name="dk_flash_dkv",
@@ -672,14 +680,15 @@ def _flash_grads(q, k, v, out, lse, do, block_q, block_k, cut, interpret,
             grid=(k.shape[0], L // block_k, group),
             in_specs=[_full_spec(L, D, of_query),
                       _qblock_spec(block_k, D, of_keys),
-                      _qblock_spec(block_k, D, of_keys),
-                      _full_spec(L, D, of_query),
+                      _qblock_spec(block_k, Dv, of_keys),
+                      _full_spec(L, Dv, of_query),
                       _fullrow_spec(nq, block_q, rows_of_query),
                       _fullrow_spec(nq, block_q, rows_of_query)],
             out_specs=[_qblock_spec(block_k, D, of_keys),
-                       _qblock_spec(block_k, D, of_keys)],
+                       _qblock_spec(block_k, Dv, of_keys)],
             out_shape=out_shape,
-            scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32)] * 2,
+            scratch_shapes=[pltpu.VMEM((block_k, width), jnp.float32)
+                            for width in (D, Dv)],
             interpret=interpret,
             name="dk_flash_dkv",
             **_parallel_kw(interpret, arbitrary=1),
@@ -692,26 +701,45 @@ def _flash_grads(q, k, v, out, lse, do, block_q, block_k, cut, interpret,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def default_tiling(L: int, D: int, block_size: int = 128, window=None):
-    """``(block_q, block_k, cut)`` for sequences of ``L`` and heads of ``D``,
+def _lanes(width: int) -> int:
+    """The lanes a row of ``width`` takes in VMEM: whole tiles of 128."""
+    return -(-width // 128) * 128
+
+
+def default_tiling(L: int, D: int, block_size: int = 128, window=None,
+                   Dv: int | None = None):
+    """``(block_q, block_k, cut)`` for sequences of ``L`` and heads of ``D``
+    (values of ``Dv`` where that is another width),
     every edge a multiple of ``block_size`` that divides ``L`` (the module
     doc has the measurements). Up to 16 blocks the tile is the sequence: no
     loop, only the diagonal's static cuts. Longer, the largest tile up to 8
     blocks whose score temporaries (some 10 bytes an element) fit the scoped
-    VMEM beside K and V. ``cut`` is a quarter of L between 2 and 4 blocks,
-    and at most half the tile. Under a ``window`` shorter than ``L`` the tile
-    also divides the window, whatever ``L``: a tile as long as the sequence
-    would hold the whole window and skip none of it."""
+    VMEM beside K and V and the program's own blocks. ``cut`` is a quarter of
+    L between 2 and 4 blocks, and at most half the tile. Under a ``window``
+    shorter than ``L`` the tile also divides the window, whatever ``L``: a
+    tile as long as the sequence would hold the whole window and skip none of
+    it."""
     units = L // block_size
     reach = units if window is None else window // block_size
     if units <= 16 and window is None:
         tile = units
     else:
-        resident = 4 * L * max(D, 128) * 2  # K and V whole, double-buffered
+        # K and V whole, double-buffered, beside the score temporaries. Where
+        # keys are wider than values (192 beside 128 take 256 and 128 lanes)
+        # that estimate, which fits equal widths as measured, is too near the
+        # limit to leave out the rest: a program's own blocks in and out and
+        # dk/dv's lse and delta (a row of floats padded to 8 sublanes), all
+        # double-buffered. Tiles of 256, not 512, at L = 8,192.
+        Dv = D if Dv is None else Dv
+        lanes = _lanes(D) + _lanes(Dv)
+        resident, own = 2 * L * lanes * 2, 0
+        if Dv != D:
+            resident, own = resident + 2 * 2 * L * 8 * 4, 8 * lanes
         tile = max(m for m in range(1, 9)
                    if units % m == 0 and reach % m == 0 and (
                        m == 1
-                       or resident + 10 * (m * block_size) ** 2 <= _VMEM_BYTES))
+                       or resident + (10 * m * block_size + own)
+                       * m * block_size <= _VMEM_BYTES))
     want = min(4, max(2, units // 4))
     cut = max(m for m in range(1, want + 1)
               if tile % m == 0 and (2 * m <= tile or m == 1))
@@ -721,11 +749,16 @@ def default_tiling(L: int, D: int, block_size: int = 128, window=None):
 def flash_attention(q, k, v, block_size: int = 128, block_k: int | None = None,
                     interpret: bool | None = None, window: int | None = None):
     """Causal FlashAttention. ``q``: [B, L, H, D], pre-scaled by 1/sqrt(D);
-    ``k, v``: [B, L, H / group, D], where query head ``h`` reads K/V head
-    ``h // group`` (group 1: plain multi-head attention). Returns
-    [B, L, H, D]. ``block_size`` is the grain: ``L`` must
-    be a multiple of it (128 on a TPU: the lane width). With ``block_k=None``
-    the tiling follows ``L`` and ``D`` (:func:`default_tiling`: square tiles
+    ``k``: [B, L, H / group, D] and ``v``: [B, L, H / group, Dv], where query
+    head ``h`` reads K/V head ``h // group`` (group 1: plain multi-head
+    attention). Returns [B, L, H, Dv]. Values may have a width of their own
+    (latent attention: keys of 192 beside values of 128): ``out``, ``do`` and
+    ``dv`` then have the values' width, ``dq`` and ``dk`` the keys', and the
+    keys' columns are contracted as one block of ``D`` whatever part of a
+    128-lane tile the last of them fills; nothing is padded in HBM. With ``Dv
+    == D`` the kernels are what they were. ``block_size`` is the grain: ``L``
+    must be a multiple of it (128 on a TPU: the lane width). With
+    ``block_k=None`` the tiling follows ``L`` and ``D`` (:func:`default_tiling`: square tiles
     of up to the whole sequence, the diagonal tile cut at 2-4 grains;
     1024 x 1024 cut at 256 for L = 1024, which visits 62.5 % of the scores).
     An explicit ``block_k`` asks for ``block_size`` x ``block_k`` tiles as
@@ -739,10 +772,12 @@ def flash_attention(q, k, v, block_size: int = 128, block_k: int | None = None,
     """
     interpret = mode.interpret("flash_attention", interpret)
     B, L, H, D = q.shape
-    kv_heads = k.shape[2]
-    if k.shape != v.shape or k.shape != (B, L, kv_heads, D) or H % kv_heads:
+    kv_heads, Dv = k.shape[2], v.shape[-1]
+    if k.shape != (B, L, kv_heads, D) or v.shape != (B, L, kv_heads, Dv) \
+            or H % kv_heads:
         raise ValueError(f"q {q.shape} against k {k.shape} and v {v.shape}: "
-                         "K/V heads must divide the query heads")
+                         "K/V heads must divide the query heads, and keys "
+                         "have the queries' width")
     if L % block_size != 0 or (block_k is not None and L % block_k != 0):
         raise ValueError(
             f"seq_len {L} not divisible by block_q {block_size} / "
@@ -753,7 +788,7 @@ def flash_attention(q, k, v, block_size: int = 128, block_k: int | None = None,
         if window is not None and window % block_size:
             raise ValueError(f"window {window} is not a multiple of the "
                              f"grain {block_size}")
-        block_q, block_k, cut = default_tiling(L, D, block_size, window)
+        block_q, block_k, cut = default_tiling(L, D, block_size, window, Dv)
     else:
         # A square tiling masks its diagonal tile by a constant (cut = tile).
         block_q = block_size
@@ -768,8 +803,8 @@ def flash_attention(q, k, v, block_size: int = 128, block_k: int | None = None,
         visited_share(L, block_q, block_k, cut, window))
 
     def to_bhld(x):
-        return x.transpose(0, 2, 1, 3).reshape(-1, L, D)
+        return x.transpose(0, 2, 1, 3).reshape(-1, L, x.shape[-1])
 
     out = _flash(to_bhld(q), to_bhld(k), to_bhld(v), block_q, block_k, cut,
                  interpret, window)
-    return out.reshape(B, H, L, D).transpose(0, 2, 1, 3)
+    return out.reshape(B, H, L, Dv).transpose(0, 2, 1, 3)

@@ -161,6 +161,21 @@ _METRICS = [
        "unbiased top-k would not have chosen: what the router's selection "
        "bias moved (`blocks.route_sigmoid_bias_top_k` marks them; models "
        "whose router has no bias never set it)."),
+    # -- the delta rule ---------------------------------------------------
+    _m("kda.chunk", "gauge", "models",
+       "Positions a chunk of Kimi Delta Attention's chunked scan, set as the "
+       "model is traced (`ops/delta_rule.py::chunk_for`: 64 where that "
+       "divides the sequence)."),
+    _m("kda.state_bytes", "gauge", "models",
+       "Bytes of the recurrent state the `kda` layers carry a step: layers x "
+       "batch x held heads x head_dim^2 float32, set as the model is traced."),
+    _m("kda.min_chunk_decay", "gauge", "models",
+       "Last round's smallest summed log-decay of a chunk and channel over "
+       "the `kda` layers (<= 0; below -88 `e^-G` would leave float32, which "
+       "is why the chunked form never takes that exponential)."),
+    _m("kda.mean_beta", "gauge", "models",
+       "Last round's mean `beta` (the delta rule's write strength, in (0, "
+       "1)) over the `kda` layers' tokens and heads."),
     # -- inference --------------------------------------------------------
     _m("predict.chunk", "span", "inference",
        "Per-chunk end-to-end predict latency."),

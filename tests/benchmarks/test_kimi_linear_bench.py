@@ -1,0 +1,412 @@
+"""The Kimi Linear family's part of the benchmark: the configuration states its
+cut and every other key as published; its parameter and FLOP counts against
+the numbers of the issue that added it; the new floors against hand-computed
+values; the reader's reduction on a hand-made program and trace; the
+reference's limits at the tiny preset. All on the CPU; nothing here is a
+measurement. (The rehearsal of ``kimi_linear_aeasgd_w1``, traced and
+untraced, is one of ``test_benchmark.py``'s cases: it rehearses every cell of
+the manifest.)"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+import types
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmarks.families import kimi_linear as family  # noqa: E402
+from benchmarks.harness import peaks, result_line  # noqa: E402
+from benchmarks.readers import trace_kda, trace_moe  # noqa: E402
+
+V5E = peaks.PEAKS["TPU v5 lite"]
+CELL = "kimi_linear_aeasgd_w1"
+NAME = "kimi-linear-48b-a3b"
+HELD = {"num_hidden_layers": 5, "num_experts": 8, "vocab_size": 20480,
+        "num_attention_heads": 8, "num_key_value_heads": 8}
+
+
+def _json(*parts):
+    with open(os.path.join(ROOT, *parts), encoding="utf-8") as f:
+        return json.load(f)
+
+
+def _config():
+    return _json("benchmarks", "configs", f"{NAME}.json")
+
+
+def test_configuration_states_the_cut_and_the_published_counts():
+    cfg = _config()
+    pub = cfg["published"]
+    assert (pub["num_hidden_layers"], pub["num_experts"], pub["vocab_size"],
+            pub["num_attention_heads"], pub["num_key_value_heads"],
+            pub["linear_attn_config"]["num_heads"]) \
+        == (27, 256, 163840, 32, 32, 32)
+    # a changed nested group is named by its top-level key
+    assert cfg["reduced"] == list(HELD) + ["linear_attn_config"]
+    assert set(cfg["held_here"]) == set(cfg["reduced"])
+    for key, value in pub.items():  # every other key as published
+        if key == "linear_attn_config":
+            assert cfg[key] == {**value, "num_heads": 8}  # and nothing else
+        else:
+            assert cfg[key] == HELD.get(key, value), key
+    m = cfg["module"]
+    linear = pub["linear_attn_config"]
+    assert (m["d_model"], m["d_ff"], m["d_expert"], m["num_heads"],
+            m["num_experts"], m["experts_per_token"], m["num_shared_experts"],
+            m["kda_head_dim"], m["conv_kernel"], m["qk_nope_head_dim"],
+            m["qk_rope_head_dim"], m["v_head_dim"], m["kv_lora_rank"],
+            m["rms_eps"], m["routed_scaling_factor"],
+            m["num_dense_layers"]) == (
+        pub["hidden_size"], pub["intermediate_size"],
+        pub["moe_intermediate_size"], pub["num_attention_heads"],
+        pub["num_experts"], pub["num_experts_per_token"],
+        pub["num_shared_experts"], linear["head_dim"],
+        linear["short_conv_kernel_size"], pub["qk_nope_head_dim"],
+        pub["qk_rope_head_dim"], pub["v_head_dim"], pub["kv_lora_rank"],
+        pub["rms_norm_eps"], pub["routed_scaling_factor"],
+        pub["first_k_dense_replace"])
+    assert (m["d_model"], m["d_ff"], m["d_expert"], m["kda_head_dim"],
+            m["qk_nope_head_dim"] + m["qk_rope_head_dim"], m["v_head_dim"],
+            m["kv_lora_rank"], m["conv_kernel"], m["routed_scaling_factor"]) \
+        == (2304, 9216, 1024, 128, 192, 128, 512, 4, 2.446)
+    # the held pattern: published layers 1-5, counted from 1
+    kinds = ["mla" if l in linear["full_attn_layers"] else "kda"
+             for l in range(1, 6)]
+    assert set(linear["full_attn_layers"]) | set(linear["kda_layers"]) \
+        == set(range(1, 28))
+    assert m["layer_types"] == kinds == ["kda", "kda", "kda", "mla", "kda"]
+    assert (m["num_layers"], m["experts_held"], m["heads_held"],
+            m["vocab_size"]) == (5, [0, 8], [0, 8], 20480)
+    assert 32 * 8 == 256 and 4 * 8 == 32 and 8 * 20480 == 163840
+    for said in ("32 chips share each layer", "the experts in 32 parts",
+                 "in 4 parts", "the vocabulary in 8 slices", "464,820,000"):
+        assert said in cfg["deployment"], said
+    assert len(cfg["departures"]) >= 6 and set(cfg["assumed"]) >= {
+        "bias", "short_conv", "qk_l2norm", "decay", "beta", "output_gate",
+        "mla_use_nope", "router", "shared_expert", "initialization",
+        "expert_bias_update"}
+    assert m["expert_bias_update"] == 5e-3 and m["attn_impl"] == "flash"
+    entry = next(c for c in _json("BENCHMARK.json")["configs"]
+                 if c["name"] == NAME)
+    assert entry["reduced"] == cfg["reduced"]
+    assert entry["source"] == cfg["source"]
+    assert entry["file"] == f"benchmarks/configs/{NAME}.json"
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if os.path.exists(catalog):
+        with open(catalog, encoding="utf-8") as f:
+            row = next(r for r in map(json.loads, f)
+                       if r["name"] == "Kimi-Linear-48B-A3B-Instruct")
+        assert row["config"] == pub and row["source_url"] == cfg["source"]
+
+
+def test_flops_and_parameters_from_the_shapes():
+    import jax
+    import jax.numpy as jnp
+
+    from distkeras_tpu.models.kimi_linear import KimiLinearLM
+
+    cfg = _config()
+    m = cfg["module"]
+    assert family.held_layers(m) == [
+        ("kda", False), ("kda", True), ("kda", True), ("mla", True),
+        ("kda", True)]
+    assert family.attention_keys_seen(cfg) == [4096.5]
+    # matmul parameters a token (the issue's): the kda operator 10,307,584,
+    # mla 8,273,920, the dense feed-forward 63,700,992, a routed layer's
+    # router + shared expert + 8 * 8/256 experts 9,437,184, the head
+    kda, mla, dense, routed, head = (10_307_584, 8_273_920, 63_700_992,
+                                     9_437_184, 47_185_920)
+    assert routed == 589_824 + 7_077_888 + 7_077_888 * 8 * 8 // 256
+    per_token = 4 * kda + mla + dense + 4 * routed + head
+    assert per_token == 198_139_904
+    assert family.matmul_params_per_token(m) == per_token
+    scores = 12 * 4096.5 * 8 * (192 + 128) / 2
+    recurrence = 21 * 128 * 128 * 8 * 4
+    assert recurrence == 11_010_048
+    assert family.recurrence_flops_per_token(m) == recurrence
+    assert family.train_flops_per_unit(cfg) \
+        == 6 * per_token + scores + recurrence
+    assert 6 * per_token == pytest.approx(1188.8e6, rel=1e-4)
+    assert scores == pytest.approx(62.9e6, rel=1e-3)
+    assert family.train_flops_per_unit(cfg) == pytest.approx(1.263e9, rel=1e-3)
+    assert family.train_flops_per_unit(cfg) * 65536 \
+        == pytest.approx(82.8e12, rel=1e-3)
+    shapes = jax.eval_shape(lambda: KimiLinearLM.from_config(m).init(
+        jax.random.key(0), jnp.zeros((1, 128), jnp.int32), train=False))
+    count = lambda tree: sum(a.size for a in jax.tree.leaves(tree))  # noqa: E731
+    p = shapes["params"]
+    assert count(p["block_0"]["kda"]) == 10_321_032   # taps, A, dt, norm too
+    assert count(p["block_0"]["mlp"]) == 63_700_992
+    assert count(p["block_0"]) == 74_026_632
+    assert count(p["block_3"]["mla"]) == 8_274_432    # with the norm's 512
+    assert count(p["block_1"]["moe"]) == 8 * 7_077_888
+    assert count(p["block_1"]["shared"]) == 7_077_888
+    assert count(p["block_1"]["router"]) == 589_824
+    assert count(p["block_1"]) == count(p["block_2"]) == count(p["block_4"]) \
+        == 74_616_456
+    assert count(p["block_3"]) == 72_569_856
+    assert count(p["tok_embed"]) == count(p["head"]) == 47_185_920
+    bias = shapes["router_bias"]
+    assert count(bias) == 4 * 256
+    assert count(p) == 464_820_000
+    # with all 32 heads (and 16): what does not fit
+    whole = jax.eval_shape(lambda: KimiLinearLM.from_config(
+        {**m, "heads_held": [0, 32]}).init(
+            jax.random.key(0), jnp.zeros((1, 128), jnp.int32), train=False))
+    assert count(whole["params"]) == 602_433_408
+
+
+def test_kda_floor_by_hand():
+    # 65,536 tokens a round, 4 kda layers, 8 heads of 128: 15 * 8 * 128
+    # elements of 2 B and beta three times (3 * 8 * 2 B) a token and layer =
+    # 30,768 B; 8.066e9 B a round -> 9.848 ms at 819 GB/s. Operations 21 *
+    # 128 * 128 * 8 * 65,536 * 4 = 7.216e11 -> 3.66 ms. Bound by bytes.
+    got = trace_kda.kda_floor(65536, 4, 8, 128, V5E)
+    assert got["bytes"] == (15 * 8 * 128 + 3 * 8) * 2 * 65536 * 4 \
+        == 8_065_646_592
+    assert got["flops"] == 21 * 128 * 128 * 8 * 65536 * 4
+    assert got["bound"] == "bytes"
+    assert got["seconds"] == pytest.approx(9.848e-3, rel=1e-3)
+    assert trace_kda.kda_floor(65536, 0, 8, 128, V5E)["seconds"] == 0
+
+
+def test_latent_flash_floor_by_hand():
+    # 65,536 tokens through one full causal layer of 8,192, 8 heads, keys of
+    # 192 beside values of 128: operations 6 * 4096.5 * 8 * 320 * 65,536 =
+    # 4.124e12 -> 20.93 ms at 197 TFLOP/s; bytes 4 * 8 * 320 * 2 * 65,536 =
+    # 1.342e9 -> 1.64 ms. Bound by operations. Layers without attention count
+    # nothing.
+    keys = family.attention_keys_seen(_config())
+    got = trace_kda.latent_flash_floor(65536, keys, 8, 192, 128, V5E)
+    assert got["flops"] == 6 * 4096.5 * 8 * 320 * 65536
+    assert got["bytes"] == 4 * 8 * 320 * 2 * 65536
+    assert got["bound"] == "flops"
+    assert got["seconds"] == pytest.approx(20.93e-3, rel=1e-3)
+    assert trace_kda.latent_flash_floor(65536, [], 8, 192, 128,
+                                        V5E)["seconds"] == 0
+    # equal widths: the floor the grouped reader has, at one K/V head a head
+    same = trace_kda.latent_flash_floor(65536, keys, 8, 128, 128, V5E)
+    assert same["flops"] == trace_moe.flash_window_floor(
+        65536, keys, 8, 8, 128, V5E)["flops"]
+
+
+def test_experts_floor_at_this_configurations_shapes():
+    # the even load: 65,536 tokens x 8 / 256 x 8 held = 16,384 assignments a
+    # layer and round, 65,536 over the 4 routed layers, in 32 layer-steps
+    # (8 steps a round): 18 * 2304 * 1024 * 65,536 = 2.783e12 -> 14.13 ms;
+    # bytes 2 * (32 * 8 * 3 * 2304 * 1024 + 65,536 * 2304) = 3.926e9 -> 4.79
+    # ms. Bound by operations.
+    got = trace_moe.experts_floor(65536, 32, 8, 2304, 1024, V5E)
+    assert got["flops"] == 18 * 2304 * 1024 * 65536
+    assert got["bytes"] == 2 * (32 * 8 * 3 * 2304 * 1024 + 65536 * 2304)
+    assert got["bound"] == "flops"
+    assert got["seconds"] == pytest.approx(14.13e-3, rel=1e-3)
+
+
+_KDA = "jit(round_fn)/dk_local_steps/dk_fwd_bwd/block_1/kda"
+HLO = f"""\
+%fused_taps (p: bf16[8]) -> bf16[8] {{
+  %add.2 = bf16[8]{{0}} add(%p, %p), metadata={{op_name="{_KDA}/dk_kda_conv/add"}}
+}}
+
+%fused_q_proj (p: bf16[8], w: bf16[8,8]) -> bf16[8] {{
+  %convolution.3 = bf16[8]{{0}} convolution(%p, %w), metadata={{op_name="{_KDA}/q_proj/dot_general"}}
+  %taps_fusion = bf16[8]{{0}} fusion(%convolution.3), kind=kLoop, calls=%fused_taps
+}}
+
+%fused_pairs (p: bf16[8]) -> f32[8] {{
+  %convolution.4 = f32[8]{{0}} convolution(%p, %p), metadata={{op_name="{_KDA}/dk_kda/bhnmrk,bhnmck->bhnmrc/dot_general"}}
+}}
+
+%fused_unnamed (p: f32[8]) -> f32[8] {{
+  %exp.7 = f32[8]{{0}} exponential(%p), metadata={{op_name="jit(round_fn)/dk_local_steps/dk_fwd_bwd/transpose(jvp(block_1))/kda/dk_kda/exp"}}
+  %convert.7 = f32[8]{{0}} convert(%exp.7)
+}}
+
+%fused_shared (p: bf16[8], w: bf16[8,8]) -> bf16[8] {{
+  %convolution.8 = bf16[8]{{0}} convolution(%p, %w), metadata={{op_name="jit(round_fn)/dk_local_steps/dk_fwd_bwd/block_1/dk_moe_shared/shared/gate/dot_general"}}
+}}
+
+%scan_body (s: f32[8]) -> f32[8] {{
+  %fusion.20 = f32[8]{{0}} fusion(%s), kind=kOutput, calls=%fused_pairs, metadata={{op_name="{_KDA}/dk_kda/while/body/bhrk,bhkv->bhrv/dot_general"}}
+}}
+
+ENTRY %main (x: bf16[8], w: bf16[8,8]) -> bf16[8] {{
+  %fusion.1 = bf16[8]{{0}} fusion(%x, %w), kind=kOutput, calls=%fused_q_proj, metadata={{op_name="{_KDA}/q_proj/dot_general"}}
+  %fusion.4 = f32[8]{{0}} fusion(%fusion.1), kind=kOutput, calls=%fused_pairs, metadata={{op_name="{_KDA}/dk_kda/bhnmrk,bhnmck->bhnmrc/dot_general"}}
+  %fusion.6 = f32[8]{{0}} fusion(%fusion.4), kind=kLoop, calls=%fused_unnamed
+  %while.9 = f32[8]{{0}} while(%fusion.6), body=%scan_body, metadata={{op_name="{_KDA}/dk_kda/while"}}
+  %fusion.8 = bf16[8]{{0}} fusion(%while.9, %w), kind=kOutput, calls=%fused_shared, metadata={{op_name="jit(round_fn)/dk_local_steps/dk_fwd_bwd/block_1/dk_moe_shared/shared/gate/dot_general"}}
+  %attn.10 = bf16[8]{{0}} custom-call(%fusion.8), metadata={{op_name="jit(round_fn)/dk_local_steps/dk_fwd_bwd/block_3/mla/dk_flash_fwd"}}
+  %dq.11 = bf16[8]{{0}} custom-call(%attn.10), metadata={{op_name="jit(round_fn)/dk_local_steps/dk_fwd_bwd/transpose(jvp(block_3))/mla/dk_flash_dq"}}
+  %dkv.12 = bf16[8]{{0}} custom-call(%dq.11), metadata={{op_name="jit(round_fn)/dk_local_steps/dk_fwd_bwd/transpose(jvp(block_3))/mla/dk_flash_dkv"}}
+  ROOT %copy.13 = bf16[8]{{0}} copy(%dkv.12)
+}}
+"""
+
+
+def _run(events, hlo=HLO, rounds=2, units=65536):
+    return types.SimpleNamespace(
+        hlo=hlo, trace={"ops0": events, "lo": 0, "hi": 10**9,
+                        "rounds": rounds},
+        window=types.SimpleNamespace(), peak=V5E, units_per_round=units,
+        chips=1)
+
+
+def test_reader_counts_the_scopes_and_leaves_the_projections_out():
+    counted, with_matmul = trace_kda.classify(HLO, trace_kda.KDA)
+    # its own name (a product of the chain, the scan and its body's steps);
+    # a fusion without one that fused the chain and no matmul
+    assert counted == {"fusion.4", "fusion.6", "while.9", "fusion.20"}
+    # W_q's product with the first tap sum fused into it: the matmul's time
+    assert with_matmul == {"fusion.1"}
+    assert trace_kda.classify(HLO, trace_kda.SHARED) == ({"fusion.8"}, set())
+    events = [(0, 50_000_000, "fusion.1"),
+              (50_000_000, 30_000_000, "fusion.4"),
+              (80_000_000, 10_000_000, "fusion.6"),
+              (90_000_000, 100_000_000, "while.9"),      # 20 ms its own
+              (100_000_000, 80_000_000, "fusion.20"),    # the body's step
+              (190_000_000, 24_000_000, "fusion.8"),
+              (220_000_000, 40_000_000, "attn.10"),
+              (260_000_000, 30_000_000, "dq.11"),
+              (290_000_000, 50_000_000, "dkv.12")]
+    got = trace_kda.reduce(HLO, events, 0, 10**9, trace_kda.KDA)
+    assert got["ns"] == 140_000_000 and got["with_matmul_ns"] == 50_000_000
+    assert got["stems"] == {"fusion": 120_000_000, "while": 20_000_000}
+    run = _run(events)
+    assert trace_kda.read(run, what="kda_ms") == pytest.approx(70.0)
+    assert trace_kda.read(run, what="shared_ms") == pytest.approx(12.0)
+    floor = trace_kda.kda_floor(65536, 4, 8, 128, V5E)
+    assert trace_kda.read(run, what="kda_roofline", config=NAME) \
+        == pytest.approx(floor["seconds"] * 1e3 / 70.0 * 100.0)
+    # the flash kernels against the one layer that has attention: 20.93 ms
+    # over 60 ms of kernels a round
+    assert trace_kda.read(run, what="flash_latent", config=NAME) \
+        == pytest.approx(20.93 / 60.0 * 100.0, rel=1e-3)
+
+
+def test_reader_returns_nothing_where_there_is_nothing_to_read():
+    # a program without the scopes (the parent's): None, and no raise
+    old = "ENTRY %main () -> f32[] {\n  %c.1 = f32[] constant(0)\n}\n"
+    run = _run([(0, 5, "c.1")], hlo=old, rounds=1)
+    for what in ("kda_ms", "kda_roofline", "shared_ms", "flash_latent"):
+        assert trace_kda.read(run, what=what, config=NAME) is None
+    # the scopes in the program and no event of them: 0.0, as trace_scope
+    run = _run([(0, 5, "copy.13")], rounds=1)
+    for what in ("kda_ms", "kda_roofline", "shared_ms", "flash_latent"):
+        assert trace_kda.read(run, what=what, config=NAME) == 0.0
+    # no trace at all
+    assert trace_kda.read(types.SimpleNamespace(trace=None)) is None
+
+
+def test_experts_share_reads_this_configurations_shapes():
+    from distkeras_tpu import telemetry
+
+    tele = telemetry.get()
+    for r, n in ((170, 1.0), (171, 60000.0), (172, 65536.0), (173, 70000.0)):
+        tele.event("moe.round", {
+            "round": r, "layers": 4, "steps": 8.0, "assignments_held": n,
+            "load_max_over_mean": 1.2, "bias_moved_share": 0.1,
+            "tokens_without_held_expert_share": 0.77})
+    hlo = HLO.replace("%copy.13 = bf16[8]{0} copy(%dkv.12)",
+                      "%ragged-dot-none.13 = bf16[8]{0} custom-call(%dkv.12)")
+    run = types.SimpleNamespace(
+        hlo=hlo, peak=V5E, units_per_round=65536, chips=1,
+        trace={"ops0": [(0, 300_000_000, "ragged-dot-none.13")], "lo": 0,
+               "hi": 400_000_000, "rounds": 3},
+        window=types.SimpleNamespace(_trace_open=169))
+    floor = trace_moe.experts_floor((60000 + 65536 + 70000) / 3, 32, 8,
+                                    2304, 1024, V5E)
+    assert trace_moe.read(run, floor="experts", config=NAME) \
+        == pytest.approx(floor["seconds"] * 1e3 / 100.0 * 100.0)
+
+
+def test_the_cell_and_its_metrics_are_in_the_manifest():
+    manifest = _json("BENCHMARK.json")
+    cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) \
+        == (NAME, "aeasgd_w1_8k_b1w8", 1)
+    assert manifest["workloads"][-1] == cell and len(manifest["configs"]) == 5
+    workload = _json("benchmarks", "workloads", f"{CELL}.json")
+    twin = _json("benchmarks", "workloads", "lfm2_aeasgd_w1.json")
+    # the same traffic (65,536 tokens of 8,192-token sequences a round, the
+    # same optimizer and feed), in steps of one sequence: the issue's stated
+    # fall-back, two do not fit (PERF.md PR 34)
+    mine, theirs = workload["trainer"]["kwargs"], twin["trainer"]["kwargs"]
+    assert {k: v for k, v in mine.items()
+            if k not in ("batch_size", "communication_window")} \
+        == {k: v for k, v in theirs.items()
+            if k not in ("batch_size", "communication_window")}
+    assert mine["batch_size"] * mine["communication_window"] \
+        == theirs["batch_size"] * theirs["communication_window"] == 8
+    assert (mine["batch_size"], mine["communication_window"]) == (1, 8)
+    assert workload["feed"] == twin["feed"]
+    untraced = result_line.declared_metrics(manifest, CELL, False)
+    assert set(untraced) == {"tokens_per_s_chip", "setup_s"}
+    traced = result_line.declared_metrics(manifest, CELL, True)
+    new = ("kernel.kda_ms.lm", "kernel.kda_roofline.lm",
+           "kernel.flash_roofline.mla", "moe.experts_roofline.kimi",
+           "moe.shared_ms.lm")
+    assert set(traced) >= set(new) | {
+        "data.stall_ms.lm", "loop.dispatch_ms.lm", "round.device_ms.lm",
+        "model.mfu.lm", "step.forward_ms.lm", "step.backward_ms.lm",
+        "step.optimizer_ms.lm", "step.remat_ms.lm", "fold.device_ms.lm",
+        "kernel.flash_ms.lm", "moe.route_ms.lm", "moe.experts_ms.lm"}
+    # the shares whose files name another configuration's shapes stay out
+    assert not set(traced) & {
+        "kernel.flash_roofline.lm", "kernel.flash_roofline.window",
+        "kernel.flash_roofline.gqa64", "moe.experts_roofline.lm",
+        "moe.experts_roofline.lfm2", "kernel.shortconv_ms.lm",
+        "kernel.shortconv_roofline.lm"}
+    assert [m["name"] for m in manifest["per_layer"][-5:]] == list(new)
+    for name in new:
+        entry = next(m for m in manifest["per_layer"] if m["name"] == name)
+        assert entry["workloads"] == [CELL]
+        assert entry["moves"] == "tokens_per_s_chip"
+        spec = _json("benchmarks", "layer_metrics", f"{name}.json")
+        assert spec["layer"] == entry["layer"]
+        assert spec["arguments"].get("config", NAME) == NAME
+    # no other cell reports the new metrics, and the new cell only joined
+    for other in (w["name"] for w in manifest["workloads"][:-1]):
+        assert not set(new) & set(
+            result_line.declared_metrics(manifest, other, True))
+
+
+def test_reference_limits_at_the_tiny_preset():
+    """In float32 with the module's dense attention the wiring agrees to
+    rounding and the routing is the same; in bfloat16 both limits hold; the
+    reference in float8 fails the logits' limit, and so does each dropped
+    term of the mathematics (``reference.FAULTS``)."""
+    import copy
+    import functools
+
+    import jax.numpy as jnp
+
+    from benchmarks.references import kimi_linear as reference
+
+    cfg = _config()
+    cfg = {**cfg, **family.TINY,
+           "module": {**cfg["module"], **family.TINY["module"]}}
+    plain = copy.deepcopy(cfg)
+    plain["module"]["attn_impl"] = "dense"
+    exact = family.reference_check(family.build_model(plain, 3), plain, 7, None)
+    assert exact["ok"] and exact["rel_l2"] < 1e-5, exact
+    assert exact["routing_agreement"] == 1.0
+    model = family.build_model(cfg, 3)
+    lowp = family.reference_check(model, cfg, 7, "bfloat16")
+    assert lowp["ok"] and lowp["rel_l2"] > exact["rel_l2"], lowp
+    assert lowp["routing_agreement"] > 0.85
+    for fault in ("float8",) + reference.FAULTS:
+        extra = dict(round_to=jnp.float8_e4m3fn) if fault == "float8" \
+            else dict(without=(fault,))
+        wrong = family.reference_check(
+            model, cfg, 7, "bfloat16", forward=functools.partial(
+                reference.forward, **extra))
+        assert not wrong["ok"], (fault, wrong)
+        assert wrong["rel_l2"] > wrong["tolerance"], (fault, wrong)

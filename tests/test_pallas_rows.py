@@ -287,3 +287,29 @@ def test_flash_compiles_for_a_v5e_with_groups_at_head_width_64(one_chip):
         shape(32), shape(8), shape(8)).compile().as_text()
     for kernel in ("dk_flash_fwd", "dk_flash_dq", "dk_flash_dkv"):
         assert kernel in text, kernel
+
+
+def test_flash_compiles_for_a_v5e_with_keys_wider_than_values(one_chip):
+    """Kimi Linear's latent-attention layer: 8 held heads, keys of 192 beside
+    values of 128 at L = 8,192, forward and backward. 192 columns take 256
+    lanes in VMEM, so K and V whole leave room for tiles of 256 (at 512
+    Mosaic refuses dk/dv by 0.7 MiB of its 16). Kept in this file for the
+    reason above."""
+    from distkeras_tpu.ops.pallas import flash_attention
+    from distkeras_tpu.ops.pallas.flash_attention import default_tiling
+
+    assert default_tiling(8192, 192, Dv=128) == (256, 256, 128)
+
+    def shape(width):
+        return jax.ShapeDtypeStruct((2, 8192, 8, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, interpret=False).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(192), shape(192), shape(128)).compile().as_text()
+    for kernel in ("dk_flash_fwd", "dk_flash_dq", "dk_flash_dkv"):
+        assert kernel in text, kernel
+    assert text.count("tpu_custom_call") == 3
