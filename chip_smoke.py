@@ -70,6 +70,7 @@ class Sizes:
     groupnorm: tuple  # (B, H, W, C, groups)
     fold: tuple       # tensor shape
     rows: tuple       # (tokens, k, D, held of 64 experts) of an expert layer
+    kda: tuple        # (B, L, H, Dh) of a Kimi Delta Attention layer
     mlp_rows: int
 
 
@@ -80,12 +81,14 @@ FULL = Sizes(layers=8, d_model=1024, heads=16, d_ff=4096, vocab=32768,
              lstm=(2048, 200, 64, 128),
              groupnorm=(128, 112, 112, 64, 32), fold=(8192, 512),
              rows=(16384, 6, 2560, 8),  # the smallthinker cell's step
+             kda=(1, 8192, 8, 128),     # the kimi_linear cell's step
              mlp_rows=8192)
 TOY = Sizes(layers=1, d_model=64, heads=2, d_ff=128, vocab=256,
             seq=128, lm_batch=2, lm_window=2, lm_rounds=3, tp_layers=1,
             cnn_batch=16, cnn_window=2,
             flash=((1, 128, 2, 16), (1, 64, 2, 16)), lstm=(8, 6, 8, 128),
             groupnorm=(2, 8, 8, 64, 32), fold=(70, 33), rows=(64, 2, 32, 16),
+            kda=(2, 128, 2, 16),
             mlp_rows=2048)
 
 
@@ -501,6 +504,53 @@ def check_rows(shape) -> dict:
     return dict(_compare("rows", got, ref, tol=1e-6), live=live)
 
 
+def check_kda_scan(shape) -> dict:
+    """The delta rule's scan over chunks (``ops/pallas/delta_rule.py``: the
+    kernels ``dk_kda_scan_fwd`` and ``dk_kda_scan_bwd``) against the same
+    three lines as a ``lax.scan`` in ``jax.numpy`` with JAX's derivative,
+    ``O`` and all six cotangents, on seeded chunks whose state stays bounded
+    (a chunk's decay in (0.1, 0.5), ``|Kd^T W|`` about 0.3)."""
+    import jax
+    import jax.numpy as jnp
+
+    from distkeras_tpu.ops.delta_rule import chunk_for
+    from distkeras_tpu.ops.pallas.delta_rule import scan_chunks
+
+    B, L, H, D = shape
+    C = chunk_for(L)
+    lead, dt, f32 = (B, H, L // C), jnp.bfloat16, jnp.float32
+    ks = jax.random.split(jax.random.key(0), 7)
+    small = 0.04 * (128 / D) ** 0.5 * (64 / C) ** 0.5
+    U = jax.random.normal(ks[0], lead + (C, D), f32)
+    W, Kd = (small * jax.random.normal(k, lead + (C, D), f32).astype(dt)
+             for k in ks[1:3])
+    Qg = jax.random.normal(ks[3], lead + (C, D), dt) * D ** -0.5
+    Bq = jnp.tril(jax.random.normal(ks[4], lead + (C, C), dt)) * C ** -0.5
+    shrink = jax.random.uniform(ks[5], lead + (D,), f32, 0.1, 0.5)
+    cotangent = jax.random.normal(ks[6], lead + (C, D), dt)
+
+    def dot(spec, a, b):
+        return jnp.einsum(spec, a.astype(dt), b.astype(dt),
+                          preferred_element_type=f32)
+
+    def reference(*chunks):
+        def step(S, xs):
+            U, W, Qg, Bq, Kd, shrink = xs
+            pseudo = U - dot("bhrk,bhkv->bhrv", W, S)
+            out = dot("bhrk,bhkv->bhrv", Qg, S) \
+                + dot("bhrc,bhcv->bhrv", Bq, pseudo)
+            S = shrink[..., None] * S + dot("bhck,bhcv->bhkv", Kd, pseudo)
+            return S, out.astype(dt)
+
+        _, out = jax.lax.scan(step, jnp.zeros((B, H, D, D), f32), tuple(
+            jnp.moveaxis(x, 2, 0) for x in chunks))
+        return jnp.moveaxis(out, 0, 2)
+
+    args = (U, W, Qg, Bq, Kd, shrink)
+    return _compare("kda_scan", _fwd_and_grads(scan_chunks, cotangent)(*args),
+                    _fwd_and_grads(reference, cotangent)(*args), tol=2e-2)
+
+
 def phase_kernels(sz: Sizes) -> dict:
     out = {}
     flash = [(f"flash_attention_L{shape[1]}", check_flash, shape)
@@ -509,7 +559,8 @@ def phase_kernels(sz: Sizes) -> dict:
                                ("lstm_seq", check_lstm, sz.lstm),
                                ("group_norm", check_groupnorm, sz.groupnorm),
                                ("fold", check_fold, sz.fold),
-                               ("rows", check_rows, sz.rows)):
+                               ("rows", check_rows, sz.rows),
+                               ("kda_scan", check_kda_scan, sz.kda)):
         t0 = time.perf_counter()
         out[name] = dict(check(shape), shape=list(shape),
                          seconds=round(time.perf_counter() - t0, 2))

@@ -1,8 +1,11 @@
 """The gated delta rule with a decay a channel (Kimi Delta Attention's
 recurrence; Kimi Linear, arXiv:2510.26692), in its **chunked form**: products
-inside a chunk, a scan over chunks. Plain ``jax.numpy`` and ``lax.scan``; the
-backward pass is JAX's own derivative of this program (no hand-written rule,
-no Pallas kernel yet: ROADMAP R5).
+inside a chunk, a scan over chunks. The in-chunk half is plain ``jax.numpy``
+and its backward pass JAX's own derivative; the scan over chunks is the Pallas
+kernel pair of ``ops/pallas/delta_rule.py`` (``dk_kda_scan_fwd``,
+``dk_kda_scan_bwd``: the state stays in VMEM, the backward is written). Not
+built: the in-chunk products and the inverse in a kernel, which is where the
+time is (ROADMAP R5).
 
 A head carries a state ``S`` ``[d_k, d_v]``, zero before the sequence starts.
 With ``alpha_t = exp(g_t)`` in (0, 1) a channel of the key and ``beta_t`` in
@@ -24,7 +27,8 @@ unit lower-triangular system:
 
 ``A``, ``B``, ``T``, ``U``, ``W`` are computed for all chunks at once, as
 batched products; only the three lines that touch ``S_0`` run in the scan over
-chunks, which carries ``S`` in float32. ``T`` is built by blocks from the
+chunks (:func:`~distkeras_tpu.ops.pallas.delta_rule.scan_chunks`), which
+carries ``S`` in float32. ``T`` is built by blocks from the
 unit diagonal up (:func:`_unit_lower_inverse`: forward substitution a block
 at a time, ``log2 C`` levels of two batched products), in float32.
 
@@ -54,6 +58,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from distkeras_tpu.ops.pallas.delta_rule import scan_chunks
 
 #: positions a chunk, where the sequence allows it, and rows a sub-chunk
 CHUNK, SUB = 64, 16
@@ -162,19 +168,7 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int | None = None):
     U = dot("bhnrc,bhncv->bhnrv", T, beta[..., None] * v.astype(f32))
     W = dot("bhnrc,bhnck->bhnrk", T, beta[..., None] * k32 * decay)
     last = G[:, :, :, -1:, :]
-    per_chunk = tuple(jnp.moveaxis(x, 2, 0) for x in (
+    out = scan_chunks(
         U, W.astype(dt), (q32 * decay).astype(dt), (Bq * tril).astype(dt),
-        (k32 * jnp.exp(last - G)).astype(dt), jnp.exp(last[:, :, :, 0])))
-
-    def step(S, xs):
-        U, W, Qg, Bq, Kd, shrink = xs
-        pseudo = U - dot("bhrk,bhkv->bhrv", W, S)
-        out = dot("bhrk,bhkv->bhrv", Qg, S) \
-            + dot("bhrc,bhcv->bhrv", Bq, pseudo)
-        S = shrink[..., None] * S + dot("bhck,bhcv->bhkv", Kd, pseudo)
-        return S, out.astype(dt)
-
-    _, out = jax.lax.scan(step, jnp.zeros((B, H, K, V), f32),
-                          per_chunk)                        # [N, B, H, C, V]
-    out = jnp.moveaxis(out, 0, 2).reshape(B, H, L, V)
-    return jnp.moveaxis(out, 1, 2), jnp.min(last)
+        (k32 * jnp.exp(last - G)).astype(dt), jnp.exp(last[:, :, :, 0]))
+    return jnp.moveaxis(out.reshape(B, H, L, V), 1, 2), jnp.min(last)

@@ -176,6 +176,14 @@ _METRICS = [
     _m("kda.mean_beta", "gauge", "models",
        "Last round's mean `beta` (the delta rule's write strength, in (0, "
        "1)) over the `kda` layers' tokens and heads."),
+    _m("pallas.kda.heads_per_program", "gauge", "kernels",
+       "Heads one program of the delta rule's scan kernels holds, set as a "
+       "call is traced (`ops/pallas/delta_rule.py::heads_per_program`: the "
+       "largest of 8, 4, 2, 1 that divides batch x heads and fits VMEM)."),
+    _m("pallas.kda.grid_steps", "gauge", "kernels",
+       "Grid steps of one call of `dk_kda_scan_fwd` / `dk_kda_scan_bwd`: "
+       "batch x heads / heads a program, times the chunks, set as a call is "
+       "traced."),
     # -- inference --------------------------------------------------------
     _m("predict.chunk", "span", "inference",
        "Per-chunk end-to-end predict latency."),

@@ -1,7 +1,12 @@
 """The two operations Kimi Linear forced into ``ops/``, on the CPU in float32:
-the gated delta rule's chunked form (``ops/delta_rule.py``) against the
-recurrence itself, values and every gradient, at two and three chunks and two
-chunk sizes; the overflow case (a decay held at -1.6 a step over whole
+the gated delta rule's chunked form (``ops/delta_rule.py``, its scan over
+chunks the kernel pair of ``ops/pallas/delta_rule.py``, interpreted) against
+the recurrence itself, values and every gradient, at two and three chunks and
+two chunk sizes; the kernel pair alone against the ``jax.numpy`` step it
+replaced and JAX's derivative of it, the state across programs and heads, the
+last chunk's ``dS`` and an underflowed decay (both kernels compiled for a
+described v5e at the cell's shape: ``tests/test_pallas_rows.py``, which
+describes the topology); the overflow case (a decay held at -1.6 a step over whole
 chunks, and at -20); keys that point one way (the triangular inverse's
 stability); the state carried across a chunk boundary; the gradients with
 bfloat16 operands, as the trainer runs it, against the float32 recurrence's,
@@ -24,6 +29,7 @@ if ROOT not in sys.path:
 from benchmarks.references import kimi_linear as reference  # noqa: E402
 from distkeras_tpu.ops.delta_rule import (chunk_for,  # noqa: E402
                                           chunked_gated_delta_rule)
+from distkeras_tpu.ops.pallas import delta_rule as kda_kernels  # noqa: E402
 from distkeras_tpu.ops.pallas.flash_attention import (  # noqa: E402
     default_tiling, flash_attention)
 
@@ -179,6 +185,176 @@ def test_bfloat16_gradients_of_the_chunked_form_against_the_recurrence(case):
             assert np.isfinite(np.asarray(got[name])).all(), (dtype, name)
             assert (rel_l2(got[name], want[name]) < tolerance) == passes, \
                 (dtype, name, rel_l2(got[name], want[name]))
+
+
+# -- the scan over chunks as a kernel pair -------------------------------------
+
+def scan_inputs(chunks, C, B=1, H=2, K=16, V=8, seed=0, dtype=jnp.float32,
+                shrink=None):
+    """One call's chunks as ``chunked_gated_delta_rule`` hands them on, drawn
+    so that the state stays bounded: ``U`` float32, ``W``, ``Qg``, ``Bq``
+    (lower-triangular), ``Kd`` in ``dtype``, a chunk's decay in (0.1, 0.9) (or
+    held at ``shrink``), and a cotangent of ``O``."""
+    ks = jax.random.split(jax.random.key(seed), 7)
+    lead = (B, H, chunks)
+    decay = jax.random.uniform(ks[5], lead + (K,), minval=0.1, maxval=0.9) \
+        if shrink is None else jnp.full(lead + (K,), shrink, jnp.float32)
+    return ((jax.random.normal(ks[0], lead + (C, V)),
+             (0.3 * jax.random.normal(ks[1], lead + (C, K)) / np.sqrt(C)
+              ).astype(dtype),
+             (jax.random.normal(ks[2], lead + (C, K)) / np.sqrt(K)
+              ).astype(dtype),
+             (jnp.tril(jax.random.normal(ks[3], lead + (C, C))) / np.sqrt(C)
+              ).astype(dtype),
+             (0.3 * jax.random.normal(ks[4], lead + (C, K)) / np.sqrt(C)
+              ).astype(dtype), decay),
+            jax.random.normal(ks[6], lead + (C, V)).astype(dtype))
+
+
+def scan_by_jax_numpy(U, W, Qg, Bq, Kd, shrink, final_state=False):
+    """The scan as it stood before the kernels: ``lax.scan`` over chunks of
+    three lines, operands of a product in ``W``'s dtype, float32 sums."""
+    dt, f32 = W.dtype, jnp.float32
+    B, H, _, _, V = U.shape
+
+    def dot(spec, a, b):
+        return jnp.einsum(spec, a.astype(dt), b.astype(dt),
+                          preferred_element_type=f32)
+
+    def step(S, xs):
+        U, W, Qg, Bq, Kd, shrink = xs
+        pseudo = U - dot("bhrk,bhkv->bhrv", W, S)
+        out = dot("bhrk,bhkv->bhrv", Qg, S) \
+            + dot("bhrc,bhcv->bhrv", Bq, pseudo)
+        S = shrink[..., None] * S + dot("bhck,bhcv->bhkv", Kd, pseudo)
+        return S, out.astype(dt)
+
+    S, out = jax.lax.scan(step, jnp.zeros((B, H, W.shape[-1], V), f32), tuple(
+        jnp.moveaxis(x, 2, 0) for x in (U, W, Qg, Bq, Kd, shrink)))
+    out = jnp.moveaxis(out, 0, 2)
+    return (out, S) if final_state else out
+
+
+SCAN_NAMES = "U W Qg Bq Kd shrink".split()
+
+
+def pair_and_step(args, cotangent, heads):
+    """``(O, the six cotangents)`` of the kernel pair and of the step."""
+    out, vjp = jax.vjp(lambda *a: kda_kernels.scan_chunks(
+        *a, heads=heads, interpret=True), *args)
+    want, want_vjp = jax.vjp(scan_by_jax_numpy, *args)
+    return (out, vjp(cotangent)), (want, want_vjp(cotangent))
+
+
+@pytest.mark.parametrize("heads", [1, None], ids=["a-head", "all-heads"])
+@pytest.mark.parametrize("chunks, C", [(1, 32), (2, 64), (5, 32), (5, 64)])
+def test_the_kernel_pair_is_the_step_and_its_derivative(chunks, C, heads):
+    """Every output and each of the six cotangents, in its primal's dtype and
+    shape, against ``jax.vjp`` of the ``jax.numpy`` step: float32 to 1e-5."""
+    args, cotangent = scan_inputs(chunks, C)
+    (out, grads), (want, want_grads) = pair_and_step(args, cotangent, heads)
+    assert out.shape == want.shape and out.dtype == want.dtype
+    assert rel_l2(out, want) < 1e-5
+    for name, x, a, b in zip(SCAN_NAMES, args, grads, want_grads):
+        assert a.shape == x.shape and a.dtype == x.dtype, name
+        if chunks == 1 and name in ("W", "Qg", "Kd") \
+                or chunks <= 2 and name == "shrink":
+            # they meet the state alone, and the first chunk's is zero; a
+            # decay wants a state before it and a chunk after it
+            assert not np.asarray(a).any() and not np.asarray(b).any(), name
+            continue
+        assert np.linalg.norm(b) > 0, name
+        assert rel_l2(a, b) < 1e-5, name
+
+
+@pytest.mark.parametrize("dtype, passes", [(jnp.bfloat16, True),
+                                           (jnp.float8_e4m3fn, False)],
+                         ids=["bfloat16", "float8-must-fail"])
+def test_the_kernel_pair_with_rounded_operands_against_the_float32_step(
+        dtype, passes):
+    """The trainer's dtype against the float32 step, ``O`` and all six
+    cotangents under the 2e-2 of the chunked form's cases; float8_e4m3
+    operands are the control that must not pass it."""
+    args, cotangent = scan_inputs(5, 64, seed=2)
+    rounded = (args[0],) + tuple(a.astype(dtype) for a in args[1:5]) \
+        + (args[5],)
+    out, vjp = jax.vjp(lambda *a: kda_kernels.scan_chunks(
+        *a, interpret=True), *rounded)
+    grads = vjp(cotangent.astype(dtype))
+    want, want_vjp = jax.vjp(scan_by_jax_numpy, *args)
+    errors = {"O": rel_l2(out.astype(jnp.float32), want)}
+    for name, a, b in zip(SCAN_NAMES, grads, want_vjp(cotangent)):
+        assert a.dtype == (jnp.float32 if name in ("U", "shrink") else dtype)
+        assert np.isfinite(np.asarray(a, np.float32)).all(), name
+        errors[name] = rel_l2(a.astype(jnp.float32), b)
+    assert (max(errors.values()) < 2e-2) == passes, errors
+
+
+def test_the_state_crosses_programs_and_starts_at_zero_for_every_head():
+    """B = 2, H = 3, other data a head, one head a program and two (a program
+    of two heads holds heads of both batch rows): a head's result is the
+    result of that head run alone, so the scratch was zero at its chunk 0
+    whatever the program before left there, and its second chunk started from
+    its own first."""
+    args, cotangent = scan_inputs(3, 32, B=2, H=3, seed=4)
+    for heads in (1, 2):
+        (out, grads), (want, want_grads) = pair_and_step(args, cotangent,
+                                                         heads)
+        assert rel_l2(out, want) < 1e-5
+        for name, a, b in zip(SCAN_NAMES, grads, want_grads):
+            assert rel_l2(a, b) < 1e-5, (heads, name)
+    alone = kda_kernels.scan_chunks(*[a[1:, 2:] for a in args],
+                                    interpret=True)
+    np.testing.assert_allclose(np.asarray(out[1:, 2:]), np.asarray(alone),
+                               rtol=1e-6, atol=1e-6)
+    # the carry matters: the last chunk run from a zero state is another result
+    last = kda_kernels.scan_chunks(*[a[:, :, 2:] for a in args],
+                                   interpret=True)
+    assert rel_l2(last, out[:, :, 2:]) > 0.05
+
+
+@pytest.mark.parametrize("shrink", [None, float(np.exp(-20.0 * 64))],
+                         ids=["decays-drawn", "a-chunks-decay-underflows"])
+def test_dS_is_zero_after_the_last_chunk_and_ds_is_exact(shrink):
+    """No final state is returned, so nothing flows into the last chunk from
+    beyond it: the step that *does* return its final state, with a zero
+    cotangent for it, gives the same six cotangents, and the last chunk's
+    ``ds`` is the plain sum of ``dS * S`` with ``dS = 0``: exactly zero. With
+    ``g = -20`` a step a whole chunk's decay is ``e^-1280 = 0`` in float32:
+    ``ds = sum_v dS+ * S`` does not pass through ``s`` and is the step's, not
+    zero and not NaN, while nothing of ``dS+`` reaches the chunk before
+    through ``s * dS+``."""
+    args, cotangent = scan_inputs(3, 64, seed=6, shrink=shrink)
+    (out, grads), _ = pair_and_step(args, cotangent, None)
+    (want, _), vjp = jax.vjp(lambda *a: scan_by_jax_numpy(
+        *a, final_state=True), *args)
+    want_grads = vjp((cotangent, jnp.zeros((1, 2, 16, 8))))
+    assert rel_l2(out, want) < 1e-5
+    for name, a, b in zip(SCAN_NAMES, grads, want_grads):
+        assert rel_l2(a, b) < 1e-5, name
+    ds = np.asarray(grads[5])
+    assert not ds[:, :, -1].any()
+    assert ds[:, :, 1].any() and np.isfinite(ds).all()
+    assert shrink is None or shrink == 0.0
+
+
+def test_heads_a_program_follow_the_shapes_and_the_gauges_say_so():
+    from distkeras_tpu import telemetry
+
+    # the cell: 1 x 8 heads of 128 in chunks of 64, bfloat16 operands
+    assert kda_kernels.heads_per_program(8, 64, 128, 128, 2) == 8
+    assert kda_kernels.heads_per_program(6, 64, 128, 128, 2) == 2
+    assert kda_kernels.heads_per_program(7, 64, 128, 128, 2) == 1
+    # a state of 512 x 512 float32 is a MiB a head: fewer fit
+    assert kda_kernels.heads_per_program(8, 64, 256, 256, 4) == 4
+    assert kda_kernels.heads_per_program(8, 64, 512, 512, 4) == 2
+    assert kda_kernels.heads_per_program(8, 64, 1024, 1024, 4) == 1
+    args, _ = scan_inputs(5, 32, B=2, H=3)
+    kda_kernels.scan_chunks(*args, interpret=True)
+    assert telemetry.gauge("pallas.kda.heads_per_program").value == 2
+    assert telemetry.gauge("pallas.kda.grid_steps").value == 3 * 5
+    with pytest.raises(ValueError, match="do not divide"):
+        kda_kernels.scan_chunks(*args, heads=4, interpret=True)
 
 
 # -- the flash kernels with a value width of their own ------------------------

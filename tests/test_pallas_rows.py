@@ -3,7 +3,8 @@ Pallas interpreter at small tiles: the gather against ``x[token]`` and the
 combine against a plain segment sum at every kind of ``live``, each as the
 other's transpose, a poisoned buffer under ``DroplessExperts``, the tile
 rule, and the kernels compiled for a described v5e at the benchmark's
-shapes."""
+shapes (with them the flash kernels at two cells' head shapes and the delta
+rule's scan kernels: one file describes the topology)."""
 
 import jax
 import jax.numpy as jnp
@@ -313,3 +314,42 @@ def test_flash_compiles_for_a_v5e_with_keys_wider_than_values(one_chip):
     for kernel in ("dk_flash_fwd", "dk_flash_dq", "dk_flash_dkv"):
         assert kernel in text, kernel
     assert text.count("tpu_custom_call") == 3
+
+
+def test_the_kernel_pair_compiles_for_a_v5e_at_the_cells_shape(one_chip,
+                                                               monkeypatch):
+    """``[1, 8192, 8, 128]`` in chunks of 64 under ``jax.checkpoint`` and
+    ``jax.grad``, bfloat16: Mosaic takes both kernels, the layer is three
+    calls (forward, recomputed, backward) and no ``while`` is left under
+    ``dk_kda``. Kept in this file for the reason above."""
+    import re
+
+    from distkeras_tpu.ops.delta_rule import chunked_gated_delta_rule
+    from distkeras_tpu.ops.pallas import mode
+
+    monkeypatch.setattr(mode, "compiles", lambda: True)  # headed for the chip
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    @jax.checkpoint
+    def layer(*a):
+        with jax.named_scope("dk_kda"):
+            return chunked_gated_delta_rule(*a)[0]
+
+    def loss(*a):
+        with jax.named_scope("model"):  # a transform renames the outermost
+            return jnp.sum(jnp.square(layer(*a).astype(jnp.float32)))
+
+    wide = (1, 8192, 8, 128)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        shape(*wide), shape(*wide), shape(*wide),
+        shape(*wide, dtype=jnp.float32),
+        shape(*wide[:3], dtype=jnp.float32)).compile().as_text()
+    calls = re.findall(r"= [^\n]*custom-call\([^\n]*"
+                       r'op_name="[^"]*/(dk_kda_scan_\w+)/', text)
+    assert sorted(calls) == ["dk_kda_scan_bwd", "dk_kda_scan_fwd",
+                             "dk_kda_scan_fwd"], calls
+    assert all("/dk_kda/" in line for line in text.splitlines()
+               if "custom-call(" in line and "dk_kda_scan_" in line)
+    assert not re.search(r'= [^\n]* while\([^\n]*op_name="[^"]*dk_kda', text)
