@@ -57,7 +57,8 @@ PASSES = ("remat", "backward", "forward")
 #: the scopes of which one must be in the program for a phase's metric to read.
 PHASE_NEEDS = {"forward": ("dk_fwd_bwd",), "backward": ("dk_fwd_bwd",),
                "remat": ("dk_fwd_bwd",), "optimizer": ("dk_optimizer",),
-               "fold": FOLD_SCOPES}
+               "fold": FOLD_SCOPES, "mixed": ("dk_fwd_bwd",),
+               "other": ("dk_fwd_bwd",)}
 
 _KERNEL = re.compile(r"^dk_(?:flash|groupnorm|lstm|fold)_\w+$")
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")

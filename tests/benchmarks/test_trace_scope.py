@@ -2,6 +2,8 @@
 time by the program's phases and kernels, the flash roofline) and
 ``timeline`` (set-up by the program's own spans), on hand-made inputs."""
 
+import glob
+import json
 import os
 import sys
 import types
@@ -12,7 +14,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 sys.path.insert(0, ROOT)
 
 from benchmarks.harness import peaks, trace_reduce  # noqa: E402
-from benchmarks.readers import timeline, trace_scope  # noqa: E402
+from benchmarks.readers import timeline, trace_busy, trace_scope  # noqa: E402
 
 STEP = "jit(round_fn)/dk_local_steps/while/body/closed_call"
 FWD = f"{STEP}/dk_fwd_bwd/jvp(TransformerLM)"
@@ -152,6 +154,54 @@ def test_read_gives_ms_per_round_none_for_a_dropped_scope_zero_for_idle():
     assert trace_scope.read(old, kernels=["dk_flash_fwd"],
                             floor={"config": "gpt2-medium"}) == 0.0
     assert trace_scope.read(_run(rounds=0), phase="forward") is None
+
+
+@pytest.mark.parametrize("phase, ns", [("mixed", 70.0), ("other", 55.0)])
+def test_mixed_and_other_read_under_the_conditions_forward_does(phase, ns):
+    assert trace_scope.read(_run(), phase=phase) == pytest.approx(ns * 1e-6 / 2)
+    # A refactor dropped dk_fwd_bwd but kept the others: None, as forward.
+    dropped = _run(hlo=HLO.replace("dk_fwd_bwd", "fwd_bwd"))
+    assert trace_scope.read(dropped, phase="forward") is None
+    assert trace_scope.read(dropped, phase=phase) is None
+    # The scope is there and the trace holds no such event: 0.0.
+    named = {"mixed": ("fusion.5", "fusion.6"),
+             # the while's own 20 ns, which no op of its body covers, too
+             "other": ("copy.1", "fusion.8", "not-in-the-text", "while.1")}
+    idle = _run(events=[e for e in EVENTS if e[2] not in named[phase]])
+    assert trace_scope.read(idle, phase=phase) == 0.0
+    assert trace_scope.read(idle, phase="forward") > 0.0
+    # A program from before the scopes, and a trace without whole rounds.
+    assert trace_scope.read(_run(hlo=HLO.replace("dk_", "x_")),
+                            phase=phase) == 0.0
+    assert trace_scope.read(_run(rounds=0), phase=phase) is None
+
+
+@pytest.mark.parametrize("family, absent", [("lm", set()),
+                                            ("img", {"remat", "fold"})])
+def test_the_phase_metrics_add_up_to_the_rounds_device_time(family, absent):
+    """forward + backward + remat + optimizer + mixed + other + fold =
+    ``round.device_ms``, through the metrics' own files: every phase of the
+    reduction has one (``.img``: but the two ResNet's program never enters),
+    so no part of the busy time stands on a ``[bench`` line alone."""
+    phases = {}
+    for path in glob.glob(os.path.join(ROOT, "benchmarks", "layer_metrics",
+                                       f"*.{family}.json")):
+        with open(path, encoding="utf-8") as f:
+            spec = json.load(f)
+        if spec["reader"] == "trace_scope" and "phase" in spec["arguments"]:
+            assert spec["arguments"]["phase"] not in phases, path
+            phases[spec["arguments"]["phase"]] = spec["arguments"]
+    assert set(trace_scope.PHASES) - set(phases) == absent
+    classes, _, _ = trace_scope.classify(HLO)
+    events = [e for e in EVENTS
+              if classes.get(e[2], ("other",))[0] not in absent]
+    run = _run(events=events)
+    run.trace["busy_s"] = trace_reduce.busy_ns(events, 0, 2000) * 1e-9
+    assert all(trace_scope.reduce(HLO, events, 0, 2000)["phases"][p] == 0.0
+               for p in absent)
+    parts = {p: trace_scope.read(run, **phases[p]) for p in phases}
+    assert None not in parts.values() and parts["mixed"] > 0 < parts["other"]
+    assert sum(parts.values()) == pytest.approx(trace_busy.read(run), rel=1e-12)
 
 
 def test_flash_floor_for_gpt2_mediums_shapes():
