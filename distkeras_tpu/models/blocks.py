@@ -59,6 +59,7 @@ from distkeras_tpu.ops.delta_rule import chunk_for, chunked_gated_delta_rule
 from distkeras_tpu.ops.pallas import rows
 from distkeras_tpu.ops.pallas.flash_attention import (FLASH_RESIDUALS,
                                                       residual_bytes)
+from distkeras_tpu.scopes import owner
 
 #: the gate of a gated feed-forward, by the name a configuration gives it
 ACTIVATIONS = {"relu": nn.relu, "silu": nn.silu}
@@ -90,11 +91,12 @@ class RMSNorm(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        x32 = x.astype(jnp.float32)
-        y = x32 * jax.lax.rsqrt(
-            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
-        return (y * scale.astype(jnp.float32)).astype(x.dtype)
+        with owner("norm"):
+            scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+            x32 = x.astype(jnp.float32)
+            y = x32 * jax.lax.rsqrt(
+                jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
+            return (y * scale.astype(jnp.float32)).astype(x.dtype)
 
 
 def rope(x, theta: float):
@@ -130,44 +132,47 @@ class GroupedQueryAttention(nn.Module):
     @nn.compact
     def __call__(self, x):
         B, L, D = x.shape
-        H, G, Dh = self.num_heads, self.num_kv_heads, self.head_dim
+        with owner("mixer"):
+            H, G, Dh = self.num_heads, self.num_kv_heads, self.head_dim
 
-        def proj(heads, name):
-            return nn.DenseGeneral((heads, Dh), use_bias=False, name=name)(x)
+            def proj(heads, name):
+                return nn.DenseGeneral((heads, Dh), use_bias=False,
+                                       name=name)(x)
 
-        q, k, v = proj(H, "query"), proj(G, "key"), proj(G, "value")
-        if self.qk_norm is not None:
-            q = RMSNorm(self.qk_norm, name="query_norm")(q)
-            k = RMSNorm(self.qk_norm, name="key_norm")(k)
-        if self.rope_theta is not None:
-            q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
-        q = q / jnp.sqrt(Dh).astype(q.dtype)
-        if self.attn_impl == "flash" and not self.is_initializing():
-            # Init only declares parameters: it takes the dense path on
-            # whatever short sample it is given (transformer.py says why).
-            from distkeras_tpu.models.transformer import _flash_block
-            from distkeras_tpu.ops.pallas import flash_attention, mode
+            q, k, v = proj(H, "query"), proj(G, "key"), proj(G, "value")
+            if self.qk_norm is not None:
+                q = RMSNorm(self.qk_norm, name="query_norm")(q)
+                k = RMSNorm(self.qk_norm, name="key_norm")(k)
+            if self.rope_theta is not None:
+                q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
+            q = q / jnp.sqrt(Dh).astype(q.dtype)
+            if self.attn_impl == "flash" and not self.is_initializing():
+                # Init only declares parameters: it takes the dense path on
+                # whatever short sample it is given (transformer.py says why).
+                from distkeras_tpu.models.transformer import _flash_block
+                from distkeras_tpu.ops.pallas import flash_attention, mode
 
-            block = _flash_block(L)
-            if self.window is not None and self.window < L \
-                    and self.window % block and not mode.compiles():
-                # The interpreter takes any grain; a CPU preset's window is
-                # shorter than a lane-aligned block.
-                block = math.gcd(L, self.window)
-            out = flash_attention(q, k, v, block_size=block,
-                                  window=self.window)
-        else:
-            i, j = jnp.arange(L)[:, None], jnp.arange(L)[None, :]
-            seen = j <= i
-            if self.window is not None:
-                seen &= i - j < self.window
-            qg = q.reshape(B, L, G, H // G, Dh)
-            scores = jnp.einsum("bqgnd,bkgd->bgnqk", qg, k)
-            scores = jnp.where(seen, scores, jnp.finfo(scores.dtype).min)
-            probs = jax.nn.softmax(scores, axis=-1)
-            out = jnp.einsum("bgnqk,bkgd->bqgnd", probs, v).reshape(B, L, H, Dh)
-        return nn.DenseGeneral(D, axis=(-2, -1), use_bias=False,
-                               name="out")(out)
+                block = _flash_block(L)
+                if self.window is not None and self.window < L \
+                        and self.window % block and not mode.compiles():
+                    # The interpreter takes any grain; a CPU preset's window is
+                    # shorter than a lane-aligned block.
+                    block = math.gcd(L, self.window)
+                out = flash_attention(q, k, v, block_size=block,
+                                      window=self.window)
+            else:
+                i, j = jnp.arange(L)[:, None], jnp.arange(L)[None, :]
+                seen = j <= i
+                if self.window is not None:
+                    seen &= i - j < self.window
+                qg = q.reshape(B, L, G, H // G, Dh)
+                scores = jnp.einsum("bqgnd,bkgd->bgnqk", qg, k)
+                scores = jnp.where(seen, scores, jnp.finfo(scores.dtype).min)
+                probs = jax.nn.softmax(scores, axis=-1)
+                out = jnp.einsum("bgnqk,bkgd->bqgnd", probs, v).reshape(
+                    B, L, H, Dh)
+            return nn.DenseGeneral(D, axis=(-2, -1), use_bias=False,
+                                   name="out")(out)
 
 
 def route_top_k(logits, k: int):
@@ -227,15 +232,17 @@ class GatedShortConv(nn.Module):
     def __call__(self, h):
         D = h.shape[-1]
         K = self.kernel_size
-        bcu = nn.Dense(3 * D, use_bias=False, name="in_proj")(h)
-        taps = self.param(
-            "taps", nn.initializers.variance_scaling(
-                1.0, "fan_in", "truncated_normal", in_axis=-1, out_axis=-2),
-            (D, K)).astype(bcu.dtype)
-        with jax.named_scope("dk_shortconv"):
-            gate_b, gate_c, u = jnp.split(bcu, 3, axis=-1)
-            y = gate_c * _causal_taps(gate_b * u, taps)
-        return nn.Dense(D, use_bias=False, name="out_proj")(y)
+        with owner("mixer"):
+            bcu = nn.Dense(3 * D, use_bias=False, name="in_proj")(h)
+            taps = self.param(
+                "taps", nn.initializers.variance_scaling(
+                    1.0, "fan_in", "truncated_normal", in_axis=-1,
+                    out_axis=-2),
+                (D, K)).astype(bcu.dtype)
+            with jax.named_scope("dk_shortconv"):
+                gate_b, gate_c, u = jnp.split(bcu, 3, axis=-1)
+                y = gate_c * _causal_taps(gate_b * u, taps)
+            return nn.Dense(D, use_bias=False, name="out_proj")(y)
 
 
 def _causal_taps(x, taps):
@@ -282,58 +289,61 @@ class KimiDeltaAttention(nn.Module):
         B, L, D = h.shape
         H, Dh = self.num_heads, self.head_dim
 
-        def dense(width, name, x=h):
-            return nn.Dense(width, use_bias=False, name=name)(x)
+        with owner("mixer"):
+            def dense(width, name, x=h):
+                return nn.Dense(width, use_bias=False, name=name)(x)
 
-        def taps(name):
-            return self.param(
-                name, nn.initializers.variance_scaling(
-                    1.0, "fan_in", "truncated_normal", in_axis=-1,
-                    out_axis=-2), (H * Dh, self.conv_kernel)).astype(h.dtype)
+            def taps(name):
+                return self.param(
+                    name, nn.initializers.variance_scaling(
+                        1.0, "fan_in", "truncated_normal", in_axis=-1,
+                        out_axis=-2),
+                    (H * Dh, self.conv_kernel)).astype(h.dtype)
 
-        projected = [(dense(H * Dh, f"{n}_proj"), taps(f"{n}_taps"))
-                     for n in ("q", "k", "v")]
-        a_log = self.param(
-            "A_log", lambda key, shape: jnp.log(jax.random.uniform(
-                key, shape, minval=1.0, maxval=16.0)), (H,))
-        dt_bias = self.param("dt_bias", _inverse_softplus_uniform(1e-3, 0.1),
-                             (H * Dh,))
-        decay_in = dense(H * Dh, "f_b", dense(Dh, "f_a"))
-        beta_in = dense(H, "b_proj")
-        gate_in = dense(H * Dh, "g_b", dense(Dh, "g_a"))
-        scale = self.param("o_norm", nn.initializers.ones, (Dh,))
-        with jax.named_scope("dk_kda_conv"):
-            q, k, v = (nn.silu(_causal_taps(x, w)).reshape(B, L, H, Dh)
-                       for x, w in projected)
-        with jax.named_scope("dk_kda"):
-            def unit(x):
-                x32 = x.astype(jnp.float32)
-                return (x32 * jax.lax.rsqrt(jnp.sum(
-                    jnp.square(x32), -1, keepdims=True) + 1e-6))
+            projected = [(dense(H * Dh, f"{n}_proj"), taps(f"{n}_taps"))
+                         for n in ("q", "k", "v")]
+            a_log = self.param(
+                "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                    key, shape, minval=1.0, maxval=16.0)), (H,))
+            dt_bias = self.param(
+                "dt_bias", _inverse_softplus_uniform(1e-3, 0.1), (H * Dh,))
+            decay_in = dense(H * Dh, "f_b", dense(Dh, "f_a"))
+            beta_in = dense(H, "b_proj")
+            gate_in = dense(H * Dh, "g_b", dense(Dh, "g_a"))
+            scale = self.param("o_norm", nn.initializers.ones, (Dh,))
+            with jax.named_scope("dk_kda_conv"):
+                q, k, v = (nn.silu(_causal_taps(x, w)).reshape(B, L, H, Dh)
+                           for x, w in projected)
+            with jax.named_scope("dk_kda"):
+                def unit(x):
+                    x32 = x.astype(jnp.float32)
+                    return (x32 * jax.lax.rsqrt(jnp.sum(
+                        jnp.square(x32), -1, keepdims=True) + 1e-6))
 
-            g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * jax.nn.softplus(
-                (decay_in.astype(jnp.float32) + dt_bias).reshape(B, L, H, Dh))
-            beta = jax.nn.sigmoid(beta_in.astype(jnp.float32))
-            o, least = chunked_gated_delta_rule(
-                (unit(q) * Dh ** -0.5).astype(h.dtype),
-                unit(k).astype(h.dtype), v, g, beta)
-            o32 = o.astype(jnp.float32)
-            o32 = o32 * jax.lax.rsqrt(jnp.mean(
-                jnp.square(o32), -1, keepdims=True) + self.rms_eps)
-            y = (o32 * scale.astype(jnp.float32) * jax.nn.sigmoid(
-                gate_in.astype(jnp.float32).reshape(B, L, H, Dh)))
-            y = y.astype(h.dtype).reshape(B, L, H * Dh)
-        if self.is_mutable_collection(ROUND_COUNTERS):
-            for name, value, join in (
-                    ("min_chunk_decay", least, jnp.minimum),
-                    ("beta_sum", jnp.sum(beta), jnp.add),
-                    ("beta_count", jnp.float32(beta.size), jnp.add),
-                    ("steps", jnp.float32(1), jnp.add)):
-                var = self.variable(ROUND_COUNTERS, name,
-                                    lambda: jnp.zeros((), jnp.float32))
-                if not self.is_initializing():  # init declares them, at zero
-                    var.value = join(var.value, value.astype(jnp.float32))
-        return dense(D, "o_proj", y)
+                g = -jnp.exp(a_log.astype(jnp.float32))[:, None] \
+                    * jax.nn.softplus((decay_in.astype(jnp.float32)
+                                       + dt_bias).reshape(B, L, H, Dh))
+                beta = jax.nn.sigmoid(beta_in.astype(jnp.float32))
+                o, least = chunked_gated_delta_rule(
+                    (unit(q) * Dh ** -0.5).astype(h.dtype),
+                    unit(k).astype(h.dtype), v, g, beta)
+                o32 = o.astype(jnp.float32)
+                o32 = o32 * jax.lax.rsqrt(jnp.mean(
+                    jnp.square(o32), -1, keepdims=True) + self.rms_eps)
+                y = (o32 * scale.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate_in.astype(jnp.float32).reshape(B, L, H, Dh)))
+                y = y.astype(h.dtype).reshape(B, L, H * Dh)
+            if self.is_mutable_collection(ROUND_COUNTERS):
+                for name, value, join in (
+                        ("min_chunk_decay", least, jnp.minimum),
+                        ("beta_sum", jnp.sum(beta), jnp.add),
+                        ("beta_count", jnp.float32(beta.size), jnp.add),
+                        ("steps", jnp.float32(1), jnp.add)):
+                    var = self.variable(ROUND_COUNTERS, name,
+                                        lambda: jnp.zeros((), jnp.float32))
+                    if not self.is_initializing():  # init declares them
+                        var.value = join(var.value, value.astype(jnp.float32))
+            return dense(D, "o_proj", y)
 
 
 def _inverse_softplus_uniform(low: float, high: float):
@@ -370,27 +380,29 @@ class LatentAttention(nn.Module):
         B, L, D = x.shape
         H, Dn, Dr, Dv = (self.num_heads, self.qk_nope_dim, self.qk_rope_dim,
                          self.v_head_dim)
-        q = nn.DenseGeneral((H, Dn + Dr), use_bias=False, name="query")(x)
-        kva = nn.Dense(self.kv_rank + Dr, use_bias=False, name="kv_a")(x)
-        c, k_rot = kva[..., :self.kv_rank], kva[..., self.kv_rank:]
-        kv = nn.DenseGeneral((H, Dn + Dv), use_bias=False, name="kv_b")(
-            RMSNorm(self.rms_eps, name="kv_norm")(c))
-        k = jnp.concatenate([kv[..., :Dn], jnp.broadcast_to(
-            k_rot[:, :, None, :], (B, L, H, Dr))], axis=-1)
-        v = kv[..., Dn:]
-        q = q * jnp.asarray((Dn + Dr) ** -0.5, q.dtype)
-        if self.attn_impl == "flash" and not self.is_initializing():
-            from distkeras_tpu.models.transformer import _flash_block
-            from distkeras_tpu.ops.pallas import flash_attention
+        with owner("mixer"):
+            q = nn.DenseGeneral((H, Dn + Dr), use_bias=False, name="query")(x)
+            kva = nn.Dense(self.kv_rank + Dr, use_bias=False, name="kv_a")(x)
+            c, k_rot = kva[..., :self.kv_rank], kva[..., self.kv_rank:]
+            kv = nn.DenseGeneral((H, Dn + Dv), use_bias=False, name="kv_b")(
+                RMSNorm(self.rms_eps, name="kv_norm")(c))
+            k = jnp.concatenate([kv[..., :Dn], jnp.broadcast_to(
+                k_rot[:, :, None, :], (B, L, H, Dr))], axis=-1)
+            v = kv[..., Dn:]
+            q = q * jnp.asarray((Dn + Dr) ** -0.5, q.dtype)
+            if self.attn_impl == "flash" and not self.is_initializing():
+                from distkeras_tpu.models.transformer import _flash_block
+                from distkeras_tpu.ops.pallas import flash_attention
 
-            out = flash_attention(q, k, v, block_size=_flash_block(L))
-        else:
-            scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
-            seen = jnp.arange(L)[None, :] <= jnp.arange(L)[:, None]
-            scores = jnp.where(seen, scores, jnp.finfo(scores.dtype).min)
-            out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v)
-        return nn.DenseGeneral(D, axis=(-2, -1), use_bias=False,
-                               name="out")(out)
+                out = flash_attention(q, k, v, block_size=_flash_block(L))
+            else:
+                scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+                seen = jnp.arange(L)[None, :] <= jnp.arange(L)[:, None]
+                scores = jnp.where(seen, scores, jnp.finfo(scores.dtype).min)
+                out = jnp.einsum("bhqk,bkhd->bqhd",
+                                 jax.nn.softmax(scores, -1), v)
+            return nn.DenseGeneral(D, axis=(-2, -1), use_bias=False,
+                                   name="out")(out)
 
 
 class GatedMLP(nn.Module):
@@ -402,12 +414,13 @@ class GatedMLP(nn.Module):
 
     @nn.compact
     def __call__(self, g):
-        def proj(width, name):
-            return nn.Dense(width, use_bias=False, name=name)
+        with owner("ffn"):
+            def proj(width, name):
+                return nn.Dense(width, use_bias=False, name=name)
 
-        hidden = ACTIVATIONS[self.activation](proj(self.d_ff, "gate")(g)) \
-            * proj(self.d_ff, "up")(g)
-        return proj(g.shape[-1], "down")(hidden)
+            hidden = ACTIVATIONS[self.activation](proj(self.d_ff, "gate")(g)) \
+                * proj(self.d_ff, "up")(g)
+            return proj(g.shape[-1], "down")(hidden)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -521,37 +534,44 @@ class DroplessExperts(nn.Module):
         T, D = x.shape
         k = experts.shape[-1]
         N = T * k
-        with jax.named_scope("dk_moe_route"):
-            local = experts.reshape(N) - self.first
-            here = (local >= 0) & (local < self.held)
-            # Held assignments first, by expert; the rest behind them.
-            key = jnp.where(here, local, self.held)
-            order = jnp.argsort(key, stable=True)
-            # The inverse of `order` without a second sort: an assignment's
-            # place is its group's first row plus how many of its group came
-            # before it (the sort is stable, and the key has held + 1 values).
-            of_group = key[None, :] == jnp.arange(self.held + 1)[:, None]
-            before = jnp.cumsum(of_group, axis=1, dtype=jnp.int32)
-            sizes = before[:, -1]
-            first_row = jnp.cumsum(sizes) - sizes
-            slot = jnp.sum(jnp.where(of_group, before - 1 + first_row[:, None],
-                                     0), axis=0).reshape(T, k)
-            group_sizes = sizes[:self.held]
-            live = jnp.sum(group_sizes)
-            buffer = rows_of_tokens(x, order, slot, live, k)
-        if self.is_mutable_collection(ROUND_COUNTERS):
-            self._count(group_sizes, here.reshape(T, k), T, rows.visited_rows(
-                live, N, rows.gather_tile(N, D, x.dtype)))
-        with jax.named_scope("dk_moe_experts"):
-            out = _GatedExperts(self.held, D, self.d_expert, self.activation,
-                                name="experts")(buffer, group_sizes)
-        with jax.named_scope("dk_moe_combine"):
-            # The grouped product writes the tiles that hold rows and leaves
-            # the others as they were: never read. The weights meet the rows
-            # in the kernel's sum, rounded to the rows' dtype as they were
-            # when a pass over the whole buffer multiplied by them.
-            return tokens_from_rows(out, weights.astype(jnp.float32), order,
-                                    slot, live).astype(x.dtype)
+        with owner("ffn"):
+            with jax.named_scope("dk_moe_route"):
+                local = experts.reshape(N) - self.first
+                here = (local >= 0) & (local < self.held)
+                # Held assignments first, by expert; the rest behind them.
+                key = jnp.where(here, local, self.held)
+                order = jnp.argsort(key, stable=True)
+                # The inverse of `order` without a second sort: an
+                # assignment's place is its group's first row plus how many
+                # of its group came before it (the sort is stable, and the
+                # key has held + 1 values).
+                of_group = key[None, :] == jnp.arange(self.held + 1)[:, None]
+                before = jnp.cumsum(of_group, axis=1, dtype=jnp.int32)
+                sizes = before[:, -1]
+                first_row = jnp.cumsum(sizes) - sizes
+                slot = jnp.sum(jnp.where(
+                    of_group, before - 1 + first_row[:, None], 0),
+                    axis=0).reshape(T, k)
+                group_sizes = sizes[:self.held]
+                live = jnp.sum(group_sizes)
+                buffer = rows_of_tokens(x, order, slot, live, k)
+            if self.is_mutable_collection(ROUND_COUNTERS):
+                self._count(group_sizes, here.reshape(T, k), T,
+                            rows.visited_rows(live, N, rows.gather_tile(
+                                N, D, x.dtype)))
+            with jax.named_scope("dk_moe_experts"):
+                out = _GatedExperts(
+                    self.held, D, self.d_expert, self.activation,
+                    name="experts")(buffer, group_sizes)
+            with jax.named_scope("dk_moe_combine"):
+                # The grouped product writes the tiles that hold rows and
+                # leaves the others as they were: never read. The weights
+                # meet the rows in the kernel's sum, rounded to the rows'
+                # dtype as they were when a pass over the whole buffer
+                # multiplied by them.
+                return tokens_from_rows(
+                    out, weights.astype(jnp.float32), order, slot,
+                    live).astype(x.dtype)
 
     def _count(self, group_sizes, here, tokens, rows_moved):
         """Add this step's routing to the round's counters (float32: exact

@@ -65,6 +65,7 @@ from distkeras_tpu.models.blocks import (DroplessExperts, GatedMLP,
                                          route_sigmoid_bias_top_k)
 from distkeras_tpu.models.lfm2 import ROUTER_BIAS
 from distkeras_tpu.ops.delta_rule import chunk_for
+from distkeras_tpu.scopes import owner
 
 #: the operator of each published layer: ``full_attn_layers`` 4, 8, ... 24 and
 #: 27 (counted from 1) are ``mla``, the other twenty ``kda``
@@ -112,7 +113,7 @@ class KimiLinearBlock(nn.Module):
             return x + GatedMLP(self.d_ff, "silu", name="mlp")(g)
         first, held = self.experts_held
         g = g.reshape(B * L, D)
-        with jax.named_scope("dk_moe_route"):
+        with owner("ffn"), jax.named_scope("dk_moe_route"):
             logits = Router(self.num_experts, name="router")(g)
             if held < self.num_experts:
                 # A share does not train its router (lfm2.py says why).
@@ -142,7 +143,7 @@ class KimiLinearBlock(nn.Module):
         y = DroplessExperts(first, held, D, self.d_expert, "silu",
                             name="moe")(g, weights, experts)
         if self.num_shared_experts:
-            with jax.named_scope("dk_moe_shared"):
+            with owner("ffn"), jax.named_scope("dk_moe_shared"):
                 y = y + GatedMLP(self.num_shared_experts * self.d_expert,
                                  "silu", name="shared")(g)
         return x + y.reshape(B, L, D)
@@ -212,9 +213,10 @@ class KimiLinearLM(DKModule):
             telemetry.gauge("kda.state_bytes").set(
                 sum(op == "kda" for op, _ in kinds)
                 * B * heads * self.kda_head_dim ** 2 * 4)
-        x = nn.Embed(self.vocab_size, self.d_model, name="tok_embed",
-                     embedding_init=nn.initializers.normal(self.embed_std))(
-                         tokens)
+        with owner("embed"):
+            x = nn.Embed(self.vocab_size, self.d_model, name="tok_embed",
+                         embedding_init=nn.initializers.normal(
+                             self.embed_std))(tokens)
         block_cls = KimiLinearBlock
         if self.remat:
             attention = sum(op == "mla" for op, _ in kinds)
@@ -232,8 +234,10 @@ class KimiLinearLM(DKModule):
                 self.expert_bias_std, self.expert_bias_update, self.rms_eps,
                 self.attn_impl, name=f"block_{l}")(x)
         x = RMSNorm(self.rms_eps, name="ln_final")(x)
-        return nn.Dense(self.vocab_size, use_bias=False, name="head",
-                        kernel_init=nn.initializers.normal(self.embed_std))(x)
+        with owner("head"):
+            return nn.Dense(
+                self.vocab_size, use_bias=False, name="head",
+                kernel_init=nn.initializers.normal(self.embed_std))(x)
 
     def publish_round_counters(self, round_index: int, counters) -> None:
         """``moe.round`` from the routed layers' counts, and from the ``kda``

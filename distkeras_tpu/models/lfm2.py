@@ -74,6 +74,7 @@ from distkeras_tpu.models.blocks import (DroplessExperts, GatedMLP,
                                          Router, publish_moe_round,
                                          remat_block,
                                          route_sigmoid_bias_top_k)
+from distkeras_tpu.scopes import owner
 
 #: the collection that holds each routed layer's expert bias: state a training
 #: step updates (like BatchNorm's statistics), never a gradient's
@@ -120,7 +121,7 @@ class Lfm2Block(nn.Module):
             return x + GatedMLP(self.d_ff, "silu", name="mlp")(g)
         first, held = self.experts_held
         g = g.reshape(B * L, D)
-        with jax.named_scope("dk_moe_route"):
+        with owner("ffn"), jax.named_scope("dk_moe_route"):
             logits = Router(self.num_experts, name="router")(g)
             if held < self.num_experts:
                 # A share does not train its router (the module doc says why).
@@ -206,7 +207,8 @@ class Lfm2MoeLM(DKModule):
                 "experts_held": [first, held], "vocab_size": self.vocab_size})
         embed = nn.Embed(self.vocab_size, self.d_model, name="tok_embed",
                          embedding_init=nn.initializers.normal(self.embed_std))
-        x = embed(tokens)
+        with owner("embed"):
+            x = embed(tokens)
         block_cls = Lfm2Block
         if self.remat:
             attention = sum(op == "full_attention" for op, _ in kinds)
@@ -222,7 +224,9 @@ class Lfm2MoeLM(DKModule):
                 self.routed_scaling_factor, self.expert_bias_std,
                 self.expert_bias_update, self.conv_kernel, self.rope_theta,
                 self.rms_eps, self.attn_impl, name=f"block_{l}")(x)
-        return embed.attend(RMSNorm(self.rms_eps, name="ln_final")(x))
+        x = RMSNorm(self.rms_eps, name="ln_final")(x)
+        with owner("head"):
+            return embed.attend(x)
 
     def publish_round_counters(self, round_index: int, counters) -> None:
         publish_moe_round(round_index, counters, self.experts_per_token)

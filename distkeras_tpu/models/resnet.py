@@ -23,6 +23,7 @@ import jax
 from flax import linen as nn
 
 from distkeras_tpu.models.base import DKModule, Model, register_model
+from distkeras_tpu.scopes import owner
 
 
 class GN(nn.Module):
@@ -42,33 +43,34 @@ class GN(nn.Module):
     def __call__(self, x):
         import jax.numpy as jnp
 
-        C = x.shape[-1]
-        # One param layout for both impls, so impl is a runtime choice (a
-        # checkpoint trained either way loads under the other).
-        gamma = self.param("scale", nn.initializers.ones, (C,))
-        beta = self.param("bias", nn.initializers.zeros, (C,))
-        # is_initializing: flax init may run eagerly on a CPU device even in
-        # a TPU process (param init is host work) — the compiled kernel can't;
-        # both impls share the param layout, so init through the HLO path.
-        if self.impl == "pallas" and not self.is_initializing():
-            from distkeras_tpu.ops.pallas.groupnorm import group_norm
+        with owner("norm"):
+            C = x.shape[-1]
+            # One param layout for both impls, so impl is a runtime choice (a
+            # checkpoint trained either way loads under the other).
+            gamma = self.param("scale", nn.initializers.ones, (C,))
+            beta = self.param("bias", nn.initializers.zeros, (C,))
+            # is_initializing: flax init may run eagerly on a CPU device even in
+            # a TPU process (param init is host work) — the compiled kernel can't;
+            # both impls share the param layout, so init through the HLO path.
+            if self.impl == "pallas" and not self.is_initializing():
+                from distkeras_tpu.ops.pallas.groupnorm import group_norm
 
-            return group_norm(x, gamma, beta, groups=self.num_groups,
-                              relu=self.relu)
-        # Functional GroupNorm, flax-equivalent: float32 stats over
-        # (spatial..., C/G) with biased variance, eps 1e-6.
-        G = self.num_groups
-        xf = x.astype(jnp.float32)
-        gshape = x.shape[:-1] + (G, C // G)
-        xg = xf.reshape(gshape)
-        axes = tuple(range(1, len(gshape) - 2)) + (len(gshape) - 1,)
-        mean = xg.mean(axes, keepdims=True)
-        var = ((xg - mean) ** 2).mean(axes, keepdims=True)
-        y = ((xg - mean) * jax.lax.rsqrt(var + 1e-6)).reshape(x.shape)
-        y = y * gamma + beta
-        if self.relu:
-            y = jnp.maximum(y, 0.0)
-        return y.astype(x.dtype)
+                return group_norm(x, gamma, beta, groups=self.num_groups,
+                                  relu=self.relu)
+            # Functional GroupNorm, flax-equivalent: float32 stats over
+            # (spatial..., C/G) with biased variance, eps 1e-6.
+            G = self.num_groups
+            xf = x.astype(jnp.float32)
+            gshape = x.shape[:-1] + (G, C // G)
+            xg = xf.reshape(gshape)
+            axes = tuple(range(1, len(gshape) - 2)) + (len(gshape) - 1,)
+            mean = xg.mean(axes, keepdims=True)
+            var = ((xg - mean) ** 2).mean(axes, keepdims=True)
+            y = ((xg - mean) * jax.lax.rsqrt(var + 1e-6)).reshape(x.shape)
+            y = y * gamma + beta
+            if self.relu:
+                y = jnp.maximum(y, 0.0)
+            return y.astype(x.dtype)
 
 
 class BottleneckBlock(nn.Module):
@@ -80,20 +82,24 @@ class BottleneckBlock(nn.Module):
     @nn.compact
     def __call__(self, x):
         residual = x
-        y = nn.Conv(self.features, (1, 1), use_bias=False)(x)
+        with owner("conv"):
+            y = nn.Conv(self.features, (1, 1), use_bias=False)(x)
         y = GN(min(self.groups, self.features), self.norm_impl, relu=True)(y)
-        y = nn.Conv(
-            self.features, (3, 3), strides=(self.strides, self.strides),
-            padding="SAME", use_bias=False,
-        )(y)
+        with owner("conv"):
+            y = nn.Conv(
+                self.features, (3, 3), strides=(self.strides, self.strides),
+                padding="SAME", use_bias=False,
+            )(y)
         y = GN(min(self.groups, self.features), self.norm_impl, relu=True)(y)
-        y = nn.Conv(self.features * 4, (1, 1), use_bias=False)(y)
+        with owner("conv"):
+            y = nn.Conv(self.features * 4, (1, 1), use_bias=False)(y)
         y = GN(min(self.groups, self.features * 4), self.norm_impl)(y)
         if residual.shape != y.shape:
-            residual = nn.Conv(
-                self.features * 4, (1, 1), strides=(self.strides, self.strides),
-                use_bias=False,
-            )(x)
+            with owner("conv"):
+                residual = nn.Conv(
+                    self.features * 4, (1, 1),
+                    strides=(self.strides, self.strides), use_bias=False,
+                )(x)
             residual = GN(min(self.groups, self.features * 4), self.norm_impl)(residual)
         return nn.relu(residual + y)
 
@@ -116,10 +122,13 @@ class ResNet(DKModule):
     @nn.compact
     def __call__(self, x, train: bool = False):
         k = (self.stem_kernel, self.stem_kernel)
-        x = nn.Conv(self.base_features, k, strides=(2, 2), padding="SAME", use_bias=False)(x)
+        with owner("conv"):
+            x = nn.Conv(self.base_features, k, strides=(2, 2), padding="SAME",
+                        use_bias=False)(x)
         x = GN(min(self.groups, self.base_features), self.norm_impl,
                relu=True)(x)
-        x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
+        with owner("conv"):  # the stem's pooling, with the stem
+            x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
         block_cls = nn.remat(BottleneckBlock) if self.remat else BottleneckBlock
         for i, block_count in enumerate(self.stage_sizes):
             features = self.base_features * (2**i)
@@ -131,8 +140,9 @@ class ResNet(DKModule):
                 x = block_cls(features, strides=strides, groups=self.groups,
                               norm_impl=self.norm_impl,
                               name=f"stage{i}_block{j}")(x)
-        x = x.mean(axis=(1, 2))  # global average pool
-        return nn.Dense(self.num_outputs)(x)
+        with owner("head"):
+            x = x.mean(axis=(1, 2))  # global average pool
+            return nn.Dense(self.num_outputs)(x)
 
 
 def resnet50(num_outputs: int = 1000, seed: int = 0, remat: bool = False,

@@ -55,6 +55,7 @@ from distkeras_tpu.models.blocks import (DroplessExperts,
                                          GroupedQueryAttention, RMSNorm,
                                          Router, publish_moe_round,
                                          remat_block, route_top_k)
+from distkeras_tpu.scopes import owner
 
 
 class SmallThinkerBlock(nn.Module):
@@ -74,7 +75,7 @@ class SmallThinkerBlock(nn.Module):
     def __call__(self, x):
         B, L, D = x.shape
         first, held = self.experts_held
-        with jax.named_scope("dk_moe_route"):
+        with owner("ffn"), jax.named_scope("dk_moe_route"):
             logits = Router(self.num_experts, name="router")(
                 x.reshape(B * L, D))
             if held < self.num_experts:
@@ -130,8 +131,9 @@ class SmallThinkerLM(DKModule):
         # the layers a share holds. flax's default (std 1/sqrt(d_model)) is a
         # fiftieth of what one block adds to it, after which every token's
         # stream is the same vector and every token chooses the same experts.
-        x = nn.Embed(self.vocab_size, self.d_model, name="tok_embed",
-                     embedding_init=nn.initializers.normal(1.0))(tokens)
+        with owner("embed"):
+            x = nn.Embed(self.vocab_size, self.d_model, name="tok_embed",
+                         embedding_init=nn.initializers.normal(1.0))(tokens)
         block_cls = SmallThinkerBlock
         if self.remat:
             block_cls = remat_block(
@@ -148,7 +150,9 @@ class SmallThinkerLM(DKModule):
                 rms_eps=self.rms_eps, attn_impl=self.attn_impl,
                 name=f"block_{l}")(x)
         x = RMSNorm(self.rms_eps, name="ln_final")(x)
-        return nn.Dense(self.vocab_size, use_bias=False, name="lm_head")(x)
+        with owner("head"):
+            return nn.Dense(self.vocab_size, use_bias=False,
+                            name="lm_head")(x)
 
     def publish_round_counters(self, round_index: int, counters) -> None:
         publish_moe_round(round_index, counters, self.experts_per_token)

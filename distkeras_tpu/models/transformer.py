@@ -32,6 +32,7 @@ from flax import linen as nn
 from distkeras_tpu.models.base import DKModule, Model, register_model
 from distkeras_tpu.models.blocks import remat_block
 from distkeras_tpu.runtime.mesh import MODEL_AXIS
+from distkeras_tpu.scopes import owner
 
 
 def _axis_is_auto(abstract_mesh, name: str) -> bool:
@@ -74,59 +75,60 @@ class CausalSelfAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        B, L, D = x.shape
-        H = self.num_heads
-        Dh = D // H
-        q = nn.DenseGeneral((H, Dh), name="query")(x)
-        k = nn.DenseGeneral((H, Dh), name="key")(x)
-        v = nn.DenseGeneral((H, Dh), name="value")(x)
-        q = q / jnp.sqrt(Dh).astype(q.dtype)
+        with owner("mixer"):
+            B, L, D = x.shape
+            H = self.num_heads
+            Dh = D // H
+            q = nn.DenseGeneral((H, Dh), name="query")(x)
+            k = nn.DenseGeneral((H, Dh), name="key")(x)
+            v = nn.DenseGeneral((H, Dh), name="value")(x)
+            q = q / jnp.sqrt(Dh).astype(q.dtype)
 
-        if self.seq_axis is not None and self.attn_impl == "ring":
-            from distkeras_tpu.ops.ring_attention import ring_attention
+            if self.seq_axis is not None and self.attn_impl == "ring":
+                from distkeras_tpu.ops.ring_attention import ring_attention
 
-            out = ring_attention(q, k, v, axis_name=self.seq_axis)
-        elif (self.seq_axis is None and self.attn_impl == "flash"
-              and not self.is_initializing()):
-            # Init only declares params (attention has none of its own), so
-            # Model.build's shape-inference pass — any L, often a (1, 1)
-            # dummy, eagerly on the default device — takes the numerically
-            # identical dense path below instead of compiling the kernel.
-            from distkeras_tpu.ops.pallas import flash_attention
+                out = ring_attention(q, k, v, axis_name=self.seq_axis)
+            elif (self.seq_axis is None and self.attn_impl == "flash"
+                  and not self.is_initializing()):
+                # Init only declares params (attention has none of its own), so
+                # Model.build's shape-inference pass — any L, often a (1, 1)
+                # dummy, eagerly on the default device — takes the numerically
+                # identical dense path below instead of compiling the kernel.
+                from distkeras_tpu.ops.pallas import flash_attention
 
-            block = _flash_block(L)
+                block = _flash_block(L)
 
-            def fa(q, k, v):
-                return flash_attention(q, k, v, block_size=block)
+                def fa(q, k, v):
+                    return flash_attention(q, k, v, block_size=block)
 
-            # Tensor parallelism: a Mosaic kernel cannot be GSPMD-auto-
-            # partitioned, so when the ambient mesh carries an (auto) model
-            # axis we manualize it locally — each shard runs flash on its own
-            # heads (attention has no cross-head communication). Works inside
-            # the SPMD engine's partially-manual region via nested shard_map.
-            am = jax.sharding.get_abstract_mesh()
-            if (MODEL_AXIS in am.axis_names and am.shape[MODEL_AXIS] > 1
-                    and _axis_is_auto(am, MODEL_AXIS)):
-                from jax.sharding import PartitionSpec as P
+                # Tensor parallelism: a Mosaic kernel cannot be GSPMD-auto-
+                # partitioned, so when the ambient mesh carries an (auto) model
+                # axis we manualize it locally — each shard runs flash on its own
+                # heads (attention has no cross-head communication). Works inside
+                # the SPMD engine's partially-manual region via nested shard_map.
+                am = jax.sharding.get_abstract_mesh()
+                if (MODEL_AXIS in am.axis_names and am.shape[MODEL_AXIS] > 1
+                        and _axis_is_auto(am, MODEL_AXIS)):
+                    from jax.sharding import PartitionSpec as P
 
-                spec = P(None, None, MODEL_AXIS, None)
-                fa = jax.shard_map(fa, mesh=am, in_specs=(spec, spec, spec),
-                                   out_specs=spec, axis_names={MODEL_AXIS},
-                                   check_vma=False)
-            out = fa(q, k, v)
-        else:
-            q_pos = _global_positions(L, self.seq_axis)
-            if self.seq_axis is not None:
-                # 'gather' sequence parallelism: K/V become global, Q stays local.
-                k = jax.lax.all_gather(k, self.seq_axis, axis=1, tiled=True)
-                v = jax.lax.all_gather(v, self.seq_axis, axis=1, tiled=True)
-            k_pos = jnp.arange(k.shape[1])
-            scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
-            mask = q_pos[:, None] >= k_pos[None, :]
-            scores = jnp.where(mask[None, None, :, :], scores, jnp.finfo(scores.dtype).min)
-            probs = jax.nn.softmax(scores, axis=-1)
-            out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-        return nn.DenseGeneral(D, axis=(-2, -1), name="out")(out)
+                    spec = P(None, None, MODEL_AXIS, None)
+                    fa = jax.shard_map(fa, mesh=am, in_specs=(spec, spec, spec),
+                                       out_specs=spec, axis_names={MODEL_AXIS},
+                                       check_vma=False)
+                out = fa(q, k, v)
+            else:
+                q_pos = _global_positions(L, self.seq_axis)
+                if self.seq_axis is not None:
+                    # 'gather' sequence parallelism: K/V become global, Q stays local.
+                    k = jax.lax.all_gather(k, self.seq_axis, axis=1, tiled=True)
+                    v = jax.lax.all_gather(v, self.seq_axis, axis=1, tiled=True)
+                k_pos = jnp.arange(k.shape[1])
+                scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+                mask = q_pos[:, None] >= k_pos[None, :]
+                scores = jnp.where(mask[None, None, :, :], scores, jnp.finfo(scores.dtype).min)
+                probs = jax.nn.softmax(scores, axis=-1)
+                out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+            return nn.DenseGeneral(D, axis=(-2, -1), name="out")(out)
 
 
 class TransformerBlock(nn.Module):
@@ -139,7 +141,8 @@ class TransformerBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        h = nn.LayerNorm(name="ln_attn")(x)
+        with owner("norm"):
+            h = nn.LayerNorm(name="ln_attn")(x)
         h = CausalSelfAttention(
             self.num_heads, self.d_model, seq_axis=self.seq_axis,
             attn_impl=self.attn_impl, name="attn",
@@ -147,10 +150,12 @@ class TransformerBlock(nn.Module):
         if self.dropout_rate > 0.0:
             h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
         x = x + h
-        h = nn.LayerNorm(name="ln_mlp")(x)
-        h = nn.Dense(self.d_ff, name="mlp_up")(h)
-        h = nn.gelu(h)
-        h = nn.Dense(self.d_model, name="mlp_down")(h)
+        with owner("norm"):
+            h = nn.LayerNorm(name="ln_mlp")(x)
+        with owner("ffn"):
+            h = nn.Dense(self.d_ff, name="mlp_up")(h)
+            h = nn.gelu(h)
+            h = nn.Dense(self.d_model, name="mlp_down")(h)
         if self.dropout_rate > 0.0:
             h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
         return x + h
@@ -172,9 +177,10 @@ class TransformerLM(DKModule):
     @nn.compact
     def __call__(self, tokens, train: bool = False):
         B, L = tokens.shape
-        x = nn.Embed(self.vocab_size, self.d_model, name="tok_embed")(tokens)
-        pos = _global_positions(L, self.seq_axis)
-        x = x + nn.Embed(self.max_seq_len, self.d_model, name="pos_embed")(pos)[None, :, :]
+        with owner("embed"):
+            x = nn.Embed(self.vocab_size, self.d_model, name="tok_embed")(tokens)
+            pos = _global_positions(L, self.seq_axis)
+            x = x + nn.Embed(self.max_seq_len, self.d_model, name="pos_embed")(pos)[None, :, :]
         block_cls = TransformerBlock
         if self.remat:
             block_cls = remat_block(
@@ -188,8 +194,10 @@ class TransformerLM(DKModule):
                 dropout_rate=self.dropout_rate, seq_axis=self.seq_axis,
                 attn_impl=self.attn_impl, name=f"block_{i}",
             )(x, train)
-        x = nn.LayerNorm(name="ln_final")(x)
-        return nn.Dense(self.vocab_size, name="lm_head")(x)
+        with owner("norm"):
+            x = nn.LayerNorm(name="ln_final")(x)
+        with owner("head"):
+            return nn.Dense(self.vocab_size, name="lm_head")(x)
 
 
 def small_transformer_lm(

@@ -141,6 +141,12 @@ _METRICS = [
        "`remat=True` keeps from the first pass a step, over its layers, set "
        "as the model is traced (`flash_attention.FLASH_RESIDUALS`; 0: the "
        "recomputed block runs `dk_flash_fwd` again)."),
+    _m("trace.owner_scopes", "counter", "kernels",
+       "Owner scopes (`with scopes.owner(name):`, a sublayer of "
+       "`scopes.OWNERS` under `scopes.PREFIX`) opened as this process traced "
+       "its models. Above 0 beside a compiled text that holds no such scope: "
+       "the executable came from a compile cache filled by a tree without "
+       "them (`benchmarks/readers/trace_owner.py`)."),
     # -- expert layers ----------------------------------------------------
     _m("moe.assignments_held", "counter", "models",
        "Token-to-expert assignments routed to the experts held here, summed "

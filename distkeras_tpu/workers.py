@@ -24,6 +24,7 @@ import optax
 from jax import lax
 
 from distkeras_tpu.models.base import ROUND_COUNTERS, _warn_uint8_rescale
+from distkeras_tpu.scopes import owner
 
 
 def make_local_loop(
@@ -107,19 +108,23 @@ def make_local_loop(
         return cast(x)
 
     def loss_on_batch(params, state, x, y, rng):
-        if compute_dtype is not None:
-            params = jax.tree.map(cast, params)
+        with owner("cast"):
+            if compute_dtype is not None:
+                params = jax.tree.map(cast, params)
+            x = cast_input(x)
         # Always provide a dropout rng: harmless for dropout-free modules, required
         # for any module that samples (flax raises at trace time otherwise).
         if cols:
             out, mut = module.apply(
-                {"params": params, **state}, cast_input(x), train=True,
+                {"params": params, **state}, x, train=True,
                 rngs={"dropout": rng}, mutable=list(cols),
             )
-            new_state = {k: mut[k] for k in cols}
-            return loss_fn(out.astype(jnp.float32), y), new_state
-        out = module.apply({"params": params}, cast_input(x), train=True, rngs={"dropout": rng})
-        return loss_fn(out.astype(jnp.float32), y), state
+            state = {k: mut[k] for k in cols}
+        else:
+            out = module.apply({"params": params}, x, train=True,
+                               rngs={"dropout": rng})
+        with owner("loss"):
+            return loss_fn(out.astype(jnp.float32), y), state
 
     def local_steps(params, opt_state, xs, ys, rng: Optional[jax.Array] = None,
                     state=None):
