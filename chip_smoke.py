@@ -348,12 +348,14 @@ def _compare(name: str, got, ref, tol: float) -> dict:
 
 
 def _fwd_and_grads(fn, cotangent):
-    """``args -> {"out": fn(*args), "grads": vjp(cotangent)}``, jitted."""
+    """``args -> {"out": fn(*args), "grads": vjp(cotangent)}``, jitted;
+    ``cotangent`` an array or a tuple of them, as ``fn`` returns."""
     import jax
 
     def run(*args):
         out, vjp = jax.vjp(fn, *args)
-        return {"out": out, "grads": vjp(cotangent.astype(out.dtype))}
+        return {"out": out, "grads": vjp(jax.tree.map(
+            lambda c, o: c.astype(o.dtype), cotangent, out))}
 
     return jax.jit(run)
 
@@ -551,6 +553,45 @@ def check_kda_scan(shape) -> dict:
                     _fwd_and_grads(reference, cotangent)(*args), tol=2e-2)
 
 
+def check_kda_chunk(shape) -> dict:
+    """The delta rule's in-chunk half (``ops/pallas/delta_rule.py``: the
+    kernels ``dk_kda_chunk_fwd`` and ``dk_kda_chunk_bwd``) against the kept
+    ``jax.numpy`` form (``ops/delta_rule.py::in_chunk_by_jax_numpy``) with
+    JAX's derivative, the six outputs and all five cotangents, on unit keys,
+    scaled unit queries, a decay from ``e^-6`` to ``e^0.5`` a step and
+    channel, ``beta`` in (0, 1)."""
+    import jax
+    import jax.numpy as jnp
+
+    from distkeras_tpu.ops.delta_rule import (SUB, chunk_for,
+                                              in_chunk_by_jax_numpy)
+    from distkeras_tpu.ops.pallas.delta_rule import chunk_products
+
+    B, L, H, D = shape
+    C, dt = chunk_for(L), jnp.bfloat16
+    ks = jax.random.split(jax.random.key(0), 11)
+
+    def unit(key):
+        x = jax.random.normal(key, shape)
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    args = ((unit(ks[0]) * D ** -0.5).astype(dt), unit(ks[1]).astype(dt),
+            jax.random.normal(ks[2], shape, dt),
+            -jnp.exp(jax.random.uniform(ks[3], shape, minval=-6, maxval=0.5)),
+            jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3])))
+
+    def form(*a):
+        return in_chunk_by_jax_numpy(*a, C)[0]
+
+    cotangents = tuple(jax.random.normal(k, o.shape) for k, o in zip(
+        ks[5:], jax.eval_shape(form, *args)))
+    return _compare(
+        "kda_chunk",
+        _fwd_and_grads(lambda *a: chunk_products(*a, C, min(SUB, C))[:6],
+                       cotangents)(*args),
+        _fwd_and_grads(form, cotangents)(*args), tol=2e-2)
+
+
 def phase_kernels(sz: Sizes) -> dict:
     out = {}
     flash = [(f"flash_attention_L{shape[1]}", check_flash, shape)
@@ -560,7 +601,8 @@ def phase_kernels(sz: Sizes) -> dict:
                                ("group_norm", check_groupnorm, sz.groupnorm),
                                ("fold", check_fold, sz.fold),
                                ("rows", check_rows, sz.rows),
-                               ("kda_scan", check_kda_scan, sz.kda)):
+                               ("kda_scan", check_kda_scan, sz.kda),
+                               ("kda_chunk", check_kda_chunk, sz.kda)):
         t0 = time.perf_counter()
         out[name] = dict(check(shape), shape=list(shape),
                          seconds=round(time.perf_counter() - t0, 2))

@@ -269,11 +269,13 @@ class KimiDeltaAttention(nn.Module):
       starts: ``S' = Diag(alpha_t) S_{t-1}``, ``S_t = S' + beta_t k_t (v_t -
       S'^T k_t)^T``, ``o_t = S_t^T q_t``: computed in the chunked form of
       ``ops/delta_rule.py`` (which says how no exponential can overflow), in
-      chunks of ``chunk_for(L)`` positions;
+      chunks of ``chunk_for(L)`` positions, by the two kernel pairs of
+      ``ops/pallas/delta_rule.py`` (the in-chunk half, which reads ``q, k, v,
+      g`` as they are reshaped here; the scan over chunks);
     * ``y = (RMSNorm_head(o; w) * sigmoid((h W_ga) W_gb)) W_o``.
 
     Scopes: ``dk_kda_conv`` (the three tap sums and their SiLU), ``dk_kda``
-    (norms, decay, in-chunk products, the scan over chunks, the gated norm);
+    (norms, decay, the four kernels of the chunked form, the gated norm);
     the projections and ``W_o`` are matmuls of the step. A round's smallest
     summed log-decay of a chunk (how near the overflow hazard the run is: -88
     is where ``e^-G`` would leave float32) and its mean ``beta`` leave the

@@ -1,11 +1,16 @@
 """The gated delta rule with a decay a channel (Kimi Delta Attention's
 recurrence; Kimi Linear, arXiv:2510.26692), in its **chunked form**: products
-inside a chunk, a scan over chunks. The in-chunk half is plain ``jax.numpy``
-and its backward pass JAX's own derivative; the scan over chunks is the Pallas
-kernel pair of ``ops/pallas/delta_rule.py`` (``dk_kda_scan_fwd``,
-``dk_kda_scan_bwd``: the state stays in VMEM, the backward is written). Not
-built: the in-chunk products and the inverse in a kernel, which is where the
-time is (ROADMAP R5).
+inside a chunk, a scan over chunks. Both halves are Pallas kernel pairs of
+``ops/pallas/delta_rule.py``, each with a written backward under one
+``jax.custom_vjp``: the in-chunk half (``dk_kda_chunk_fwd``,
+``dk_kda_chunk_bwd``: ``G``, ``A``, ``Bq``, the triangular inverse, ``U``,
+``W`` made in VMEM from ``q, k, v, g, beta`` as the projections leave them)
+and the scan over chunks (``dk_kda_scan_fwd``, ``dk_kda_scan_bwd``: the state
+stays in VMEM). The in-chunk half in plain ``jax.numpy``
+(:func:`in_chunk_by_jax_numpy`) is kept as what the kernels are held to; no
+model path reaches it. Not built: the two pairs as one kernel (``U`` to
+``Kd`` go through HBM between them), the scan's output written as ``[B, L,
+H x d]`` (ROADMAP S14).
 
 A head carries a state ``S`` ``[d_k, d_v]``, zero before the sequence starts.
 With ``alpha_t = exp(g_t)`` in (0, 1) a channel of the key and ``beta_t`` in
@@ -25,12 +30,16 @@ unit lower-triangular system:
     o = (Q * e^G) S_0 + tril(B) u~    B_ri = q_r . (k_i * e^{G_r - G_i}), i <= r
     S_C = Diag(e^{G_C}) S_0 + (K * e^{G_C - G})^T u~
 
-``A``, ``B``, ``T``, ``U``, ``W`` are computed for all chunks at once, as
-batched products; only the three lines that touch ``S_0`` run in the scan over
-chunks (:func:`~distkeras_tpu.ops.pallas.delta_rule.scan_chunks`), which
-carries ``S`` in float32. ``T`` is built by blocks from the
-unit diagonal up (:func:`_unit_lower_inverse`: forward substitution a block
-at a time, ``log2 C`` levels of two batched products), in float32.
+``A``, ``B``, ``T``, ``U``, ``W`` are computed for all chunks at once
+(:func:`~distkeras_tpu.ops.pallas.delta_rule.chunk_products`: a program a
+chunk and group of heads, in any order); only the three lines that touch
+``S_0`` run in the scan over chunks
+(:func:`~distkeras_tpu.ops.pallas.delta_rule.scan_chunks`), which carries
+``S`` in float32. ``T`` is built by blocks from the unit diagonal up, in
+float32: here (:func:`_unit_lower_inverse`) forward substitution a block at a
+time, ``log2 C`` levels of two batched products; in the kernel the ``SUB`` x
+``SUB`` blocks on the diagonal by forward substitution a diagonal at a time,
+the levels above them by the same two products.
 
 **The overflow hazard, and what is done about it.** ``e^{-G}`` overflows
 float32 once a chunk's summed decay passes 88 (``g`` near -1.6 a step, which
@@ -59,7 +68,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from distkeras_tpu.ops.pallas.delta_rule import scan_chunks
+from distkeras_tpu.ops.pallas.delta_rule import (chunk_products,
+                                                   scan_chunks)
 
 #: positions a chunk, where the sequence allows it, and rows a sub-chunk
 CHUNK, SUB = 64, 16
@@ -103,21 +113,17 @@ def _unit_lower_inverse(n):
     return blocks.reshape(*lead, C, C)
 
 
-def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int | None = None):
-    """``o_t`` of the recurrence above for every position, from a zero
-    state. ``q, k``: [B, L, H, d_k] (already normalised and scaled); ``v``:
-    [B, L, H, d_v]; ``g``: [B, L, H, d_k], the log of the decay (``<= 0``);
-    ``beta``: [B, L, H]. ``chunk``, a power of two, divides ``L``
-    (``chunk_for(L)`` by default). Products take operands of ``v``'s dtype
-    and accumulate in float32; decays, the triangular inverse and the carried
-    state are float32. Returns ``(o [B, L, H, d_v] in v's dtype, the smallest
-    summed log-decay of a chunk and channel)``."""
+def in_chunk_by_jax_numpy(q, k, v, g, beta, chunk: int):
+    """The in-chunk half in plain ``jax.numpy``, as it ran until the kernels
+    of ``ops/pallas/delta_rule.py`` (:func:`~distkeras_tpu.ops.pallas.
+    delta_rule.chunk_products`) took its place: **no model path reaches
+    this**; tests and ``chip_smoke.py`` hold the kernels, and their written
+    backward against JAX's derivative of this, to it. Arguments as
+    :func:`chunked_gated_delta_rule`. Returns ``(U, W, Qg, Bq, Kd, s)`` as
+    ``scan_chunks`` reads them (``[B, H, N, C, .]``, ``s`` ``[B, H, N, d_k]``)
+    and the summed log-decay of each chunk ``[B, H, N, d_k]``."""
     B, L, H, K = k.shape
-    V = v.shape[-1]
-    C = chunk_for(L) if chunk is None else chunk
-    if L % C or C & (C - 1):
-        raise ValueError(f"a sequence of {L} in chunks of {C}: a chunk is a "
-                         "power of two and must divide the sequence")
+    C = chunk
     sub = min(SUB, C)
     N, M = L // C, C // sub
     dt, f32 = v.dtype, jnp.float32
@@ -167,8 +173,27 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int | None = None):
     decay = jnp.exp(G)
     U = dot("bhnrc,bhncv->bhnrv", T, beta[..., None] * v.astype(f32))
     W = dot("bhnrc,bhnck->bhnrk", T, beta[..., None] * k32 * decay)
-    last = G[:, :, :, -1:, :]
-    out = scan_chunks(
-        U, W.astype(dt), (q32 * decay).astype(dt), (Bq * tril).astype(dt),
-        (k32 * jnp.exp(last - G)).astype(dt), jnp.exp(last[:, :, :, 0]))
-    return jnp.moveaxis(out.reshape(B, H, L, V), 1, 2), jnp.min(last)
+    last = G[:, :, :, -1, :]
+    return (U, W.astype(dt), (q32 * decay).astype(dt), (Bq * tril).astype(dt),
+            (k32 * jnp.exp(last[:, :, :, None] - G)).astype(dt),
+            jnp.exp(last)), last
+
+
+def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int | None = None):
+    """``o_t`` of the recurrence above for every position, from a zero
+    state. ``q, k``: [B, L, H, d_k] (already normalised and scaled); ``v``:
+    [B, L, H, d_v]; ``g``: [B, L, H, d_k], the log of the decay (``<= 0``);
+    ``beta``: [B, L, H]. ``chunk``, a power of two, divides ``L``
+    (``chunk_for(L)`` by default). Products take operands of ``v``'s dtype
+    and accumulate in float32; decays, the triangular inverse and the carried
+    state are float32. Returns ``(o [B, L, H, d_v] in v's dtype, the smallest
+    summed log-decay of a chunk and channel)``."""
+    B, L, H, _ = k.shape
+    C = chunk_for(L) if chunk is None else chunk
+    if L % C or C & (C - 1):
+        raise ValueError(f"a sequence of {L} in chunks of {C}: a chunk is a "
+                         "power of two and must divide the sequence")
+    *products, last = chunk_products(q, k, v, g, beta, C, min(SUB, C))
+    out = scan_chunks(*products)
+    return (jnp.moveaxis(out.reshape(B, H, L, v.shape[-1]), 1, 2),
+            jnp.min(jax.lax.stop_gradient(last)))

@@ -186,10 +186,17 @@ _METRICS = [
        "Heads one program of the delta rule's scan kernels holds, set as a "
        "call is traced (`ops/pallas/delta_rule.py::heads_per_program`: the "
        "largest of 8, 4, 2, 1 that divides batch x heads and fits VMEM)."),
+    _m("pallas.kda.chunk_heads_per_program", "gauge", "kernels",
+       "Heads one program of the delta rule's in-chunk kernels "
+       "(`dk_kda_chunk_fwd`, `dk_kda_chunk_bwd`) holds, set as a call is "
+       "traced (`ops/pallas/delta_rule.py::chunk_heads_per_program`: "
+       "neighbours in one batch row of `[B, L, H x d]`, the most up to 8 "
+       "that divide the heads, keep whole lane tiles and fit VMEM)."),
     _m("pallas.kda.grid_steps", "gauge", "kernels",
-       "Grid steps of one call of `dk_kda_scan_fwd` / `dk_kda_scan_bwd`: "
-       "batch x heads / heads a program, times the chunks, set as a call is "
-       "traced."),
+       "Grid steps of the delta rule's kernel call traced last "
+       "(`dk_kda_scan_*`: batch x heads / heads a program, times the "
+       "chunks; `dk_kda_chunk_*`: the same with their own heads a "
+       "program)."),
     # -- inference --------------------------------------------------------
     _m("predict.chunk", "span", "inference",
        "Per-chunk end-to-end predict latency."),

@@ -1,11 +1,14 @@
 """The two operations Kimi Linear forced into ``ops/``, on the CPU in float32:
-the gated delta rule's chunked form (``ops/delta_rule.py``, its scan over
-chunks the kernel pair of ``ops/pallas/delta_rule.py``, interpreted) against
-the recurrence itself, values and every gradient, at two and three chunks and
-two chunk sizes; the kernel pair alone against the ``jax.numpy`` step it
+the gated delta rule's chunked form (``ops/delta_rule.py``, both halves the
+kernel pairs of ``ops/pallas/delta_rule.py``, interpreted) against the
+recurrence itself, values and every gradient, at two and three chunks and two
+chunk sizes; the scan's kernel pair alone against the ``jax.numpy`` step it
 replaced and JAX's derivative of it, the state across programs and heads, the
-last chunk's ``dS`` and an underflowed decay (both kernels compiled for a
-described v5e at the cell's shape: ``tests/test_pallas_rows.py``, which
+last chunk's ``dS`` and an underflowed decay; the in-chunk pair alone against
+the kept ``jax.numpy`` form and JAX's derivative of it, six outputs and five
+cotangents, across batch rows and heads, in bfloat16 with a float8 control,
+under held decays and keys that point one way (all four kernels compiled for
+a described v5e at the cell's shape: ``tests/test_pallas_rows.py``, which
 describes the topology); the overflow case (a decay held at -1.6 a step over whole
 chunks, and at -20); keys that point one way (the triangular inverse's
 stability); the state carried across a chunk boundary; the gradients with
@@ -27,8 +30,9 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmarks.references import kimi_linear as reference  # noqa: E402
-from distkeras_tpu.ops.delta_rule import (chunk_for,  # noqa: E402
-                                          chunked_gated_delta_rule)
+from distkeras_tpu.ops.delta_rule import (SUB, chunk_for,  # noqa: E402
+                                          chunked_gated_delta_rule,
+                                          in_chunk_by_jax_numpy)
 from distkeras_tpu.ops.pallas import delta_rule as kda_kernels  # noqa: E402
 from distkeras_tpu.ops.pallas.flash_attention import (  # noqa: E402
     default_tiling, flash_attention)
@@ -147,11 +151,13 @@ def test_the_state_is_carried_across_the_chunk_boundary():
                                    for a in args], chunk=96)
 
 
-#: The trainer's dtype: the backward pass is JAX's derivative through a
+#: The trainer's dtype: the backward pass is the kernels' written one (until
+#: PR 39 JAX's derivative of the ``jax.numpy`` in-chunk half), through a
 #: float32 triangular inverse whose products take bfloat16 operands. Relative
-#: L2 of each gradient against the float32 recurrence's, on the CPU, seeds 0
-#: to 3: 3.9e-3 to 6.2e-3 in bfloat16 on keys drawn apart, 5.8e-2 to 9.6e-2
-#: in float8_e4m3; 2e-2 lies a factor of three from either. On keys nine
+#: L2 of each gradient against the float32 recurrence's, on the CPU: 3.8e-3 to
+#: 5.1e-3 in bfloat16 on keys drawn apart (3.9e-3 to 6.2e-3 before the
+#: kernels, seeds 0 to 3), 6.1e-2 to 9.3e-2 in float8_e4m3 (5.8e-2 to
+#: 9.6e-2); 2e-2 lies a factor of three from either. On keys nine
 #: parts in ten alike the system is badly conditioned whatever computes it:
 #: q, k, v and beta read 1.9e-2 to 6.5e-2 in bfloat16 and 0.28 to 1.0 in
 #: float8, the same factor of fifteen apart (the decay's gradient, of a
@@ -355,6 +361,127 @@ def test_heads_a_program_follow_the_shapes_and_the_gauges_say_so():
     assert telemetry.gauge("pallas.kda.grid_steps").value == 3 * 5
     with pytest.raises(ValueError, match="do not divide"):
         kda_kernels.scan_chunks(*args, heads=4, interpret=True)
+
+
+# -- the in-chunk half as a kernel pair ----------------------------------------
+
+CHUNK_NAMES = "U W Qg Bq Kd s".split()
+INPUT_NAMES = "q k v g beta".split()
+
+
+def chunk_pair_and_form(C, heads, rounded=None):
+    """A jitted ``(args, seed) -> (pair, form)``, each the six outputs and
+    the five cotangents under ``jax.vjp`` with one set of drawn cotangents:
+    of the kernel pair (operands in ``rounded`` where given) and of the kept
+    ``jax.numpy`` in-chunk form in float32."""
+    def run(args, key):
+        q, k, v, g, beta = args
+        given = args if rounded is None else (
+            q.astype(rounded), k.astype(rounded), v.astype(rounded), g, beta)
+        got, vjp = jax.vjp(lambda *a: kda_kernels.chunk_products(
+            *a, C, min(SUB, C), heads=heads, interpret=True)[:6], *given)
+        want, want_vjp = jax.vjp(lambda *a: in_chunk_by_jax_numpy(*a, C)[0],
+                                 *args)
+        cotangents = tuple(jax.random.normal(k, w.shape) for k, w in zip(
+            jax.random.split(key, 6), want))
+        return ((got, vjp(tuple(c.astype(o.dtype) for c, o in zip(
+            cotangents, got)))), (want, want_vjp(cotangents)))
+    return jax.jit(run)
+
+
+@pytest.mark.parametrize("chunks, C, heads", [
+    (1, 32, None), (2, 64, None), (5, 32, None), (5, 64, None), (2, 64, 1),
+    (5, 32, 1)], ids=lambda x: "all-heads" if x is None else str(x))
+def test_the_chunk_pair_is_the_in_chunk_form_and_its_derivative(chunks, C,
+                                                                heads):
+    """``B`` = 2, ``H`` = 3: programs span batch rows and a head is a lane
+    slice of ``[B, L, H x d]``. All six outputs and each of the five
+    cotangents, in its primal's dtype and shape, against ``jax.vjp`` of the
+    ``jax.numpy`` form: float32 to 1e-5. The gauges say what was traced."""
+    from distkeras_tpu import telemetry
+
+    args = delta_inputs(chunks * C, B=2, H=3, seed=chunks)
+    (got, grads), (want, want_grads) = chunk_pair_and_form(C, heads)(
+        args, jax.random.key(1))
+    for name, a, b in zip(CHUNK_NAMES, got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert rel_l2(a, b) < 1e-5, name
+    for name, x, a, b in zip(INPUT_NAMES, args, grads, want_grads):
+        assert a.shape == x.shape and a.dtype == x.dtype, name
+        assert np.linalg.norm(b) > 0, name
+        assert rel_l2(a, b) < 1e-5, name
+    held = 3 if heads is None else heads
+    assert telemetry.gauge("pallas.kda.chunk_heads_per_program").value == held
+    assert telemetry.gauge("pallas.kda.grid_steps").value \
+        == 2 * 3 // held * chunks
+
+
+@pytest.mark.parametrize("dtype, passes", [(jnp.bfloat16, True),
+                                           (jnp.float8_e4m3fn, False)],
+                         ids=["bfloat16", "float8-must-fail"])
+def test_the_chunk_pair_with_rounded_operands_against_the_float32_form(
+        dtype, passes):
+    """The trainer's dtype against the float32 form, the six outputs and the
+    five cotangents under 2e-2; float8_e4m3 operands are the control that
+    must not pass it."""
+    args = delta_inputs(5 * 64, B=1, H=2, seed=2)
+    (got, grads), (want, want_grads) = chunk_pair_and_form(
+        64, None, rounded=dtype)(args, jax.random.key(3))
+    errors = {}
+    for name, a, b in (*zip(CHUNK_NAMES, got, want),
+                       *zip(INPUT_NAMES, grads, want_grads)):
+        wanted = jnp.float32 if name in ("U", "s", "g", "beta") else dtype
+        assert a.dtype == wanted, name
+        assert np.isfinite(np.asarray(a, np.float32)).all(), name
+        errors[name] = rel_l2(a.astype(jnp.float32), b)
+    assert (max(errors.values()) < 2e-2) == passes, errors
+
+
+def test_the_chunk_pair_under_held_decays_and_keys_that_point_one_way():
+    """One trace, five inputs of one shape. ``g`` held at -1.6, -20 and 0
+    over whole chunks: every output and cotangent finite and the form's (a
+    sub-chunk's summed decay of -320 underflows both factors of a pair to
+    zero and nothing overflows). Keys that point one way: the kernel's
+    inverse (substitution inside the 16 x 16 blocks, by blocks above them)
+    gives the ``U`` and ``W`` of ``_unit_lower_inverse``."""
+    both = chunk_pair_and_form(64, None)
+    cases = {f"g={g}": delta_inputs(128, g=g) for g in (-1.6, -20.0, 0.0)}
+    cases.update({f"alike={alike}": alike_inputs(alike, beta)
+                  for alike, beta in ((0.9, 0.5), (1.0, 0.99))})
+    for case, args in cases.items():
+        (got, grads), (want, want_grads) = both(args, jax.random.key(4))
+        for name, a, b in (*zip(CHUNK_NAMES, got, want),
+                           *zip(INPUT_NAMES, grads, want_grads)):
+            assert np.isfinite(np.asarray(a)).all(), (case, name)
+            if case.startswith("alike") and name in INPUT_NAMES \
+                    or case == "g=-20.0" and name == "g":
+                # a system that ill conditioned, and a gradient that is
+                # rounding at e^-20: finite is the claim
+                continue
+            assert rel_l2(a, b) < 1e-4, (case, name, rel_l2(a, b))
+    # the smallest summed log-decay leaves the kernel beside the products
+    last = kda_kernels.chunk_products(*cases["g=-1.6"], 64, SUB,
+                                      interpret=True)[6]
+    assert last.shape == (2, 2, 2, 16)
+    np.testing.assert_allclose(np.asarray(last), -1.6 * 64, rtol=1e-5)
+
+
+def test_chunk_heads_a_program_follow_the_shapes():
+    # the cell: 8 heads of 128 in chunks of 64, bfloat16 operands
+    assert kda_kernels.chunk_heads_per_program(8, 64, 128, 128, 2) == 8
+    assert kda_kernels.chunk_heads_per_program(32, 64, 128, 128, 2) == 8
+    assert kda_kernels.chunk_heads_per_program(6, 64, 128, 128, 2) == 6
+    assert kda_kernels.chunk_heads_per_program(7, 64, 128, 128, 2) == 7
+    # a program's heads are a lane slice of [B, L, H x d]: whole tiles of
+    # 128 lanes, or the whole row
+    assert kda_kernels.chunk_heads_per_program(12, 64, 64, 64, 2) == 6
+    assert kda_kernels.chunk_heads_per_program(3, 32, 16, 8, 4) == 3
+    assert kda_kernels.chunk_heads_per_program(16, 64, 16, 8, 4) == 16
+    # wider heads: fewer fit beside their blocks, twice
+    assert kda_kernels.chunk_heads_per_program(8, 64, 512, 512, 4) == 2
+    with pytest.raises(ValueError, match="do not divide"):
+        kda_kernels.chunk_products(*delta_inputs(64, B=1, H=3), 32, SUB,
+                                   heads=2, interpret=True)
 
 
 # -- the flash kernels with a value width of their own ------------------------

@@ -316,12 +316,16 @@ def test_flash_compiles_for_a_v5e_with_keys_wider_than_values(one_chip):
     assert text.count("tpu_custom_call") == 3
 
 
-def test_the_kernel_pair_compiles_for_a_v5e_at_the_cells_shape(one_chip,
-                                                               monkeypatch):
+def test_the_kernel_pairs_compile_for_a_v5e_at_the_cells_shape(one_chip,
+                                                                monkeypatch):
     """``[1, 8192, 8, 128]`` in chunks of 64 under ``jax.checkpoint`` and
-    ``jax.grad``, bfloat16: Mosaic takes both kernels, the layer is three
-    calls (forward, recomputed, backward) and no ``while`` is left under
-    ``dk_kda``. Kept in this file for the reason above."""
+    ``jax.value_and_grad``, bfloat16: Mosaic takes all four kernels (the
+    in-chunk pair ``dk_kda_chunk_*`` and the scan pair ``dk_kda_scan_*``), the
+    layer is six calls (both forward kernels in the forward and again in the
+    recomputed pass, then the two backward kernels), every one under
+    ``dk_kda``; no ``while`` is left there and the pairwise ``[.., 16, 16,
+    128]`` tensors of the ``jax.numpy`` form are gone from the program. Kept
+    in this file for the reason above."""
     import re
 
     from distkeras_tpu.ops.delta_rule import chunked_gated_delta_rule
@@ -347,9 +351,12 @@ def test_the_kernel_pair_compiles_for_a_v5e_at_the_cells_shape(one_chip,
         shape(*wide, dtype=jnp.float32),
         shape(*wide[:3], dtype=jnp.float32)).compile().as_text()
     calls = re.findall(r"= [^\n]*custom-call\([^\n]*"
-                       r'op_name="[^"]*/(dk_kda_scan_\w+)/', text)
-    assert sorted(calls) == ["dk_kda_scan_bwd", "dk_kda_scan_fwd",
-                             "dk_kda_scan_fwd"], calls
+                       r'op_name="[^"]*/(dk_kda_(?:scan|chunk)_\w+)/', text)
+    assert sorted(calls) == ["dk_kda_chunk_bwd", "dk_kda_chunk_fwd",
+                             "dk_kda_chunk_fwd", "dk_kda_scan_bwd",
+                             "dk_kda_scan_fwd", "dk_kda_scan_fwd"], calls
+    assert text.count('custom_call_target="tpu_custom_call"') == 6
     assert all("/dk_kda/" in line for line in text.splitlines()
-               if "custom-call(" in line and "dk_kda_scan_" in line)
+               if "custom-call(" in line and "dk_kda_" in line)
     assert not re.search(r'= [^\n]* while\([^\n]*op_name="[^"]*dk_kda', text)
+    assert not re.search(r"\[[0-9,]*16,16,128\]", text)
