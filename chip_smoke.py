@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import importlib.metadata
 import json
 import os
@@ -72,6 +73,8 @@ class Sizes:
     rows: tuple       # (tokens, k, D, held of 64 experts) of an expert layer
     kda: tuple        # (B, L, H, Dh) of a Kimi Delta Attention layer
     mlp_rows: int
+    gdn: tuple        # (B, L, key heads, value heads, Dh) of Gated DeltaNet
+    flash_gqa: tuple  # (B, L, H, K/V heads, Dh) of grouped attention
 
 
 FULL = Sizes(layers=8, d_model=1024, heads=16, d_ff=4096, vocab=32768,
@@ -82,14 +85,16 @@ FULL = Sizes(layers=8, d_model=1024, heads=16, d_ff=4096, vocab=32768,
              groupnorm=(128, 112, 112, 64, 32), fold=(8192, 512),
              rows=(16384, 6, 2560, 8),  # the smallthinker cell's step
              kda=(1, 8192, 8, 128),     # the kimi_linear cell's step
-             mlp_rows=8192)
+             mlp_rows=8192,
+             gdn=(1, 8192, 16, 32, 128),       # a qwen3next cell's sequence
+             flash_gqa=(1, 8192, 16, 2, 256))  # its gated attention's heads
 TOY = Sizes(layers=1, d_model=64, heads=2, d_ff=128, vocab=256,
             seq=128, lm_batch=2, lm_window=2, lm_rounds=3, tp_layers=1,
             cnn_batch=16, cnn_window=2,
             flash=((1, 128, 2, 16), (1, 64, 2, 16)), lstm=(8, 6, 8, 128),
             groupnorm=(2, 8, 8, 64, 32), fold=(70, 33), rows=(64, 2, 32, 16),
             kda=(2, 128, 2, 16),
-            mlp_rows=2048)
+            mlp_rows=2048, gdn=(2, 128, 2, 4, 16), flash_gqa=(1, 128, 4, 2, 16))
 
 
 class SmokeFailure(AssertionError):
@@ -361,24 +366,42 @@ def _fwd_and_grads(fn, cotangent):
 
 
 def check_flash(shape) -> dict:
+    """The flash kernels at ``(B, L, H, D)``, or with K/V heads fewer than
+    query heads at ``(B, L, H, G, D)`` (Qwen3-Next's gated attention: 16
+    over 2 heads of 256 at L = 8,192, where the kernels ask for more scoped
+    VMEM than Mosaic's default), against dense attention in float32, forward
+    and dq / dk / dv; the reference in blocks of 512 queries, each recomputed
+    in its backward, so that a block's scores are what is alive."""
     import jax
     import jax.numpy as jnp
 
     from distkeras_tpu.ops.pallas import flash_attention
 
-    B, L, H, D = shape
+    B, L, H, *G, D = shape
+    G = G[0] if G else H
     ks = jax.random.split(jax.random.key(0), 4)
-    q, k, v, w = (jax.random.normal(kk, shape, jnp.bfloat16) for kk in ks)
+    q, w = (jax.random.normal(kk, (B, L, H, D), jnp.bfloat16)
+            for kk in (ks[0], ks[3]))
+    k, v = (jax.random.normal(kk, (B, L, G, D), jnp.bfloat16)
+            for kk in ks[1:3])
     q = q * (D ** -0.5)
+    step = min(512, L)
 
-    def dense_one(q, k, v):  # one batch row, float32, exact matmuls
-        q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
-        s = jnp.einsum("qhd,khd->hqk", q, k, precision="highest")
-        s = jnp.where(jnp.tril(jnp.ones((L, L), bool))[None], s, -1e30)
+    @jax.checkpoint
+    def block(qb, k, v, q0):
+        s = jnp.einsum("qhd,khd->hqk", qb, k, precision="highest")
+        keep = jnp.arange(L)[None, :] <= q0 + jnp.arange(qb.shape[0])[:, None]
+        s = jnp.where(keep[None], s, -1e30)
         return jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, axis=-1), v,
                           precision="highest")
 
-    def reference(q, k, v, w):  # row by row: the O(L^2) scores stay small
+    def dense_one(q, k, v):  # one batch row, float32, exact matmuls
+        q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+        k, v = (jnp.repeat(a, H // G, axis=1) for a in (k, v))
+        return jnp.concatenate([block(q[q0:q0 + step], k, v, q0)
+                                for q0 in range(0, L, step)])
+
+    def reference(q, k, v, w):  # row by row
         def one(args):
             qi, ki, vi, wi = args
             out, vjp = jax.vjp(dense_one, qi, ki, vi)
@@ -506,12 +529,14 @@ def check_rows(shape) -> dict:
     return dict(_compare("rows", got, ref, tol=1e-6), live=live)
 
 
-def check_kda_scan(shape) -> dict:
+def check_kda_scan(shape, scalar_decay: bool = False) -> dict:
     """The delta rule's scan over chunks (``ops/pallas/delta_rule.py``: the
     kernels ``dk_kda_scan_fwd`` and ``dk_kda_scan_bwd``) against the same
     three lines as a ``lax.scan`` in ``jax.numpy`` with JAX's derivative,
     ``O`` and all six cotangents, on seeded chunks whose state stays bounded
-    (a chunk's decay in (0.1, 0.5), ``|Kd^T W|`` about 0.3)."""
+    (a chunk's decay in (0.1, 0.5), ``|Kd^T W|`` about 0.3). With
+    ``scalar_decay`` a chunk's decay is one a head, the same over its
+    channels, as Gated DeltaNet hands it on."""
     import jax
     import jax.numpy as jnp
 
@@ -528,7 +553,9 @@ def check_kda_scan(shape) -> dict:
              for k in ks[1:3])
     Qg = jax.random.normal(ks[3], lead + (C, D), dt) * D ** -0.5
     Bq = jnp.tril(jax.random.normal(ks[4], lead + (C, C), dt)) * C ** -0.5
-    shrink = jax.random.uniform(ks[5], lead + (D,), f32, 0.1, 0.5)
+    shrink = jax.random.uniform(ks[5], lead + (1 if scalar_decay else D,),
+                                f32, 0.1, 0.5)
+    shrink = jnp.broadcast_to(shrink, lead + (D,))
     cotangent = jax.random.normal(ks[6], lead + (C, D), dt)
 
     def dot(spec, a, b):
@@ -549,17 +576,21 @@ def check_kda_scan(shape) -> dict:
         return jnp.moveaxis(out, 0, 2)
 
     args = (U, W, Qg, Bq, Kd, shrink)
-    return _compare("kda_scan", _fwd_and_grads(scan_chunks, cotangent)(*args),
+    return _compare("gdn_scan" if scalar_decay else "kda_scan",
+                    _fwd_and_grads(scan_chunks, cotangent)(*args),
                     _fwd_and_grads(reference, cotangent)(*args), tol=2e-2)
 
 
-def check_kda_chunk(shape) -> dict:
+def check_kda_chunk(shape, key_heads=None) -> dict:
     """The delta rule's in-chunk half (``ops/pallas/delta_rule.py``: the
     kernels ``dk_kda_chunk_fwd`` and ``dk_kda_chunk_bwd``) against the kept
     ``jax.numpy`` form (``ops/delta_rule.py::in_chunk_by_jax_numpy``) with
     JAX's derivative, the six outputs and all five cotangents, on unit keys,
     scaled unit queries, a decay from ``e^-6`` to ``e^0.5`` a step and
-    channel, ``beta`` in (0, 1)."""
+    channel, ``beta`` in (0, 1). With ``key_heads``, as Gated DeltaNet hands
+    them on (``models/blocks.py::grouped_gated_delta_rule``): ``q, k`` drawn
+    at the key heads and repeated to the value heads, one decay a value head
+    broadcast over the channels."""
     import jax
     import jax.numpy as jnp
 
@@ -571,22 +602,33 @@ def check_kda_chunk(shape) -> dict:
     C, dt = chunk_for(L), jnp.bfloat16
     ks = jax.random.split(jax.random.key(0), 11)
 
-    def unit(key):
-        x = jax.random.normal(key, shape)
-        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    heads = key_heads or H
 
+    def unit(key):
+        x = jax.random.normal(key, (B, L, heads, D))
+        x = x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+        return jnp.repeat(x, H // heads, axis=2)
+
+    decay = (B, L, H, 1) if key_heads else shape
     args = ((unit(ks[0]) * D ** -0.5).astype(dt), unit(ks[1]).astype(dt),
             jax.random.normal(ks[2], shape, dt),
-            -jnp.exp(jax.random.uniform(ks[3], shape, minval=-6, maxval=0.5)),
+            jnp.broadcast_to(-jnp.exp(jax.random.uniform(
+                ks[3], decay, minval=-6, maxval=0.5)), shape),
             jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3])))
 
     def form(*a):
-        return in_chunk_by_jax_numpy(*a, C)[0]
+        if H <= 8:
+            return in_chunk_by_jax_numpy(*a, C)[0]
+        # eight heads at a time, each recomputed in the backward: the form's
+        # pairwise temporaries at 32 heads would not leave the chip room
+        parts = [jax.checkpoint(lambda *x: in_chunk_by_jax_numpy(*x, C)[0])(
+            *(x[:, :, h:h + 8] for x in a)) for h in range(0, H, 8)]
+        return tuple(jnp.concatenate(p, axis=1) for p in zip(*parts))
 
     cotangents = tuple(jax.random.normal(k, o.shape) for k, o in zip(
         ks[5:], jax.eval_shape(form, *args)))
     return _compare(
-        "kda_chunk",
+        "gdn_chunk" if key_heads else "kda_chunk",
         _fwd_and_grads(lambda *a: chunk_products(*a, C, min(SUB, C))[:6],
                        cotangents)(*args),
         _fwd_and_grads(form, cotangents)(*args), tol=2e-2)
@@ -596,7 +638,14 @@ def phase_kernels(sz: Sizes) -> dict:
     out = {}
     flash = [(f"flash_attention_L{shape[1]}", check_flash, shape)
              for shape in sz.flash]
+    B, L, key_heads, H, D = sz.gdn
+    gdn = [("gdn_chunk", functools.partial(check_kda_chunk,
+                                           key_heads=key_heads), (B, L, H, D)),
+           ("gdn_scan", functools.partial(check_kda_scan, scalar_decay=True),
+            (B, L, H, D))]
     for name, check, shape in (*flash,
+                               ("flash_grouped", check_flash, sz.flash_gqa),
+                               *gdn,
                                ("lstm_seq", check_lstm, sz.lstm),
                                ("group_norm", check_groupnorm, sz.groupnorm),
                                ("fold", check_fold, sz.fold),

@@ -1,13 +1,17 @@
 """Blocks of today's open decoders, for the modules that are built from them
-(``models/smallthinker.py``, ``models/lfm2.py``, ``models/kimi_linear.py``):
-RMSNorm, rotary positions by the half-split rule, grouped-query attention
-without biases under a full or a sliding-window causal mask (with RMSNorm on
-each query and key head where the family has it), latent attention (a
-compressed K/V projection, keys wider than values, no positions), a gated
-short convolution and Kimi Delta Attention (a gated delta rule with a decay a
-channel, as a chunked scan) in attention's place, a gated dense feed-forward,
-two routers (softmax, and sigmoid with a selection bias), and a dropless
-mixture of gated experts that holds a share of the experts it routes over.
+(``models/smallthinker.py``, ``models/lfm2.py``, ``models/kimi_linear.py``,
+``models/qwen3_next.py``): RMSNorm, rotary positions by the half-split rule
+(over a head or its first columns), grouped-query attention without biases
+under a full or a sliding-window causal mask (with RMSNorm on each query and
+key head where the family has it, and a sigmoid gate on each head's output
+where Qwen3-Next has it), latent attention (a compressed K/V projection, keys
+wider than values, no positions), a gated short convolution, Kimi Delta
+Attention (a gated delta rule with a decay a channel, as a chunked scan) and
+Gated DeltaNet (the same rule with a decay a head and key heads shared by
+value heads, through the same chunked scan) in attention's place, a gated
+dense feed-forward, two routers (softmax, and sigmoid with a selection
+bias), and a dropless mixture of gated experts that holds a share of the
+experts it routes over.
 The two attention modules and the delta rule are told how many *heads* they
 hold, as the expert layer is told its experts: a chip that shares a layer's
 heads computes its own heads' part of ``W_o``'s sum. ``TransformerLM`` and
@@ -99,10 +103,15 @@ class RMSNorm(nn.Module):
             return (y * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x, theta: float):
-    """Rotary positions over the whole head width by the half-split rule:
-    ``x``: [B, L, H, D]; pair ``i`` is ``(x[i], x[i + D/2])``, turned by
-    ``pos * theta^(-2i/D)``. Angles and products in float32."""
+def rope(x, theta: float, rotary_dim: int | None = None):
+    """Rotary positions by the half-split rule over the first ``rotary_dim``
+    columns of a head (the whole head where ``None``): ``x``: [B, L, H, D];
+    with ``R = rotary_dim``, pair ``i`` is ``(x[i], x[i + R/2])``, turned by
+    ``pos * theta^(-2i/R)``; columns from ``R`` on pass as they are. Angles
+    and products in float32."""
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        return jnp.concatenate([rope(x[..., :rotary_dim], theta),
+                                x[..., rotary_dim:]], axis=-1)
     L, D = x.shape[1], x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
     angle = jnp.arange(L, dtype=jnp.float32)[:, None] * inv[None, :]
@@ -116,10 +125,15 @@ class GroupedQueryAttention(nn.Module):
     """Causal attention of ``num_heads`` query heads over ``num_kv_heads``
     K/V heads (query head ``n`` reads K/V head ``n // group``), no biases.
     ``window``: query ``i`` sees keys ``j`` with ``0 <= i - j < window``.
-    ``rope_theta``: rotate q and k, or leave positions out (``None``).
-    ``qk_norm``: the epsilon of an RMSNorm over each head of q and of k (one
-    weight vector of ``head_dim`` each), before the rotation; ``None``: no
-    such norm and no such parameters."""
+    ``rope_theta``: rotate q and k, or leave positions out (``None``);
+    ``rotary_dim``: rotate only a head's first that many columns (``None``:
+    all of them). ``qk_norm``: the epsilon of an RMSNorm over each head of q
+    and of k (one weight vector of ``head_dim`` each), before the rotation;
+    ``None``: no such norm and no such parameters. ``output_gate``: ``W_q``
+    gives each head its query and, beside it, a gate of ``head_dim``
+    columns, and a head's output is multiplied by ``sigmoid(gate)`` before
+    ``W_o`` (Qwen3-Next's gated attention). With the last three at their
+    defaults the module is what it was."""
 
     num_heads: int
     num_kv_heads: int
@@ -128,6 +142,8 @@ class GroupedQueryAttention(nn.Module):
     rope_theta: float | None = None
     attn_impl: str = "dense"  # 'dense' | 'flash'
     qk_norm: float | None = None
+    rotary_dim: int | None = None
+    output_gate: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -139,12 +155,19 @@ class GroupedQueryAttention(nn.Module):
                 return nn.DenseGeneral((heads, Dh), use_bias=False,
                                        name=name)(x)
 
-            q, k, v = proj(H, "query"), proj(G, "key"), proj(G, "value")
+            if self.output_gate:
+                q = nn.DenseGeneral((H, 2 * Dh), use_bias=False,
+                                    name="query")(x)
+                q, gate = q[..., :Dh], q[..., Dh:]
+            else:
+                q = proj(H, "query")
+            k, v = proj(G, "key"), proj(G, "value")
             if self.qk_norm is not None:
                 q = RMSNorm(self.qk_norm, name="query_norm")(q)
                 k = RMSNorm(self.qk_norm, name="key_norm")(k)
             if self.rope_theta is not None:
-                q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
+                q, k = (rope(a, self.rope_theta, self.rotary_dim)
+                        for a in (q, k))
             q = q / jnp.sqrt(Dh).astype(q.dtype)
             if self.attn_impl == "flash" and not self.is_initializing():
                 # Init only declares parameters: it takes the dense path on
@@ -171,6 +194,9 @@ class GroupedQueryAttention(nn.Module):
                 probs = jax.nn.softmax(scores, axis=-1)
                 out = jnp.einsum("bgnqk,bkgd->bqgnd", probs, v).reshape(
                     B, L, H, Dh)
+            if self.output_gate:
+                out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))).astype(out.dtype)
             return nn.DenseGeneral(D, axis=(-2, -1), use_bias=False,
                                    name="out")(out)
 
@@ -335,17 +361,126 @@ class KimiDeltaAttention(nn.Module):
                 y = (o32 * scale.astype(jnp.float32) * jax.nn.sigmoid(
                     gate_in.astype(jnp.float32).reshape(B, L, H, Dh)))
                 y = y.astype(h.dtype).reshape(B, L, H * Dh)
-            if self.is_mutable_collection(ROUND_COUNTERS):
-                for name, value, join in (
-                        ("min_chunk_decay", least, jnp.minimum),
-                        ("beta_sum", jnp.sum(beta), jnp.add),
-                        ("beta_count", jnp.float32(beta.size), jnp.add),
-                        ("steps", jnp.float32(1), jnp.add)):
-                    var = self.variable(ROUND_COUNTERS, name,
-                                        lambda: jnp.zeros((), jnp.float32))
-                    if not self.is_initializing():  # init declares them
-                        var.value = join(var.value, value.astype(jnp.float32))
+            _count_delta_rule(self, least, beta)
             return dense(D, "o_proj", y)
+
+
+class GatedDeltaNet(nn.Module):
+    """Gated DeltaNet in attention's place (the ``linear_attention`` layers
+    of Qwen3-Next), ``num_value_heads`` heads of ``head_dim`` values served
+    by ``num_key_heads`` heads of ``head_dim`` keys (value head ``j`` reads
+    key head ``j // (num_value_heads / num_key_heads)``), no bias in any
+    projection:
+
+    * ``[q~ | k~ | v~ | z] = h W_qkvz`` (key, key, value, value widths);
+      ``[b | a] = h W_ba``, one of each a value head;
+    * ``q, k, v = SiLU(conv(q~ | k~ | v~))``, one causal depthwise
+      convolution of ``conv_kernel`` taps over all three;
+    * ``q = q / |q| * head_dim^-1/2``, ``k = k / |k|`` over a head;
+    * **one decay a head**: ``g = -exp(A_log) * softplus(a + dt_bias)`` in
+      float32, ``alpha = exp(g)``; ``beta = sigmoid(b)``;
+    * the state ``S`` ``[head_dim, head_dim]`` a value head: ``S_t = alpha_t
+      S_{t-1} + beta_t k_t (v_t - alpha_t S_{t-1}^T k_t)^T``, ``o_t = S_t^T
+      q_t``: the recurrence of ``ops/delta_rule.py`` with the decay a channel
+      held at the head's one value (``g`` broadcast over the key channels)
+      and ``q, k`` repeated to the value heads, **through the same two kernel
+      pairs** Kimi Delta Attention runs (:func:`grouped_gated_delta_rule`;
+      both are exact);
+    * ``y = (RMSNorm_head(o; w) * SiLU(z)) W_o``.
+
+    Scopes: ``dk_gdn_conv`` (the tap sums and their SiLU), ``dk_gdn`` (norms,
+    decay, the repeat, the kernels of the chunked form, the gated norm). A
+    round's smallest summed log-decay of a chunk and its mean ``beta`` leave
+    the program in ``ROUND_COUNTERS``, as ``KimiDeltaAttention``'s do."""
+
+    num_key_heads: int
+    num_value_heads: int
+    head_dim: int = 128
+    conv_kernel: int = 4
+    rms_eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, h):
+        B, L, D = h.shape
+        Hk, Hv, Dh = self.num_key_heads, self.num_value_heads, self.head_dim
+        if Hv % Hk:
+            raise ValueError(f"{Hk} key heads cannot serve {Hv} value heads")
+        keys, values = Hk * Dh, Hv * Dh
+
+        with owner("mixer"):
+            def dense(width, name, x=h):
+                return nn.Dense(width, use_bias=False, name=name)(x)
+
+            qkvz = dense(2 * keys + 2 * values, "in_proj_qkvz")
+            ba = dense(2 * Hv, "in_proj_ba")
+            taps = self.param(
+                "conv_taps", nn.initializers.variance_scaling(
+                    1.0, "fan_in", "truncated_normal", in_axis=-1,
+                    out_axis=-2), (2 * keys + values, self.conv_kernel))
+            a_log = self.param(
+                "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                    key, shape, minval=1e-6, maxval=16.0)), (Hv,))
+            dt_bias = self.param("dt_bias", nn.initializers.ones, (Hv,))
+            scale = self.param("o_norm", nn.initializers.ones, (Dh,))
+            with jax.named_scope("dk_gdn_conv"):
+                qkv = nn.silu(_causal_taps(qkvz[..., :2 * keys + values],
+                                           taps.astype(h.dtype)))
+            with jax.named_scope("dk_gdn"):
+                def unit(x, by):
+                    """A key head's columns over their norm, times ``by``."""
+                    x32 = x.reshape(B, L, Hk, Dh).astype(jnp.float32)
+                    return (x32 * (by * jax.lax.rsqrt(jnp.sum(
+                        jnp.square(x32), -1, keepdims=True) + 1e-6))
+                            ).astype(h.dtype)
+
+                ba32 = ba.astype(jnp.float32)
+                beta = jax.nn.sigmoid(ba32[..., :Hv])
+                g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
+                    ba32[..., Hv:] + dt_bias)
+                o, least = grouped_gated_delta_rule(
+                    unit(qkv[..., :keys], Dh ** -0.5),
+                    unit(qkv[..., keys:2 * keys], 1.0),
+                    qkv[..., 2 * keys:].reshape(B, L, Hv, Dh), g, beta)
+                o32 = o.astype(jnp.float32)
+                o32 = o32 * jax.lax.rsqrt(jnp.mean(
+                    jnp.square(o32), -1, keepdims=True) + self.rms_eps)
+                y = o32 * scale.astype(jnp.float32) * nn.silu(
+                    qkvz[..., 2 * keys + values:].astype(jnp.float32)
+                    .reshape(B, L, Hv, Dh))
+                y = y.astype(h.dtype).reshape(B, L, values)
+            _count_delta_rule(self, least, beta)
+            return dense(D, "out_proj", y)
+
+
+def grouped_gated_delta_rule(q, k, v, g, beta):
+    """The delta rule with **one decay a value head** and **key heads read
+    by group**, through :func:`~distkeras_tpu.ops.delta_rule.
+    chunked_gated_delta_rule` and so through the kernels Kimi Delta Attention
+    runs: ``q, k`` [B, L, Hk, d_k] are repeated to the ``Hv`` value heads
+    (value head ``j`` reads key head ``j // (Hv / Hk)``) and ``g`` [B, L, Hv]
+    is broadcast over the key channels, which the per-channel recurrence
+    then computes exactly. ``v``: [B, L, Hv, d_v]; ``beta``: [B, L, Hv].
+    Returns what that function returns."""
+    group = v.shape[2] // k.shape[2]
+    q, k = (jnp.repeat(a, group, axis=2) for a in (q, k))
+    return chunked_gated_delta_rule(
+        q, k, v, jnp.broadcast_to(g[..., None], k.shape), beta)
+
+
+def _count_delta_rule(module, least, beta) -> None:
+    """Add a step's smallest summed log-decay of a chunk and its ``beta`` to
+    ``module``'s round counters (where the collection is mutable)."""
+    if not module.is_mutable_collection(ROUND_COUNTERS):
+        return
+    for name, value, join in (
+            ("min_chunk_decay", least, jnp.minimum),
+            ("beta_sum", jnp.sum(beta), jnp.add),
+            ("beta_count", jnp.float32(beta.size), jnp.add),
+            ("steps", jnp.float32(1), jnp.add)):
+        var = module.variable(ROUND_COUNTERS, name,
+                              lambda: jnp.zeros((), jnp.float32))
+        if not module.is_initializing():  # init declares them
+            var.value = join(var.value, value.astype(jnp.float32))
 
 
 def _inverse_softplus_uniform(low: float, high: float):

@@ -20,7 +20,11 @@ bounds, the tests and the gauge ``pallas.flash.visited_share`` all read):
   loop at all, every step a static rectangle up to ``cut`` x L that the
   scheduler can interleave with the next — and beyond that the largest tile
   up to 1024 that divides L and fits Mosaic's 16 MiB of scoped VMEM beside
-  the resident K and V. ``cut`` is 256, and 512 from L = 2048.
+  the resident K and V. ``cut`` is 256, and 512 from L = 2048. Where K and V
+  whole leave no room for two blocks in 16 MiB (heads of 256 at L = 8,192,
+  PR 40), the kernels ask for 48 MiB (:func:`scoped_vmem`) and the tile is
+  sized against that: 1024, cut 512 (``dk_flash_dkv`` takes 34.4 MiB there);
+  every narrower shape asks for the default and tiles as before.
 * an explicit ``block_k`` gives rectangular ``block_size`` x ``block_k``
   tiles whose straddling tiles are visited and masked whole (the general
   path; what ran everywhere until PR 27, with a k-chunk of up to 8 q-blocks,
@@ -134,6 +138,10 @@ FLASH_RESIDUALS = ("dk_flash_out", "dk_flash_lse")
 
 _NEG = -1e30
 _VMEM_BYTES = 16 * 2 ** 20  # Mosaic's scoped limit for one kernel on a v5e
+#: what the kernels ask for where K and V whole leave no room for a tile of
+#: two blocks in that limit (heads of 256 at L = 8,192: K and V take all 16
+#: MiB); a v5e core has 128 MiB of VMEM
+_VMEM_WIDE_BYTES = 48 * 2 ** 20
 
 
 def _qblock_spec(block, D, index=lambda bh, i: (bh, i, 0)):
@@ -156,15 +164,18 @@ def _fullrow_spec(nq, block, index=lambda bh, i: (bh, 0, 0, 0)):
     return pl.BlockSpec((1, nq, 1, block), index, memory_space=pltpu.VMEM)
 
 
-def _parallel_kw(interpret: bool, arbitrary: int = 0) -> dict:
+def _parallel_kw(interpret: bool, arbitrary: int = 0, vmem=None) -> dict:
     """Two grid dims order-independent -> Mosaic overlaps fetch/compute
     across programs. Only valid because no output block is revisited, but
     along the ``arbitrary`` trailing dims (dk/dv's walk over a K/V head's
-    group of query heads, which revisits one output block in order)."""
+    group of query heads, which revisits one output block in order).
+    ``vmem``: the scoped VMEM the kernel asks for (``None``: Mosaic's
+    default, :func:`scoped_vmem`)."""
     if interpret:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=("parallel",) * 2 + ("arbitrary",) * arbitrary)}
+        dimension_semantics=("parallel",) * 2 + ("arbitrary",) * arbitrary,
+        vmem_limit_bytes=vmem)}
 
 
 def causal_span(i, own: int, other: int):
@@ -550,7 +561,8 @@ def _traced_once(fn):
     trace, and ``inline`` replays it into the caller's jaxpr under the
     caller's name stack, so the program and its ``op_name``s stay the same."""
     return jax.jit(fn, static_argnames=("block_q", "block_k", "cut",
-                                        "interpret", "window"), inline=True)
+                                        "interpret", "window", "vmem"),
+                   inline=True)
 
 
 def _kv_specs(L, D, Dv, group: int):
@@ -565,7 +577,8 @@ def _kv_specs(L, D, Dv, group: int):
 
 
 @_traced_once
-def _flash_bhld(q, k, v, block_q, block_k, cut, interpret, window=None):
+def _flash_bhld(q, k, v, block_q, block_k, cut, interpret, window=None,
+                vmem=None):
     """Forward on [BH, L, D] queries (k: [BH / group, L, D], v: [BH / group,
     L, Dv]); returns (out [BH, L, Dv], lse [BH, nq, 1, block_q])."""
     BH, L, D = q.shape
@@ -587,7 +600,7 @@ def _flash_bhld(q, k, v, block_q, block_k, cut, interpret, window=None):
         ],
         interpret=interpret,
         name="dk_flash_fwd",
-        **_parallel_kw(interpret),
+        **_parallel_kw(interpret, vmem=vmem),
     )
     # The kernel's name (the Mosaic call's, on a chip) and the scope of the
     # same name (the interpreted ops', on a CPU) are what a trace finds it by.
@@ -596,14 +609,16 @@ def _flash_bhld(q, k, v, block_q, block_k, cut, interpret, window=None):
     return out, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, block_q, block_k, cut, interpret, window):
-    out, _ = _flash_bhld(q, k, v, block_q, block_k, cut, interpret, window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, block_q, block_k, cut, interpret, window, vmem):
+    out, _ = _flash_bhld(q, k, v, block_q, block_k, cut, interpret, window,
+                         vmem)
     return out
 
 
-def _flash_fwd(q, k, v, block_q, block_k, cut, interpret, window):
-    out, lse = _flash_bhld(q, k, v, block_q, block_k, cut, interpret, window)
+def _flash_fwd(q, k, v, block_q, block_k, cut, interpret, window, vmem):
+    out, lse = _flash_bhld(q, k, v, block_q, block_k, cut, interpret, window,
+                           vmem)
     out, lse = map(checkpoint_name, (out, lse), FLASH_RESIDUALS)
     return out, (q, k, v, out, lse)
 
@@ -616,13 +631,14 @@ def residual_bytes(batch: int, seq_len: int, heads: int, head_dim: int,
     return batch * heads * seq_len * (head_dim * jnp.dtype(dtype).itemsize + 4)
 
 
-def _flash_bwd(block_q, block_k, cut, interpret, window, res, do):
-    return _flash_grads(*res, do, block_q, block_k, cut, interpret, window)
+def _flash_bwd(block_q, block_k, cut, interpret, window, vmem, res, do):
+    return _flash_grads(*res, do, block_q, block_k, cut, interpret, window,
+                        vmem)
 
 
 @_traced_once
 def _flash_grads(q, k, v, out, lse, do, block_q, block_k, cut, interpret,
-                 window=None):
+                 window=None, vmem=None):
     BH, L, D = q.shape
     Dv = v.shape[-1]
     nq = L // block_q
@@ -641,7 +657,7 @@ def _flash_grads(q, k, v, out, lse, do, block_q, block_k, cut, interpret,
         out_shape=jax.ShapeDtypeStruct((BH, L, D), q.dtype),
         interpret=interpret,
         name="dk_flash_dq",
-        **_parallel_kw(interpret),
+        **_parallel_kw(interpret, vmem=vmem),
     )
     with jax.named_scope("dk_flash_dq"):
         dq = dq_call(q, k, v, do, lse, delta)
@@ -661,7 +677,7 @@ def _flash_grads(q, k, v, out, lse, do, block_q, block_k, cut, interpret,
             out_shape=out_shape,
             interpret=interpret,
             name="dk_flash_dkv",
-            **_parallel_kw(interpret),
+            **_parallel_kw(interpret, vmem=vmem),
         )
     else:
         # One program a (K/V head, block of keys, query head of the group);
@@ -691,7 +707,7 @@ def _flash_grads(q, k, v, out, lse, do, block_q, block_k, cut, interpret,
                             for width in (D, Dv)],
             interpret=interpret,
             name="dk_flash_dkv",
-            **_parallel_kw(interpret, arbitrary=1),
+            **_parallel_kw(interpret, arbitrary=1, vmem=vmem),
         )
     with jax.named_scope("dk_flash_dkv"):
         dk, dv = dkv_call(q, k, v, do, lse, delta)
@@ -706,6 +722,29 @@ def _lanes(width: int) -> int:
     return -(-width // 128) * 128
 
 
+def _resident(L: int, D: int, Dv: int) -> tuple:
+    """``(bytes, bytes a row)`` that K and V whole, double-buffered, take in
+    VMEM beside a tile, and what a program's own rows add where keys are
+    wider than values (:func:`default_tiling` says why)."""
+    lanes = _lanes(D) + _lanes(Dv)
+    if Dv == D:
+        return 2 * L * lanes * 2, 0
+    return 2 * L * lanes * 2 + 2 * 2 * L * 8 * 4, 8 * lanes
+
+
+def scoped_vmem(L: int, D: int, block_size: int = 128,
+                Dv: int | None = None):
+    """The scoped VMEM the kernels ask for at ``(L, D, Dv)``: ``None``,
+    Mosaic's default 16 MiB, wherever K and V whole leave room in it for a
+    tile of two blocks (every shape the benchmark ran before heads of 256),
+    else ``_VMEM_WIDE_BYTES``."""
+    resident, own = _resident(L, D, D if Dv is None else Dv)
+    two = 2 * block_size
+    if resident + (10 * two + own) * two <= _VMEM_BYTES:
+        return None
+    return _VMEM_WIDE_BYTES
+
+
 def default_tiling(L: int, D: int, block_size: int = 128, window=None,
                    Dv: int | None = None):
     """``(block_q, block_k, cut)`` for sequences of ``L`` and heads of ``D``
@@ -718,7 +757,9 @@ def default_tiling(L: int, D: int, block_size: int = 128, window=None,
     L between 2 and 4 blocks, and at most half the tile. Under a ``window``
     shorter than ``L`` the tile also divides the window, whatever ``L``: a
     tile as long as the sequence would hold the whole window and skip none of
-    it."""
+    it. Where K and V whole leave no room for two blocks in the default
+    limit (:func:`scoped_vmem`), the tile is sized against the limit the
+    kernels then ask for."""
     units = L // block_size
     reach = units if window is None else window // block_size
     if units <= 16 and window is None:
@@ -731,15 +772,13 @@ def default_tiling(L: int, D: int, block_size: int = 128, window=None,
         # dk/dv's lse and delta (a row of floats padded to 8 sublanes), all
         # double-buffered. Tiles of 256, not 512, at L = 8,192.
         Dv = D if Dv is None else Dv
-        lanes = _lanes(D) + _lanes(Dv)
-        resident, own = 2 * L * lanes * 2, 0
-        if Dv != D:
-            resident, own = resident + 2 * 2 * L * 8 * 4, 8 * lanes
+        resident, own = _resident(L, D, Dv)
+        limit = scoped_vmem(L, D, block_size, Dv) or _VMEM_BYTES
         tile = max(m for m in range(1, 9)
                    if units % m == 0 and reach % m == 0 and (
                        m == 1
                        or resident + (10 * m * block_size + own)
-                       * m * block_size <= _VMEM_BYTES))
+                       * m * block_size <= limit))
     want = min(4, max(2, units // 4))
     cut = max(m for m in range(1, want + 1)
               if tile % m == 0 and (2 * m <= tile or m == 1))
@@ -789,7 +828,9 @@ def flash_attention(q, k, v, block_size: int = 128, block_k: int | None = None,
             raise ValueError(f"window {window} is not a multiple of the "
                              f"grain {block_size}")
         block_q, block_k, cut = default_tiling(L, D, block_size, window, Dv)
+        vmem = scoped_vmem(L, D, block_size, Dv)
     else:
+        vmem = None
         # A square tiling masks its diagonal tile by a constant (cut = tile).
         block_q = block_size
         cut = block_size if block_k == block_size else None
@@ -806,5 +847,5 @@ def flash_attention(q, k, v, block_size: int = 128, block_k: int | None = None,
         return x.transpose(0, 2, 1, 3).reshape(-1, L, x.shape[-1])
 
     out = _flash(to_bhld(q), to_bhld(k), to_bhld(v), block_q, block_k, cut,
-                 interpret, window)
+                 interpret, window, vmem)
     return out.reshape(B, H, L, Dv).transpose(0, 2, 1, 3)

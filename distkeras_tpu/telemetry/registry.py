@@ -182,6 +182,25 @@ _METRICS = [
     _m("kda.mean_beta", "gauge", "models",
        "Last round's mean `beta` (the delta rule's write strength, in (0, "
        "1)) over the `kda` layers' tokens and heads."),
+    _m("gdn.chunk", "gauge", "models",
+       "Positions a chunk of Gated DeltaNet's chunked form (Qwen3-Next's "
+       "`gdn` layers, through the kernels `kda` runs), set as the model is "
+       "traced (`ops/delta_rule.py::chunk_for`)."),
+    _m("gdn.state_bytes", "gauge", "models",
+       "Bytes of the recurrent state the `gdn` layers carry a step: layers x "
+       "batch x value heads x head_dim^2 float32, set as the model is "
+       "traced."),
+    _m("gdn.min_chunk_decay", "gauge", "models",
+       "Last round's smallest summed log-decay of a chunk over the `gdn` "
+       "layers (one decay a value head, broadcast over the key channels; <= "
+       "0; the chunked form never takes `e^-G`)."),
+    _m("gdn.mean_beta", "gauge", "models",
+       "Last round's mean `beta` (the delta rule's write strength, in (0, "
+       "1)) over the `gdn` layers' tokens and value heads."),
+    _m("moe.shared_gate_mean", "gauge", "models",
+       "Last round's mean of the shared expert's gate `sigmoid(g W_sg)` over "
+       "the layers' tokens (Qwen3-Next; models whose shared expert has no "
+       "gate never set it)."),
     _m("pallas.kda.heads_per_program", "gauge", "kernels",
        "Heads one program of the delta rule's scan kernels holds, set as a "
        "call is traced (`ops/pallas/delta_rule.py::heads_per_program`: the "
