@@ -86,7 +86,7 @@ FULL = Sizes(layers=8, d_model=1024, heads=16, d_ff=4096, vocab=32768,
              rows=(16384, 6, 2560, 8),  # the smallthinker cell's step
              kda=(1, 8192, 8, 128),     # the kimi_linear cell's step
              mlp_rows=8192,
-             gdn=(1, 8192, 16, 32, 128),       # a qwen3next cell's sequence
+             gdn=(2, 8192, 16, 32, 128),       # the qwen3next cell's step
              flash_gqa=(1, 8192, 16, 2, 256))  # its gated attention's heads
 TOY = Sizes(layers=1, d_model=64, heads=2, d_ff=128, vocab=256,
             seq=128, lm_batch=2, lm_window=2, lm_rounds=3, tp_layers=1,
@@ -587,36 +587,43 @@ def check_kda_chunk(shape, key_heads=None) -> dict:
     ``jax.numpy`` form (``ops/delta_rule.py::in_chunk_by_jax_numpy``) with
     JAX's derivative, the six outputs and all five cotangents, on unit keys,
     scaled unit queries, a decay from ``e^-6`` to ``e^0.5`` a step and
-    channel, ``beta`` in (0, 1). With ``key_heads``, as Gated DeltaNet hands
-    them on (``models/blocks.py::grouped_gated_delta_rule``): ``q, k`` drawn
-    at the key heads and repeated to the value heads, one decay a value head
-    broadcast over the channels."""
+    channel, ``beta`` in (0, 1). With ``key_heads``, Gated DeltaNet's pair
+    ``dk_gdn_chunk_fwd`` / ``dk_gdn_chunk_bwd`` as the model hands it its
+    inputs: ``q, k`` at the key heads and one decay a value head ``[B, L,
+    H]``; the form takes them repeated to the value heads and broadcast over
+    the channels, and ``q, k``'s cotangents are its summed over each group."""
     import jax
     import jax.numpy as jnp
 
     from distkeras_tpu.ops.delta_rule import (SUB, chunk_for,
                                               in_chunk_by_jax_numpy)
-    from distkeras_tpu.ops.pallas.delta_rule import chunk_products
+    from distkeras_tpu.ops.pallas.delta_rule import (chunk_products,
+                                                     gdn_chunk_products)
 
     B, L, H, D = shape
     C, dt = chunk_for(L), jnp.bfloat16
     ks = jax.random.split(jax.random.key(0), 11)
-
     heads = key_heads or H
+    group = H // heads
 
     def unit(key):
         x = jax.random.normal(key, (B, L, heads, D))
-        x = x / jnp.linalg.norm(x, axis=-1, keepdims=True)
-        return jnp.repeat(x, H // heads, axis=2)
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
 
-    decay = (B, L, H, 1) if key_heads else shape
     args = ((unit(ks[0]) * D ** -0.5).astype(dt), unit(ks[1]).astype(dt),
             jax.random.normal(ks[2], shape, dt),
-            jnp.broadcast_to(-jnp.exp(jax.random.uniform(
-                ks[3], decay, minval=-6, maxval=0.5)), shape),
+            -jnp.exp(jax.random.uniform(ks[3], shape[:3] if key_heads
+                                        else shape, minval=-6, maxval=0.5)),
             jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3])))
 
+    def widened(q, k, v, g, beta):  # to the form's per-channel shapes
+        if not key_heads:
+            return q, k, v, g, beta
+        return (jnp.repeat(q, group, axis=2), jnp.repeat(k, group, axis=2), v,
+                jnp.broadcast_to(g[..., None], shape), beta)
+
     def form(*a):
+        a = widened(*a)
         if H <= 8:
             return in_chunk_by_jax_numpy(*a, C)[0]
         # eight heads at a time, each recomputed in the backward: the form's
@@ -625,13 +632,16 @@ def check_kda_chunk(shape, key_heads=None) -> dict:
             *(x[:, :, h:h + 8] for x in a)) for h in range(0, H, 8)]
         return tuple(jnp.concatenate(p, axis=1) for p in zip(*parts))
 
+    def pair(*a):
+        if key_heads:
+            return gdn_chunk_products(*a, C)[:6]
+        return chunk_products(*a, C, min(SUB, C))[:6]
+
     cotangents = tuple(jax.random.normal(k, o.shape) for k, o in zip(
         ks[5:], jax.eval_shape(form, *args)))
-    return _compare(
-        "gdn_chunk" if key_heads else "kda_chunk",
-        _fwd_and_grads(lambda *a: chunk_products(*a, C, min(SUB, C))[:6],
-                       cotangents)(*args),
-        _fwd_and_grads(form, cotangents)(*args), tol=2e-2)
+    return _compare("gdn_chunk" if key_heads else "kda_chunk",
+                    _fwd_and_grads(pair, cotangents)(*args),
+                    _fwd_and_grads(form, cotangents)(*args), tol=2e-2)
 
 
 def phase_kernels(sz: Sizes) -> dict:

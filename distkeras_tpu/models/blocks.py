@@ -381,15 +381,14 @@ class GatedDeltaNet(nn.Module):
       float32, ``alpha = exp(g)``; ``beta = sigmoid(b)``;
     * the state ``S`` ``[head_dim, head_dim]`` a value head: ``S_t = alpha_t
       S_{t-1} + beta_t k_t (v_t - alpha_t S_{t-1}^T k_t)^T``, ``o_t = S_t^T
-      q_t``: the recurrence of ``ops/delta_rule.py`` with the decay a channel
-      held at the head's one value (``g`` broadcast over the key channels)
-      and ``q, k`` repeated to the value heads, **through the same two kernel
-      pairs** Kimi Delta Attention runs (:func:`grouped_gated_delta_rule`;
-      both are exact);
+      q_t``: the recurrence of ``ops/delta_rule.py`` with one decay a value
+      head and ``q, k`` read at the key heads, through the in-chunk pair
+      ``dk_gdn_chunk_*`` and the scan Kimi Delta Attention runs
+      (:func:`grouped_gated_delta_rule`; exact);
     * ``y = (RMSNorm_head(o; w) * SiLU(z)) W_o``.
 
     Scopes: ``dk_gdn_conv`` (the tap sums and their SiLU), ``dk_gdn`` (norms,
-    decay, the repeat, the kernels of the chunked form, the gated norm). A
+    decay, the kernels of the chunked form, the gated norm). A
     round's smallest summed log-decay of a chunk and its mean ``beta`` leave
     the program in ``ROUND_COUNTERS``, as ``KimiDeltaAttention``'s do."""
 
@@ -454,17 +453,13 @@ class GatedDeltaNet(nn.Module):
 
 def grouped_gated_delta_rule(q, k, v, g, beta):
     """The delta rule with **one decay a value head** and **key heads read
-    by group**, through :func:`~distkeras_tpu.ops.delta_rule.
-    chunked_gated_delta_rule` and so through the kernels Kimi Delta Attention
-    runs: ``q, k`` [B, L, Hk, d_k] are repeated to the ``Hv`` value heads
-    (value head ``j`` reads key head ``j // (Hv / Hk)``) and ``g`` [B, L, Hv]
-    is broadcast over the key channels, which the per-channel recurrence
-    then computes exactly. ``v``: [B, L, Hv, d_v]; ``beta``: [B, L, Hv].
+    by group**: ``q, k`` [B, L, Hk, d_k] (value head ``j`` reads key head ``j
+    // (Hv / Hk)``), ``v`` [B, L, Hv, d_v], ``g`` and ``beta`` [B, L, Hv],
+    handed as they are to :func:`~distkeras_tpu.ops.delta_rule.
+    chunked_gated_delta_rule`, whose kernels ``dk_gdn_chunk_*`` read the key
+    heads by group and the decay a head (nothing is repeated or broadcast).
     Returns what that function returns."""
-    group = v.shape[2] // k.shape[2]
-    q, k = (jnp.repeat(a, group, axis=2) for a in (q, k))
-    return chunked_gated_delta_rule(
-        q, k, v, jnp.broadcast_to(g[..., None], k.shape), beta)
+    return chunked_gated_delta_rule(q, k, v, g, beta)
 
 
 def _count_delta_rule(module, least, beta) -> None:

@@ -6,11 +6,16 @@ inside a chunk, a scan over chunks. Both halves are Pallas kernel pairs of
 ``dk_kda_chunk_bwd``: ``G``, ``A``, ``Bq``, the triangular inverse, ``U``,
 ``W`` made in VMEM from ``q, k, v, g, beta`` as the projections leave them)
 and the scan over chunks (``dk_kda_scan_fwd``, ``dk_kda_scan_bwd``: the state
-stays in VMEM). The in-chunk half in plain ``jax.numpy``
-(:func:`in_chunk_by_jax_numpy`) is kept as what the kernels are held to; no
-model path reaches it. Not built: the two pairs as one kernel (``U`` to
-``Kd`` go through HBM between them), the scan's output written as ``[B, L,
-H x d]`` (ROADMAP S14).
+stays in VMEM). With **one decay a head** (Gated DeltaNet; Qwen3-Next) the
+same recurrence holds with ``alpha_t`` the same over a head's channels and
+``q, k`` read at fewer key heads; its in-chunk half is a pair of its own
+(``dk_gdn_chunk_fwd``, ``dk_gdn_chunk_bwd``: ``Gamma`` one ``[C, C]`` mask a
+head, ``A`` and ``Bq`` one product a key head times it), which the decay's
+rank picks in :func:`chunked_gated_delta_rule`; the scan is shared. The
+in-chunk half in plain ``jax.numpy`` (:func:`in_chunk_by_jax_numpy`) is kept
+as what both in-chunk pairs are held to; no model path reaches it. Not
+built: the two halves as one kernel (``U`` to ``Kd`` go through HBM between
+them), the scan's output written as ``[B, L, H x d]`` (ROADMAP S14).
 
 A head carries a state ``S`` ``[d_k, d_v]``, zero before the sequence starts.
 With ``alpha_t = exp(g_t)`` in (0, 1) a channel of the key and ``beta_t`` in
@@ -69,6 +74,7 @@ import jax
 import jax.numpy as jnp
 
 from distkeras_tpu.ops.pallas.delta_rule import (chunk_products,
+                                                   gdn_chunk_products,
                                                    scan_chunks)
 
 #: positions a chunk, where the sequence allows it, and rows a sub-chunk
@@ -181,19 +187,35 @@ def in_chunk_by_jax_numpy(q, k, v, g, beta, chunk: int):
 
 def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int | None = None):
     """``o_t`` of the recurrence above for every position, from a zero
-    state. ``q, k``: [B, L, H, d_k] (already normalised and scaled); ``v``:
-    [B, L, H, d_v]; ``g``: [B, L, H, d_k], the log of the decay (``<= 0``);
-    ``beta``: [B, L, H]. ``chunk``, a power of two, divides ``L``
-    (``chunk_for(L)`` by default). Products take operands of ``v``'s dtype
-    and accumulate in float32; decays, the triangular inverse and the carried
-    state are float32. Returns ``(o [B, L, H, d_v] in v's dtype, the smallest
-    summed log-decay of a chunk and channel)``."""
-    B, L, H, _ = k.shape
+    state. ``v``: [B, L, H, d_v]; ``beta``: [B, L, H]; ``g``, the log of the
+    decay (``<= 0``), comes in one of two shapes, and the shape picks the
+    kernels of the in-chunk half:
+
+    * [B, L, H, d_k], **a decay a channel** (Kimi Delta Attention): ``q, k``
+      [B, L, H, d_k] (already normalised and scaled), through
+      ``dk_kda_chunk_*`` (:func:`~distkeras_tpu.ops.pallas.delta_rule.
+      chunk_products`);
+    * [B, L, H], **a decay a head** (Gated DeltaNet): ``q, k`` [B, L, Hk,
+      d_k] at ``Hk`` key heads dividing ``H``, value head ``j`` reading key
+      head ``j // (H / Hk)``, through ``dk_gdn_chunk_*``
+      (:func:`~distkeras_tpu.ops.pallas.delta_rule.gdn_chunk_products`):
+      ``Gamma_ri = e^{G_r - G_i}`` is one ``[C, C]`` mask a head, so ``A``
+      and ``Bq`` are ``k k^T`` and ``q k^T`` (once a key head) times it.
+
+    Both feed the same scan over chunks. ``chunk``, a power of two, divides
+    ``L`` (``chunk_for(L)`` by default). Products take operands of ``v``'s
+    dtype and accumulate in float32; decays, the triangular inverse and the
+    carried state are float32. Returns ``(o [B, L, H, d_v] in v's dtype,
+    the smallest summed log-decay of a chunk (and channel))``."""
+    B, L, H, V = v.shape
     C = chunk_for(L) if chunk is None else chunk
     if L % C or C & (C - 1):
         raise ValueError(f"a sequence of {L} in chunks of {C}: a chunk is a "
                          "power of two and must divide the sequence")
-    *products, last = chunk_products(q, k, v, g, beta, C, min(SUB, C))
+    if g.ndim == 3:
+        *products, last = gdn_chunk_products(q, k, v, g, beta, C)
+    else:
+        *products, last = chunk_products(q, k, v, g, beta, C, min(SUB, C))
     out = scan_chunks(*products)
-    return (jnp.moveaxis(out.reshape(B, H, L, v.shape[-1]), 1, 2),
+    return (jnp.moveaxis(out.reshape(B, H, L, V), 1, 2),
             jnp.min(jax.lax.stop_gradient(last)))

@@ -1,7 +1,9 @@
-"""The gated delta rule's chunked form as two Pallas TPU kernel pairs: the
-in-chunk half (:func:`chunk_products`) and the scan over chunks
-(:func:`scan_chunks`), each under one ``jax.custom_vjp`` with a written
-backward. ``ops/delta_rule.py`` has the mathematics and calls them in turn.
+"""The gated delta rule's chunked form as Pallas TPU kernel pairs: the
+in-chunk half with a decay a channel (:func:`chunk_products`) or a decay a
+value head (:func:`gdn_chunk_products`), and the scan over chunks
+(:func:`scan_chunks`) both feed, each under one ``jax.custom_vjp`` with a
+written backward. ``ops/delta_rule.py`` has the mathematics, picks the
+in-chunk pair by the rank of the decay and calls them in turn.
 
 **The scan over chunks.** What is sequential: for a head and chunk ``n``, from
 ``S = 0`` before chunk 0,
@@ -109,9 +111,69 @@ blocks (a multiple of the tile, or the whole dimension) holds for any of them
 and nothing is padded or refused; there is one path. Mosaic's products are
 measured at the published widths alone (128, 128, chunks of 64).
 
-All four kernels keep the caller's named scope: forward, recomputed and
-backward calls carry ``dk_kda`` in their ``op_name``. Off a TPU the same
-kernels run under the Pallas interpreter (``ops/pallas/mode.py``).
+**The in-chunk half with one decay a value head** (Gated DeltaNet;
+:func:`gdn_chunk_products`). A second pair, for ``g`` ``[B, L, Hv]`` and
+``q, k`` at ``Hk`` key heads dividing ``Hv`` (value head ``j`` reads key head
+``j // (Hv / Hk)``), read as the projections leave them: ``q, k`` as ``[B, L,
+Hk x d_k]`` (a program's value heads read their key heads through the index
+map), ``v`` as ``[B, L, Hv x d_v]``, ``g`` and ``beta`` as the columns of
+``[B, Hv / heads, L, heads]``. Nothing is repeated or broadcast. With one
+decay a row, ``Gamma_ri = e^{G_r - G_i}`` is the same for every channel:
+one ``[C, C]`` mask a head, its exponent masked to ``-inf`` above the
+diagonal before the exponential (``<= 0`` by construction: no sub-chunk
+split, nothing to overflow, for any decay), and
+
+    A = strict_lower(k k^T * Gamma)      Bq = q k^T * Gamma
+    T = (I + Diag(beta) A)^-1            U = T (beta V)    W = T (beta K e^G)
+    Qg = Q e^G    Kd = K e^{G_C - G}     s = e^{G_C} across the [1, d_k] lanes
+
+``k k^T`` and ``q k^T`` are one product a key head for its group. ``T`` is
+built by blocks from the unit diagonal up, in float32 at
+``Precision.HIGHEST`` (:func:`_inverse_above`'s levels, ``log2 C`` of them,
+the first ``I - N_1`` with no product): that chain of dependent products is
+what the forward costs, so **two value heads sit side by side** in the 128
+lanes (``[C, 2C]``), a level is one product of their 64 rows against the
+block-diagonal ``[2C, 2C]`` of the two, and every pair's level is taken
+before the next (:func:`_inverses_side_by_side`; on a TPU v5e at Qwen3-Next's
+shape a call took 16.0 ms a head at a time and 4.9 side by side, PERF.md
+§6). The forward rule saves ``T`` a pair side by side.
+
+* :func:`_gdn_fwd_kernel` (``dk_gdn_chunk_fwd``): grid ``(batch x value
+  heads / heads a program, chunks)``, both ``parallel``; writes ``U, W, Qg,
+  Bq, Kd, s`` exactly as :func:`scan_chunks` reads them, so the scan pair is
+  the same for both models.
+* :func:`_gdn_bwd_kernel` (``dk_gdn_chunk_bwd``): JAX's derivative of the
+  ``jax.numpy`` form (``ops/delta_rule.py::in_chunk_by_jax_numpy``) with
+  ``g`` broadcast and ``q, k`` repeated, written for one decay a head. With
+  ``R_V``, ``R_K``, ``dT``, ``dM``, ``dA`` as in the per-channel pair above,
+  ``E = dA * Gamma`` (strictly lower) and ``F = dBq * Gamma`` (lower; ``dBq``
+  above the diagonal meets ``Gamma``'s zeros):
+
+      dbeta = rowsum(dM * A) + rowsum(R_V * V) + rowsum(R_K * K e^G)
+      dV = beta * R_V      dQ = F k + dQg * e^G
+      dK = E k + E^T k + F^T q + beta e^G * R_K + dKd * e^{G_C - G}
+      P = dA * A + dBq * Bq                  (A, Bq unrounded, float32)
+      dG = rowsum(P) - colsum(P) + rowsum(beta K e^G * R_K + dQg * Qg
+           - dKd * Kd)
+      dG_C += sum_r rowsum(dKd * Kd)_r + sum(ds) * e^{G_C}
+      dg = the running sum of dG from below
+
+  ``dQ`` and ``dK`` are summed over a key head's group inside the program and
+  written once at key width; ``colsum(P)`` comes to a column through the
+  diagonal (one term a row: exact); ``ds`` is summed over its ``[1, d_k]``
+  lanes; ``dg`` and ``dbeta`` leave as ``[B, L, Hv]``. The two float32
+  products ``T^T dT T^T`` of every head are taken in lockstep.
+
+**Heads a program of the per-head pair**
+(:func:`gdn_chunk_heads_per_program`): whole groups of one batch row, whole
+lane tiles at the key and the value heads; 8 value heads over 4 key heads at
+the cell's shape (gauge ``pallas.gdn.chunk_heads_per_program``; counter
+``pallas.gdn.chunk_calls`` counts the pair's traced calls).
+
+All six kernels keep the caller's named scope: forward, recomputed and
+backward calls carry ``dk_kda`` (or ``dk_gdn``) in their ``op_name``. Off a
+TPU the same kernels run under the Pallas interpreter
+(``ops/pallas/mode.py``).
 """
 
 from __future__ import annotations
@@ -476,6 +538,12 @@ def _earlier_pairs(G, k, q, sub: int, dt):
     return jnp.concatenate(a, 0), jnp.concatenate(b, 0)
 
 
+def _level(s: int, row, col):
+    """Where ``N_s`` lies: the lower-left ``s`` x ``s`` block of each ``2s``
+    x ``2s`` block on the diagonal."""
+    return ((row ^ col) < 2 * s) & ((row & s) != 0) & ((col & s) == 0)
+
+
 def _inverse_above(X, N, sub: int):
     """``(I + N)^-1`` from ``X``, the inverse of its ``sub`` x ``sub``
     diagonal blocks, by blocks upward: a level is ``X - X N_s X`` with
@@ -483,8 +551,8 @@ def _inverse_above(X, N, sub: int):
     C = X.shape[0]
     row, col, s = _iota((C, C), 0), _iota((C, C), 1), sub
     while s < C:
-        below = ((row ^ col) < 2 * s) & ((row & s) != 0) & ((col & s) == 0)
-        X = X - _nn(_nn(X, jnp.where(below, N, 0.0), _EXACT), X, _EXACT)
+        X = X - _nn(_nn(X, jnp.where(_level(s, row, col), N, 0.0), _EXACT), X,
+                    _EXACT)
         s *= 2
     return X
 
@@ -785,3 +853,355 @@ def chunk_products(q, k, v, g, beta, chunk: int, sub: int,
         beta.astype(f32), chunk, sub, heads, interpret)
     return tuple(x.reshape(B, H, N, *x.shape[2:]) for x in products[:5]) + (
         products[5].reshape(B, H, N, K), last.reshape(B, H, N, K))
+
+
+# -- the in-chunk half with one decay a value head -----------------------------
+
+def gdn_chunk_heads_per_program(value_heads: int, key_heads: int, chunk: int,
+                                d_k: int, d_v: int, itemsize: int) -> int:
+    """Value heads one program of ``dk_gdn_chunk_*`` holds: whole groups
+    (their key heads are read once and their cotangents summed inside the
+    program), neighbours in one batch row whose lanes, at the key heads and
+    at the value heads, are whole tiles of 128 or the whole row; of those
+    the most, up to 8 (or one group where a group is wider), whose blocks fit
+    :data:`_VMEM_BUDGET` in the backward kernel, every block in and out
+    twice; where none does, the fewest that keep the rule for blocks."""
+    group = value_heads // key_heads
+    k, v, c = _lanes(d_k), _lanes(d_v), _lanes(chunk)
+    blocks = chunk * (4 * k * itemsize / group     # q, k and dq, dk
+                      + 2 * v * itemsize + v * 4   # v, dv, dU
+                      + 3 * k * itemsize           # dW, dQg, dKd
+                      + c * (itemsize + 4))        # dBq, T
+    whole = [h for h in range(group, value_heads + 1, group)
+             if value_heads % h == 0 and (h == value_heads or not (
+                 h // group * d_k % _LANES or h * d_v % _LANES))]
+    fit = [h for h in whole if h <= max(8, group) and (
+        h == group or 2 * h * blocks <= _VMEM_BUDGET)]
+    return max(fit) if fit else min(whole)
+
+
+def _pairs(heads: int) -> list:
+    """Value heads two at a time, side by side in the lanes; an odd last head
+    is paired with itself (its second copy is computed and dropped)."""
+    return [(a, min(a + 1, heads - 1)) for a in range(0, heads, 2)]
+
+
+def _sides(a: int, b: int) -> tuple:
+    return ((0, a),) if a == b else ((0, a), (1, b))
+
+
+def _side_by_side(G, a: int, b: int, row, col, right):
+    """``Gamma_ri = e^{G_r - G_i}`` for ``i <= r``, else 0, of value heads
+    ``a`` and ``b`` side by side ``[C, 2C]``, from ``G`` ``[C, heads]``: a
+    column's ``G_i`` is the diagonal summed down the sublanes (one term:
+    exact, so the diagonal's exponent is 0), and the difference is masked to
+    ``-inf`` before the exponential."""
+    Gab = jnp.where(right, G[:, b:b + 1], G[:, a:a + 1])
+    along = jnp.sum(jnp.where(row == col, Gab, 0.0), axis=0, keepdims=True)
+    return jnp.exp(jnp.where(row >= col, Gab - along, -jnp.inf))
+
+
+def _pair_gram(x_ref, k_ref, a: int, b: int, group: int, K: int, right):
+    """``[x_a k_a^T | x_b k_b^T]`` ``[C, 2C]`` float32 for value heads ``a``
+    and ``b``, ``x`` and ``k`` at their key heads: one product where the two
+    share a key head."""
+    ja, jb = a // group, b // group
+    both = jnp.concatenate([k_ref[0, :, ja * K:(ja + 1) * K],
+                            k_ref[0, :, jb * K:(jb + 1) * K]], 0)
+    left = _nt(x_ref[0, :, ja * K:(ja + 1) * K], both)
+    if ja == jb:
+        return left
+    return jnp.where(right, _nt(x_ref[0, :, jb * K:(jb + 1) * K], both), left)
+
+
+def _inverses_side_by_side(Ns, row, col, right):
+    """``(I + N)^-1`` of each pair ``[C, 2C]`` in ``Ns``, by blocks from the
+    unit diagonal up (:func:`_inverse_above`'s levels, ``X - X N_s X`` in
+    float32 at ``Precision.HIGHEST``; the first level, from ``X = I``, is ``I
+    - N_1``). A product takes the pair's ``C`` rows against the
+    block-diagonal ``[2C, 2C]`` of its two heads, and every pair's level is
+    taken before the next: the chain of ``2 log2 C`` dependent products is
+    what costs, not their passes."""
+    C = row.shape[0]
+
+    def blocks(m):
+        return jnp.concatenate([jnp.where(right, 0.0, m),
+                                jnp.where(right, m, 0.0)], 0)
+
+    Xs = [jnp.where(row == col, 1.0, 0.0) - jnp.where(_level(1, row, col), N,
+                                                      0.0) for N in Ns]
+    s = 2
+    while s < C:
+        Ys = [_nn(X, blocks(jnp.where(_level(s, row, col), N, 0.0)), _EXACT)
+              for X, N in zip(Xs, Ns)]
+        Xs = [X - _nn(Y, blocks(X), _EXACT) for X, Y in zip(Xs, Ys)]
+        s *= 2
+    return Xs
+
+
+def _wide_iotas(C: int):
+    """``row``, ``col`` (within a head) and ``right`` (the second head) of a
+    pair side by side ``[C, 2C]``."""
+    row, lane = _iota((C, 2 * C), 0), _iota((C, 2 * C), 1)
+    return row, lane & (C - 1), lane >= C
+
+
+def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, u_ref, w_ref, qg_ref,
+                    bq_ref, kd_ref, s_ref, *saved, heads: int, group: int):
+    C = q_ref.shape[1]
+    K, V = k_ref.shape[2] * group // heads, v_ref.shape[2] // heads
+    dt, f32 = q_ref.dtype, jnp.float32
+    row, col, right = _wide_iotas(C)
+    G = _running_sum(g_ref[0, 0])                            # [C, heads]
+    pairs, Ns = _pairs(heads), []
+    for a, b in pairs:
+        decays = _side_by_side(G, a, b, row, col, right)
+        beta = jnp.where(right, b_ref[0, 0, :, b:b + 1],
+                         b_ref[0, 0, :, a:a + 1])
+        Ns.append(beta * jnp.where(
+            row > col, _pair_gram(k_ref, k_ref, a, b, group, K, right)
+            * decays, 0.0))
+        Bq = _pair_gram(q_ref, k_ref, a, b, group, K, right) * decays
+        for side, h in _sides(a, b):
+            bq_ref[h, 0] = Bq[:, side * C:(side + 1) * C].astype(dt)
+    for p, ((a, b), T) in enumerate(zip(pairs, _inverses_side_by_side(
+            Ns, row, col, right))):
+        if saved:
+            saved[0][p, 0] = T
+        Td = T.astype(dt)
+        for side, h in _sides(a, b):
+            def lifted(x):  # [C, .] in the pair's rows of this head
+                x, zero = x.astype(dt), jnp.zeros(x.shape, dt)
+                return jnp.concatenate([zero, x] if side else [x, zero], 0)
+
+            j, beta, Gh = h // group, b_ref[0, 0, :, h:h + 1], G[:, h:h + 1]
+            k32, q32 = (ref[0, :, j * K:(j + 1) * K].astype(f32)
+                        for ref in (k_ref, q_ref))
+            decay, last = jnp.exp(Gh), Gh[C - 1:]
+            v = v_ref[0, :, h * V:(h + 1) * V].astype(f32)
+            u_ref[h, 0] = _nn(Td, lifted(beta * v))
+            w_ref[h, 0] = _nn(Td, lifted(beta * k32 * decay)).astype(dt)
+            qg_ref[h, 0] = (q32 * decay).astype(dt)
+            kd_ref[h, 0] = (k32 * jnp.exp(last - Gh)).astype(dt)
+            s_ref[h, 0] = jnp.broadcast_to(jnp.exp(last), (1, K))
+
+
+def _gdn_specs(Hv: int, C: int, K: int, V: int, heads: int, group: int):
+    """Block specs of one program (``heads`` value heads of one batch row and
+    their ``heads / group`` key heads, one chunk): the inputs ``q, k`` as
+    ``[B, L, Hk x d_k]``, ``v`` as ``[B, L, Hv x d_v]``, ``g`` and ``beta``
+    as columns ``[B, Hv / heads, L, heads]``; then the outputs ``U, W, Qg,
+    Bq, Kd, s`` and ``T`` as ``[B x Hv, N, ...]``."""
+    groups = Hv // heads
+
+    def spec(block, index):
+        return pl.BlockSpec(block, index, memory_space=pltpu.VMEM)
+
+    def wide_rows(lanes):
+        return spec((1, C, lanes), lambda i, n: (i // groups, n, i % groups))
+
+    def chunk_rows(*dims):
+        return spec((heads, 1) + dims, lambda i, n: (i, n) + (0,) * len(dims))
+
+    column = spec((1, 1, C, heads),
+                  lambda i, n: (i // groups, i % groups, n, 0))
+    ins = [wide_rows(heads // group * K), wide_rows(heads // group * K),
+           wide_rows(heads * V), column, column]
+    outs = [chunk_rows(C, V), chunk_rows(C, K), chunk_rows(C, K),
+            chunk_rows(C, C), chunk_rows(C, K), chunk_rows(1, K),
+            spec((len(_pairs(heads)), 1, C, 2 * C), lambda i, n: (i, n, 0, 0))]
+    return ins, outs
+
+
+def _columns(x, heads: int):
+    """``[B, L, Hv]`` as the kernels read it: ``[B, Hv / heads, L, heads]``."""
+    B, L, H = x.shape
+    return jnp.moveaxis(x.reshape(B, L, H // heads, heads), 2, 1)
+
+
+@functools.partial(_traced_once, static_argnames=(
+    "chunk", "heads", "key_heads", "interpret", "save"))
+def _gdn_chunk_fwd(q, k, v, g, beta, chunk: int, heads: int, key_heads: int,
+                   interpret: bool, save: bool):
+    """``U, W, Qg, Bq, Kd, s`` as ``[B x Hv, N, ...]`` from ``q, k`` ``[B, L,
+    Hk x d_k]``, ``v`` ``[B, L, Hv x d_v]``, ``g`` and ``beta`` ``[B, L,
+    Hv]``; with ``save`` also ``T`` ``[B x Hv, N, C, C]`` float32."""
+    B, L, Hv = beta.shape
+    K, V, C, N = k.shape[-1] // key_heads, v.shape[-1] // Hv, chunk, L // chunk
+    group = Hv // key_heads
+    ins, outs = _gdn_specs(Hv, C, K, V, heads, group)
+    rows, dt, f32 = B * Hv, v.dtype, jnp.float32
+    out_shape = [jax.ShapeDtypeStruct((rows, N) + dims, dtype)
+                 for dims, dtype in (((C, V), f32), ((C, K), dt), ((C, K), dt),
+                                     ((C, C), dt), ((C, K), dt),
+                                     ((1, K), f32))]
+    out_shape.append(jax.ShapeDtypeStruct(
+        (rows // heads * len(_pairs(heads)), N, C, 2 * C), f32))
+    kept = len(outs) if save else len(outs) - 1
+    call = pl.pallas_call(
+        functools.partial(_gdn_fwd_kernel, heads=heads, group=group),
+        grid=(rows // heads, N), in_specs=ins, out_specs=outs[:kept],
+        out_shape=out_shape[:kept], interpret=interpret,
+        name="dk_gdn_chunk_fwd",
+        **_compiler_kw(interpret, ("parallel", "parallel")))
+    with jax.named_scope("dk_gdn_chunk_fwd"):
+        return tuple(call(q, k, v, _columns(g, heads), _columns(beta, heads)))
+
+
+def _gdn_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, t_ref, du_ref, dw_ref,
+                    dqg_ref, dbq_ref, dkd_ref, ds_ref, dq_ref, dk_ref, dv_ref,
+                    dg_ref, db_ref, *, heads: int, group: int):
+    C = q_ref.shape[1]
+    K, V = k_ref.shape[2] * group // heads, v_ref.shape[2] // heads
+    dt, f32 = q_ref.dtype, jnp.float32
+    wide = _wide_iotas(C)
+    # a head's own row and column (Mosaic's compiler stops on a slice of an
+    # iota)
+    right, row, col = wide[2], _iota((C, C), 0), _iota((C, C), 1)
+    at_last = _iota((C, 1), 0) == C - 1
+    G = _running_sum(g_ref[0, 0])                            # [C, heads]
+    # What flows into T, a head at a time; the two float32 products of
+    # every head are then taken in lockstep, as the forward's levels are.
+    held, Ts, dTs = [], [], []
+    for p, (a, b) in enumerate(_pairs(heads)):
+        decays = _side_by_side(G, a, b, *wide)
+        kk = _pair_gram(k_ref, k_ref, a, b, group, K, right)
+        qk = _pair_gram(q_ref, k_ref, a, b, group, K, right)
+        for side, h in _sides(a, b):
+            cut = slice(side * C, (side + 1) * C)
+            j, beta, Gh = h // group, b_ref[0, 0, :, h:h + 1], G[:, h:h + 1]
+            ke = k_ref[0, :, j * K:(j + 1) * K].astype(f32) * jnp.exp(Gh)
+            v = v_ref[0, :, h * V:(h + 1) * V].astype(f32)
+            dU, dW = du_ref[h, 0].astype(dt), dw_ref[h, 0]
+            Ts.append(t_ref[p, 0, :, cut])
+            dTs.append(_nt(dU, (beta * v).astype(dt))
+                       + _nt(dW, (beta * ke).astype(dt)))
+            held.append((h, j, beta, Gh, decays[:, cut], kk[:, cut],
+                         qk[:, cut], ke, v, dU, dW))
+    lefts = [_tn(T, dT, _EXACT) for T, dT in zip(Ts, dTs)]
+    dMs = [jnp.where(row > col, -_nt(left, T, _EXACT), 0.0)
+           for left, T in zip(lefts, Ts)]
+    dq = [jnp.zeros((C, K), f32) for _ in range(heads // group)]
+    dk = [jnp.zeros((C, K), f32) for _ in range(heads // group)]
+    for (h, j, beta, Gh, decays, kk, qk, ke, v, dU, dW), T, dM in zip(
+            held, Ts, dMs):
+        k, q = (ref[0, :, j * K:(j + 1) * K] for ref in (k_ref, q_ref))
+        k32, q32 = k.astype(f32), q.astype(f32)
+        decay, last = jnp.exp(Gh), Gh[C - 1:]
+        tail, Td = jnp.exp(last - Gh), T.astype(dt)
+        A = jnp.where(row > col, kk * decays, 0.0)
+        R_V, R_K = _tn(Td, dU), _tn(Td, dW)
+        dA, dBq = beta * dM, dbq_ref[h, 0].astype(f32)
+        # Through Gamma: dA * A + dBq * Bq, its rows' sums less its columns'
+        # (brought to rows by the diagonal); dBq above the diagonal meets
+        # Gamma's zeros.
+        pairs = dA * A + dBq * qk * decays
+        pairs = pairs - jnp.where(row == col, jnp.sum(
+            pairs, axis=0, keepdims=True), 0.0)
+        E, F = (dA * decays).astype(dt), (dBq * decays).astype(dt)
+        dQg, dKd = dqg_ref[h, 0].astype(f32), dkd_ref[h, 0].astype(f32)
+        dq[j] = dq[j] + _nn(F, k) + dQg * decay
+        dk[j] = dk[j] + _nn(E, k) + _tn(E, k) + _tn(F, q) \
+            + beta * decay * R_K + dKd * tail
+        through_kd = jnp.sum(dKd * k32 * tail, axis=1, keepdims=True)
+        dG = jnp.sum(pairs, axis=1, keepdims=True) + jnp.sum(
+            beta * ke * R_K + dQg * q32 * decay, axis=1,
+            keepdims=True) - through_kd
+        dg_ref[0, 0, :, h:h + 1] = jnp.where(
+            at_last, dG + jnp.sum(through_kd, axis=0, keepdims=True)
+            + jnp.sum(ds_ref[h, 0], axis=1, keepdims=True) * jnp.exp(last),
+            dG)
+        dv_ref[0, :, h * V:(h + 1) * V] = (beta * R_V).astype(dt)
+        db_ref[0, 0, :, h:h + 1] = jnp.sum(dM * A, axis=1, keepdims=True) \
+            + jnp.sum(R_V * v, axis=1, keepdims=True) \
+            + jnp.sum(R_K * ke, axis=1, keepdims=True)
+    for j in range(heads // group):
+        dq_ref[0, :, j * K:(j + 1) * K] = dq[j].astype(dt)
+        dk_ref[0, :, j * K:(j + 1) * K] = dk[j].astype(dt)
+    dg_ref[0, 0] = _running_sum(dg_ref[0, 0], up=True)
+
+
+@functools.partial(_traced_once, static_argnames=(
+    "chunk", "heads", "key_heads", "interpret"))
+def _gdn_chunk_bwd(q, k, v, g, beta, T, dU, dW, dQg, dBq, dKd, ds, chunk: int,
+                   heads: int, key_heads: int, interpret: bool):
+    """The cotangents of ``q, k`` (at the key heads), ``v``, ``g`` and
+    ``beta``, each in its primal's dtype and shape."""
+    B, L, Hv = beta.shape
+    K, V, C, N = k.shape[-1] // key_heads, v.shape[-1] // Hv, chunk, L // chunk
+    group = Hv // key_heads
+    ins, outs = _gdn_specs(Hv, C, K, V, heads, group)
+    cols = [_columns(x, heads) for x in (g, beta)]
+    call = pl.pallas_call(
+        functools.partial(_gdn_bwd_kernel, heads=heads, group=group),
+        grid=(B * Hv // heads, N), in_specs=ins + [outs[6]] + outs[:6],
+        out_specs=ins, out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                                  for x in (q, k, v, *cols)],
+        interpret=interpret, name="dk_gdn_chunk_bwd",
+        **_compiler_kw(interpret, ("parallel", "parallel")))
+    with jax.named_scope("dk_gdn_chunk_bwd"):
+        dq, dk, dv, dg, dbeta = call(q, k, v, *cols, T, dU, dW, dQg, dBq,
+                                     dKd, ds)
+    return dq, dk, dv, *(jnp.moveaxis(x, 1, 2).reshape(B, L, Hv)
+                         for x in (dg, dbeta))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _gdn_chunk(q, k, v, g, beta, chunk, heads, key_heads, interpret):
+    return _gdn_chunk_fwd(q, k, v, g, beta, chunk=chunk, heads=heads,
+                          key_heads=key_heads, interpret=interpret, save=False)
+
+
+def _gdn_chunk_fwd_rule(q, k, v, g, beta, chunk, heads, key_heads, interpret):
+    *out, T = _gdn_chunk_fwd(q, k, v, g, beta, chunk=chunk, heads=heads,
+                             key_heads=key_heads, interpret=interpret,
+                             save=True)
+    return tuple(out), (q, k, v, g, beta, T)
+
+
+def _gdn_chunk_bwd_rule(chunk, heads, key_heads, interpret, res, cotangents):
+    return _gdn_chunk_bwd(*res, *cotangents, chunk=chunk, heads=heads,
+                          key_heads=key_heads, interpret=interpret)
+
+
+_gdn_chunk.defvjp(_gdn_chunk_fwd_rule, _gdn_chunk_bwd_rule)
+
+
+def gdn_chunk_products(q, k, v, g, beta, chunk: int,
+                       heads: int | None = None,
+                       interpret: bool | None = None):
+    """:func:`chunk_products` for **one decay a value head** and **key heads
+    read by group** (Gated DeltaNet): ``q, k``: [B, L, Hk, d_k] at the key
+    heads and ``v``: [B, L, Hv, d_v], ``Hk`` dividing ``Hv``, in the
+    products' dtype; ``g`` and ``beta``: [B, L, Hv], float32 here whatever
+    they come as. Value head ``j`` reads key head ``j // (Hv / Hk)``. Returns
+    ``(U, W, Qg, Bq, Kd, s)`` at the value heads as :func:`scan_chunks` reads
+    them and the summed log-decay of each chunk ``[B, Hv, N]`` (no cotangent
+    flows into that one). Differentiable in all five, ``q, k``'s cotangents
+    at the key heads. ``heads`` (value heads a program) follows the shapes
+    (:func:`gdn_chunk_heads_per_program`); ``interpret=None`` compiles on a
+    TPU and interprets elsewhere."""
+    interpret = mode.interpret("gdn_chunk", interpret)
+    B, L, Hk, K = k.shape
+    Hv, V, N = v.shape[2], v.shape[-1], L // chunk
+    if Hv % Hk:
+        raise ValueError(f"{Hk} key heads cannot serve {Hv} value heads")
+    if heads is None:
+        heads = gdn_chunk_heads_per_program(Hv, Hk, chunk, K, V,
+                                            jnp.dtype(v.dtype).itemsize)
+    if Hv % heads or heads % (Hv // Hk):
+        raise ValueError(f"{heads} value heads a program are not whole "
+                         f"groups of {Hv // Hk} that divide {Hv}")
+    from distkeras_tpu import telemetry
+
+    telemetry.counter("pallas.gdn.chunk_calls").add(1)
+    telemetry.gauge("pallas.gdn.chunk_heads_per_program").set(heads)
+    f32 = jnp.float32
+    g = g.astype(f32)
+    products = _gdn_chunk(
+        q.reshape(B, L, Hk * K), k.reshape(B, L, Hk * K),
+        v.reshape(B, L, Hv * V), g, beta.astype(f32), chunk, heads, Hk,
+        interpret)
+    last = jnp.moveaxis(jnp.sum(g.reshape(B, N, chunk, Hv), 2), 1, 2)
+    return tuple(x.reshape(B, Hv, N, *x.shape[2:]) for x in products[:5]) + (
+        products[5].reshape(B, Hv, N, K), last)

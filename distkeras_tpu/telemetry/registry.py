@@ -184,16 +184,16 @@ _METRICS = [
        "1)) over the `kda` layers' tokens and heads."),
     _m("gdn.chunk", "gauge", "models",
        "Positions a chunk of Gated DeltaNet's chunked form (Qwen3-Next's "
-       "`gdn` layers, through the kernels `kda` runs), set as the model is "
-       "traced (`ops/delta_rule.py::chunk_for`)."),
+       "`gdn` layers, through `dk_gdn_chunk_*` and the scan `kda` runs), set "
+       "as the model is traced (`ops/delta_rule.py::chunk_for`)."),
     _m("gdn.state_bytes", "gauge", "models",
        "Bytes of the recurrent state the `gdn` layers carry a step: layers x "
        "batch x value heads x head_dim^2 float32, set as the model is "
        "traced."),
     _m("gdn.min_chunk_decay", "gauge", "models",
        "Last round's smallest summed log-decay of a chunk over the `gdn` "
-       "layers (one decay a value head, broadcast over the key channels; <= "
-       "0; the chunked form never takes `e^-G`)."),
+       "layers (one decay a value head; <= 0; the chunked form never takes "
+       "`e^-G`)."),
     _m("gdn.mean_beta", "gauge", "models",
        "Last round's mean `beta` (the delta rule's write strength, in (0, "
        "1)) over the `gdn` layers' tokens and value heads."),
@@ -211,6 +211,16 @@ _METRICS = [
        "traced (`ops/pallas/delta_rule.py::chunk_heads_per_program`: "
        "neighbours in one batch row of `[B, L, H x d]`, the most up to 8 "
        "that divide the heads, keep whole lane tiles and fit VMEM)."),
+    _m("pallas.gdn.chunk_calls", "counter", "kernels",
+       "In-chunk calls of the delta rule traced through the pair for one "
+       "decay a value head and key heads read by group (`dk_gdn_chunk_fwd`, "
+       "`dk_gdn_chunk_bwd`; Gated DeltaNet), counted as a call is traced; "
+       "a decay a channel takes `dk_kda_chunk_*` and leaves it alone."),
+    _m("pallas.gdn.chunk_heads_per_program", "gauge", "kernels",
+       "Value heads one program of `dk_gdn_chunk_*` holds, set as a call is "
+       "traced (`ops/pallas/delta_rule.py::gdn_chunk_heads_per_program`: "
+       "whole groups of one batch row, whole lane tiles at the key and "
+       "value heads, the most up to 8 that fit VMEM)."),
     _m("pallas.kda.grid_steps", "gauge", "kernels",
        "Grid steps of the delta rule's kernel call traced last "
        "(`dk_kda_scan_*`: batch x heads / heads a program, times the "

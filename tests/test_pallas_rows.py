@@ -360,3 +360,50 @@ def test_the_kernel_pairs_compile_for_a_v5e_at_the_cells_shape(one_chip,
                if "custom-call(" in line and "dk_kda_" in line)
     assert not re.search(r'= [^\n]* while\([^\n]*op_name="[^"]*dk_kda', text)
     assert not re.search(r"\[[0-9,]*16,16,128\]", text)
+
+
+def test_the_gdn_pair_compiles_for_a_v5e_at_the_cells_shape(one_chip,
+                                                             monkeypatch):
+    """Gated DeltaNet's layer at the Qwen3-Next cell's step: ``q, k`` ``[2,
+    8192, 16, 128]`` at the key heads, ``v`` at 32 value heads, one decay a
+    value head ``[2, 8192, 32]``, bfloat16, under ``jax.checkpoint`` and
+    ``jax.value_and_grad``: Mosaic takes the per-head in-chunk pair
+    ``dk_gdn_chunk_*`` beside the scan pair, six calls under ``dk_gdn``, and
+    the program holds no decay broadcast over the key channels (``f32[2,
+    8192, 32, 128]``) and no key heads repeated to the value heads.
+    Kept in this file for the reason above."""
+    import re
+
+    from distkeras_tpu.ops.delta_rule import chunked_gated_delta_rule
+    from distkeras_tpu.ops.pallas import mode
+
+    monkeypatch.setattr(mode, "compiles", lambda: True)  # headed for the chip
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    @jax.checkpoint
+    def layer(*a):
+        with jax.named_scope("dk_gdn"):
+            return chunked_gated_delta_rule(*a)[0]
+
+    def loss(*a):
+        with jax.named_scope("model"):  # a transform renames the outermost
+            return jnp.sum(jnp.square(layer(*a).astype(jnp.float32)))
+
+    keys, values = (2, 8192, 16, 128), (2, 8192, 32, 128)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        shape(*keys), shape(*keys), shape(*values),
+        shape(*values[:3], dtype=jnp.float32),
+        shape(*values[:3], dtype=jnp.float32)).compile().as_text()
+    calls = re.findall(r"= [^\n]*custom-call\([^\n]*"
+                       r'op_name="[^"]*/(dk_(?:gdn|kda)_(?:scan|chunk)_\w+)/',
+                       text)
+    assert sorted(calls) == ["dk_gdn_chunk_bwd", "dk_gdn_chunk_fwd",
+                             "dk_gdn_chunk_fwd", "dk_kda_scan_bwd",
+                             "dk_kda_scan_fwd", "dk_kda_scan_fwd"], calls
+    assert text.count('custom_call_target="tpu_custom_call"') == 6
+    assert all("/dk_gdn/" in line for line in text.splitlines()
+               if "custom-call(" in line and "_chunk_" in line)
+    assert not re.search(r"f32\[2,8192,32,128\]", text)
+    assert not re.search(r"bf16\[2,8192,16,2,128\]", text)

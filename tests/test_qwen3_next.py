@@ -1,14 +1,14 @@
 """Qwen3-Next's decoder on the CPU at a small size, seeded weights: Gated
-DeltaNet through the chunked kernels Kimi Delta Attention runs (interpreted)
-against the position-at-a-time recurrence with one decay a head and key heads
-read by group, values and every gradient, under decays held at -1.6 and -20
-a step over whole chunks, and with bfloat16 operands against a float8
-control; partial rotation and the output gate of gated attention, flash
-against the dense path and the reference; the shared expert's gate; the
-shares of experts add up to the uncut layer with the shared expert once; the
-module against the plain reference on logits, loss and every gradient; the
-reference notices each dropped term; the trainer path publishes
-``moe.round`` and ``gdn.round``."""
+DeltaNet through its own in-chunk kernel pair and the scan Kimi Delta
+Attention runs (interpreted) against the position-at-a-time recurrence with
+one decay a head and key heads read by group, values and every gradient,
+under decays held at -1.6 and -20 a step over whole chunks, and with
+bfloat16 operands against a float8 control; partial rotation and the output
+gate of gated attention, flash against the dense path and the reference; the
+shared expert's gate; the shares of experts add up to the uncut layer with
+the shared expert once; the module against the plain reference on logits,
+loss and every gradient; the reference notices each dropped term; the
+trainer path publishes ``moe.round`` and ``gdn.round``."""
 
 import os
 import sys
@@ -81,9 +81,9 @@ def test_gated_deltanet_through_the_kernels_is_the_recurrence(g):
     """Two chunks of 64; ``g`` = -1.6 a step sums to -102 over a chunk, past
     the 88 at which ``e^-G`` leaves float32, and -20 is far past anything
     (the published initialisation draws ``A`` up to 16: -21 a step). The
-    value heads' decay broadcast over the key channels and the key heads
-    repeated are the scalar-decay, grouped recurrence, gradients of the key
-    heads and of the scalar decay included."""
+    per-head pair, reading the key heads by group and one decay a value head,
+    is the scalar-decay, grouped recurrence, gradients of the key heads and
+    of the scalar decay included."""
     args = gdn_inputs(128, g=g)
     out, least = grouped_gated_delta_rule(*args)
     want = reference.delta_rule(*args)
