@@ -75,6 +75,7 @@ class Sizes:
     mlp_rows: int
     gdn: tuple        # (B, L, key heads, value heads, Dh) of Gated DeltaNet
     flash_gqa: tuple  # (B, L, H, K/V heads, Dh) of grouped attention
+    ssd: tuple        # (B, L, heads, head_dim, groups, state) of Mamba-2
 
 
 FULL = Sizes(layers=8, d_model=1024, heads=16, d_ff=4096, vocab=32768,
@@ -87,14 +88,16 @@ FULL = Sizes(layers=8, d_model=1024, heads=16, d_ff=4096, vocab=32768,
              kda=(1, 8192, 8, 128),     # the kimi_linear cell's step
              mlp_rows=8192,
              gdn=(2, 8192, 16, 32, 128),       # the qwen3next cell's step
-             flash_gqa=(1, 8192, 16, 2, 256))  # its gated attention's heads
+             flash_gqa=(1, 8192, 16, 2, 256),  # its gated attention's heads
+             ssd=(1, 8192, 32, 64, 4, 128))    # the nemotron3nano cell's step
 TOY = Sizes(layers=1, d_model=64, heads=2, d_ff=128, vocab=256,
             seq=128, lm_batch=2, lm_window=2, lm_rounds=3, tp_layers=1,
             cnn_batch=16, cnn_window=2,
             flash=((1, 128, 2, 16), (1, 64, 2, 16)), lstm=(8, 6, 8, 128),
             groupnorm=(2, 8, 8, 64, 32), fold=(70, 33), rows=(64, 2, 32, 16),
             kda=(2, 128, 2, 16),
-            mlp_rows=2048, gdn=(2, 128, 2, 4, 16), flash_gqa=(1, 128, 4, 2, 16))
+            mlp_rows=2048, gdn=(2, 128, 2, 4, 16), flash_gqa=(1, 128, 4, 2, 16),
+            ssd=(1, 128, 4, 16, 2, 32))
 
 
 class SmokeFailure(AssertionError):
@@ -644,6 +647,62 @@ def check_kda_chunk(shape, key_heads=None) -> dict:
                     _fwd_and_grads(form, cotangents)(*args), tol=2e-2)
 
 
+def check_ssd(shape) -> dict:
+    """Mamba-2's chunked form (``ops/delta_rule.py::chunked_ssd``: the
+    in-chunk half in ``jax.numpy``, the scan pair ``dk_kda_scan_*`` with no
+    ``W``) as the model hands it its inputs (``C``, ``B`` at the groups,
+    ``dt * x`` and the products in bfloat16) against the position-at-a-time
+    recurrence in float32 with exact matmuls, the outputs and the cotangents
+    of ``x``, ``dt``, ``B`` and ``C``. ``dt`` log-uniform in [1e-3, 1e-1] as
+    the initialisation draws it and ``A = -(1..heads)``: a chunk's summed
+    decay reaches -200, past the 88 at which ``e^-G`` leaves float32. The
+    reference scans blocks of 128 positions, each recomputed in the
+    backward, so that the carried states of the whole sequence are not kept."""
+    import jax
+    import jax.numpy as jnp
+
+    from distkeras_tpu.ops.delta_rule import chunked_ssd
+
+    B, L, H, P, G, N = shape
+    ks = jax.random.split(jax.random.key(5), 5)
+    x = jax.random.normal(ks[0], (B, L, H, P))
+    dt = jnp.exp(jax.random.uniform(ks[1], (B, L, H), minval=jnp.log(1e-3),
+                                    maxval=jnp.log(1e-1)))
+    Bm, Cm = (jax.random.normal(k, (B, L, G, N)) * N ** -0.5 for k in ks[2:4])
+    A = -jnp.arange(1, H + 1, dtype=jnp.float32)
+    cotangent = jax.random.normal(ks[4], (B, L, H, P))
+    bf = jnp.bfloat16
+
+    def chunked(x, dt, Bm, Cm):
+        return chunked_ssd(Cm.astype(bf), Bm.astype(bf),
+                           (x * dt[..., None]).astype(bf), dt * A)[0]
+
+    def recurrence(x, dt, Bm, Cm):
+        group = jnp.arange(H) // (H // G)
+
+        def step(h, xs):
+            x, dt, b, c = xs
+            h = jnp.exp(dt * A)[..., None, None] * h \
+                + (dt[..., None] * b[:, group])[..., None] * x[..., None, :]
+            return h, jnp.einsum("bhnp,bhn->bhp", h, c[:, group],
+                                 precision="highest")
+
+        @jax.checkpoint
+        def block(h, xs):
+            return jax.lax.scan(step, h, xs)
+
+        span = min(128, L)
+        xs = tuple(jnp.moveaxis(a, 1, 0).reshape(L // span, span, B,
+                                                 *a.shape[2:])
+                   for a in (x, dt, Bm, Cm))
+        _, y = jax.lax.scan(block, jnp.zeros((B, H, N, P)), xs)
+        return jnp.moveaxis(y.reshape(L, B, H, P), 0, 1)
+
+    args = (x, dt, Bm, Cm)
+    return _compare("ssd", _fwd_and_grads(chunked, cotangent)(*args),
+                    _fwd_and_grads(recurrence, cotangent)(*args), tol=2e-2)
+
+
 def phase_kernels(sz: Sizes) -> dict:
     out = {}
     flash = [(f"flash_attention_L{shape[1]}", check_flash, shape)
@@ -661,7 +720,8 @@ def phase_kernels(sz: Sizes) -> dict:
                                ("fold", check_fold, sz.fold),
                                ("rows", check_rows, sz.rows),
                                ("kda_scan", check_kda_scan, sz.kda),
-                               ("kda_chunk", check_kda_chunk, sz.kda)):
+                               ("kda_chunk", check_kda_chunk, sz.kda),
+                               ("ssd", check_ssd, sz.ssd)):
         t0 = time.perf_counter()
         out[name] = dict(check(shape), shape=list(shape),
                          seconds=round(time.perf_counter() - t0, 2))

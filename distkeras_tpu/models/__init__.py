@@ -14,6 +14,7 @@ from distkeras_tpu.models.smallthinker import SmallThinkerLM, small_smallthinker
 from distkeras_tpu.models.lfm2 import Lfm2MoeLM, small_lfm2_lm  # noqa: F401
 from distkeras_tpu.models.kimi_linear import KimiLinearLM, small_kimi_linear_lm  # noqa: F401
 from distkeras_tpu.models.qwen3_next import Qwen3NextLM, small_qwen3_next_lm  # noqa: F401
+from distkeras_tpu.models.nemotron_h import NemotronHLM, small_nemotron_h_lm  # noqa: F401
 
 __all__ = [
     "DKModule",
@@ -38,4 +39,6 @@ __all__ = [
     "small_kimi_linear_lm",
     "Qwen3NextLM",
     "small_qwen3_next_lm",
+    "NemotronHLM",
+    "small_nemotron_h_lm",
 ]

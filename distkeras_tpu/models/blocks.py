@@ -1,6 +1,7 @@
 """Blocks of today's open decoders, for the modules that are built from them
 (``models/smallthinker.py``, ``models/lfm2.py``, ``models/kimi_linear.py``,
-``models/qwen3_next.py``): RMSNorm, rotary positions by the half-split rule
+``models/qwen3_next.py``, ``models/nemotron_h.py``): RMSNorm, rotary
+positions by the half-split rule
 (over a head or its first columns), grouped-query attention without biases
 under a full or a sliding-window causal mask (with RMSNorm on each query and
 key head where the family has it, and a sigmoid gate on each head's output
@@ -8,11 +9,13 @@ where Qwen3-Next has it), latent attention (a compressed K/V projection, keys
 wider than values, no positions), a gated short convolution, Kimi Delta
 Attention (a gated delta rule with a decay a channel, as a chunked scan) and
 Gated DeltaNet (the same rule with a decay a head and key heads shared by
-value heads, through the same chunked scan) in attention's place, a gated
-dense feed-forward, two routers (softmax, and sigmoid with a selection
-bias), and a dropless mixture of gated experts that holds a share of the
-experts it routes over.
-The two attention modules and the delta rule are told how many *heads* they
+value heads, through the same chunked scan) and a Mamba-2 state-space mixer
+(a decay a head and no delta rule, through the same scan with no ``W``) in
+attention's place, a gated and a plain dense feed-forward, two routers
+(softmax, and sigmoid with a selection bias), and a dropless mixture of gated
+or plain experts that holds a share of the experts it routes over.
+The two attention modules, the delta rule and the state-space mixer are told
+how many *heads* they
 hold, as the expert layer is told its experts: a chip that shares a layer's
 heads computes its own heads' part of ``W_o``'s sum. ``TransformerLM`` and
 ``MoETransformerLM`` share none of these parts (LayerNorm, learned positions,
@@ -59,14 +62,17 @@ import numpy as np
 from flax import linen as nn
 
 from distkeras_tpu.models.base import ROUND_COUNTERS
-from distkeras_tpu.ops.delta_rule import chunk_for, chunked_gated_delta_rule
+from distkeras_tpu.ops.delta_rule import (chunk_for, chunked_gated_delta_rule,
+                                          chunked_ssd)
 from distkeras_tpu.ops.pallas import rows
 from distkeras_tpu.ops.pallas.flash_attention import (FLASH_RESIDUALS,
                                                       residual_bytes)
 from distkeras_tpu.scopes import owner
 
-#: the gate of a gated feed-forward, by the name a configuration gives it
-ACTIVATIONS = {"relu": nn.relu, "silu": nn.silu}
+#: the gate of a gated feed-forward, or a plain one's activation, by the
+#: name a configuration gives it
+ACTIVATIONS = {"relu": nn.relu, "silu": nn.silu,
+               "relu2": lambda x: jnp.square(nn.relu(x))}
 
 
 def remat_block(block_cls, module, flash_layers: int, batch: int,
@@ -462,15 +468,16 @@ def grouped_gated_delta_rule(q, k, v, g, beta):
     return chunked_gated_delta_rule(q, k, v, g, beta)
 
 
-def _count_delta_rule(module, least, beta) -> None:
-    """Add a step's smallest summed log-decay of a chunk and its ``beta`` to
+def _count_delta_rule(module, least, beta, what: str = "beta") -> None:
+    """Add a step's smallest summed log-decay of a chunk and its ``beta`` (or
+    what ``what`` names: the sum and count of ``beta``'s values) to
     ``module``'s round counters (where the collection is mutable)."""
     if not module.is_mutable_collection(ROUND_COUNTERS):
         return
     for name, value, join in (
             ("min_chunk_decay", least, jnp.minimum),
-            ("beta_sum", jnp.sum(beta), jnp.add),
-            ("beta_count", jnp.float32(beta.size), jnp.add),
+            (f"{what}_sum", jnp.sum(beta), jnp.add),
+            (f"{what}_count", jnp.float32(beta.size), jnp.add),
             ("steps", jnp.float32(1), jnp.add)):
         var = module.variable(ROUND_COUNTERS, name,
                               lambda: jnp.zeros((), jnp.float32))
@@ -484,6 +491,107 @@ def _inverse_softplus_uniform(low: float, high: float):
         u = jax.random.uniform(key, shape, dtype, minval=low, maxval=high)
         return u + jnp.log(-jnp.expm1(-u))
     return init
+
+
+def _inverse_softplus_log_uniform(low: float, high: float, floor: float):
+    """An initializer: ``x`` with ``softplus(x)`` log-uniform in ``[low,
+    high)``, floored at ``floor`` (Mamba-2's ``dt_bias``)."""
+    def init(key, shape, dtype=jnp.float32):
+        u = jax.random.uniform(key, shape, dtype, minval=math.log(low),
+                               maxval=math.log(high))
+        dt = jnp.maximum(jnp.exp(u), floor)
+        return dt + jnp.log(-jnp.expm1(-dt))
+    return init
+
+
+class Mamba2Mixer(nn.Module):
+    """A Mamba-2 state-space layer in attention's place (the ``M`` layers of
+    Nemotron-H), for ``num_heads`` heads of ``head_dim`` held here, which
+    read ``num_groups`` groups of ``B`` and ``C`` of ``state_dim`` (head ``j``
+    reads group ``j // (num_heads / num_groups)``); ``first_head``: the first
+    held head's index in the layer (it sets ``A``'s initial value). No bias
+    in a projection:
+
+    * ``[z | x | B | C | dt] = h W_in``; ``x, B, C = SiLU(conv(x | B | C) +
+      b)``, one causal depthwise convolution of ``conv_kernel`` taps **with a
+      bias**;
+    * ``dt = softplus(dt + dt_bias)`` and ``A = -exp(A_log)``, one of each a
+      head; the state ``h`` ``[state_dim, head_dim]`` a head, zero as the
+      sequence starts: ``h_t = e^{dt_t A} h_{t-1} + dt_t B_t x_t^T``, ``y_t =
+      h_t^T C_t + D x_t``, computed in the chunked form of
+      ``ops/delta_rule.py`` (:func:`~distkeras_tpu.ops.delta_rule.
+      chunked_ssd`: the in-chunk half in ``jax.numpy``, the delta rule's
+      scan pair with ``W=None``);
+    * ``y = RMSNorm_group(y * SiLU(z)) * w`` over ``num_groups`` groups of
+      heads (one weight of ``num_heads * head_dim``), then ``W_out``.
+
+    Scopes: ``dk_ssd_conv`` (the tap sums, the bias, SiLU), ``dk_ssd``
+    (``dt``, the decay, ``dt * x``, the chunked form, the ``D`` skip, the
+    gated norm). A round's smallest summed log-decay of a chunk and its mean
+    ``dt`` leave the program in ``ROUND_COUNTERS``."""
+
+    num_heads: int
+    num_groups: int
+    first_head: int = 0
+    head_dim: int = 64
+    state_dim: int = 128
+    conv_kernel: int = 4
+    rms_eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, h):
+        B, L, D = h.shape
+        H, G, P, N = self.num_heads, self.num_groups, self.head_dim, \
+            self.state_dim
+        if H % G:
+            raise ValueError(f"{G} groups cannot serve {H} heads")
+        inner, bc, K = H * P, G * N, self.conv_kernel
+
+        with owner("mixer"):
+            zxbcdt = nn.Dense(2 * inner + 2 * bc + H, use_bias=False,
+                              name="in_proj")(h)
+            taps = self.param(
+                "conv_taps", nn.initializers.variance_scaling(
+                    1.0, "fan_in", "truncated_normal", in_axis=-1,
+                    out_axis=-2), (inner + 2 * bc, K))
+            conv_bias = self.param(
+                "conv_bias", lambda key, shape: jax.random.uniform(
+                    key, shape, minval=-K ** -0.5, maxval=K ** -0.5),
+                (inner + 2 * bc,))
+            a_log = self.param("A_log", lambda key, shape: jnp.log(
+                jnp.arange(1, H + 1, dtype=jnp.float32) + self.first_head),
+                (H,))
+            dt_bias = self.param(
+                "dt_bias", _inverse_softplus_log_uniform(1e-3, 0.1, 1e-4),
+                (H,))
+            skip = self.param("D", nn.initializers.ones, (H,))
+            scale = self.param("norm", nn.initializers.ones, (inner,))
+            with jax.named_scope("dk_ssd_conv"):
+                xbc = nn.silu(_causal_taps(
+                    zxbcdt[..., inner:2 * inner + 2 * bc],
+                    taps.astype(h.dtype)) + conv_bias.astype(h.dtype))
+            with jax.named_scope("dk_ssd"):
+                x = xbc[..., :inner].reshape(B, L, H, P)
+                dt = jax.nn.softplus(
+                    zxbcdt[..., 2 * inner + 2 * bc:].astype(jnp.float32)
+                    + dt_bias)                                 # [B, L, H]
+                g = -jnp.exp(a_log.astype(jnp.float32)) * dt
+                y, least = chunked_ssd(
+                    xbc[..., inner + bc:].reshape(B, L, G, N),
+                    xbc[..., inner:inner + bc].reshape(B, L, G, N),
+                    (x.astype(jnp.float32) * dt[..., None]).astype(h.dtype),
+                    g)
+                y = y.astype(jnp.float32) + skip.astype(jnp.float32)[:, None] \
+                    * x.astype(jnp.float32)
+                y = y.reshape(B, L, inner) * nn.silu(
+                    zxbcdt[..., :inner].astype(jnp.float32))
+                y = y.reshape(B, L, G, inner // G)
+                y = y * jax.lax.rsqrt(jnp.mean(
+                    jnp.square(y), -1, keepdims=True) + self.rms_eps)
+                y = (y.reshape(B, L, inner) * scale.astype(jnp.float32)
+                     ).astype(h.dtype)
+            _count_delta_rule(self, least, dt, what="dt")
+            return nn.Dense(D, use_bias=False, name="out_proj")(y)
 
 
 class LatentAttention(nn.Module):
@@ -553,6 +661,21 @@ class GatedMLP(nn.Module):
             hidden = ACTIVATIONS[self.activation](proj(self.d_ff, "gate")(g)) \
                 * proj(self.d_ff, "up")(g)
             return proj(g.shape[-1], "down")(hidden)
+
+
+class PlainMLP(nn.Module):
+    """``act(g W_up) W_down``, no bias and no gate: the shared expert of the
+    families whose experts are plain (Nemotron-H's ReLU^2)."""
+
+    d_ff: int
+    activation: str = "relu2"
+
+    @nn.compact
+    def __call__(self, g):
+        with owner("ffn"):
+            hidden = ACTIVATIONS[self.activation](
+                nn.Dense(self.d_ff, use_bias=False, name="up")(g))
+            return nn.Dense(g.shape[-1], use_bias=False, name="down")(hidden)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -648,18 +771,42 @@ class _GatedExperts(nn.Module):
         return grouped(act(grouped(rows, gate)) * grouped(rows, up), down)
 
 
+class _PlainExperts(nn.Module):
+    """``act(g W_up) W_down`` for sorted rows, group by group: two grouped
+    products, no gate."""
+
+    held: int
+    d_model: int
+    d_expert: int
+    activation: str = "relu2"
+
+    @nn.compact
+    def __call__(self, rows, group_sizes):
+        D, F = self.d_model, self.d_expert
+        up = _ExpertBank(self.held, D, F, name="up")().astype(rows.dtype)
+        down = _ExpertBank(self.held, F, D, name="down")().astype(rows.dtype)
+
+        def grouped(a, w):
+            return jax.lax.ragged_dot(a, w, group_sizes,
+                                      preferred_element_type=rows.dtype)
+
+        return grouped(ACTIVATIONS[self.activation](grouped(rows, up)), down)
+
+
 class DroplessExperts(nn.Module):
     """This chip's part of a routed expert layer: experts ``first .. first +
     held - 1`` of those ``experts [T, k]`` names, weighted by ``weights``
     (already normalised over all ``k``, held or not). A token none of whose
     experts is held gets zero. ``activation``: the experts' gate, ``"relu"``
-    (ReGLU) or ``"silu"`` (SwiGLU)."""
+    (ReGLU) or ``"silu"`` (SwiGLU); with ``gated=False`` the plain experts'
+    activation (``"relu2"``: Nemotron-H's ``relu(g W_up)^2 W_down``)."""
 
     first: int
     held: int
     d_model: int
     d_expert: int
     activation: str = "relu"
+    gated: bool = True
 
     @nn.compact
     def __call__(self, x, weights, experts):
@@ -692,7 +839,7 @@ class DroplessExperts(nn.Module):
                             rows.visited_rows(live, N, rows.gather_tile(
                                 N, D, x.dtype)))
             with jax.named_scope("dk_moe_experts"):
-                out = _GatedExperts(
+                out = (_GatedExperts if self.gated else _PlainExperts)(
                     self.held, D, self.d_expert, self.activation,
                     name="experts")(buffer, group_sizes)
             with jax.named_scope("dk_moe_combine"):

@@ -66,6 +66,21 @@ difference that is ``<= 0`` by construction, sub-chunk by sub-chunk (``SUB`` =
 Nothing is clamped: a decay of any strength gives the recurrence's result
 (``tests/test_kimi_linear_ops.py`` holds ``g`` at -1.6 over whole chunks, and
 at -20).
+
+**The state-space case** (Mamba-2's SSD; :func:`chunked_ssd`). A head's state
+``h`` ``[d_k, d_v]`` with one decay a head and no delta rule:
+
+    h_t = e^{g_t} h_{t-1} + k_t u_t^T        o_t = h_t^T q_t
+
+(``q = C``, ``k = B`` read by group, ``u = dt * x``, ``g = dt * A``) is the
+chunked form above with ``T = I`` and ``W = 0``: ``U = u``, ``Qg = q e^G``,
+``Bq = tril((q k^T)_group * Gamma_head)``, ``Kd = k e^{G_C - G}``, ``s =
+e^{G_C}``. There is no triangular inverse and no ``W``: the in-chunk half is
+plain ``jax.numpy`` (:func:`ssd_in_chunk`: one ``[C, C]`` product ``q k^T`` a
+group and chunk, times ``Gamma_ri = e^{G_r - G_i}`` a head, its exponent
+masked to ``-inf`` above the diagonal *before* the exponential, so every
+exponent is ``<= 0``), and the scan pair runs with ``W=None``. A Pallas
+in-chunk pair for it is not built.
 """
 
 from __future__ import annotations
@@ -217,5 +232,65 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int | None = None):
     else:
         *products, last = chunk_products(q, k, v, g, beta, C, min(SUB, C))
     out = scan_chunks(*products)
+    return (jnp.moveaxis(out.reshape(B, H, L, V), 1, 2),
+            jnp.min(jax.lax.stop_gradient(last)))
+
+
+def ssd_in_chunk(q, k, v, g, chunk: int):
+    """The state-space in-chunk half in ``jax.numpy``: ``q, k`` [B, L, G, d_k]
+    at ``G`` groups dividing the ``H`` heads (head ``j`` reads group ``j //
+    (H / G)``), ``v`` [B, L, H, d_v], ``g`` [B, L, H] (``<= 0``). Returns
+    ``(U, Qg, Bq, Kd, s)`` as ``scan_chunks`` reads them with ``W=None``
+    (``U`` float32, the others in ``v``'s dtype but ``s``) and the summed
+    log-decay of each chunk ``[B, H, N]``."""
+    B, L, G, K = k.shape
+    H = v.shape[2]
+    C, N, per = chunk, L // chunk, H // G
+    dt, f32 = v.dtype, jnp.float32
+
+    def chunks(x):  # [B, L, X, ...] -> [B, X, N, C, ...]
+        x = jnp.moveaxis(x, 2, 1)
+        return x.reshape(B, x.shape[1], N, C, *x.shape[3:])
+
+    def by_group(x):  # [B, H, ...] -> [B, G, H / G, ...]
+        return x.reshape(B, G, per, *x.shape[2:])
+
+    q, k, v = chunks(q), chunks(k), chunks(v)
+    Gc = jnp.cumsum(chunks(g.astype(f32)), axis=-1)          # [B, H, N, C]
+    last = Gc[..., -1]                                       # [B, H, N]
+    qk = jnp.einsum("bgnrk,bgnik->bgnri", q.astype(dt), k.astype(dt),
+                    preferred_element_type=f32)              # [B, G, N, C, C]
+    lower = jnp.arange(C)[:, None] >= jnp.arange(C)[None, :]
+    gamma = jnp.exp(jnp.where(lower, Gc[..., :, None] - Gc[..., None, :],
+                              -jnp.inf))                     # [B, H, N, C, C]
+    Bq = qk[:, :, None] * by_group(gamma)
+    Qg = q.astype(f32)[:, :, None] * by_group(jnp.exp(Gc))[..., None]
+    Kd = k.astype(f32)[:, :, None] * by_group(
+        jnp.exp(last[..., None] - Gc))[..., None]
+    s = jnp.broadcast_to(jnp.exp(last)[..., None], (B, H, N, K))
+
+    def heads(x):  # [B, G, H / G, ...] -> [B, H, ...]
+        return x.reshape(B, H, *x.shape[3:]).astype(dt)
+
+    return (v.astype(f32), heads(Qg), heads(Bq), heads(Kd), s), last
+
+
+def chunked_ssd(q, k, v, g, chunk: int | None = None):
+    """``o_t`` of the state-space recurrence (the module's last section) for
+    every position, from a zero state: ``q`` (``C``), ``k`` (``B``) [B, L, G,
+    d_k] at ``G`` groups, ``v`` (``dt * x``) [B, L, H, d_v], ``g`` (``dt *
+    A``) [B, L, H]. The in-chunk half by :func:`ssd_in_chunk`, the scan over
+    chunks by the delta rule's pair with ``W=None``. ``chunk`` as in
+    :func:`chunked_gated_delta_rule`. Returns ``(o [B, L, H, d_v] in v's
+    dtype, the smallest summed log-decay of a chunk)``."""
+    B, L, H, V = v.shape
+    C = chunk_for(L) if chunk is None else chunk
+    if L % C or C & (C - 1):
+        raise ValueError(f"a sequence of {L} in chunks of {C}: a chunk is a "
+                         "power of two and must divide the sequence")
+    if H % k.shape[2]:
+        raise ValueError(f"{k.shape[2]} groups cannot serve {H} heads")
+    (U, Qg, Bq, Kd, s), last = ssd_in_chunk(q, k, v, g, C)
+    out = scan_chunks(U, None, Qg, Bq, Kd, s)
     return (jnp.moveaxis(out.reshape(B, H, L, V), 1, 2),
             jnp.min(jax.lax.stop_gradient(last)))

@@ -35,6 +35,12 @@ saved and 3.2 backward, and read the arrays as they are computed.
 
   each cotangent in its primal's dtype.
 
+* **No ``W``** (``scan_chunks(U, None, ...)``, a state-space layer: ``T =
+  I``, ``W = 0``): the same grid and kernels, traced with ``with_w=False``,
+  read no ``W`` and take ``P = U``; the backward writes no ``dW`` and leaves
+  ``- W^T dP`` out of ``dS``. ``d_v`` (64 there) may differ from ``d_k``
+  (128): a block holds both whole.
+
 **The state is held transposed**, ``St = S^T`` ``[d_v, d_k]``: the decay of a
 chunk is a value a key channel, stored as the ``[1, d_k]`` lanes it is read
 as, and against ``St`` it broadcasts along sublanes as it is (against ``S`` it
@@ -230,10 +236,12 @@ def _tn(a, b, precision=None):  # a^T b: contracts dimension 0 of both
                                precision=precision)
 
 
-def _fwd_kernel(u_ref, w_ref, qg_ref, bq_ref, kd_ref, s_ref, o_ref, *rest,
-                heads: int):
+def _fwd_kernel(*refs, heads: int, with_w: bool):
+    if not with_w:  # no W is read: P = U
+        refs = refs[:1] + (None,) + refs[1:]
+    u_ref, w_ref, qg_ref, bq_ref, kd_ref, s_ref, o_ref, *rest = refs
     *entering, state = rest  # the forward rule's output, then the scratch
-    dt = w_ref.dtype
+    dt = qg_ref.dtype
 
     @pl.when(pl.program_id(1) == 0)
     def _():
@@ -244,16 +252,21 @@ def _fwd_kernel(u_ref, w_ref, qg_ref, bq_ref, kd_ref, s_ref, o_ref, *rest,
         if entering:
             entering[0][h, 0] = St
         Sd = St.astype(dt)
-        P = (u_ref[h, 0] - _nt(w_ref[h, 0], Sd)).astype(dt)  # [C, d_v]
+        if w_ref is None:
+            P = u_ref[h, 0].astype(dt)
+        else:
+            P = (u_ref[h, 0] - _nt(w_ref[h, 0], Sd)).astype(dt)  # [C, d_v]
         o_ref[h, 0] = (_nt(qg_ref[h, 0], Sd)
                        + _nn(bq_ref[h, 0], P)).astype(o_ref.dtype)
         state[h] = s_ref[h, 0] * St + _tn(P, kd_ref[h, 0])
 
 
-def _bwd_kernel(u_ref, w_ref, qg_ref, bq_ref, kd_ref, s_ref, st_ref, do_ref,
-                du_ref, dw_ref, dqg_ref, dbq_ref, dkd_ref, ds_ref, dstate, *,
-                heads: int):
-    dt = w_ref.dtype
+def _bwd_kernel(*refs, heads: int, with_w: bool):
+    if not with_w:  # no W read, no dW written, no W^T dP in dS
+        refs = refs[:1] + (None,) + refs[1:8] + (None,) + refs[8:]
+    (u_ref, w_ref, qg_ref, bq_ref, kd_ref, s_ref, st_ref, do_ref, du_ref,
+     dw_ref, dqg_ref, dbq_ref, dkd_ref, ds_ref, dstate) = refs
+    dt = qg_ref.dtype
 
     @pl.when(pl.program_id(1) == 0)  # the last chunk: the grid runs it first
     def _():
@@ -262,17 +275,26 @@ def _bwd_kernel(u_ref, w_ref, qg_ref, bq_ref, kd_ref, s_ref, st_ref, do_ref,
     for h in range(heads):
         St, dSt = st_ref[h, 0], dstate[h]                    # [d_v, d_k]
         Sd, dSd, dO = St.astype(dt), dSt.astype(dt), do_ref[h, 0]
-        w, qg = w_ref[h, 0], qg_ref[h, 0]
-        P = (u_ref[h, 0] - _nt(w, Sd)).astype(dt)            # [C, d_v]
+        if w_ref is None:
+            qg = qg_ref[h, 0]
+            P = u_ref[h, 0].astype(dt)
+        else:
+            w, qg = w_ref[h, 0], qg_ref[h, 0]
+            P = (u_ref[h, 0] - _nt(w, Sd)).astype(dt)        # [C, d_v]
         dP32 = _tn(bq_ref[h, 0], dO) + _nt(kd_ref[h, 0], dSd)
-        dP = dP32.astype(dt)
+        if w_ref is not None:
+            dP = dP32.astype(dt)
         du_ref[h, 0] = dP32.astype(du_ref.dtype)
-        dw_ref[h, 0] = (-_nn(dP, Sd)).astype(dw_ref.dtype)
+        if w_ref is not None:
+            dw_ref[h, 0] = (-_nn(dP, Sd)).astype(dw_ref.dtype)
         dqg_ref[h, 0] = _nn(dO, Sd).astype(dqg_ref.dtype)
         dbq_ref[h, 0] = _nt(dO, P).astype(dbq_ref.dtype)
         dkd_ref[h, 0] = _nn(P, dSd).astype(dkd_ref.dtype)
         ds_ref[h, 0] = jnp.sum(dSt * St, axis=0, keepdims=True)
-        dstate[h] = _tn(dO, qg) - _tn(dP, w) + s_ref[h, 0] * dSt
+        if w_ref is None:
+            dstate[h] = _tn(dO, qg) + s_ref[h, 0] * dSt
+        else:
+            dstate[h] = _tn(dO, qg) - _tn(dP, w) + s_ref[h, 0] * dSt
 
 
 def _specs(heads: int, C: int, K: int, V: int, chunks: int | None = None):
@@ -307,19 +329,25 @@ def _compiler_kw(interpret: bool,
 _traced_once = functools.partial(jax.jit, inline=True)
 
 
+def _without_w(items, W):
+    """``items`` less its second (``W``'s place) where there is no ``W``."""
+    return list(items) if W is not None else [items[0], *items[2:]]
+
+
 @functools.partial(_traced_once, static_argnames=("heads", "interpret", "save"))
 def _scan_fwd(U, W, Qg, Bq, Kd, s, heads: int, interpret: bool, save: bool):
-    """``O`` ``[rows, N, C, d_v]`` in ``W``'s dtype and, with ``save``, the
-    transposed state entering each chunk ``[rows, N, d_v, d_k]`` float32."""
+    """``O`` ``[rows, N, C, d_v]`` in ``Qg``'s dtype and, with ``save``, the
+    transposed state entering each chunk ``[rows, N, d_v, d_k]`` float32.
+    ``W=None``: ``P = U``, and nothing of ``W`` is read."""
     rows, N, C, V = U.shape
-    K = W.shape[-1]
+    K = Qg.shape[-1]
     *in_specs, entering, out = _specs(heads, C, K, V)
-    out_shape = [jax.ShapeDtypeStruct((rows, N, C, V), W.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((rows, N, C, V), Qg.dtype)]
     if save:
         out_shape.append(jax.ShapeDtypeStruct((rows, N, V, K), jnp.float32))
     call = pl.pallas_call(
-        functools.partial(_fwd_kernel, heads=heads),
-        grid=(rows // heads, N), in_specs=in_specs,
+        functools.partial(_fwd_kernel, heads=heads, with_w=W is not None),
+        grid=(rows // heads, N), in_specs=_without_w(in_specs, W),
         out_specs=[out, entering][:len(out_shape)], out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((heads, V, K), jnp.float32)],
         interpret=interpret, name="dk_kda_scan_fwd",
@@ -327,24 +355,26 @@ def _scan_fwd(U, W, Qg, Bq, Kd, s, heads: int, interpret: bool, save: bool):
     # The kernel's name (the Mosaic call's, on a chip) and the scope of the
     # same name (the interpreted ops', on a CPU) are what a trace finds it by.
     with jax.named_scope("dk_kda_scan_fwd"):
-        return call(U, W, Qg, Bq, Kd, s)
+        return call(*_without_w((U, W, Qg, Bq, Kd, s), W))
 
 
 @functools.partial(_traced_once, static_argnames=("heads", "interpret"))
 def _scan_bwd(U, W, Qg, Bq, Kd, s, entering, dO, heads: int, interpret: bool):
     rows, N, C, V = U.shape
-    K = W.shape[-1]
+    K = Qg.shape[-1]
     specs = _specs(heads, C, K, V, chunks=N)
+    primals = _without_w((U, W, Qg, Bq, Kd, s), W)
     call = pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=heads),
-        grid=(rows // heads, N), in_specs=specs, out_specs=specs[:6],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
-                   for x in (U, W, Qg, Bq, Kd, s)],
+        functools.partial(_bwd_kernel, heads=heads, with_w=W is not None),
+        grid=(rows // heads, N), in_specs=_without_w(specs, W),
+        out_specs=_without_w(specs[:6], W),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in primals],
         scratch_shapes=[pltpu.VMEM((heads, V, K), jnp.float32)],
         interpret=interpret, name="dk_kda_scan_bwd",
         **_compiler_kw(interpret))
     with jax.named_scope("dk_kda_scan_bwd"):
-        return tuple(call(U, W, Qg, Bq, Kd, s, entering, dO))
+        grads = list(call(*primals, entering, dO))
+    return tuple(grads if W is not None else grads[:1] + [None] + grads[1:])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
@@ -372,24 +402,31 @@ def scan_chunks(U, W, Qg, Bq, Kd, shrink, heads: int | None = None,
     ``U``: [B, H, N, C, d_v] float32; ``W``, ``Qg``, ``Kd``: [B, H, N, C,
     d_k] and ``Bq``: [B, H, N, C, C] (lower-triangular) in the products'
     dtype; ``shrink``: [B, H, N, d_k] float32, the decay of a whole chunk.
+    ``W=None`` is the case with no ``W`` (a state-space layer, Mamba-2:
+    ``P = U``), static: no ``W`` is read, no ``W S`` product, no ``dW`` and
+    no ``W^T dP`` in the backward; the counter ``pallas.ssd.scan_calls``
+    counts such calls as they are traced. ``d_v`` may differ from ``d_k``.
     Returns [B, H, N, C, d_v] in the products' dtype. Differentiable in all
-    six. ``heads`` a program follows the shapes (:func:`heads_per_program`);
-    ``interpret=None`` compiles on a TPU and interprets elsewhere."""
+    it is given. ``heads`` a program follows the shapes
+    (:func:`heads_per_program`); ``interpret=None`` compiles on a TPU and
+    interprets elsewhere."""
     interpret = mode.interpret("kda_scan", interpret)
     B, H, N, C, V = U.shape
-    K = W.shape[-1]
+    K = Qg.shape[-1]
     rows = B * H
     if heads is None:
-        heads = heads_per_program(rows, C, K, V, jnp.dtype(W.dtype).itemsize)
+        heads = heads_per_program(rows, C, K, V, jnp.dtype(Qg.dtype).itemsize)
     if rows % heads:
         raise ValueError(f"{heads} heads a program do not divide {B} x {H}")
     from distkeras_tpu import telemetry
 
     telemetry.gauge("pallas.kda.heads_per_program").set(heads)
     telemetry.gauge("pallas.kda.grid_steps").set(rows // heads * N)
+    if W is None:
+        telemetry.counter("pallas.ssd.scan_calls").add(1)
 
     def flat(x):
-        return x.reshape(rows, N, *x.shape[3:])
+        return None if x is None else x.reshape(rows, N, *x.shape[3:])
 
     out = _scan(flat(U), flat(W), flat(Qg), flat(Bq), flat(Kd),
                 shrink.reshape(rows, N, 1, K), heads, interpret)

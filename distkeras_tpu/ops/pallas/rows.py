@@ -34,8 +34,11 @@ tile, ``[T, 1, W]`` of 32-bit words (:func:`_addressable`, kernel
 ``dk_rows_pack``): a float32 row as it is, ``W = D``; a bfloat16 row with
 column ``c`` in the low half of word ``c`` and column ``c + D / 2`` in the
 high half, ``W = D / 2`` (shifts and masks, exact: a bfloat16 is the high
-half of its float32). That pass reads dense tiles and writes as many bytes,
-and like the gather it visits the live tiles only.
+half of its float32). A row of words is whole lane tiles, or Mosaic cannot
+slice it: a width that is not (Nemotron-H's 2688 bfloat16 columns are 1344
+words) is first padded with zero columns to one that is. That pass reads
+dense tiles and writes as many bytes, and like the gather it visits the live
+tiles only.
 
 **The buffer's dead rows.** The gather writes the tiles that hold a live row
 (the first tile always): in them the rows from ``live`` on are zeros. The
@@ -51,8 +54,8 @@ dead rows but must let none of it reach a live row, an output or a gradient;
 two up to 512 rows whose scratch and double-buffered blocks fit
 ``_VMEM_BUDGET``, and never more than the (padded) array: the interpreter,
 which pays by the grid step, gets one step for the tests' sizes. A buffer or
-a token count that is not a whole number of tiles, or a bfloat16 width that
-is odd, is padded here, nowhere else.
+a token count that is not a whole number of tiles, or a width that is not
+whole lane tiles of words, is padded here, nowhere else.
 
 Off a TPU the same kernels run under the Pallas interpreter
 (``ops/pallas/mode.py``).
@@ -181,10 +184,11 @@ def _addressable(x, live, interpret: bool):
     take one row of. Tiles past the one that holds row ``live - 1`` are
     neither read nor written."""
     M, D = x.shape
-    W = _words(D, x.dtype)
-    if x.dtype.itemsize == 2 and D % 2:
-        x = jnp.pad(x, ((0, 0), (0, 1)))
+    whole = _LANES * 4 // x.dtype.itemsize  # columns of a lane tile of words
+    if D % whole:
+        x = jnp.pad(x, ((0, 0), (0, _round_up(D, whole) - D)))
     D = x.shape[1]
+    W = _words(D, x.dtype)
     tile = tile_rows(M, 2 * _lane_bytes(D, x.dtype.itemsize)
                      + 2 * _lane_bytes(W, 4))
     rows = _round_up(M, tile)
@@ -333,6 +337,8 @@ def _combine_kernel(list_s, count_s, live_s, first_v, slot_v, weight_v,
         # A select: scratch past the fetched rows is whatever was there.
         words = jnp.where(row < q, buf[pl.ds(at, chunk), 0, :], 0)
         if exact:
+            if words.shape[1] != D:  # the padding columns
+                words = words[:, :D]
             out[...] += jnp.dot(
                 owns, jax.lax.bitcast_convert_type(words, dtype)
                 .astype(jnp.float32), precision=jax.lax.Precision.HIGHEST,
@@ -343,6 +349,8 @@ def _combine_kernel(list_s, count_s, live_s, first_v, slot_v, weight_v,
         for half, at_column in ((words << 16, 0),
                                 (words & jnp.uint32(_HIGH), W)):
             width = min(W, D - at_column)
+            if width <= 0:  # the high halves hold padding columns alone
+                continue
             part = jnp.dot(
                 owns, jax.lax.bitcast_convert_type(half, jnp.float32)
                 .astype(jnp.bfloat16), preferred_element_type=jnp.float32)

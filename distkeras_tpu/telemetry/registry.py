@@ -197,6 +197,22 @@ _METRICS = [
     _m("gdn.mean_beta", "gauge", "models",
        "Last round's mean `beta` (the delta rule's write strength, in (0, "
        "1)) over the `gdn` layers' tokens and value heads."),
+    _m("ssd.chunk", "gauge", "models",
+       "Positions a chunk of the Mamba-2 layers' chunked form (Nemotron-H's "
+       "`mamba` layers: the in-chunk half in `jax.numpy`, the scan `kda` runs "
+       "with no `W`), set as the model is traced "
+       "(`ops/delta_rule.py::chunk_for`)."),
+    _m("ssd.state_bytes", "gauge", "models",
+       "Bytes of the state the `mamba` layers carry a step: layers x batch x "
+       "held heads x state_dim x head_dim float32, set as the model is "
+       "traced."),
+    _m("ssd.min_chunk_decay", "gauge", "models",
+       "Last round's smallest summed log-decay `sum dt A` of a chunk over the "
+       "`mamba` layers (one decay a head; <= 0; the chunked form never takes "
+       "`e^-G`)."),
+    _m("ssd.mean_dt", "gauge", "models",
+       "Last round's mean step `dt = softplus(dt + dt_bias)` over the `mamba` "
+       "layers' tokens and held heads."),
     _m("moe.shared_gate_mean", "gauge", "models",
        "Last round's mean of the shared expert's gate `sigmoid(g W_sg)` over "
        "the layers' tokens (Qwen3-Next; models whose shared expert has no "
@@ -221,6 +237,10 @@ _METRICS = [
        "traced (`ops/pallas/delta_rule.py::gdn_chunk_heads_per_program`: "
        "whole groups of one batch row, whole lane tiles at the key and "
        "value heads, the most up to 8 that fit VMEM)."),
+    _m("pallas.ssd.scan_calls", "counter", "kernels",
+       "Calls of the delta rule's scan pair (`dk_kda_scan_*`) traced with no "
+       "`W` (`scan_chunks(U, None, ...)`: a state-space layer, Mamba-2), "
+       "counted as a call is traced; the delta rule's calls leave it alone."),
     _m("pallas.kda.grid_steps", "gauge", "kernels",
        "Grid steps of the delta rule's kernel call traced last "
        "(`dk_kda_scan_*`: batch x heads / heads a program, times the "

@@ -16,6 +16,7 @@ import distkeras_tpu
 from distkeras_tpu import telemetry, workers
 from distkeras_tpu.models.kimi_linear import KimiLinearLM
 from distkeras_tpu.models.lfm2 import Lfm2MoeLM
+from distkeras_tpu.models.nemotron_h import NemotronHLM
 from distkeras_tpu.models.resnet import ResNet
 from distkeras_tpu.models.smallthinker import SmallThinkerLM
 from distkeras_tpu.models.transformer import TransformerLM
@@ -56,6 +57,14 @@ FAMILIES = {
                              v_head_dim=16, kv_lora_rank=24, d_ff=48,
                              num_dense_layers=1,
                              layer_types=("kda", "mla", "kda"), **SHARE),
+        TOKENS, LM, {"norm", "mixer", "ffn"}),
+    "nemotron_h": (
+        lambda: NemotronHLM(num_layers=3, num_kv_heads=2, head_dim=8,
+                            heads_held=(0, 2), mamba_num_heads=8,
+                            mamba_head_dim=8, mamba_heads_held=(0, 4),
+                            n_groups=4, ssm_state_size=16, d_shared=32,
+                            layer_types=("moe", "mamba", "attention"),
+                            **SHARE),
         TOKENS, LM, {"norm", "mixer", "ffn"}),
     "resnet": (
         lambda: ResNet(stage_sizes=(1, 1), base_features=8, num_outputs=10,

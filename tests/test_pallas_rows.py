@@ -86,10 +86,11 @@ def test_combine_is_the_segment_sum_of_the_live_rows(live, dtype):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(again))
 
 
-@pytest.mark.parametrize("width", [7, 130])
+@pytest.mark.parametrize("width", [7, 130, 2688])
 def test_an_odd_width_and_the_default_tile(width):
-    """bfloat16 rows of an odd width are padded to whole words inside; the
-    default tile makes one program of the tests' sizes."""
+    """bfloat16 rows of an odd width, or of words that are not whole lane
+    tiles (2688 columns: 1344 words), are padded inside; the default tile
+    makes one program of the tests' sizes."""
     token, slot = routing(3)
     x, buffer = values((T, width), jnp.bfloat16), values((N, width),
                                                          jnp.bfloat16, 2)
@@ -245,8 +246,10 @@ def one_chip():
 
 @pytest.mark.parametrize("tokens, dtype, k, width", [
     (16384, jnp.bfloat16, 6, 2560), (128, jnp.float32, 6, 2560),
-    (128, jnp.bfloat16, 6, 2560), (16384, jnp.bfloat16, 4, 2048)],
-    ids=["the-cell", "build-float32", "build-bfloat16", "the-lfm2-cell"])
+    (128, jnp.bfloat16, 6, 2560), (16384, jnp.bfloat16, 4, 2048),
+    (8192, jnp.bfloat16, 6, 2688), (8192, jnp.float32, 6, 2688)],
+    ids=["the-cell", "build-float32", "build-bfloat16", "the-lfm2-cell",
+         "the-nemotron-cell", "nemotron-float32"])
 def test_the_kernels_compile_for_a_v5e(one_chip, tokens, dtype, k, width):
     """Mosaic takes both kernels at the published widths: the benchmark's
     steps (16,384 tokens; k = 6 of 2,560 in SmallThinker's cell, k = 4 of
@@ -407,3 +410,50 @@ def test_the_gdn_pair_compiles_for_a_v5e_at_the_cells_shape(one_chip,
                if "custom-call(" in line and "_chunk_" in line)
     assert not re.search(r"f32\[2,8192,32,128\]", text)
     assert not re.search(r"bf16\[2,8192,16,2,128\]", text)
+
+
+def test_the_ssd_scan_compiles_for_a_v5e_at_the_cells_shape(one_chip,
+                                                            monkeypatch):
+    """Mamba-2's chunked form at the Nemotron-3-Nano cell's step: ``C``,
+    ``B`` ``[1, 8192, 4, 128]`` at the held groups, ``dt x`` at 32 heads of
+    64, one decay a head, bfloat16, under ``jax.checkpoint`` and
+    ``jax.value_and_grad``: Mosaic takes the scan pair with no ``W`` at
+    values of 64 beside a state of 128, four calls under ``dk_ssd`` (forward,
+    recomputed, backward: the in-chunk half is ``jax.numpy``), the forward
+    kernel given five operands (no ``W``). Kept in this file for the reason
+    above."""
+    import re
+
+    from distkeras_tpu.ops.delta_rule import chunked_ssd
+    from distkeras_tpu.ops.pallas import mode
+
+    monkeypatch.setattr(mode, "compiles", lambda: True)  # headed for the chip
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    @jax.checkpoint
+    def layer(*a):
+        with jax.named_scope("dk_ssd"):
+            return chunked_ssd(*a)[0]
+
+    def loss(*a):
+        with jax.named_scope("model"):  # a transform renames the outermost
+            return jnp.sum(jnp.square(layer(*a).astype(jnp.float32)))
+
+    groups, values = (1, 8192, 4, 128), (1, 8192, 32, 64)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(
+        shape(*groups), shape(*groups), shape(*values),
+        shape(*values[:3], dtype=jnp.float32)).compile().as_text()
+    calls = re.findall(r"= [^\n]*custom-call\([^\n]*"
+                       r'op_name="[^"]*/(dk_kda_scan_\w+)/', text)
+    assert sorted(calls) == ["dk_kda_scan_bwd", "dk_kda_scan_fwd",
+                             "dk_kda_scan_fwd"], calls
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert all("/dk_ssd/" in line for line in text.splitlines()
+               if "custom-call(" in line and "dk_kda_scan" in line)
+    forward = [line for line in text.splitlines()
+               if "custom-call(" in line and "dk_kda_scan_fwd" in line]
+    assert forward and all(
+        line.split("custom-call(")[1].split(")")[0].count("%") == 5
+        for line in forward), forward
