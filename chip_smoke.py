@@ -76,6 +76,7 @@ class Sizes:
     gdn: tuple        # (B, L, key heads, value heads, Dh) of Gated DeltaNet
     flash_gqa: tuple  # (B, L, H, K/V heads, Dh) of grouped attention
     ssd: tuple        # (B, L, heads, head_dim, groups, state) of Mamba-2
+    experts: tuple    # (buffer rows, D, F, held experts, live rows)
 
 
 FULL = Sizes(layers=8, d_model=1024, heads=16, d_ff=4096, vocab=32768,
@@ -89,7 +90,8 @@ FULL = Sizes(layers=8, d_model=1024, heads=16, d_ff=4096, vocab=32768,
              mlp_rows=8192,
              gdn=(2, 8192, 16, 32, 128),       # the qwen3next cell's step
              flash_gqa=(1, 8192, 16, 2, 256),  # its gated attention's heads
-             ssd=(1, 8192, 32, 64, 4, 128))    # the nemotron3nano cell's step
+             ssd=(1, 8192, 32, 64, 4, 128),    # the nemotron3nano cell's step
+             experts=(49152, 2688, 1856, 8, 3072))  # and its expert layer
 TOY = Sizes(layers=1, d_model=64, heads=2, d_ff=128, vocab=256,
             seq=128, lm_batch=2, lm_window=2, lm_rounds=3, tp_layers=1,
             cnn_batch=16, cnn_window=2,
@@ -97,7 +99,7 @@ TOY = Sizes(layers=1, d_model=64, heads=2, d_ff=128, vocab=256,
             groupnorm=(2, 8, 8, 64, 32), fold=(70, 33), rows=(64, 2, 32, 16),
             kda=(2, 128, 2, 16),
             mlp_rows=2048, gdn=(2, 128, 2, 4, 16), flash_gqa=(1, 128, 4, 2, 16),
-            ssd=(1, 128, 4, 16, 2, 32))
+            ssd=(1, 128, 4, 16, 2, 32), experts=(256, 32, 464, 4, 100))
 
 
 class SmokeFailure(AssertionError):
@@ -703,6 +705,61 @@ def check_ssd(shape) -> dict:
                     _fwd_and_grads(recurrence, cotangent)(*args), tol=2e-2)
 
 
+def check_experts(shape) -> dict:
+    """Nemotron-3-Nano's plain expert bank (``blocks._PlainExperts``, whose
+    grouped products run at ``blocks.padded_expert_width``) at the cell's
+    step: a buffer of ``rows`` x ``D`` whose live rows ``groups`` experts
+    share by a seeded draw, expert width ``F``; forward and backward against
+    the same pair at the published width, on the live rows. Each is timed as
+    the median of ten calls and printed; no time is held to a bound."""
+    import jax
+    import jax.numpy as jnp
+
+    from distkeras_tpu.models.blocks import _PlainExperts, padded_expert_width
+
+    rows, D, F, groups, live = shape
+    rng = np.random.default_rng(6)
+    sizes = jnp.asarray(rng.multinomial(live, [1 / groups] * groups),
+                        jnp.int32)
+    ks = jax.random.split(jax.random.key(6), 4)
+    x = jax.random.normal(ks[0], (rows, D), jnp.bfloat16)
+    up = (jax.random.normal(ks[1], (groups, D, F)) * D ** -0.5).astype(
+        jnp.bfloat16)
+    down = (jax.random.normal(ks[2], (groups, F, D)) * F ** -0.5).astype(
+        jnp.bfloat16)
+    cotangent = jax.random.normal(ks[3], (rows, D), jnp.bfloat16)
+    bank = _PlainExperts(groups, D, F)
+
+    def published(rows, up, down):
+        def grouped(a, w):
+            return jax.lax.ragged_dot(a, w, sizes,
+                                      preferred_element_type=rows.dtype)
+
+        return grouped(jnp.square(jax.nn.relu(grouped(rows, up))), down)
+
+    def padded(rows, up, down):
+        return bank.apply({"params": {"up": {"kernel": up},
+                                      "down": {"kernel": down}}}, rows, sizes)
+
+    ms, got = {}, {}
+    for name, fn in (("published", published), ("padded", padded)):
+        run = _fwd_and_grads(fn, cotangent)
+        jax.block_until_ready(run(x, up, down))
+        times = []
+        for _ in range(10):
+            t0 = time.perf_counter()
+            out = jax.block_until_ready(run(x, up, down))
+            times.append(time.perf_counter() - t0)
+        ms[name] = round(1e3 * float(np.median(times)), 3)
+        got[name] = {"out": out["out"][:live],
+                     "d_rows": out["grads"][0][:live],
+                     "d_up": out["grads"][1], "d_down": out["grads"][2]}
+    print(f"[kernels] experts {shape}: forward and backward ms {ms} (padded "
+          f"to {padded_expert_width(F)})", flush=True)
+    return dict(_compare("experts", got["padded"], got["published"],
+                         tol=1e-2), ms=ms, width=padded_expert_width(F))
+
+
 def phase_kernels(sz: Sizes) -> dict:
     out = {}
     flash = [(f"flash_attention_L{shape[1]}", check_flash, shape)
@@ -721,7 +778,8 @@ def phase_kernels(sz: Sizes) -> dict:
                                ("rows", check_rows, sz.rows),
                                ("kda_scan", check_kda_scan, sz.kda),
                                ("kda_chunk", check_kda_chunk, sz.kda),
-                               ("ssd", check_ssd, sz.ssd)):
+                               ("ssd", check_ssd, sz.ssd),
+                               ("experts", check_experts, sz.experts)):
         t0 = time.perf_counter()
         out[name] = dict(check(shape), shape=list(shape),
                          seconds=round(time.perf_counter() - t0, 2))

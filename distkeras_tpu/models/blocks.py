@@ -747,9 +747,47 @@ class _ExpertBank(nn.Module):
                 batch_axis=(0,)), (self.held, self.d_in, self.d_out))
 
 
+#: the widest window the compiler's grouped-matmul kernel
+#: (``jax.lax.ragged_dot`` on a TPU) steps along a dimension it divides
+EXPERT_TILE = 512
+
+
+def padded_expert_width(width: int) -> int:
+    """The expert width the grouped products run at: ``width`` rounded up to
+    a multiple of 512 where that adds at most an eighth of it, else
+    ``width``. The compiler's ragged-dot kernel tiles a dimension by the
+    largest of 512, 256 and 128 that divides it, and by 128 (the last tile
+    masked) where none does; at 128 a block of rows takes four grid steps
+    where 512 takes one, and the kernel's cost a step, not the MXU, sets the
+    pace. Nemotron-3-Nano's 1,856 runs at 2,048 (+10.3 % products, on
+    zeros); 768 would need +33 % and tiles at 256 as it is."""
+    padded = -(-width // EXPERT_TILE) * EXPERT_TILE
+    return padded if 8 * (padded - width) <= width else width
+
+
+def _at_padded_width(widen, down):
+    """The banks at :func:`padded_expert_width`: ``widen`` (those that make
+    the ``[rows, F]`` activations) with zero columns, ``down`` with zero
+    rows. Zero columns give zero activations (``relu``, ``relu2`` and
+    ``silu`` keep 0 at 0, and a gate multiplies a zero ``up``), zero rows
+    add exact zeros: the same function. The pad's transpose is a slice,
+    so the parameters and their gradients keep ``[held, D, F]``. The counter
+    ``moe.padded_expert_layers`` counts the layers traced padded."""
+    F = down.shape[1]
+    extra = padded_expert_width(F) - F
+    if not extra:
+        return widen, down
+    from distkeras_tpu import telemetry
+
+    telemetry.counter("moe.padded_expert_layers").add(1)
+    return ([jnp.pad(w, ((0, 0), (0, 0), (0, extra))) for w in widen],
+            jnp.pad(down, ((0, 0), (0, extra), (0, 0))))
+
+
 class _GatedExperts(nn.Module):
     """``(act(g W_gate) * (g W_up)) W_down`` for sorted rows, group by
-    group. No loop: three grouped products the trace can see."""
+    group. No loop: three grouped products the trace can see, at
+    :func:`padded_expert_width`."""
 
     held: int
     d_model: int
@@ -762,6 +800,7 @@ class _GatedExperts(nn.Module):
         gate = _ExpertBank(self.held, D, F, name="gate")().astype(rows.dtype)
         up = _ExpertBank(self.held, D, F, name="up")().astype(rows.dtype)
         down = _ExpertBank(self.held, F, D, name="down")().astype(rows.dtype)
+        (gate, up), down = _at_padded_width((gate, up), down)
 
         def grouped(a, w):
             return jax.lax.ragged_dot(a, w, group_sizes,
@@ -773,7 +812,7 @@ class _GatedExperts(nn.Module):
 
 class _PlainExperts(nn.Module):
     """``act(g W_up) W_down`` for sorted rows, group by group: two grouped
-    products, no gate."""
+    products, no gate, at :func:`padded_expert_width`."""
 
     held: int
     d_model: int
@@ -785,6 +824,7 @@ class _PlainExperts(nn.Module):
         D, F = self.d_model, self.d_expert
         up = _ExpertBank(self.held, D, F, name="up")().astype(rows.dtype)
         down = _ExpertBank(self.held, F, D, name="down")().astype(rows.dtype)
+        (up,), down = _at_padded_width((up,), down)
 
         def grouped(a, w):
             return jax.lax.ragged_dot(a, w, group_sizes,

@@ -167,6 +167,12 @@ _METRICS = [
        "unbiased top-k would not have chosen: what the router's selection "
        "bias moved (`blocks.route_sigmoid_bias_top_k` marks them; models "
        "whose router has no bias never set it)."),
+    _m("moe.padded_expert_layers", "counter", "models",
+       "Expert layers traced with their grouped products at a padded width "
+       "(`models/blocks.py::padded_expert_width`: the expert width rounded "
+       "up to a multiple of 512 where that adds at most an eighth; "
+       "Nemotron-3-Nano's 1,856 runs at 2,048), counted as a layer is "
+       "traced; 0 where every width is left alone."),
     # -- the delta rule ---------------------------------------------------
     _m("kda.chunk", "gauge", "models",
        "Positions a chunk of Kimi Delta Attention's chunked scan, set as the "

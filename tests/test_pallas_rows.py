@@ -457,3 +457,65 @@ def test_the_ssd_scan_compiles_for_a_v5e_at_the_cells_shape(one_chip,
     assert forward and all(
         line.split("custom-call(")[1].split(")")[0].count("%") == 5
         for line in forward), forward
+
+
+def ragged_dot_windows(text):
+    """For each ``ragged-dot-none`` call of a compiled program (the
+    compiler's grouped-matmul kernel): ``(shape, window)`` of its ``lhs``,
+    ``rhs`` and output, the windows read from the Mosaic body the call
+    carries."""
+    import base64
+    import re
+
+    calls = []
+    for line in text.splitlines():
+        m = re.match(r"\s*%?ragged-dot-none[.\d]* = \w+\[([\d,]+)\]", line)
+        if not m:
+            continue
+        operands = re.search(r"operand_layout_constraints=\{((?:[^{}]|"
+                             r"\{[^{}]*\})*)\}", line).group(1)
+        shapes = re.findall(r"(?:bf16|f32)\[([\d,]+)\]", operands)[-2:]
+        body = base64.b64decode(re.search(r'"body":"([^"]+)"', line)
+                                .group(1)).decode()
+        windows = re.findall(r"window_bounds = array<i64: ([\d, ]+)>", body)
+        assert len(windows) == 3, windows
+        calls.append([(tuple(map(int, s.split(","))),
+                       tuple(map(int, w.split(","))))
+                      for s, w in zip(shapes + [m.group(1)], windows)])
+    return calls
+
+
+@pytest.mark.parametrize("rule", ["padded", "published"])
+def test_the_expert_pair_compiles_at_whole_512_tiles_for_a_v5e(one_chip,
+                                                               monkeypatch,
+                                                               rule):
+    """Nemotron-3-Nano's plain experts at the cell's step (a buffer of
+    49,152 rows of 2,688, 8 held experts, expert width 1,856), forward and
+    backward: the six grouped products run at the padded width 2,048 and no
+    Mosaic window along it is narrower than 512. With the rule held off the
+    same program steps 1,856 by windows of 128: the tiling the rule rests
+    on, by divisibility. Kept in this file for the reason above."""
+    rows_, D, F, held = 49152, 2688, 1856, 8
+    if rule == "published":
+        monkeypatch.setattr(blocks, "padded_expert_width", lambda width: width)
+    width = blocks.padded_expert_width(F)
+    bank = blocks._PlainExperts(held, D, F)
+    buffer = jax.ShapeDtypeStruct((rows_, D), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(bank.init, jax.random.key(0), buffer, sizes))
+
+    def loss(params, buffer, sizes):
+        return jnp.sum(bank.apply(params, buffer, sizes).astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        params, buffer, sizes).compile().as_text()
+    calls = ragged_dot_windows(text)
+    assert len(calls) == 6, calls
+    for call in calls:
+        dims = [d for shape, _ in call for d in shape]
+        assert width in dims and (width == F or F not in dims), call
+        along = [w for shape, window in call
+                 for d, w in zip(shape, window) if d == width]
+        assert along and min(along) == (512 if width != F else 128), call
