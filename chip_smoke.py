@@ -649,9 +649,58 @@ def check_kda_chunk(shape, key_heads=None) -> dict:
                     _fwd_and_grads(form, cotangents)(*args), tol=2e-2)
 
 
+def check_ssd_chunk(shape) -> dict:
+    """Mamba-2's in-chunk half (``ops/pallas/delta_rule.py``: the kernels
+    ``dk_ssd_chunk_fwd`` and ``dk_ssd_chunk_bwd``) against the kept
+    ``jax.numpy`` form (``ops/delta_rule.py::ssd_in_chunk``) with JAX's
+    derivative, the five outputs and the cotangents of ``C``, ``B`` (at the
+    groups), ``dt x`` and the decay, as the model hands them (``C``, ``B``,
+    ``dt x`` in bfloat16, ``dt`` log-uniform in [1e-3, 1e-1] and ``A =
+    -(1..heads)``). Each side is timed as the median of ten calls, forward
+    and backward, and printed; no time is held to a bound."""
+    import jax
+    import jax.numpy as jnp
+
+    from distkeras_tpu.ops.delta_rule import chunk_for, ssd_in_chunk
+    from distkeras_tpu.ops.pallas.delta_rule import ssd_chunk_products
+
+    B, L, H, P, G, N = shape
+    C, bf = chunk_for(L), jnp.bfloat16
+    ks = jax.random.split(jax.random.key(7), 9)
+    dt = jnp.exp(jax.random.uniform(ks[0], (B, L, H), minval=jnp.log(1e-3),
+                                    maxval=jnp.log(1e-1)))
+    args = ((jax.random.normal(ks[1], (B, L, G, N)) * N ** -0.5).astype(bf),
+            (jax.random.normal(ks[2], (B, L, G, N)) * N ** -0.5).astype(bf),
+            (jax.random.normal(ks[3], (B, L, H, P)) * dt[..., None]).astype(bf),
+            dt * -jnp.arange(1, H + 1, dtype=jnp.float32))
+
+    def form(*a):
+        return ssd_in_chunk(*a, C)[0]
+
+    def pair(*a):
+        return ssd_chunk_products(*a, C)[0]
+
+    cotangents = tuple(jax.random.normal(k, o.shape) for k, o in zip(
+        ks[4:], jax.eval_shape(form, *args)))
+    ms, got = {}, {}
+    for name, fn in (("pair", pair), ("jax_numpy", form)):
+        run = _fwd_and_grads(fn, cotangents)
+        got[name] = jax.block_until_ready(run(*args))
+        times = []
+        for _ in range(10):
+            t0 = time.perf_counter()
+            jax.block_until_ready(run(*args))
+            times.append(time.perf_counter() - t0)
+        ms[name] = round(1e3 * float(np.median(times)), 3)
+    print(f"[kernels] ssd_chunk {shape}: forward and backward ms {ms}",
+          flush=True)
+    return dict(_compare("ssd_chunk", got["pair"], got["jax_numpy"],
+                         tol=2e-2), ms=ms)
+
+
 def check_ssd(shape) -> dict:
     """Mamba-2's chunked form (``ops/delta_rule.py::chunked_ssd``: the
-    in-chunk half in ``jax.numpy``, the scan pair ``dk_kda_scan_*`` with no
+    in-chunk pair ``dk_ssd_chunk_*``, the scan pair ``dk_kda_scan_*`` with no
     ``W``) as the model hands it its inputs (``C``, ``B`` at the groups,
     ``dt * x`` and the products in bfloat16) against the position-at-a-time
     recurrence in float32 with exact matmuls, the outputs and the cotangents
@@ -779,6 +828,7 @@ def phase_kernels(sz: Sizes) -> dict:
                                ("kda_scan", check_kda_scan, sz.kda),
                                ("kda_chunk", check_kda_chunk, sz.kda),
                                ("ssd", check_ssd, sz.ssd),
+                               ("ssd_chunk", check_ssd_chunk, sz.ssd),
                                ("experts", check_experts, sz.experts)):
         t0 = time.perf_counter()
         out[name] = dict(check(shape), shape=list(shape),

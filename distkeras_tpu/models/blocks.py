@@ -520,8 +520,8 @@ class Mamba2Mixer(nn.Module):
       sequence starts: ``h_t = e^{dt_t A} h_{t-1} + dt_t B_t x_t^T``, ``y_t =
       h_t^T C_t + D x_t``, computed in the chunked form of
       ``ops/delta_rule.py`` (:func:`~distkeras_tpu.ops.delta_rule.
-      chunked_ssd`: the in-chunk half in ``jax.numpy``, the delta rule's
-      scan pair with ``W=None``);
+      chunked_ssd`: the in-chunk kernel pair ``dk_ssd_chunk_*``, the delta
+      rule's scan pair with ``W=None``);
     * ``y = RMSNorm_group(y * SiLU(z)) * w`` over ``num_groups`` groups of
       heads (one weight of ``num_heads * head_dim``), then ``W_out``.
 

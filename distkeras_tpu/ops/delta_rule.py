@@ -76,11 +76,11 @@ at -20).
 chunked form above with ``T = I`` and ``W = 0``: ``U = u``, ``Qg = q e^G``,
 ``Bq = tril((q k^T)_group * Gamma_head)``, ``Kd = k e^{G_C - G}``, ``s =
 e^{G_C}``. There is no triangular inverse and no ``W``: the in-chunk half is
-plain ``jax.numpy`` (:func:`ssd_in_chunk`: one ``[C, C]`` product ``q k^T`` a
-group and chunk, times ``Gamma_ri = e^{G_r - G_i}`` a head, its exponent
-masked to ``-inf`` above the diagonal *before* the exponential, so every
-exponent is ``<= 0``), and the scan pair runs with ``W=None``. A Pallas
-in-chunk pair for it is not built.
+a pair of its own (``dk_ssd_chunk_*``, :func:`~distkeras_tpu.ops.pallas.
+delta_rule.ssd_chunk_products`: one ``[C, C]`` product ``q k^T`` a group and
+chunk, times ``Gamma_ri = e^{G_r - G_i}`` a head, masked to ``-inf`` above the
+diagonal *before* the exponential: every exponent ``<= 0``), the scan pair
+runs with ``W=None``, and :func:`ssd_in_chunk` is that half in ``jax.numpy``.
 """
 
 from __future__ import annotations
@@ -88,9 +88,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from distkeras_tpu.ops.pallas.delta_rule import (chunk_products,
-                                                   gdn_chunk_products,
-                                                   scan_chunks)
+from distkeras_tpu.ops.pallas.delta_rule import (
+    chunk_products, gdn_chunk_products, scan_chunks,
+    ssd_chunk_products)
 
 #: positions a chunk, where the sequence allows it, and rows a sub-chunk
 CHUNK, SUB = 64, 16
@@ -237,12 +237,16 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int | None = None):
 
 
 def ssd_in_chunk(q, k, v, g, chunk: int):
-    """The state-space in-chunk half in ``jax.numpy``: ``q, k`` [B, L, G, d_k]
-    at ``G`` groups dividing the ``H`` heads (head ``j`` reads group ``j //
-    (H / G)``), ``v`` [B, L, H, d_v], ``g`` [B, L, H] (``<= 0``). Returns
-    ``(U, Qg, Bq, Kd, s)`` as ``scan_chunks`` reads them with ``W=None``
-    (``U`` float32, the others in ``v``'s dtype but ``s``) and the summed
-    log-decay of each chunk ``[B, H, N]``."""
+    """The state-space in-chunk half in ``jax.numpy``, as it ran until the
+    kernel pair ``dk_ssd_chunk_*`` (:func:`~distkeras_tpu.ops.pallas.
+    delta_rule.ssd_chunk_products`) took its place: **no model path reaches
+    this**; tests and ``chip_smoke.py`` hold the pair, and its written
+    backward against JAX's derivative of this, to it. ``q, k`` [B, L, G,
+    d_k] at ``G`` groups dividing the ``H`` heads (head ``j`` reads group
+    ``j // (H / G)``), ``v`` [B, L, H, d_v], ``g`` [B, L, H] (``<= 0``).
+    Returns ``(U, Qg, Bq, Kd, s)`` as ``scan_chunks`` reads them with
+    ``W=None`` (``U`` float32, the others in ``v``'s dtype but ``s``) and the
+    summed log-decay of each chunk ``[B, H, N]``."""
     B, L, G, K = k.shape
     H = v.shape[2]
     C, N, per = chunk, L // chunk, H // G
@@ -279,18 +283,18 @@ def chunked_ssd(q, k, v, g, chunk: int | None = None):
     """``o_t`` of the state-space recurrence (the module's last section) for
     every position, from a zero state: ``q`` (``C``), ``k`` (``B``) [B, L, G,
     d_k] at ``G`` groups, ``v`` (``dt * x``) [B, L, H, d_v], ``g`` (``dt *
-    A``) [B, L, H]. The in-chunk half by :func:`ssd_in_chunk`, the scan over
-    chunks by the delta rule's pair with ``W=None``. ``chunk`` as in
-    :func:`chunked_gated_delta_rule`. Returns ``(o [B, L, H, d_v] in v's
-    dtype, the smallest summed log-decay of a chunk)``."""
+    A``) [B, L, H]. The in-chunk half by the kernel pair ``dk_ssd_chunk_*``
+    (:func:`~distkeras_tpu.ops.pallas.delta_rule.ssd_chunk_products`; ``U``
+    in ``v``'s dtype), the scan over chunks by the delta rule's pair with
+    ``W=None``. ``chunk`` as in :func:`chunked_gated_delta_rule`. Returns
+    ``(o [B, L, H, d_v] in v's dtype, the smallest summed log-decay of a
+    chunk)``."""
     B, L, H, V = v.shape
     C = chunk_for(L) if chunk is None else chunk
     if L % C or C & (C - 1):
         raise ValueError(f"a sequence of {L} in chunks of {C}: a chunk is a "
                          "power of two and must divide the sequence")
-    if H % k.shape[2]:
-        raise ValueError(f"{k.shape[2]} groups cannot serve {H} heads")
-    (U, Qg, Bq, Kd, s), last = ssd_in_chunk(q, k, v, g, C)
+    (U, Qg, Bq, Kd, s), last = ssd_chunk_products(q, k, v, g, C)
     out = scan_chunks(U, None, Qg, Bq, Kd, s)
     return (jnp.moveaxis(out.reshape(B, H, L, V), 1, 2),
             jnp.min(jax.lax.stop_gradient(last)))

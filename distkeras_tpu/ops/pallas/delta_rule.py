@@ -1,9 +1,9 @@
-"""The gated delta rule's chunked form as Pallas TPU kernel pairs: the
-in-chunk half with a decay a channel (:func:`chunk_products`) or a decay a
-value head (:func:`gdn_chunk_products`), and the scan over chunks
-(:func:`scan_chunks`) both feed, each under one ``jax.custom_vjp`` with a
-written backward. ``ops/delta_rule.py`` has the mathematics, picks the
-in-chunk pair by the rank of the decay and calls them in turn.
+"""The gated delta rule's chunked form as Pallas TPU kernel pairs, each
+under one ``jax.custom_vjp`` with a written backward: the in-chunk half
+with a decay a channel (:func:`chunk_products`), with a decay a value head
+(:func:`gdn_chunk_products`) or of a state-space layer (the last section,
+:func:`ssd_chunk_products`), and the scan over chunks (:func:`scan_chunks`)
+all three feed. ``ops/delta_rule.py`` has the mathematics and calls them.
 
 **The scan over chunks.** What is sequential: for a head and chunk ``n``, from
 ``S = 0`` before chunk 0,
@@ -176,9 +176,9 @@ lane tiles at the key and the value heads; 8 value heads over 4 key heads at
 the cell's shape (gauge ``pallas.gdn.chunk_heads_per_program``; counter
 ``pallas.gdn.chunk_calls`` counts the pair's traced calls).
 
-All six kernels keep the caller's named scope: forward, recomputed and
-backward calls carry ``dk_kda`` (or ``dk_gdn``) in their ``op_name``. Off a
-TPU the same kernels run under the Pallas interpreter
+Every kernel keeps the caller's named scope: forward, recomputed and
+backward calls carry ``dk_kda`` (``dk_gdn``, ``dk_ssd``) in their
+``op_name``. Off a TPU the same kernels run under the Pallas interpreter
 (``ops/pallas/mode.py``).
 """
 
@@ -399,8 +399,8 @@ _scan.defvjp(_scan_fwd_rule, _scan_bwd_rule)
 def scan_chunks(U, W, Qg, Bq, Kd, shrink, heads: int | None = None,
                 interpret: bool | None = None):
     """``O`` of the three lines above for every chunk, from a zero state.
-    ``U``: [B, H, N, C, d_v] float32; ``W``, ``Qg``, ``Kd``: [B, H, N, C,
-    d_k] and ``Bq``: [B, H, N, C, C] (lower-triangular) in the products'
+    ``U``: [B, H, N, C, d_v] float32 (``v``'s dtype, a state-space layer);
+    ``W, Qg, Kd``: [.., d_k] and ``Bq``: [.., C, C] (lower) in the products'
     dtype; ``shrink``: [B, H, N, d_k] float32, the decay of a whole chunk.
     ``W=None`` is the case with no ``W`` (a state-space layer, Mamba-2:
     ``P = U``), static: no ``W`` is read, no ``W S`` product, no ``dW`` and
@@ -1242,3 +1242,316 @@ def gdn_chunk_products(q, k, v, g, beta, chunk: int,
     last = jnp.moveaxis(jnp.sum(g.reshape(B, N, chunk, Hv), 2), 1, 2)
     return tuple(x.reshape(B, Hv, N, *x.shape[2:]) for x in products[:5]) + (
         products[5].reshape(B, Hv, N, K), last)
+
+
+# -- the state-space in-chunk half (Mamba-2) -----------------------------------
+
+def ssd_chunks_per_program(chunks: int, chunk: int) -> int:
+    """Chunks one program of ``dk_ssd_chunk_*`` holds: the fewest whose
+    positions fill whole tiles of 128 lanes (``dt x`` and the decay are read
+    with positions on lanes), else all of them (the whole row)."""
+    return next((n for n in range(1, chunks + 1)
+                 if chunks % n == 0 and n * chunk % _LANES == 0), chunks)
+
+
+def ssd_chunk_heads_per_program(heads: int, groups: int, chunk: int, d_k: int,
+                                d_v: int, itemsize: int) -> int:
+    """Heads one program of ``dk_ssd_chunk_*`` holds: whole groups (a
+    group's ``C`` and ``B`` are read once and their cotangents summed inside
+    the program), neighbours in one batch row whose blocks keep Mosaic's
+    rule (the groups' lanes whole tiles of 128, the heads' rows of ``dt x``
+    whole tiles of 16 and of the decay of 8, or the whole row); of those the
+    most, up to 8 (or one group where a group is wider), whose blocks fit
+    :data:`_VMEM_BUDGET` in the backward kernel, every block in and out
+    twice; where none does, the fewest that keep the rule for blocks."""
+    group = heads // groups
+    k, v, c = _lanes(d_k), _lanes(d_v), _lanes(chunk)
+    span = _LANES  # positions a program, at the least
+    blocks = span * (4 * k * itemsize / group      # q, k and dq, dk
+                     + 2 * v * itemsize + d_v * 4  # dU, dv and its scratch
+                     + 2 * k * itemsize            # dQg, dKd
+                     + c * itemsize + 3 * 4)       # dBq; g, dg, scratch
+    whole = [h for h in range(group, heads + 1, group)
+             if heads % h == 0 and (h == heads or not (
+                 h // group * d_k % _LANES or h * d_v % 16 or h % 8))]
+    fit = [h for h in whole if h <= max(8, group) and (
+        h == group or 2 * h * blocks <= _VMEM_BUDGET)]
+    return max(fit) if fit else min(whole)
+
+
+def _grams(q_ref, k_ref, group: int, K: int, right):
+    """``(ja, jb) -> [q_ja k_ja^T | q_jb k_jb^T]`` ``[C, 2C]`` float32 for a
+    pair of heads by their groups (:func:`_pair_gram`), from one chunk's
+    rows of ``q`` and ``k``, each taken once a program and chunk: a pair
+    inside one group is the group's one product, laid twice side by side."""
+    return functools.cache(lambda ja, jb: _pair_gram(
+        q_ref, k_ref, ja * group, jb * group, group, K, right))
+
+
+def _ssd_fwd_kernel(q_ref, k_ref, v_ref, g_ref, u_ref, qg_ref, bq_ref, kd_ref,
+                    s_ref, *, heads: int, group: int):
+    chunks, C, V = u_ref.shape[1:]
+    K = qg_ref.shape[3]
+    dt, f32 = v_ref.dtype, jnp.float32
+    row, col, right = _wide_iotas(C)
+    # positions to sublanes: one transpose a program of dt x and of g
+    vs, gs = v_ref[0].T, g_ref[0].T                  # [span, heads x V, heads]
+    for n in range(chunks):
+        at = slice(n * C, (n + 1) * C)
+        q, k = q_ref[0, at], k_ref[0, at]
+        G = _running_sum(gs[at])                             # [C, heads]
+        decay, tail = jnp.exp(G), jnp.exp(G[C - 1:] - G)
+        gram = _grams(q_ref.at[:, at], k_ref.at[:, at], group, K, right)
+        for a, b in _pairs(heads):
+            Bq = gram(a // group, b // group) * _side_by_side(
+                G, a, b, row, col, right)
+            for side, h in _sides(a, b):
+                bq_ref[h, n] = Bq[:, side * C:(side + 1) * C].astype(dt)
+        for j in range(heads // group):
+            q32, k32 = (x[:, j * K:(j + 1) * K].astype(f32) for x in (q, k))
+            for h in range(j * group, (j + 1) * group):
+                qg_ref[h, n] = (q32 * decay[:, h:h + 1]).astype(dt)
+                kd_ref[h, n] = (k32 * tail[:, h:h + 1]).astype(dt)
+                s_ref[h, n] = jnp.broadcast_to(jnp.exp(G[C - 1:, h:h + 1]),
+                                               (1, K))
+                u_ref[h, n] = vs[at, h * V:(h + 1) * V].astype(u_ref.dtype)
+
+
+def _ssd_specs(H: int, C: int, K: int, V: int, heads: int, group: int,
+               chunks: int):
+    """Block specs of one program (``heads`` heads of one batch row and
+    their ``heads / group`` groups, ``chunks`` chunks): the inputs ``q, k``
+    as ``[B, L, G x d_k]``, ``dt x`` as ``[B, H x d_v, L]`` and ``g`` as
+    ``[B, H, L]`` (positions on lanes, as XLA lays them out); then the
+    outputs ``U, Qg, Bq, Kd, s`` as ``[B x H, N, ...]``."""
+    programs, span = H // heads, chunks * C
+
+    def spec(block, index):
+        return pl.BlockSpec(block, index, memory_space=pltpu.VMEM)
+
+    def chunk_rows(*dims):
+        return spec((heads, chunks) + dims,
+                    lambda i, n: (i, n) + (0,) * len(dims))
+
+    positions = spec((1, span, heads // group * K),
+                     lambda i, n: (i // programs, n, i % programs))
+    ins = [positions, positions,
+           spec((1, heads * V, span),
+                lambda i, n: (i // programs, i % programs, n)),
+           spec((1, heads, span),
+                lambda i, n: (i // programs, i % programs, n))]
+    outs = [chunk_rows(C, V), chunk_rows(C, K), chunk_rows(C, C),
+            chunk_rows(C, K), chunk_rows(1, K)]
+    return ins, outs
+
+
+@functools.partial(_traced_once, static_argnames=(
+    "chunk", "heads", "groups", "interpret"))
+def _ssd_chunk_fwd(q, k, vT, gT, chunk: int, heads: int, groups: int,
+                   interpret: bool):
+    """``U, Qg, Bq, Kd, s`` as ``[B x H, N, ...]`` from ``q, k`` ``[B, L, G x
+    d_k]``, ``vT`` ``[B, H x d_v, L]`` and ``gT`` ``[B, H, L]``."""
+    B, H, L = gT.shape
+    K, V, C, N = k.shape[-1] // groups, vT.shape[1] // H, chunk, L // chunk
+    group, chunks = H // groups, ssd_chunks_per_program(L // chunk, chunk)
+    ins, outs = _ssd_specs(H, C, K, V, heads, group, chunks)
+    rows, dt = B * H, vT.dtype
+    out_shape = [jax.ShapeDtypeStruct((rows, N) + dims, dtype)
+                 for dims, dtype in (((C, V), dt), ((C, K), dt), ((C, C), dt),
+                                     ((C, K), dt), ((1, K), jnp.float32))]
+    call = pl.pallas_call(
+        functools.partial(_ssd_fwd_kernel, heads=heads, group=group),
+        grid=(rows // heads, N // chunks), in_specs=ins, out_specs=outs,
+        out_shape=out_shape, interpret=interpret, name="dk_ssd_chunk_fwd",
+        **_compiler_kw(interpret, ("parallel", "parallel")))
+    with jax.named_scope("dk_ssd_chunk_fwd"):
+        return tuple(call(q, k, vT, gT))
+
+
+def _ssd_bwd_kernel(q_ref, k_ref, g_ref, du_ref, dqg_ref, dbq_ref, dkd_ref,
+                    ds_ref, dq_ref, dk_ref, dv_ref, dg_ref, dvs, dgs, sums, *,
+                    heads: int, group: int):
+    chunks, C, V = du_ref.shape[1:]
+    K = dqg_ref.shape[3]
+    dt, f32 = q_ref.dtype, jnp.float32
+    row, col, right = _wide_iotas(C)
+    at_last = _iota((C, 1), 0) == C - 1
+    gs = g_ref[0].T                                          # [span, heads]
+    # a head's three row sums for dG go to its column of ``sums``: dG is
+    # then taken for all heads at once, [C, heads]
+    for n in range(chunks):
+        at = slice(n * C, (n + 1) * C)
+        q, k = q_ref[0, at], k_ref[0, at]
+        G = _running_sum(gs[at])                             # [C, heads]
+        decay, tail = jnp.exp(G), jnp.exp(G[C - 1:] - G)
+        gram = _grams(q_ref.at[:, at], k_ref.at[:, at], group, K, right)
+        # dBq * Gamma summed over each group's heads, two heads side by side
+        F = [jnp.zeros((C, 2 * C), f32) for _ in range(heads // group)]
+        for a, b in _pairs(heads):
+            dBq = jnp.concatenate([dbq_ref[a, n].astype(f32), (
+                dbq_ref[b, n].astype(f32) if b != a
+                else jnp.zeros((C, C), f32))], 1)
+            E = dBq * _side_by_side(G, a, b, row, col, right)
+            ja, jb = a // group, b // group
+            if ja == jb:
+                F[ja] = F[ja] + E
+            else:
+                F[ja] = F[ja] + jnp.where(right, 0.0, E)
+                F[jb] = F[jb] + jnp.where(right, E, 0.0)
+            # dBq * Bq: its rows' sums less its columns' (brought to the rows
+            # by the diagonal), a head at a time
+            P = E * gram(ja, jb)
+            P = P - jnp.where(row == col, jnp.sum(P, axis=0, keepdims=True),
+                              0.0)
+            for side, h in _sides(a, b):
+                sums[0, :, h:h + 1] = jnp.sum(P[:, side * C:(side + 1) * C],
+                                              axis=1, keepdims=True)
+        for j in range(heads // group):
+            qj, kj = (x[:, j * K:(j + 1) * K] for x in (q, k))
+            q32, k32 = qj.astype(f32), kj.astype(f32)
+            Fj = (F[j][:, :C] + F[j][:, C:]).astype(dt)
+            dq, dk = _nn(Fj, kj), _tn(Fj, qj)
+            for h in range(j * group, (j + 1) * group):
+                dQg = dqg_ref[h, n].astype(f32)
+                dKd = dkd_ref[h, n].astype(f32)
+                dq = dq + dQg * decay[:, h:h + 1]
+                dk = dk + dKd * tail[:, h:h + 1]
+                sums[1, :, h:h + 1] = jnp.sum(dQg * q32, axis=1,
+                                              keepdims=True)
+                sums[2, :, h:h + 1] = jnp.sum(dKd * k32, axis=1,
+                                              keepdims=True)
+                sums[0, C - 1:, h:h + 1] += jnp.sum(
+                    ds_ref[h, n], axis=1, keepdims=True) * decay[C - 1:,
+                                                                 h:h + 1]
+                dvs[at, h * V:(h + 1) * V] = du_ref[h, n].astype(f32)
+            dq_ref[0, at, j * K:(j + 1) * K] = dq.astype(dq_ref.dtype)
+            dk_ref[0, at, j * K:(j + 1) * K] = dk.astype(dk_ref.dtype)
+        through_kd = tail * sums[2]                          # [C, heads]
+        dG = sums[0] + decay * sums[1] - through_kd
+        dgs[at] = _running_sum(jnp.where(at_last, dG + jnp.sum(
+            through_kd, axis=0, keepdims=True), dG), up=True)
+    # positions back to lanes: one transpose a program of each
+    dv_ref[0] = dvs[...].T.astype(dv_ref.dtype)
+    dg_ref[0] = dgs[...].T
+
+
+@functools.partial(_traced_once, static_argnames=(
+    "chunk", "heads", "groups", "interpret"))
+def _ssd_chunk_bwd(q, k, gT, dU, dQg, dBq, dKd, ds, chunk: int, heads: int,
+                   groups: int, interpret: bool):
+    """The cotangents of ``q, k`` (at the groups), ``vT`` (in ``dU``'s
+    dtype, which is ``v``'s) and ``gT``, in their shapes: ``[B, L, G x
+    d_k]``, ``[B, H x d_v, L]`` and ``[B, H, L]``."""
+    B, H, L = gT.shape
+    K, V, C = k.shape[-1] // groups, dU.shape[-1], chunk
+    group, chunks = H // groups, ssd_chunks_per_program(L // chunk, chunk)
+    ins, outs = _ssd_specs(H, C, K, V, heads, group, chunks)
+    f32 = jnp.float32
+    call = pl.pallas_call(
+        functools.partial(_ssd_bwd_kernel, heads=heads, group=group),
+        grid=(B * H // heads, L // (chunks * C)),
+        in_specs=[ins[0], ins[1], ins[3]] + outs, out_specs=ins,
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct((B, H * V, L), dU.dtype),
+                   jax.ShapeDtypeStruct(gT.shape, f32)],
+        scratch_shapes=[pltpu.VMEM((chunks * C, heads * V), f32),
+                        pltpu.VMEM((chunks * C, heads), f32),
+                        pltpu.VMEM((3, C, heads), f32)],
+        interpret=interpret, name="dk_ssd_chunk_bwd",
+        **_compiler_kw(interpret, ("parallel", "parallel")))
+    with jax.named_scope("dk_ssd_chunk_bwd"):
+        return tuple(call(q, k, gT, dU, dQg, dBq, dKd, ds))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _ssd_chunk(q, k, vT, gT, chunk, heads, groups, interpret):
+    return _ssd_chunk_fwd(q, k, vT, gT, chunk=chunk, heads=heads,
+                          groups=groups, interpret=interpret)
+
+
+def _ssd_chunk_fwd_rule(q, k, vT, gT, chunk, heads, groups, interpret):
+    return _ssd_chunk_fwd(q, k, vT, gT, chunk=chunk, heads=heads,
+                          groups=groups, interpret=interpret), (q, k, gT)
+
+
+def _ssd_chunk_bwd_rule(chunk, heads, groups, interpret, res, cotangents):
+    return _ssd_chunk_bwd(*res, *cotangents, chunk=chunk, heads=heads,
+                          groups=groups, interpret=interpret)
+
+
+_ssd_chunk.defvjp(_ssd_chunk_fwd_rule, _ssd_chunk_bwd_rule)
+
+
+def ssd_chunk_products(q, k, v, g, chunk: int, heads: int | None = None,
+                       interpret: bool | None = None):
+    """The state-space in-chunk half (Mamba-2's SSD; ``ops/delta_rule.py::
+    ssd_in_chunk`` in ``jax.numpy``) as the kernel pair ``dk_ssd_chunk_fwd``
+    / ``dk_ssd_chunk_bwd`` under one ``jax.custom_vjp``. ``q`` (``C``) and
+    ``k`` (``B``): [B, L, G, d_k] at ``G`` groups dividing the ``H`` heads,
+    read as ``[B, L, G x d_k]`` (head ``h`` reads group ``h // (H / G)``, a
+    group a lane slice: nothing repeated or moved); ``v`` (``dt x``): [B, L,
+    H, d_v], read as ``[B, H x d_v, L]``, and ``g`` (``dt A``, ``<= 0``): [B,
+    L, H], float32 here whatever it comes as, read as ``[B, H, L]``: with
+    positions on lanes, as XLA lays out the Mixer's ``[B, L, H, 64]`` arrays
+    (read with positions on sublanes, they cost it relayouts of float32
+    ``[L, H x d_v]`` arrays around the pair, about 4 GB a step at
+    Nemotron-3-Nano's cell); a program turns them in VMEM, one transpose of
+    each. With ``G`` the running sum of ``g`` a head inside a
+    chunk of ``C`` positions and ``Gamma_ri = e^{G_r - G_i}`` for ``i <= r``
+    (its exponent masked to ``-inf`` above the diagonal *before* the
+    exponential, so every exponent is ``<= 0``, for any decay):
+
+        Bq_h = (q k^T)_group * Gamma_h    Qg_h = q e^{G_h}
+        Kd_h = k e^{G_C - G_h}            s_h = e^{G_C} on the [1, d_k] lanes
+        U_h = v_h
+
+    ``q k^T`` is one product a group for all its heads; ``Gamma`` of two
+    heads side by side fills the 128 lanes (``_side_by_side``). ``U`` is
+    written in ``v``'s dtype (it *is* ``v``); ``Qg, Bq, Kd`` in ``v``'s
+    dtype from products of operands in it accumulated in float32; decays and
+    ``Gamma`` in float32. The backward is written: with ``F_g = sum_{h in g}
+    dBq_h * Gamma_h`` (lower-triangular) and ``P_h = dBq_h * Bq_h``
+    (``Bq_h`` unrounded),
+
+        dq_g = F_g k_g + sum_h dQg_h e^{G_h}
+        dk_g = F_g^T q_g + sum_h dKd_h e^{G_C - G_h}
+        dG_h = rowsum(P_h) - colsum(P_h) + rowsum(dQg_h * Qg_h)
+               - rowsum(dKd_h * Kd_h)
+        dG_h,C += sum_r rowsum(dKd_h * Kd_h)_r + sum(ds_h) e^{G_C}
+        dv_h = dU_h      dg = the running sum of dG from below
+
+    ``F_g`` is summed over the group before its two products; ``colsum``
+    comes to a column through the diagonal (one term a row: exact). The
+    forward rule saves ``q, k, g`` alone. Grid ``(batch x heads / heads a
+    program, chunks / chunks a program)``, both ``parallel``; ``heads`` and
+    the chunks follow the shapes (:func:`ssd_chunk_heads_per_program`,
+    :func:`ssd_chunks_per_program`: one group of 8 heads and two chunks of
+    64 at Nemotron-3-Nano's); the counter ``pallas.ssd.chunk_calls`` counts the
+    pair's traced calls. Returns, as ``ssd_in_chunk`` does, ``(U, Qg, Bq,
+    Kd, s)`` as :func:`scan_chunks` reads them with ``W=None`` and the summed
+    log-decay of each chunk ``[B, H, N]`` (no cotangent flows into that
+    one). ``interpret=None`` compiles on a TPU and interprets elsewhere."""
+    interpret = mode.interpret("ssd_chunk", interpret)
+    B, L, G, K = k.shape
+    H, V, N = v.shape[2], v.shape[-1], L // chunk
+    if H % G:
+        raise ValueError(f"{G} groups cannot serve {H} heads")
+    if heads is None:
+        heads = ssd_chunk_heads_per_program(H, G, chunk, K, V,
+                                            jnp.dtype(v.dtype).itemsize)
+    if H % heads or heads % (H // G):
+        raise ValueError(f"{heads} heads a program are not whole groups of "
+                         f"{H // G} that divide {H}")
+    from distkeras_tpu import telemetry
+
+    telemetry.counter("pallas.ssd.chunk_calls").add(1)
+    telemetry.gauge("pallas.ssd.chunk_heads_per_program").set(heads)
+    g = g.astype(jnp.float32)
+    *products, s = _ssd_chunk(
+        q.reshape(B, L, G * K), k.reshape(B, L, G * K),
+        jnp.swapaxes(v.reshape(B, L, H * V), 1, 2), jnp.swapaxes(g, 1, 2),
+        chunk, heads, G, interpret)
+    last = jnp.moveaxis(jnp.sum(g.reshape(B, N, chunk, H), 2), 1, 2)
+    return tuple(x.reshape(B, H, N, *x.shape[2:]) for x in products) + (
+        s.reshape(B, H, N, K),), last

@@ -205,8 +205,8 @@ _METRICS = [
        "1)) over the `gdn` layers' tokens and value heads."),
     _m("ssd.chunk", "gauge", "models",
        "Positions a chunk of the Mamba-2 layers' chunked form (Nemotron-H's "
-       "`mamba` layers: the in-chunk half in `jax.numpy`, the scan `kda` runs "
-       "with no `W`), set as the model is traced "
+       "`mamba` layers: the in-chunk pair `dk_ssd_chunk_*`, the scan `kda` "
+       "runs with no `W`), set as the model is traced "
        "(`ops/delta_rule.py::chunk_for`)."),
     _m("ssd.state_bytes", "gauge", "models",
        "Bytes of the state the `mamba` layers carry a step: layers x batch x "
@@ -247,6 +247,15 @@ _METRICS = [
        "Calls of the delta rule's scan pair (`dk_kda_scan_*`) traced with no "
        "`W` (`scan_chunks(U, None, ...)`: a state-space layer, Mamba-2), "
        "counted as a call is traced; the delta rule's calls leave it alone."),
+    _m("pallas.ssd.chunk_calls", "counter", "kernels",
+       "Calls of the state-space in-chunk pair (`dk_ssd_chunk_fwd`, "
+       "`dk_ssd_chunk_bwd`; Mamba-2, `ops/delta_rule.py::chunked_ssd`), "
+       "counted as a call is traced; the delta rule's calls leave it alone."),
+    _m("pallas.ssd.chunk_heads_per_program", "gauge", "kernels",
+       "Heads one program of `dk_ssd_chunk_*` holds, set as a call is traced "
+       "(`ops/pallas/delta_rule.py::ssd_chunk_heads_per_program`: whole "
+       "groups of one batch row, whole lane tiles at the groups and the "
+       "heads, the most up to 8 that fit VMEM)."),
     _m("pallas.kda.grid_steps", "gauge", "kernels",
        "Grid steps of the delta rule's kernel call traced last "
        "(`dk_kda_scan_*`: batch x heads / heads a program, times the "

@@ -418,10 +418,13 @@ def test_the_ssd_scan_compiles_for_a_v5e_at_the_cells_shape(one_chip,
     ``B`` ``[1, 8192, 4, 128]`` at the held groups, ``dt x`` at 32 heads of
     64, one decay a head, bfloat16, under ``jax.checkpoint`` and
     ``jax.value_and_grad``: Mosaic takes the scan pair with no ``W`` at
-    values of 64 beside a state of 128, four calls under ``dk_ssd`` (forward,
-    recomputed, backward: the in-chunk half is ``jax.numpy``), the forward
-    kernel given five operands (no ``W``). Kept in this file for the reason
-    above."""
+    values of 64 beside a state of 128 and the in-chunk pair
+    ``dk_ssd_chunk_*``, six calls under ``dk_ssd`` (both forward kernels in
+    the forward and again in the recomputed pass, then the two backward
+    kernels), the scan's forward kernel given five operands (no ``W``); the
+    program holds no ``C`` or ``B`` widened to the heads (``[1, 4, 8, 128,
+    64, 128]``, ten times in the ``jax.numpy`` form's). Kept in this file for
+    the reason above."""
     import re
 
     from distkeras_tpu.ops.delta_rule import chunked_ssd
@@ -446,12 +449,16 @@ def test_the_ssd_scan_compiles_for_a_v5e_at_the_cells_shape(one_chip,
         shape(*groups), shape(*groups), shape(*values),
         shape(*values[:3], dtype=jnp.float32)).compile().as_text()
     calls = re.findall(r"= [^\n]*custom-call\([^\n]*"
-                       r'op_name="[^"]*/(dk_kda_scan_\w+)/', text)
+                       r'op_name="[^"]*/(dk_(?:kda_scan|ssd_chunk)_\w+)/',
+                       text)
     assert sorted(calls) == ["dk_kda_scan_bwd", "dk_kda_scan_fwd",
-                             "dk_kda_scan_fwd"], calls
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+                             "dk_kda_scan_fwd", "dk_ssd_chunk_bwd",
+                             "dk_ssd_chunk_fwd", "dk_ssd_chunk_fwd"], calls
+    assert text.count('custom_call_target="tpu_custom_call"') == 6
     assert all("/dk_ssd/" in line for line in text.splitlines()
-               if "custom-call(" in line and "dk_kda_scan" in line)
+               if "custom-call(" in line and (
+                   "dk_kda_scan" in line or "dk_ssd_chunk" in line))
+    assert not re.search(r"\[1,4,8,128,64,128\]", text)
     forward = [line for line in text.splitlines()
                if "custom-call(" in line and "dk_kda_scan_fwd" in line]
     assert forward and all(
