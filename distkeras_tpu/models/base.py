@@ -33,6 +33,11 @@ from distkeras_tpu.runtime.serialization import (
 #: ``publish_round_counters(round, counters)``, which owns the telemetry names.
 ROUND_COUNTERS = "round_counters"
 
+#: The collection that holds each routed layer's expert bias
+#: (``blocks.RoutedFFN``): state a training step updates, like BatchNorm's
+#: statistics, and never a gradient's.
+ROUTER_BIAS = "router_bias"
+
 
 def _coerce(v):
     # JSON round-trips tuples as lists; flax module fields want tuples back.

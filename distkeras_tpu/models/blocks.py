@@ -12,8 +12,10 @@ Gated DeltaNet (the same rule with a decay a head and key heads shared by
 value heads, through the same chunked scan) and a Mamba-2 state-space mixer
 (a decay a head and no delta rule, through the same scan with no ``W``) in
 attention's place, a gated and a plain dense feed-forward, two routers
-(softmax, and sigmoid with a selection bias), and a dropless mixture of gated
-or plain experts that holds a share of the experts it routes over.
+(softmax, and sigmoid with a selection bias), a dropless mixture of gated
+or plain experts that holds a share of the experts it routes over, and the
+routed feed-forward that joins a router, the experts and a shared expert
+(:class:`RoutedFFN`, the one every expert model's block calls).
 The two attention modules, the delta rule and the state-space mixer are told
 how many *heads* they
 hold, as the expert layer is told its experts: a chip that shares a layer's
@@ -55,13 +57,14 @@ from __future__ import annotations
 
 import functools
 import math
+from collections.abc import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
-from distkeras_tpu.models.base import ROUND_COUNTERS
+from distkeras_tpu.models.base import ROUND_COUNTERS, ROUTER_BIAS
 from distkeras_tpu.ops.delta_rule import (chunk_for, chunked_gated_delta_rule,
                                           chunked_ssd)
 from distkeras_tpu.ops.pallas import rows
@@ -896,25 +899,160 @@ class DroplessExperts(nn.Module):
         """Add this step's routing to the round's counters (float32: exact
         up to 2**24 a round). ``rows_moved``: the buffer rows in the tiles
         the row kernels visited."""
-        for name, value in (
-                ("assignments_held", group_sizes.astype(jnp.float32)),
-                ("tokens_without_held_expert", jnp.sum(
-                    ~jnp.any(here, axis=-1)).astype(jnp.float32)),
-                ("tokens", jnp.float32(tokens)),
-                ("rows_moved", rows_moved.astype(jnp.float32)),
-                ("steps", jnp.float32(1))):
-            var = self.variable(ROUND_COUNTERS, name,
-                                lambda v=value: jnp.zeros_like(v))
-            if not self.is_initializing():  # init declares them, at zero
-                var.value = var.value + value
+        _add_to_round(
+            self, assignments_held=group_sizes.astype(jnp.float32),
+            tokens_without_held_expert=jnp.sum(
+                ~jnp.any(here, axis=-1)).astype(jnp.float32),
+            tokens=jnp.float32(tokens),
+            rows_moved=rows_moved.astype(jnp.float32), steps=jnp.float32(1))
+
+
+def _add_to_round(module, **values) -> None:
+    """Add a step's float32 ``values`` to ``module``'s round counters, which
+    init declares at zero."""
+    for name, value in values.items():
+        var = module.variable(ROUND_COUNTERS, name,
+                              lambda v=value: jnp.zeros_like(v))
+        if not module.is_initializing():
+            var.value = var.value + value
+
+
+def check_share(what: str, share, of: int, grain: int = 1) -> None:
+    """Refuse ``share = (first, count)`` unless it is a share of ``of``
+    experts or heads: inside them, not empty, and in whole groups of
+    ``grain``."""
+    first, held = share
+    if not 0 <= first <= first + held <= of or held < 1 \
+            or first % grain or held % grain:
+        raise ValueError(f"{what} {(first, held)} is no share of {of}" + (
+            f" in whole groups of {grain}" if grain > 1 else ""))
+
+
+class RoutedFFN(nn.Module):
+    """A routed layer's feed-forward, from the normalised stream ``g [B, L,
+    D]`` to what it adds to the residual: the router in float32 under
+    ``dk_moe_route``, this chip's share ``experts_held = (first, count)`` of
+    the experts (:class:`DroplessExperts`) and, where the family has one, a
+    shared expert every token passes, under ``dk_moe_shared``. It takes its
+    block's scope: ``router``, ``moe``, ``shared`` and ``shared_gate`` are
+    the block's parameters, ``expert_bias`` its ``ROUTER_BIAS``, and the
+    chosen experts (``intermediates``, ``experts`` ``[B, L, k]``: top-k is
+    discontinuous, and a comparison in another precision needs to know which
+    experts this side chose) and the counts are the block's.
+
+    ``route``, the router with its ``k``, scale and ``eps`` bound, is
+    :func:`route_top_k` (``route(logits)``), or with ``expert_bias_std``
+    :func:`route_sigmoid_bias_top_k` (``route(logits, bias)``).
+    ``route_from`` is what the router reads where that is not ``g``
+    (SmallThinker's, the block's input). ``shared`` makes the shared expert;
+    ``shared_gate`` weighs it by ``sigmoid(g W_sg)`` a token and counts the
+    gate's sum and count (``shared_gate_sum``, ``shared_gate_count``).
+
+    **A share does not train its router.** Where fewer experts are held than
+    routed over, no gradient passes through the router's logits. The
+    gradient a share can compute is the held experts' part of the router's;
+    the absent experts' part is what balances it, and applied alone it sends
+    every token to the held experts (on the chip at SmallThinker's published
+    widths, Adam 3e-4: by the second round of four steps one layer routed
+    all 98,304 of a step's assignments to its 8 held experts, and the
+    round's time swung by 17 % with the load; PERF.md). In the
+    deployment that part arrives with the exchange this cut leaves out. A
+    module that holds every expert trains its router as any other parameter.
+
+    **The expert bias balances the load, without a gradient** (top-k passes
+    none). Drawn from ``N(0, expert_bias_std)``, it moves in a training step
+    by the rule of the families that publish ``use_expert_bias``
+    (auxiliary-loss-free balancing): ``b_e += expert_bias_update * sign(mean
+    load - load_e)`` over all ``num_experts``, the load counted over the
+    tokens this module was given (across chips the counts would be summed
+    first; that exchange is not built). The speed is in no published
+    ``config.json``: set by measurement. Held at 0, a frozen router reading
+    a stream that training moves drifts from an even load within a few
+    rounds (on the chip at LFM2's published widths, held experts at 0.7 to
+    1.6 times the even load, 3 to 5 times the mean on a layer's worst, and
+    the round's time with it; PERF.md); 1e-3, DeepSeek-V3's, does
+    not hold it through the first hundred steps from a random start; 5e-3
+    holds every layer within a few percent of even from the third round on.
+    The assignments the bias moved are counted (``assignments_moved_by_bias``).
+    """
+
+    num_experts: int               # the router's width, as published
+    experts_held: tuple
+    d_expert: int
+    activation: str                # the experts' (DroplessExperts)
+    route: Callable
+    gated: bool = True
+    expert_bias_std: float | None = None  # None: no selection bias
+    expert_bias_update: float = 0.0
+    shared: Callable | None = None  # GatedMLP or PlainMLP, its width bound
+    shared_gate: bool = False
+
+    def setup(self):
+        nn.share_scope(self.parent, self)
+
+    @nn.compact
+    def __call__(self, g, route_from=None):
+        B, L, D = g.shape
+        first, held = self.experts_held
+        g = g.reshape(B * L, D)
+        with owner("ffn"), jax.named_scope("dk_moe_route"):
+            logits = Router(self.num_experts, name="router")(
+                g if route_from is None else route_from.reshape(B * L, D))
+            if held < self.num_experts:
+                # A share does not train its router (the class doc says why).
+                logits = jax.lax.stop_gradient(logits)
+            if self.expert_bias_std is None:
+                weights, experts = self.route(logits)
+            else:
+                weights, experts, moved = self._route_with_bias(logits)
+        counting = self.is_mutable_collection(ROUND_COUNTERS)
+        if counting and self.expert_bias_std is not None:
+            _add_to_round(self, assignments_moved_by_bias=jnp.sum(
+                moved, dtype=jnp.float32))
+        self.sow("intermediates", "experts", experts.reshape(B, L, -1))
+        y = DroplessExperts(first, held, D, self.d_expert, self.activation,
+                            self.gated, name="moe")(g, weights, experts)
+        if self.shared is not None:
+            with owner("ffn"), jax.named_scope("dk_moe_shared"):
+                shared = self.shared(name="shared")(g)
+                if not self.shared_gate:
+                    y = y + shared
+                else:
+                    gate = jax.nn.sigmoid(nn.Dense(
+                        1, use_bias=False, name="shared_gate")(g).astype(
+                            jnp.float32))
+                    y = y + (gate * shared.astype(jnp.float32)).astype(
+                        y.dtype)
+            if counting and self.shared_gate:
+                _add_to_round(self, shared_gate_sum=jnp.sum(gate),
+                              shared_gate_count=jnp.float32(gate.size))
+        return y.reshape(B, L, D)
+
+    def _route_with_bias(self, logits):
+        """Route by ``logits`` and the expert bias; in a training step (the
+        bias mutable) move the bias against this step's load."""
+        bias = self.variable(
+            ROUTER_BIAS, "expert_bias",
+            lambda: nn.initializers.normal(self.expert_bias_std)(
+                self.make_rng("params"), (self.num_experts,)))
+        weights, experts, moved = self.route(logits, bias.value)
+        if self.expert_bias_update and not self.is_initializing() \
+                and self.is_mutable_collection(ROUTER_BIAS):
+            load = jnp.sum(experts.reshape(-1, 1)
+                           == jnp.arange(self.num_experts), axis=0,
+                           dtype=jnp.float32)
+            bias.value = bias.value + self.expert_bias_update * jnp.sign(
+                jnp.mean(load) - load)
+        return weights, experts, moved
 
 
 def publish_moe_round(round_index: int, counters,
                       experts_per_token: int) -> None:
-    """A round's expert load, from the routed layers' ``ROUND_COUNTERS``
-    (numpy, one entry a routed layer: its expert layer's counts under
-    ``"moe"``, and ``"assignments_moved_by_bias"`` where its router has a
-    selection bias): counter ``moe.assignments_held``, gauges
+    """A round's expert load, from a model's ``ROUND_COUNTERS`` (numpy, one
+    entry a layer; the routed ones, those that hold their expert layer's
+    counts under ``"moe"`` and ``"assignments_moved_by_bias"`` where the
+    router has a selection bias, are read, the others passed over; none:
+    nothing is published): counter ``moe.assignments_held``, gauges
     ``moe.load_max_over_mean`` (over the held experts, worst layer),
     ``moe.tokens_without_held_expert_share``, ``moe.rows_moved_share`` (buffer
     rows in the tiles the row kernels visited over the buffers' rows) and,
@@ -924,7 +1062,9 @@ def publish_moe_round(round_index: int, counters,
     layers with them. What a model's ``publish_round_counters`` calls."""
     from distkeras_tpu import telemetry
 
-    routed = [c for _, c in sorted(counters.items())]
+    routed = [c for _, c in sorted(counters.items()) if "moe" in c]
+    if not routed:
+        return
     layers = [c["moe"] for c in routed]
     assigned = np.stack([np.asarray(c["assignments_held"], np.float64)
                          for c in layers])            # [layers, held]

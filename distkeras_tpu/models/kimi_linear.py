@@ -29,15 +29,15 @@ projection:
 
 **What this chip holds** of a layer that several chips share:
 ``experts_held = (first, count)`` of the routed experts and ``vocab_size``
-rows, as in ``models/lfm2.py``, and ``heads_held = (first, count)`` of the
-``num_heads`` heads of every ``kda`` and ``mla`` layer: the head projections
-and ``W_o``'s rows are the held heads', while ``W_fa``, ``W_ga``, ``W_kva``,
-the norms, the router, the shared expert and the dense layer are whole on
-every chip (a width is never cut). What the absent heads and experts would
+rows, as in the other expert models, and ``heads_held = (first, count)`` of
+the ``num_heads`` heads of every ``kda`` and ``mla`` layer: the head
+projections and ``W_o``'s rows are the held heads', while ``W_fa``, ``W_ga``,
+``W_kva``, the norms, the router, the shared expert and the dense layer are
+whole on every chip (a width is never cut). What the absent heads and experts would
 have added is left out; the sum over the chips that share a layer's heads (an
-all-reduce after ``W_o``) is not built. A share of the experts does not train
-its router, and the correction bias is state in ``ROUTER_BIAS`` that a
-training step moves against the load (``models/lfm2.py`` says how and why).
+all-reduce after ``W_o``) is not built. The routed feed-forward is
+``blocks.RoutedFFN``, which says why a share of the experts does not train
+its router and how the correction bias moves against the load.
 
 ``remat=True`` recomputes each layer in the backward pass, all but the flash
 forward of the ``mla`` layers (``blocks.remat_block``). A round's routing and
@@ -52,18 +52,18 @@ Parameters do not depend on the sequence length: build with a short sample.
 
 from __future__ import annotations
 
-import jax
+import functools
+from collections.abc import Callable
+
 import jax.numpy as jnp
 from flax import linen as nn
 
-from distkeras_tpu.models.base import (ROUND_COUNTERS, DKModule, Model,
-                                       register_model)
-from distkeras_tpu.models.blocks import (DroplessExperts, GatedMLP,
-                                         KimiDeltaAttention, LatentAttention,
-                                         RMSNorm, Router, publish_moe_round,
+from distkeras_tpu.models.base import DKModule, Model, register_model
+from distkeras_tpu.models.blocks import (GatedMLP, KimiDeltaAttention,
+                                         LatentAttention, RMSNorm, RoutedFFN,
+                                         check_share, publish_moe_round,
                                          remat_block,
                                          route_sigmoid_bias_top_k)
-from distkeras_tpu.models.lfm2 import ROUTER_BIAS
 from distkeras_tpu.ops.delta_rule import chunk_for
 from distkeras_tpu.scopes import owner
 
@@ -75,7 +75,7 @@ PUBLISHED_LAYER_TYPES = tuple(
 
 class KimiLinearBlock(nn.Module):
     operator: str          # 'kda' | 'mla'
-    routed: bool           # shared + routed experts, or the dense feed-forward
+    routed: Callable | None  # blocks.RoutedFFN with its fields; None: dense
     heads: int             # the heads held here
     kda_head_dim: int
     conv_kernel: int
@@ -84,20 +84,11 @@ class KimiLinearBlock(nn.Module):
     v_head_dim: int
     kv_rank: int
     d_ff: int
-    d_expert: int
-    num_experts: int
-    experts_per_token: int
-    experts_held: tuple
-    num_shared_experts: int
-    routed_scaling_factor: float
-    expert_bias_std: float
-    expert_bias_update: float
     rms_eps: float
     attn_impl: str
 
     @nn.compact
     def __call__(self, x):
-        B, L, D = x.shape
         h = RMSNorm(self.rms_eps, name="ln_op")(x)
         if self.operator == "kda":
             x = x + KimiDeltaAttention(self.heads, self.kda_head_dim,
@@ -109,44 +100,9 @@ class KimiLinearBlock(nn.Module):
                 self.v_head_dim, self.kv_rank, self.rms_eps, self.attn_impl,
                 name="mla")(h)
         g = RMSNorm(self.rms_eps, name="ln_ffn")(x)
-        if not self.routed:
+        if self.routed is None:
             return x + GatedMLP(self.d_ff, "silu", name="mlp")(g)
-        first, held = self.experts_held
-        g = g.reshape(B * L, D)
-        with owner("ffn"), jax.named_scope("dk_moe_route"):
-            logits = Router(self.num_experts, name="router")(g)
-            if held < self.num_experts:
-                # A share does not train its router (lfm2.py says why).
-                logits = jax.lax.stop_gradient(logits)
-            bias = self.variable(
-                ROUTER_BIAS, "expert_bias",
-                lambda: nn.initializers.normal(self.expert_bias_std)(
-                    self.make_rng("params"), (self.num_experts,)))
-            weights, experts, moved = route_sigmoid_bias_top_k(
-                logits, bias.value, self.experts_per_token,
-                self.routed_scaling_factor, eps=1e-20)
-            if self.expert_bias_update and not self.is_initializing() \
-                    and self.is_mutable_collection(ROUTER_BIAS):
-                load = jnp.sum(experts.reshape(-1, 1)
-                               == jnp.arange(self.num_experts), axis=0,
-                               dtype=jnp.float32)
-                bias.value = bias.value + self.expert_bias_update * jnp.sign(
-                    jnp.mean(load) - load)
-        if self.is_mutable_collection(ROUND_COUNTERS):
-            count = self.variable(ROUND_COUNTERS, "assignments_moved_by_bias",
-                                  lambda: jnp.zeros((), jnp.float32))
-            if not self.is_initializing():  # init declares it, at zero
-                count.value = count.value + jnp.sum(moved, dtype=jnp.float32)
-        # For whoever asks (`mutable=["intermediates"]`): the reference check
-        # needs to know which experts this side chose (smallthinker.py).
-        self.sow("intermediates", "experts", experts.reshape(B, L, -1))
-        y = DroplessExperts(first, held, D, self.d_expert, "silu",
-                            name="moe")(g, weights, experts)
-        if self.num_shared_experts:
-            with owner("ffn"), jax.named_scope("dk_moe_shared"):
-                y = y + GatedMLP(self.num_shared_experts * self.d_expert,
-                                 "silu", name="shared")(g)
-        return x + y.reshape(B, L, D)
+        return x + self.routed(name="routed")(g)
 
 
 @register_model
@@ -184,11 +140,8 @@ class KimiLinearLM(DKModule):
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):
-        for what, (first, held), of in (
-                ("experts_held", self.experts_held, self.num_experts),
-                ("heads_held", self.heads_held, self.num_heads)):
-            if not 0 <= first <= first + held <= of or held < 1:
-                raise ValueError(f"{what} {(first, held)} is no share of {of}")
+        check_share("experts_held", self.experts_held, self.num_experts)
+        check_share("heads_held", self.heads_held, self.num_heads)
         if len(self.layer_types) < self.num_layers \
                 or set(self.layer_types) - {"kda", "mla"}:
             raise ValueError("layer_types needs 'kda' or 'mla' for each of "
@@ -224,15 +177,23 @@ class KimiLinearLM(DKModule):
                 KimiLinearBlock, self,
                 attention if self.attn_impl == "flash" else 0,
                 B, L, heads, self.v_head_dim, x.dtype)
-        for l, (operator, routed) in enumerate(kinds):
+        routed = functools.partial(
+            RoutedFFN, self.num_experts, tuple(self.experts_held),
+            self.d_expert, "silu", functools.partial(
+                route_sigmoid_bias_top_k, k=self.experts_per_token,
+                scale=self.routed_scaling_factor, eps=1e-20),
+            expert_bias_std=self.expert_bias_std,
+            expert_bias_update=self.expert_bias_update,
+            shared=functools.partial(
+                GatedMLP, self.num_shared_experts * self.d_expert, "silu")
+            if self.num_shared_experts else None)
+        for l, (operator, is_routed) in enumerate(kinds):
             x = block_cls(
-                operator, routed, heads, self.kda_head_dim, self.conv_kernel,
-                self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim,
-                self.kv_lora_rank, self.d_ff, self.d_expert, self.num_experts,
-                self.experts_per_token, tuple(self.experts_held),
-                self.num_shared_experts, self.routed_scaling_factor,
-                self.expert_bias_std, self.expert_bias_update, self.rms_eps,
-                self.attn_impl, name=f"block_{l}")(x)
+                operator, routed if is_routed else None, heads,
+                self.kda_head_dim, self.conv_kernel, self.qk_nope_head_dim,
+                self.qk_rope_head_dim, self.v_head_dim, self.kv_lora_rank,
+                self.d_ff, self.rms_eps, self.attn_impl,
+                name=f"block_{l}")(x)
         x = RMSNorm(self.rms_eps, name="ln_final")(x)
         with owner("head"):
             return nn.Dense(
@@ -246,9 +207,7 @@ class KimiLinearLM(DKModule):
         ``kda.mean_beta``, and one ``kda.round`` event."""
         from distkeras_tpu import telemetry
 
-        routed = {name: c for name, c in counters.items() if "moe" in c}
-        if routed:
-            publish_moe_round(round_index, routed, self.experts_per_token)
+        publish_moe_round(round_index, counters, self.experts_per_token)
         kda = [c["kda"] for _, c in sorted(counters.items()) if "kda" in c]
         if not kda:
             return

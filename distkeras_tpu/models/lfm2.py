@@ -26,29 +26,11 @@ Layer ``l`` on the residual stream ``x`` ``[B, L, d_model]``, no bias anywhere:
 ``experts_held = (first, count)`` and ``vocab_size`` are this chip's share, as
 in ``models/smallthinker.py``: the router keeps its published width and
 ``k``, the weights are normalised over all chosen experts held or not, and
-embedding, head and loss are over the held rows. **A share does not train its
-router** (``smallthinker.py`` says why): where fewer experts are held than
-routed over, no gradient passes through the router's logits; a module that
-holds them all trains it.
-
-**The expert bias balances the load, without a gradient.** Top-k passes none,
-so none reaches ``b``; it lives in the collection ``ROUTER_BIAS`` beside the
-parameters, and a training step moves it by the rule of the families that
-publish ``use_expert_bias`` (auxiliary-loss-free balancing): after routing a
-step's tokens, ``b_e += expert_bias_update * sign(mean load - load_e)`` over
-all ``num_experts``, the load counted over the tokens this module was given
-(which in the benchmark's cell are those of all the chips that share a layer;
-across chips the counts would be summed first, and that exchange is not
-built). The speed (``expert_bias_update``) is not in ``config.json``: an
-assumption, set by measurement. With it at 0 the bias stays as the seed drew
-it, and a frozen router reading a stream that training moves drifts away from
-an even load within a few rounds: on the chip, at the published widths from a
-random start, the held experts took 0.7 to 1.6 times the even load and 3 to
-5 times the mean on the worst expert of a layer, and the round's time moved
-with it (PERF.md §6, PR 32). 1e-3 a step, the speed DeepSeek-V3's report
-gives, does not hold the load through the first hundred steps of such a
-start; 5e-3 holds every layer within a few percent of even from the third
-round on.
+embedding, head and loss are over the held rows. The routed feed-forward is
+``blocks.RoutedFFN``, which says why a share does not train its router and
+how the expert bias ``b`` (state in ``ROUTER_BIAS``, no gradient's) moves
+against the load at ``expert_bias_update`` a step, a speed no published
+``config.json`` gives, set by measurement.
 
 ``remat=True`` recomputes each layer in the backward pass, all but the flash
 forward of the attention layers (``blocks.remat_block``). What a round routed
@@ -62,23 +44,20 @@ Parameters do not depend on the sequence length: build with a short sample
 
 from __future__ import annotations
 
-import jax
+import functools
+from collections.abc import Callable
+
 import jax.numpy as jnp
 from flax import linen as nn
 
-from distkeras_tpu.models.base import (ROUND_COUNTERS, DKModule, Model,
-                                       register_model)
-from distkeras_tpu.models.blocks import (DroplessExperts, GatedMLP,
-                                         GatedShortConv,
+from distkeras_tpu.models.base import ROUTER_BIAS  # noqa: F401 (importable)
+from distkeras_tpu.models.base import DKModule, Model, register_model
+from distkeras_tpu.models.blocks import (GatedMLP, GatedShortConv,
                                          GroupedQueryAttention, RMSNorm,
-                                         Router, publish_moe_round,
-                                         remat_block,
+                                         RoutedFFN, check_share,
+                                         publish_moe_round, remat_block,
                                          route_sigmoid_bias_top_k)
 from distkeras_tpu.scopes import owner
-
-#: the collection that holds each routed layer's expert bias: state a training
-#: step updates (like BatchNorm's statistics), never a gradient's
-ROUTER_BIAS = "router_bias"
 
 #: ``layer_types`` as published: two ``conv`` layers, then periods of one
 #: attention layer and three ``conv`` layers, 40 in all
@@ -88,18 +67,11 @@ PUBLISHED_LAYER_TYPES = ("conv", "conv") + (
 
 class Lfm2Block(nn.Module):
     operator: str          # 'conv' | 'full_attention'
-    routed: bool           # a mixture of experts, or the dense feed-forward
+    routed: Callable | None  # blocks.RoutedFFN with its fields; None: dense
     num_heads: int
     num_kv_heads: int
     head_dim: int
     d_ff: int
-    d_expert: int
-    num_experts: int
-    experts_per_token: int
-    experts_held: tuple
-    routed_scaling_factor: float
-    expert_bias_std: float
-    expert_bias_update: float
     conv_kernel: int
     rope_theta: float
     rms_eps: float
@@ -107,7 +79,6 @@ class Lfm2Block(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        B, L, D = x.shape
         h = RMSNorm(self.rms_eps, name="ln_op")(x)
         if self.operator == "conv":
             x = x + GatedShortConv(self.conv_kernel, name="conv")(h)
@@ -117,42 +88,9 @@ class Lfm2Block(nn.Module):
                 rope_theta=self.rope_theta, attn_impl=self.attn_impl,
                 qk_norm=self.rms_eps, name="attn")(h)
         g = RMSNorm(self.rms_eps, name="ln_ffn")(x)
-        if not self.routed:
+        if self.routed is None:
             return x + GatedMLP(self.d_ff, "silu", name="mlp")(g)
-        first, held = self.experts_held
-        g = g.reshape(B * L, D)
-        with owner("ffn"), jax.named_scope("dk_moe_route"):
-            logits = Router(self.num_experts, name="router")(g)
-            if held < self.num_experts:
-                # A share does not train its router (the module doc says why).
-                logits = jax.lax.stop_gradient(logits)
-            bias = self.variable(
-                ROUTER_BIAS, "expert_bias",
-                lambda: nn.initializers.normal(self.expert_bias_std)(
-                    self.make_rng("params"), (self.num_experts,)))
-            weights, experts, moved = route_sigmoid_bias_top_k(
-                logits, bias.value, self.experts_per_token,
-                self.routed_scaling_factor)
-            if self.expert_bias_update and not self.is_initializing() \
-                    and self.is_mutable_collection(ROUTER_BIAS):
-                # A training step: the next one chooses with a bias moved
-                # against this one's load (the module doc has the rule).
-                load = jnp.sum(experts.reshape(-1, 1)
-                               == jnp.arange(self.num_experts), axis=0,
-                               dtype=jnp.float32)
-                bias.value = bias.value + self.expert_bias_update * jnp.sign(
-                    jnp.mean(load) - load)
-        if self.is_mutable_collection(ROUND_COUNTERS):
-            count = self.variable(ROUND_COUNTERS, "assignments_moved_by_bias",
-                                  lambda: jnp.zeros((), jnp.float32))
-            if not self.is_initializing():  # init declares it, at zero
-                count.value = count.value + jnp.sum(moved, dtype=jnp.float32)
-        # For whoever asks (`mutable=["intermediates"]`): the reference check
-        # needs to know which experts this side chose (smallthinker.py).
-        self.sow("intermediates", "experts", experts.reshape(B, L, -1))
-        y = DroplessExperts(first, held, D, self.d_expert, "silu",
-                            name="moe")(g, weights, experts)
-        return x + y.reshape(B, L, D)
+        return x + self.routed(name="routed")(g)
 
 
 @register_model
@@ -186,10 +124,7 @@ class Lfm2MoeLM(DKModule):
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):
-        first, held = self.experts_held
-        if not 0 <= first <= first + held <= self.num_experts or held < 1:
-            raise ValueError(f"experts_held {self.experts_held} is no share "
-                             f"of {self.num_experts} experts")
+        check_share("experts_held", self.experts_held, self.num_experts)
         if len(self.layer_types) < self.num_layers \
                 or set(self.layer_types) - {"conv", "full_attention"}:
             raise ValueError("layer_types needs 'conv' or 'full_attention' "
@@ -204,7 +139,8 @@ class Lfm2MoeLM(DKModule):
                 "operators": [op for op, _ in kinds],
                 "feed_forward": ["routed" if r else "dense"
                                  for _, r in kinds],
-                "experts_held": [first, held], "vocab_size": self.vocab_size})
+                "experts_held": list(self.experts_held),
+                "vocab_size": self.vocab_size})
         embed = nn.Embed(self.vocab_size, self.d_model, name="tok_embed",
                          embedding_init=nn.initializers.normal(self.embed_std))
         with owner("embed"):
@@ -216,14 +152,19 @@ class Lfm2MoeLM(DKModule):
                 Lfm2Block, self,
                 attention if self.attn_impl == "flash" else 0,
                 *tokens.shape, self.num_heads, self.head_dim, x.dtype)
-        for l, (operator, routed) in enumerate(kinds):
+        routed = functools.partial(
+            RoutedFFN, self.num_experts, tuple(self.experts_held),
+            self.d_expert, "silu", functools.partial(
+                route_sigmoid_bias_top_k, k=self.experts_per_token,
+                scale=self.routed_scaling_factor),
+            expert_bias_std=self.expert_bias_std,
+            expert_bias_update=self.expert_bias_update)
+        for l, (operator, is_routed) in enumerate(kinds):
             x = block_cls(
-                operator, routed, self.num_heads, self.num_kv_heads,
-                self.head_dim, self.d_ff, self.d_expert, self.num_experts,
-                self.experts_per_token, (first, held),
-                self.routed_scaling_factor, self.expert_bias_std,
-                self.expert_bias_update, self.conv_kernel, self.rope_theta,
-                self.rms_eps, self.attn_impl, name=f"block_{l}")(x)
+                operator, routed if is_routed else None, self.num_heads,
+                self.num_kv_heads, self.head_dim, self.d_ff, self.conv_kernel,
+                self.rope_theta, self.rms_eps, self.attn_impl,
+                name=f"block_{l}")(x)
         x = RMSNorm(self.rms_eps, name="ln_final")(x)
         with owner("head"):
             return embed.attend(x)

@@ -28,7 +28,7 @@ Then ``logits = RMSNorm(x) . W_head`` over the held rows of the vocabulary.
 
 **What this chip holds** of a layer that several chips share:
 ``experts_held = (first, count)`` of the routed experts and ``vocab_size``
-rows, as in ``models/kimi_linear.py``; ``mamba_heads_held = (first, count)``
+rows, as in the other expert models; ``mamba_heads_held = (first, count)``
 of the ``mamba_num_heads`` heads of every ``mamba`` layer, in whole groups
 (``B``, ``C`` and the gated norm's groups go with their heads, so the norm
 never crosses chips), and ``heads_held = (first, count)`` of the attention's
@@ -36,10 +36,10 @@ never crosses chips), and ``heads_held = (first, count)`` of the attention's
 ``W_out``'s rows are the held heads'; the router, the shared expert and the
 norms are whole on every chip (a width is never cut). What the absent heads
 and experts would have added is left out; the sum over the chips that share a
-layer's heads (an all-reduce after ``W_out`` and ``W_o``) is not built. A
-share of the experts does not train its router, and the correction bias is
-state in ``ROUTER_BIAS`` that a training step moves against the load
-(``models/lfm2.py`` says how and why).
+layer's heads (an all-reduce after ``W_out`` and ``W_o``) is not built. The
+``moe`` layer is ``blocks.RoutedFFN``, which says why a share of the experts
+does not train its router and how the correction bias moves against the
+load.
 
 ``remat=True`` recomputes each layer in the backward pass, all but the flash
 forward of the attention layers (``blocks.remat_block``). A round's routing and
@@ -54,18 +54,18 @@ Parameters do not depend on the sequence length: build with a short sample.
 
 from __future__ import annotations
 
-import jax
+import functools
+from collections.abc import Callable
+
 import jax.numpy as jnp
 from flax import linen as nn
 
-from distkeras_tpu.models.base import (ROUND_COUNTERS, DKModule, Model,
-                                       register_model)
-from distkeras_tpu.models.blocks import (DroplessExperts,
-                                         GroupedQueryAttention, Mamba2Mixer,
-                                         PlainMLP, RMSNorm, Router,
-                                         publish_moe_round, remat_block,
+from distkeras_tpu.models.base import DKModule, Model, register_model
+from distkeras_tpu.models.blocks import (GroupedQueryAttention, Mamba2Mixer,
+                                         PlainMLP, RMSNorm, RoutedFFN,
+                                         check_share, publish_moe_round,
+                                         remat_block,
                                          route_sigmoid_bias_top_k)
-from distkeras_tpu.models.lfm2 import ROUTER_BIAS
 from distkeras_tpu.ops.delta_rule import chunk_for
 from distkeras_tpu.scopes import owner
 
@@ -78,6 +78,7 @@ PUBLISHED_LAYER_TYPES = tuple(KINDS[c] for c in PUBLISHED_PATTERN)
 
 class NemotronHBlock(nn.Module):
     kind: str              # 'mamba' | 'moe' | 'attention'
+    routed: Callable       # blocks.RoutedFFN with its fields
     num_heads: int         # attention: query heads held
     num_kv_heads: int      # attention: K/V heads held
     head_dim: int
@@ -87,20 +88,11 @@ class NemotronHBlock(nn.Module):
     mamba_head_dim: int
     state_dim: int
     conv_kernel: int
-    d_expert: int
-    d_shared: int
-    num_experts: int
-    experts_per_token: int
-    experts_held: tuple
-    routed_scaling_factor: float
-    expert_bias_std: float
-    expert_bias_update: float
     rms_eps: float
     attn_impl: str
 
     @nn.compact
     def __call__(self, x):
-        B, L, D = x.shape
         h = RMSNorm(self.rms_eps, name="norm")(x)
         if self.kind == "mamba":
             return x + Mamba2Mixer(
@@ -111,40 +103,7 @@ class NemotronHBlock(nn.Module):
             return x + GroupedQueryAttention(
                 self.num_heads, self.num_kv_heads, self.head_dim,
                 attn_impl=self.attn_impl, name="attn")(h)
-        first, held = self.experts_held
-        g = h.reshape(B * L, D)
-        with owner("ffn"), jax.named_scope("dk_moe_route"):
-            logits = Router(self.num_experts, name="router")(g)
-            if held < self.num_experts:
-                # A share does not train its router (lfm2.py says why).
-                logits = jax.lax.stop_gradient(logits)
-            bias = self.variable(
-                ROUTER_BIAS, "expert_bias",
-                lambda: nn.initializers.normal(self.expert_bias_std)(
-                    self.make_rng("params"), (self.num_experts,)))
-            weights, experts, moved = route_sigmoid_bias_top_k(
-                logits, bias.value, self.experts_per_token,
-                self.routed_scaling_factor, eps=1e-20)
-            if self.expert_bias_update and not self.is_initializing() \
-                    and self.is_mutable_collection(ROUTER_BIAS):
-                load = jnp.sum(experts.reshape(-1, 1)
-                               == jnp.arange(self.num_experts), axis=0,
-                               dtype=jnp.float32)
-                bias.value = bias.value + self.expert_bias_update * jnp.sign(
-                    jnp.mean(load) - load)
-        if self.is_mutable_collection(ROUND_COUNTERS):
-            count = self.variable(ROUND_COUNTERS, "assignments_moved_by_bias",
-                                  lambda: jnp.zeros((), jnp.float32))
-            if not self.is_initializing():  # init declares it, at zero
-                count.value = count.value + jnp.sum(moved, dtype=jnp.float32)
-        # For whoever asks (`mutable=["intermediates"]`): the reference check
-        # needs to know which experts this side chose (smallthinker.py).
-        self.sow("intermediates", "experts", experts.reshape(B, L, -1))
-        y = DroplessExperts(first, held, D, self.d_expert, "relu2",
-                            gated=False, name="moe")(g, weights, experts)
-        with owner("ffn"), jax.named_scope("dk_moe_shared"):
-            y = y + PlainMLP(self.d_shared, "relu2", name="shared")(g)
-        return x + y.reshape(B, L, D)
+        return x + self.routed(name="routed")(h)
 
 
 @register_model
@@ -184,17 +143,15 @@ class NemotronHLM(DKModule):
         per_group = self.mamba_num_heads // self.n_groups
         per_kv = self.num_heads // self.num_kv_heads
         query = self.heads_held[1]
-        for what, (first, held), of, grain in (
-                ("experts_held", self.experts_held, self.num_experts, 1),
-                ("mamba_heads_held", self.mamba_heads_held,
-                 self.mamba_num_heads, per_group),
-                ("heads_held", self.heads_held, self.num_heads,
-                 min(per_kv, query))):
-            if not 0 <= first <= first + held <= of or held < 1 \
-                    or first % grain or held % grain \
-                    or (what == "heads_held" and per_kv % grain):
-                raise ValueError(f"{what} {(first, held)} is no share of {of} "
-                                 f"in whole groups of {grain}")
+        grain = min(per_kv, query)
+        check_share("experts_held", self.experts_held, self.num_experts)
+        check_share("mamba_heads_held", self.mamba_heads_held,
+                    self.mamba_num_heads, per_group)
+        check_share("heads_held", self.heads_held, self.num_heads, grain)
+        if per_kv % grain:  # inside one K/V head's group, shares divide it
+            raise ValueError(f"heads_held {tuple(self.heads_held)} is no "
+                             f"share of {self.num_heads} in whole groups of "
+                             f"{grain}")
         return {"mamba_heads": self.mamba_heads_held[1],
                 "mamba_groups": self.mamba_heads_held[1] // per_group,
                 "mamba_first": self.mamba_heads_held[0],
@@ -238,15 +195,20 @@ class NemotronHLM(DKModule):
                 NemotronHBlock, self,
                 attention if self.attn_impl == "flash" else 0,
                 B, L, shares["num_heads"], self.head_dim, x.dtype)
+        routed = functools.partial(
+            RoutedFFN, self.num_experts, tuple(self.experts_held),
+            self.d_expert, "relu2", functools.partial(
+                route_sigmoid_bias_top_k, k=self.experts_per_token,
+                scale=self.routed_scaling_factor, eps=1e-20),
+            gated=False, expert_bias_std=self.expert_bias_std,
+            expert_bias_update=self.expert_bias_update,
+            shared=functools.partial(PlainMLP, self.d_shared, "relu2"))
         for l, kind in enumerate(kinds):
             x = block_cls(
-                kind, shares["num_heads"], shares["num_kv_heads"],
+                kind, routed, shares["num_heads"], shares["num_kv_heads"],
                 self.head_dim, shares["mamba_heads"], shares["mamba_groups"],
                 shares["mamba_first"], self.mamba_head_dim,
-                self.ssm_state_size, self.conv_kernel, self.d_expert,
-                self.d_shared, self.num_experts, self.experts_per_token,
-                tuple(self.experts_held), self.routed_scaling_factor,
-                self.expert_bias_std, self.expert_bias_update, self.rms_eps,
+                self.ssm_state_size, self.conv_kernel, self.rms_eps,
                 self.attn_impl, name=f"block_{l}")(x)
         x = RMSNorm(self.rms_eps, name="ln_final")(x)
         with owner("head"):
@@ -261,9 +223,7 @@ class NemotronHLM(DKModule):
         ``ssd.mean_dt``, and one ``ssd.round`` event."""
         from distkeras_tpu import telemetry
 
-        routed = {name: c for name, c in counters.items() if "moe" in c}
-        if routed:
-            publish_moe_round(round_index, routed, self.experts_per_token)
+        publish_moe_round(round_index, counters, self.experts_per_token)
         ssd = [c["mixer"] for _, c in sorted(counters.items())
                if "mixer" in c]
         if not ssd:

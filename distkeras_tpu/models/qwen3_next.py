@@ -27,10 +27,11 @@ projection:
 
 **What this chip holds** of a layer that several chips share:
 ``experts_held = (first, count)`` of the routed experts and ``vocab_size``
-rows, as in ``models/smallthinker.py``; the heads of both operators, the
+rows, as in the other expert models; the heads of both operators, the
 router and the shared expert are whole on every chip. What the absent
-experts would have added is left out. A share of the experts does not train
-its router (``models/smallthinker.py`` says why).
+experts would have added is left out. The feed-forward is
+``blocks.RoutedFFN``, which says why a share of the experts does not train
+its router.
 
 ``remat=True`` recomputes each layer in the backward pass, all but the flash
 forward of the attention layers (``blocks.remat_block``). A round's routing,
@@ -45,16 +46,18 @@ Parameters do not depend on the sequence length: build with a short sample.
 
 from __future__ import annotations
 
-import jax
+import functools
+from collections.abc import Callable
+
 import jax.numpy as jnp
 from flax import linen as nn
 
-from distkeras_tpu.models.base import (ROUND_COUNTERS, DKModule, Model,
-                                       register_model)
-from distkeras_tpu.models.blocks import (DroplessExperts, GatedDeltaNet,
-                                         GatedMLP, GroupedQueryAttention,
-                                         RMSNorm, Router, publish_moe_round,
-                                         remat_block, route_top_k)
+from distkeras_tpu.models.base import DKModule, Model, register_model
+from distkeras_tpu.models.blocks import (GatedDeltaNet, GatedMLP,
+                                         GroupedQueryAttention, RMSNorm,
+                                         RoutedFFN, check_share,
+                                         publish_moe_round, remat_block,
+                                         route_top_k)
 from distkeras_tpu.ops.delta_rule import chunk_for
 from distkeras_tpu.scopes import owner
 
@@ -66,6 +69,7 @@ PUBLISHED_LAYER_TYPES = tuple(
 
 class Qwen3NextBlock(nn.Module):
     operator: str          # 'gdn' | 'attention'
+    routed: Callable       # blocks.RoutedFFN with its fields
     num_heads: int
     num_kv_heads: int
     head_dim: int
@@ -75,17 +79,11 @@ class Qwen3NextBlock(nn.Module):
     linear_value_heads: int
     linear_head_dim: int
     conv_kernel: int
-    d_expert: int
-    d_shared: int
-    num_experts: int
-    experts_per_token: int
-    experts_held: tuple
     rms_eps: float
     attn_impl: str
 
     @nn.compact
     def __call__(self, x):
-        B, L, D = x.shape
         h = RMSNorm(self.rms_eps, name="ln_op")(x)
         if self.operator == "gdn":
             x = x + GatedDeltaNet(
@@ -98,32 +96,8 @@ class Qwen3NextBlock(nn.Module):
                 rope_theta=self.rope_theta, attn_impl=self.attn_impl,
                 qk_norm=self.rms_eps, rotary_dim=self.rotary_dim,
                 output_gate=True, name="attn")(h)
-        g = RMSNorm(self.rms_eps, name="ln_ffn")(x).reshape(B * L, D)
-        first, held = self.experts_held
-        with owner("ffn"), jax.named_scope("dk_moe_route"):
-            logits = Router(self.num_experts, name="router")(g)
-            if held < self.num_experts:
-                # A share does not train its router (smallthinker.py says why).
-                logits = jax.lax.stop_gradient(logits)
-            weights, experts = route_top_k(logits, self.experts_per_token)
-        # For whoever asks (`mutable=["intermediates"]`): the reference check
-        # needs to know which experts this side chose (smallthinker.py).
-        self.sow("intermediates", "experts", experts.reshape(B, L, -1))
-        y = DroplessExperts(first, held, D, self.d_expert, "silu",
-                            name="moe")(g, weights, experts)
-        with owner("ffn"), jax.named_scope("dk_moe_shared"):
-            shared = GatedMLP(self.d_shared, "silu", name="shared")(g)
-            gate = jax.nn.sigmoid(nn.Dense(
-                1, use_bias=False, name="shared_gate")(g).astype(jnp.float32))
-            y = y + (gate * shared.astype(jnp.float32)).astype(y.dtype)
-        if self.is_mutable_collection(ROUND_COUNTERS):
-            for name, value in (("shared_gate_sum", jnp.sum(gate)),
-                                ("shared_gate_count", jnp.float32(gate.size))):
-                var = self.variable(ROUND_COUNTERS, name,
-                                    lambda: jnp.zeros((), jnp.float32))
-                if not self.is_initializing():  # init declares them, at zero
-                    var.value = var.value + value
-        return x + y.reshape(B, L, D)
+        g = RMSNorm(self.rms_eps, name="ln_ffn")(x)
+        return x + self.routed(name="routed")(g)
 
 
 @register_model
@@ -153,10 +127,7 @@ class Qwen3NextLM(DKModule):
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):
-        first, held = self.experts_held
-        if not 0 <= first <= first + held <= self.num_experts or held < 1:
-            raise ValueError(f"experts_held {self.experts_held} is no share "
-                             f"of {self.num_experts} experts")
+        check_share("experts_held", self.experts_held, self.num_experts)
         if len(self.layer_types) < self.num_layers \
                 or set(self.layer_types) - {"gdn", "attention"}:
             raise ValueError("layer_types needs 'gdn' or 'attention' for each "
@@ -188,15 +159,19 @@ class Qwen3NextLM(DKModule):
                 Qwen3NextBlock, self,
                 attention if self.attn_impl == "flash" else 0,
                 B, L, self.num_heads, self.head_dim, x.dtype)
+        routed = functools.partial(
+            RoutedFFN, self.num_experts, tuple(self.experts_held),
+            self.d_expert, "silu",
+            functools.partial(route_top_k, k=self.experts_per_token),
+            shared=functools.partial(GatedMLP, self.d_shared, "silu"),
+            shared_gate=True)
         for l, operator in enumerate(kinds):
             x = block_cls(
-                operator, self.num_heads, self.num_kv_heads, self.head_dim,
-                self.rotary_dim, self.rope_theta, self.linear_key_heads,
-                self.linear_value_heads, self.linear_head_dim,
-                self.conv_kernel, self.d_expert, self.d_shared,
-                self.num_experts, self.experts_per_token,
-                tuple(self.experts_held), self.rms_eps, self.attn_impl,
-                name=f"block_{l}")(x)
+                operator, routed, self.num_heads, self.num_kv_heads,
+                self.head_dim, self.rotary_dim, self.rope_theta,
+                self.linear_key_heads, self.linear_value_heads,
+                self.linear_head_dim, self.conv_kernel, self.rms_eps,
+                self.attn_impl, name=f"block_{l}")(x)
         x = RMSNorm(self.rms_eps, name="ln_final")(x)
         with owner("head"):
             return nn.Dense(

@@ -19,19 +19,9 @@ experts (``models/blocks.py::DroplessExperts``): the router keeps its
 published width and ``k``, the weights are normalised over all chosen experts
 held or not, and what the absent experts would have added is left out.
 ``vocab_size`` is the rows of the vocabulary held here: embedding, head and
-loss are over that slice, and the data draws its ids from it.
-
-**A share does not train its router.** Where fewer experts are held than
-routed over, no gradient passes through the router's logits. The gradient a
-share can compute is the held experts' part of the router's, and the absent
-experts' part is what balances it: applied alone, it sends every token to the
-held experts (measured on the chip at the published widths, Adam 3e-4: by the
-second round of four steps one layer routed all 98,304 of a step's 98,304
-assignments to its 8 held experts, and the round's time swung by 17 % with
-the load; PERF.md §6, PR 28). In the deployment that part arrives with the
-exchange this cut leaves out, so the share routes with the router it was
-given, and the load it sees is the deployment's. A module that holds every
-expert trains its router as any other parameter.
+loss are over that slice, and the data draws its ids from it. The
+feed-forward is ``blocks.RoutedFFN``, which says why a share does not train
+its router.
 
 ``remat=True`` recomputes each block in the backward pass, all but the flash
 kernel's forward: its ``out`` and ``lse`` are kept from the first pass (2 B x
@@ -46,15 +36,17 @@ would be ``[28, 8192, 8192]`` float32).
 
 from __future__ import annotations
 
-import jax
+import functools
+from collections.abc import Callable
+
 import jax.numpy as jnp
 from flax import linen as nn
 
 from distkeras_tpu.models.base import DKModule, Model, register_model
-from distkeras_tpu.models.blocks import (DroplessExperts,
-                                         GroupedQueryAttention, RMSNorm,
-                                         Router, publish_moe_round,
-                                         remat_block, route_top_k)
+from distkeras_tpu.models.blocks import (GroupedQueryAttention, RMSNorm,
+                                         RoutedFFN, check_share,
+                                         publish_moe_round, remat_block,
+                                         route_top_k)
 from distkeras_tpu.scopes import owner
 
 
@@ -62,10 +54,7 @@ class SmallThinkerBlock(nn.Module):
     num_heads: int
     num_kv_heads: int
     head_dim: int
-    d_expert: int
-    num_experts: int
-    experts_per_token: int
-    experts_held: tuple
+    routed: Callable       # blocks.RoutedFFN with its fields
     window: int | None
     rope_theta: float | None
     rms_eps: float
@@ -73,28 +62,14 @@ class SmallThinkerBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        B, L, D = x.shape
-        first, held = self.experts_held
-        with owner("ffn"), jax.named_scope("dk_moe_route"):
-            logits = Router(self.num_experts, name="router")(
-                x.reshape(B * L, D))
-            if held < self.num_experts:
-                # A share does not train its router (the module doc says why).
-                logits = jax.lax.stop_gradient(logits)
-            weights, experts = route_top_k(logits, self.experts_per_token)
-        # For whoever asks (`mutable=["intermediates"]`): top-k is
-        # discontinuous, and a comparison in another precision needs to know
-        # which experts this side chose.
-        self.sow("intermediates", "experts", experts.reshape(B, L, -1))
         h = RMSNorm(self.rms_eps, name="ln_attn")(x)
-        x = x + GroupedQueryAttention(
+        y = x + GroupedQueryAttention(
             self.num_heads, self.num_kv_heads, self.head_dim,
             window=self.window, rope_theta=self.rope_theta,
             attn_impl=self.attn_impl, name="attn")(h)
-        g = RMSNorm(self.rms_eps, name="ln_moe")(x)
-        y = DroplessExperts(first, held, D, self.d_expert, name="moe")(
-            g.reshape(B * L, D), weights, experts)
-        return x + y.reshape(B, L, D)
+        g = RMSNorm(self.rms_eps, name="ln_moe")(y)
+        # The router reads the block's input, from before attention.
+        return y + self.routed(name="routed")(g, route_from=x)
 
 
 @register_model
@@ -119,10 +94,7 @@ class SmallThinkerLM(DKModule):
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):
-        first, held = self.experts_held
-        if not 0 <= first <= first + held <= self.num_experts or held < 1:
-            raise ValueError(f"experts_held {self.experts_held} is no share "
-                             f"of {self.num_experts} experts")
+        check_share("experts_held", self.experts_held, self.num_experts)
         if min(len(self.rope_layout), len(self.window_layout)) \
                 < self.num_layers:
             raise ValueError("rope_layout and window_layout need an entry a "
@@ -140,11 +112,13 @@ class SmallThinkerLM(DKModule):
                 SmallThinkerBlock, self,
                 self.num_layers if self.attn_impl == "flash" else 0,
                 *tokens.shape, self.num_heads, self.head_dim, x.dtype)
+        routed = functools.partial(
+            RoutedFFN, self.num_experts, tuple(self.experts_held),
+            self.d_expert, "relu",
+            functools.partial(route_top_k, k=self.experts_per_token))
         for l in range(self.num_layers):
             x = block_cls(
-                self.num_heads, self.num_kv_heads, self.head_dim,
-                self.d_expert, self.num_experts, self.experts_per_token,
-                (first, held),
+                self.num_heads, self.num_kv_heads, self.head_dim, routed,
                 window=self.window if self.window_layout[l] else None,
                 rope_theta=self.rope_theta if self.rope_layout[l] else None,
                 rms_eps=self.rms_eps, attn_impl=self.attn_impl,

@@ -150,8 +150,9 @@ _METRICS = [
     # -- expert layers ----------------------------------------------------
     _m("moe.assignments_held", "counter", "models",
        "Token-to-expert assignments routed to the experts held here, summed "
-       "over layers, added once a round (`models/blocks.py::DroplessExperts` "
-       "counts; `publish_round_counters` adds)."),
+       "over layers, added once a round (the expert layer of "
+       "`models/blocks.py::RoutedFFN`, the one routed feed-forward, counts; "
+       "`publish_round_counters` adds)."),
     _m("moe.load_max_over_mean", "gauge", "models",
        "Last round's largest load of a held expert over the mean load, in "
        "the layer where that is worst (1.0: even)."),
@@ -165,8 +166,9 @@ _METRICS = [
     _m("moe.bias_moved_share", "gauge", "models",
        "Last round's share of token-to-expert assignments whose expert the "
        "unbiased top-k would not have chosen: what the router's selection "
-       "bias moved (`blocks.route_sigmoid_bias_top_k` marks them; models "
-       "whose router has no bias never set it)."),
+       "bias moved (`blocks.RoutedFFN` counts what "
+       "`route_sigmoid_bias_top_k` marks as `assignments_moved_by_bias`; "
+       "models whose router has no bias never set it)."),
     _m("moe.padded_expert_layers", "counter", "models",
        "Expert layers traced with their grouped products at a padded width "
        "(`models/blocks.py::padded_expert_width`: the expert width rounded "
@@ -221,8 +223,9 @@ _METRICS = [
        "layers' tokens and held heads."),
     _m("moe.shared_gate_mean", "gauge", "models",
        "Last round's mean of the shared expert's gate `sigmoid(g W_sg)` over "
-       "the layers' tokens (Qwen3-Next; models whose shared expert has no "
-       "gate never set it)."),
+       "the layers' tokens (`blocks.RoutedFFN` counts `shared_gate_sum` and "
+       "`shared_gate_count` where the shared expert has a gate: Qwen3-Next; "
+       "models whose shared expert has none never set it)."),
     _m("pallas.kda.heads_per_program", "gauge", "kernels",
        "Heads one program of the delta rule's scan kernels holds, set as a "
        "call is traced (`ops/pallas/delta_rule.py::heads_per_program`: the "

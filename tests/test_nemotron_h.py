@@ -25,11 +25,10 @@ import distkeras_tpu as dk  # noqa: E402
 from benchmarks.references import nemotron_h as reference  # noqa: E402
 from distkeras_tpu import telemetry  # noqa: E402
 from distkeras_tpu.models import NemotronHLM, small_nemotron_h_lm  # noqa: E402
-from distkeras_tpu.models.base import ROUND_COUNTERS  # noqa: E402
+from distkeras_tpu.models.base import ROUND_COUNTERS, ROUTER_BIAS  # noqa: E402
 from distkeras_tpu.models.blocks import (DroplessExperts,  # noqa: E402
                                          GroupedQueryAttention, Mamba2Mixer,
                                          PlainMLP, route_sigmoid_bias_top_k)
-from distkeras_tpu.models.lfm2 import ROUTER_BIAS  # noqa: E402
 from distkeras_tpu.models.nemotron_h import (PUBLISHED_LAYER_TYPES,  # noqa: E402
                                              PUBLISHED_PATTERN)
 from distkeras_tpu.ops.delta_rule import chunked_ssd  # noqa: E402
