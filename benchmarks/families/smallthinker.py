@@ -56,13 +56,14 @@ def build_model(config: dict, seed: int):
         seed=seed)
 
 
-def make_dataframe(config: dict, rows: int, seed: int):
+def make_dataframe(config: dict, rows: int, seed: int, ids: str = "zipf"):
     """The seeded learnable stream of ``transformer_lm``, its ids drawn from
-    the held rows of the vocabulary."""
+    the held rows of the vocabulary, by the law the workload's ``feed.ids``
+    names."""
     import distkeras_tpu as dk
 
     x, y = learnable_tokens(rows, config["seq_len"],
-                            config["module"]["vocab_size"], seed)
+                            config["module"]["vocab_size"], seed, ids=ids)
     return dk.DataFrame({"features": x, "label": y})
 
 

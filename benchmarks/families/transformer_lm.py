@@ -24,14 +24,35 @@ def build_model(config: dict, seed: int):
                        jnp.zeros((1, config["seq_len"]), jnp.int32), seed=seed)
 
 
-def learnable_tokens(n: int, seq: int, vocab: int, seed: int):
-    """A seeded token stream a language model can learn (a copy of
-    ``chip_smoke.learnable_tokens``): Zipf unigrams, and three quarters of the
-    transitions follow one fixed successor map. Uniform noise would pin the
-    loss at ln V, and a falling loss would prove nothing."""
+#: How a stream's fresh ids are drawn, by the name a workload's ``feed.ids``
+#: gives: Zipf over the vocabulary, or uniform over it.
+IDS = ("zipf", "uniform")
+
+
+def learnable_tokens(n: int, seq: int, vocab: int, seed: int,
+                     ids: str = "zipf"):
+    """A seeded token stream a language model can learn
+    (``chip_smoke.learnable_tokens``, with a choice of law): fresh ids, and
+    three quarters of the transitions follow one fixed successor map.
+    Uniform noise would pin the loss at ln V, and a falling loss would prove
+    nothing.
+
+    ``ids="zipf"`` draws the fresh ids by Zipf's law: a few hundred ids and
+    their successors then carry half of the stream. ``"uniform"`` draws them
+    evenly over the vocabulary; the successor map keeps the stream learnable.
+    A router that is not trained sends an id to the same experts all run
+    long, so under Zipf the few ids at the head decide the share of the work
+    the experts a chip holds get, and that share moves with the seed; evenly
+    drawn ids leave it to the router's own columns and to what training does
+    to the stream (PERF.md §6)."""
+    if ids not in IDS:
+        raise ValueError(f"ids {ids!r}: one of {IDS}")
     rng = np.random.default_rng(seed)
-    p = 1.0 / np.arange(1, vocab + 1)
-    draws = rng.choice(vocab, size=(n, seq + 1), p=p / p.sum())
+    if ids == "zipf":
+        p = 1.0 / np.arange(1, vocab + 1)
+        draws = rng.choice(vocab, size=(n, seq + 1), p=p / p.sum())
+    else:
+        draws = rng.integers(0, vocab, size=(n, seq + 1))
     follow = rng.random((n, seq + 1)) < 0.75
     x = draws.copy()
     for t in range(1, seq + 1):

@@ -15,7 +15,9 @@ Phases, each ending on a tick:
 
 1. warm-up: the first round (which compiles) and two more;
 2. traced runs only: the profiler runs for whole rounds, about 3 s and at
-   least 4 rounds (at most 8 s), inside a ``TraceAnnotation``. The round in
+   least 4 rounds (at most 8 s), inside a ``TraceAnnotation``; a rehearsal
+   (``trace_min_s=0``) stops at the 4 rounds, since a CPU's trace of 3 s holds
+   millions of thread-pool events and measures nothing. The round in
    flight as the trace starts and the one in flight as it stops are cut, so
    four ticks leave a bracket of three whole rounds on the device. Then
    ``stop_trace`` (seconds, during which the host dispatches nothing) and two
@@ -45,11 +47,13 @@ class WindowClosed(BaseException):
 
 class Window:
     def __init__(self, seconds: float, *, t_start: float, trace_dir=None,
-                 on_open=None, on_close=None, clock=time.perf_counter):
+                 trace_min_s: float = TRACE_MIN_S, on_open=None, on_close=None,
+                 clock=time.perf_counter):
         self.seconds = float(seconds)
         self.t_start = t_start          # process start, on ``clock``
         self.t_train = None             # set by run.py as train() is called
         self.trace_dir = trace_dir      # None: untraced
+        self.trace_min_s = trace_min_s
         self.on_open, self.on_close = on_open, on_close
         self.clock = clock
         self.ticks: list = []           # ticks[i]: round i's loss fetched
@@ -82,7 +86,7 @@ class Window:
                 self._start_trace(i)
         elif self.phase == "trace":
             took, n = now - self.ticks[self._trace_open], i - self._trace_open
-            if (took >= TRACE_MIN_S and n >= TRACE_MIN_ROUNDS) \
+            if (took >= self.trace_min_s and n >= TRACE_MIN_ROUNDS) \
                     or (took >= TRACE_MAX_S and n >= 1):
                 self._stop_trace(n)
                 self._settle_until = i + SETTLE_ROUNDS

@@ -4,8 +4,8 @@ width 256 (``families/qwen3_next.py``).
 
 The program names the chain with ``jax.named_scope`` inside ``dk_fwd_bwd``,
 forward, recomputed and backward alike: ``dk_gdn_conv`` (the tap sums over
-``q | k | v`` and their SiLU), ``dk_gdn`` (norms, the decay, the repeat of the
-key heads, the kernels of the chunked form, the gated norm). An instruction
+``q | k | v`` and their SiLU), ``dk_gdn`` (norms, the decay, the kernels of
+the chunked form, the gated norm). An instruction
 counts for the scopes as ``trace_kda`` counts (:func:`trace_kda.classify`,
 :func:`trace_kda.reduce`, imported, not copied): its own ``op_name`` under one
 of them, or a fusion that fused such an instruction and holds no matmul; a
@@ -15,10 +15,9 @@ time.
 
 **The floor is of Gated DeltaNet's work, whatever implements it**
 (:func:`gdn_floor`): one decay a value head, and ``q``, ``k`` at the key
-heads. Today the chain broadcasts the decay over the key channels and
-repeats ``q`` and ``k`` to the value heads before the kernels Kimi Delta
-Attention runs; a later kernel that reads the scalar decay or the key heads
-by group reads as a higher share.
+heads. The chain's in-chunk pair ``dk_gdn_chunk_*`` reads the decay a value
+head and ``q``, ``k`` at the key heads by group, with no repeat to the value
+heads, and feeds the scan pair Kimi Delta Attention runs.
 
 Where there is nothing to read, ``read`` returns ``None`` and says why on a
 ``[bench`` line: no trace, or a program without the scopes (the parent of the

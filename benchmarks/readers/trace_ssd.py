@@ -5,7 +5,7 @@ recurrence's own work, and the plain (not gated) experts' share of theirs
 The program names the chain with ``jax.named_scope`` inside ``dk_fwd_bwd``,
 forward, recomputed and backward alike: ``dk_ssd_conv`` (the tap sums over
 ``x | B | C``, the convolution's bias and SiLU), ``dk_ssd`` (``dt``, the
-decay, ``dt * x``, the in-chunk half in ``jax.numpy``, the scan pair
+decay, ``dt * x``, the in-chunk kernel pair ``dk_ssd_chunk_*``, the scan pair
 ``dk_kda_scan_*`` with no ``W``, the ``D`` skip, the gated norm). An
 instruction counts for the scopes as ``trace_kda`` counts
 (:func:`trace_kda.classify`, :func:`trace_kda.reduce`, imported, not copied):

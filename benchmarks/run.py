@@ -140,6 +140,16 @@ class CompileLog:
         return sum(1 for t, _ in self.ends if t0 < t <= t1)
 
 
+def make_window(args, trace_dir, **hooks):
+    """The run's clock (``harness/window.py``) as ``main`` builds it. On the
+    chip the trace spans about ``TRACE_MIN_S``; a rehearsal's closes at the
+    fewest rounds the bracket's rule takes, since it measures nothing."""
+    from benchmarks.harness.window import TRACE_MIN_S, Window
+
+    return Window(args.seconds, t_start=T_START, trace_dir=trace_dir,
+                  trace_min_s=0.0 if args.rehearse else TRACE_MIN_S, **hooks)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -152,7 +162,7 @@ def main(argv=None) -> int:
     traced = bool(args.trace)
 
     from benchmarks.harness import peaks, result_line, trace_reduce
-    from benchmarks.harness.window import Window, WindowClosed
+    from benchmarks.harness.window import WindowClosed
 
     with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
         manifest = json.load(f)
@@ -215,7 +225,11 @@ def main(argv=None) -> int:
             f"the built model disagrees with references/{config['family']}.py "
             f"on the logits: relative L2 {check['rel_l2']:.3g} above "
             f"{check['tolerance']:g}")
-    df = family.make_dataframe(config, workload["feed"]["rows"], args.seed)
+    # The feed's other keys are the traffic's parameters for the generator.
+    feed = {k: v for k, v in workload["feed"].items()
+            if k not in ("rows", "num_epoch")}
+    df = family.make_dataframe(config, workload["feed"]["rows"], args.seed,
+                               **feed)
     steps, batch = kwargs[workload["trainer"]["steps_kwarg"]], kwargs["batch_size"]
     units_per_round = (kwargs["num_workers"] * steps * batch
                        * family.units_per_sample(config))
@@ -223,10 +237,10 @@ def main(argv=None) -> int:
     tele = telemetry.get()
     marks = {}
     trace_dir = tempfile.mkdtemp(prefix="dkbench-trace-") if traced else None
-    window = Window(args.seconds, t_start=T_START, trace_dir=trace_dir,
-                    on_open=lambda: marks.update(open=tele.mark()),
-                    on_close=lambda: marks.update(spans=tele.delta(
-                        marks["open"])[0]["spans"]))
+    window = make_window(
+        args, trace_dir, on_open=lambda: marks.update(open=tele.mark()),
+        on_close=lambda: marks.update(spans=tele.delta(
+            marks["open"])[0]["spans"]))
     trainer = getattr(dk, workload["trainer"]["class"])(
         model, **kwargs, num_epoch=workload["feed"]["num_epoch"], seed=seed,
         on_round=window)

@@ -2,27 +2,31 @@
 its files agree, the result line's check refuses what the driver would, the
 trace reduction does its arithmetic on hand-made intervals, the FLOPs come out
 of the shapes, each plain reference agrees with its family's tiny preset and
-notices a skipped block, and ``run.py --rehearse`` prints an accepted line for
-every cell. All on the CPU; nothing here is a measurement."""
+notices a skipped block, the window traces by its rule, and every cell has a
+file of its own that rehearses ``run.py --rehearse`` (``rehearsal.py``). All
+on the CPU; nothing here is a measurement."""
 
 from __future__ import annotations
 
+import argparse
 import copy
 import glob
 import importlib
 import json
 import os
 import re
-import subprocess
 import sys
 
 import pytest
+import rehearsal
+from test_manifest_appends import appended
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 BENCH = os.path.join(ROOT, "benchmarks")
 
+from benchmarks import run as bench_run  # noqa: E402
 from benchmarks.harness import result_line, trace_reduce  # noqa: E402
 from benchmarks.harness.window import Window, WindowClosed  # noqa: E402
 from benchmarks.readers import trace_collective  # noqa: E402
@@ -277,6 +281,36 @@ def test_window_opens_after_warmup_and_closes_on_a_tick():
     assert w.segment_losses() == [3.0, 4.0, 5.0, 6.0, 7.0, 8.0, 9.0]
 
 
+@pytest.mark.parametrize("rehearse, round_s, rounds", [
+    (False, 0.25, 12),  # on the chip: about 3 s ...
+    (False, 1.5, 4),    # ... and at least 4 rounds ...
+    (False, 5.0, 2),    # ... but at most 8 s
+    (True, 0.25, 4),    # a rehearsal: the 4 rounds alone
+    (True, 5.0, 2),
+])
+def test_the_trace_spans_the_rounds_run_py_asks_for(
+        monkeypatch, rehearse, round_s, rounds):
+    """The window as ``run.py`` builds it, with and without ``--rehearse``,
+    on a hand-made clock; the profiler's calls are stubbed."""
+    import jax
+
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda *a, **k: calls.append("start"))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: calls.append("stop"))
+    args = argparse.Namespace(seconds=30.0, rehearse=rehearse)
+    now = [0.0]
+    w = bench_run.make_window(args, "unused", clock=lambda: now[0])
+    for r in range(40):
+        now[0] = round_s * r
+        w(r, float(r))
+        if w.phase != "trace" and w.trace_rounds:
+            break
+    assert calls == ["start", "stop"]
+    assert w.trace_rounds == rounds
+
+
 # ---------------------------------------------------------------------------
 # operations from shapes
 # ---------------------------------------------------------------------------
@@ -361,50 +395,42 @@ def test_reference_pins_the_form_of_gelu():
 
 
 # ---------------------------------------------------------------------------
-# the command, rehearsed
+# the command, rehearsed: each cell in ``test_rehearse_<cell>.py``
 # ---------------------------------------------------------------------------
 
-def _run(cell, trace, *extra, devices=None):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    chips = next(w["chips"] for w in MANIFEST["workloads"] if w["name"] == cell)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices or chips}"
-    return subprocess.run(
-        [sys.executable, os.path.join(BENCH, "run.py"), "--workload", cell,
-         "--seed", "3000000019", "--seconds", "2", "--trace", str(trace),
-         *extra], env=env, capture_output=True, text=True, timeout=600,
-        cwd=ROOT)
+def check_every_cell_is_rehearsed(manifest):
+    """Each cell of ``manifest`` has its ``test_rehearse_<cell>.py``, naming
+    it, and every such file rehearses a cell of ``manifest``: as sets."""
+    files = rehearsal.rehearsed_cells()
+    cells = {w["name"] for w in manifest["workloads"]}
+    missing = sorted(cells - set(files))
+    assert not missing, "no rehearsal for cell(s) " + ", ".join(
+        f"{c} (add tests/benchmarks/{rehearsal.file_name(c)})" for c in missing)
+    stray = {files[c] for c in set(files) - cells}
+    assert not stray, f"rehearsal files of no cell: {sorted(stray)}"
+    misnamed = {c: f for c, f in files.items() if f != rehearsal.file_name(c)}
+    assert not misnamed, f"a rehearsal file under another name: {misnamed}"
 
 
-@pytest.mark.parametrize("trace", [0, 1])
-@pytest.mark.parametrize("cell", CELLS)
-def test_rehearsal_prints_a_line_the_check_accepts(cell, trace):
-    chips = next(w["chips"] for w in MANIFEST["workloads"] if w["name"] == cell)
-    done = _run(cell, trace, "--rehearse")
-    if chips > 1 and trace:
-        # The CPU backend's trace holds no event for the collective (its
-        # thunks run untraced), so the one reader that needs it finds nothing
-        # and the run must fail, naming the metric: never a line without it.
-        assert done.returncode == 2, done.stderr[-2000:]
-        assert "fold.allreduce_ms.lm" in done.stderr
-        assert not done.stdout.strip().splitlines()[-1].startswith("{")
-        return
-    assert done.returncode == 0, done.stderr[-3000:]
-    line = json.loads(done.stdout.strip().splitlines()[-1])
-    declared = result_line.declared_metrics(MANIFEST, cell, bool(trace))
-    result_line.validate(line, declared, chips, bool(trace), platform="cpu")
-    assert line["correct"] is True and line["failed"] == 0
-    assert line["attempted"] >= 1
-    assert ("breakdown" in line) == bool(trace)
+def test_every_cell_has_a_rehearsal_file_of_its_own():
+    check_every_cell_is_rehearsed(MANIFEST)
+
+
+def test_a_cell_appended_without_its_rehearsal_file_is_refused_by_name():
+    """The manifest as a later PR leaves it (``test_manifest_appends.py``):
+    its cell has no file, and the guard names it."""
+    with pytest.raises(AssertionError, match="a_later_cell"):
+        check_every_cell_is_rehearsed(appended(MANIFEST))
 
 
 def test_without_rehearse_a_cpu_is_refused_by_name():
-    done = _run(CELLS[0], 0)
+    done = rehearsal.run(CELLS[0], 0)
     assert done.returncode != 0
     assert "'cpu'" in done.stderr
     assert not any(ln.startswith("{") for ln in done.stdout.splitlines())
 
 
 def test_wrong_number_of_chips_is_refused():
-    done = _run(CELLS[0], 0, "--rehearse", devices=2)
+    done = rehearsal.run(CELLS[0], 0, "--rehearse", devices=2)
     assert done.returncode != 0 and "chip" in done.stderr
     assert not any(ln.startswith("{") for ln in done.stdout.splitlines())

@@ -4,8 +4,7 @@ the numbers of the issue that added it; the new floors against hand-computed
 values; the reader's reduction on a hand-made program and trace; the
 reference's limits at the tiny preset. All on the CPU; nothing here is a
 measurement. (The rehearsal of ``kimi_linear_aeasgd_w1``, traced and
-untraced, is one of ``test_benchmark.py``'s cases: it rehearses every cell of
-the manifest.)"""
+untraced, is ``test_rehearse_kimi_linear_aeasgd_w1.py``.)"""
 
 from __future__ import annotations
 

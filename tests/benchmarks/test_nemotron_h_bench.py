@@ -4,8 +4,8 @@ the deployment's arithmetic; the two new floors against
 hand-computed values; the reader's reduction on a hand-made program and
 trace. All on the CPU; nothing here is a measurement. (The rehearsal of
 ``nemotron3nano_aeasgd_w1``, traced and untraced, through ``run.py
---rehearse`` and ``harness/result_line.py``'s check, is one of
-``test_benchmark.py``'s cases: it rehearses every cell of the manifest.)"""
+--rehearse`` and ``harness/result_line.py``'s check, is
+``test_rehearse_nemotron3nano_aeasgd_w1.py``.)"""
 
 from __future__ import annotations
 

@@ -5,8 +5,8 @@ values; the reader's reduction on a hand-made program and trace; the flash
 kernels' tiling pinned at every shape the cells before this one run. All on
 the CPU; nothing here is a measurement. (The rehearsal of
 ``qwen3next_aeasgd_w1``, traced and untraced, through ``run.py --rehearse``
-and ``harness/result_line.py``'s check, is one of ``test_benchmark.py``'s
-cases: it rehearses every cell of the manifest.)"""
+and ``harness/result_line.py``'s check, is
+``test_rehearse_qwen3next_aeasgd_w1.py``.)"""
 
 from __future__ import annotations
 

@@ -262,3 +262,75 @@ def test_reference_limits_at_the_tiny_preset():
         program.route_top_k = route_top_k
     assert not other["ok"] and other["rel_l2"] <= other["tolerance"], other
     assert other["routing_agreement"] < 0.1, other
+
+
+def test_the_cell_draws_its_ids_evenly_and_the_feed_passes_the_law_on():
+    """The cell's workload names the even law; ``make_dataframe`` takes it
+    from the feed; the stream stays in the slice and learnable (three
+    quarters of the transitions follow the successor map); an unknown law
+    is refused."""
+    import numpy as np
+
+    from benchmarks.families.transformer_lm import learnable_tokens
+
+    with open(os.path.join(ROOT, "benchmarks", "workloads",
+                           "smallthinker_aeasgd_w1.json"),
+              encoding="utf-8") as f:
+        feed = json.load(f)["feed"]
+    assert feed["ids"] == "uniform"
+    config = {"seq_len": 256, "module": {"vocab_size": 4096}}
+    extra = {k: v for k, v in feed.items() if k not in ("rows", "num_epoch")}
+    df = family.make_dataframe(config, 16, 7, **extra)
+    x, y = np.asarray(df["features"]), np.asarray(df["label"])
+    assert x.shape == y.shape == (16, 256)
+    assert 0 <= x.min() and x.max() < 4096
+    assert np.array_equal(x[:, 1:], y[:, :-1])
+    followed = np.mean(y == (x * 31 + 7) % 4096)
+    assert 0.7 < followed < 0.8
+    zipf, _ = learnable_tokens(16, 256, 4096, 7)
+    even, _ = learnable_tokens(16, 256, 4096, 7, ids="uniform")
+    assert np.array_equal(even, x)
+    # Zipf puts a tenth of the fresh draws on id 0; the even law 1/4096
+    assert np.mean(zipf == 0) > 20 * np.mean(even == 0)
+    with pytest.raises(ValueError, match="ids"):
+        learnable_tokens(1, 8, 16, 0, ids="zipfian")
+
+
+@pytest.mark.parametrize("ids, widest", [("zipf", None), ("uniform", 0.03)])
+def test_an_untrained_router_gives_every_seed_one_share_under_even_ids(
+        ids, widest):
+    """Why the cell draws its ids evenly: a router at initialisation sends an
+    id to the same experts every time, so the held experts' share of the
+    assignments is a sum over the stream's ids. Under Zipf's law a few
+    hundred ids carry half of it and the share moves with the seed; under
+    the even law it holds. A first layer's routing (embeddings N(0, 1), a
+    lecun-normal router, top 6 of 64, experts 0-7 held) over eight seeds, at
+    a vocabulary and width a test can hold: the even law's relative spread
+    stays under ``widest``, Zipf's is over twice the even law's. (At the
+    cell's 18,992 ids and width 2,560, over its 256 rows: 3.7 % and 0.87 %,
+    PERF.md §6.)"""
+    import numpy as np
+
+    from benchmarks.families.transformer_lm import learnable_tokens
+
+    V, D, E, K, HELD = 4096, 256, 64, 6, 8
+
+    def shares(law):
+        out = []
+        for seed in range(8):
+            rng = np.random.default_rng(100 + seed)
+            emb = rng.standard_normal((V, D), dtype=np.float32)
+            router = rng.standard_normal((D, E), dtype=np.float32) / D ** 0.5
+            top = np.argpartition(-(emb @ router), K, axis=1)[:, :K]
+            x, _ = learnable_tokens(32, 2048, V, seed, ids=law)
+            count = np.bincount(x.ravel(), minlength=V)
+            out.append((count * (top < HELD).sum(1)).sum()
+                       / (count.sum() * K))
+        out = np.asarray(out)
+        return out.std() / out.mean()
+
+    spread = shares(ids)
+    if widest is not None:
+        assert spread < widest
+    else:
+        assert spread > 2 * shares("uniform")

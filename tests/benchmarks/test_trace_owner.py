@@ -337,8 +337,9 @@ def test_the_metric_is_in_the_manifest_with_its_file(name):
 
 @pytest.mark.parametrize("cell", LM + IMG)
 def test_a_traced_run_of_the_cell_declares_its_owner_metrics(cell):
-    """``test_benchmark.py`` rehearses every cell with ``--trace 1`` and holds
-    the line to what the cell declares: these names are among that."""
+    """Each cell's ``test_rehearse_<cell>.py`` rehearses it with ``--trace 1``
+    and holds the line to what the cell declares: these names are among
+    that."""
     declared = result_line.declared_metrics(_json("BENCHMARK.json"), cell,
                                             traced=True)
     assert {n for n in declared if n.startswith("owner.")} \
